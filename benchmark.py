@@ -117,7 +117,7 @@ def benchmark_model(model_name: str, args) -> OrderedDict:
                 params, opt_state = carry
 
                 def loss_fn(p):
-                    return cross_entropy(nnx.merge(graphdef_t, p, rest)(x), t)
+                    return cross_entropy(nnx.merge(graphdef_t, p, rest, copy=True)(x), t)
                 loss, grads = jax.value_and_grad(loss_fn)(params)
                 updates, opt_state = opt.update(grads, opt_state, params, lr=1e-4)
                 return (optax.apply_updates(params, updates), opt_state), loss

@@ -1,6 +1,9 @@
 #!/usr/bin/env python3
 """Run validate.py / benchmark.py over model lists as subprocesses
 (reference: bulk_runner.py:1-244 — used to produce results/*.csv).
+
+One process per chip: this parent lists model names and never makes a JAX
+device call, so each child, run one at a time, has the device to itself.
 """
 from __future__ import annotations
 

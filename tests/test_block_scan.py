@@ -259,35 +259,33 @@ def test_trace_cost_o1_in_depth():
 # ---- 3. persistent compile cache ---------------------------------------------
 
 _CACHE_PROBE = r'''
-import importlib.util, sys
+import importlib.util, os, sys
 import jax, jax.numpy as jnp
 spec = importlib.util.spec_from_file_location('cc_mod', sys.argv[1])
 mod = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(mod)
-assert mod.configure_compile_cache(sys.argv[2], min_entry_size_bytes=0,
-                                   min_compile_time_secs=0.0) == sys.argv[2]
-events = []
-from jax._src import monitoring
-monitoring.register_event_listener(lambda e, **kw: events.append(e))
+cache_dir = mod.configure_compile_cache(min_compile_time_secs=0.0)
+assert cache_dir == os.environ['JAX_COMPILATION_CACHE_DIR']
 f = jax.jit(lambda a: ((a @ a) @ a).sum())
-f(jnp.ones((128, 128), jnp.float32)).block_until_ready()
-print('CACHE_HITS', sum('/compilation_cache/cache_hits' in e for e in events))
+with mod.collect_cache_events() as events:
+    f(jnp.ones((128, 128), jnp.float32)).block_until_ready()
+print('CACHE_HITS', mod.cache_event_total(events, 'cache_hits'))
 '''
 
 
 @pytest.mark.compilecache
 def test_compile_cache_survives_processes(tmp_path):
-    """Acceptance: a second cold process with TIMM_TPU_COMPILE_CACHE set
+    """Acceptance: a second cold process with JAX_COMPILATION_CACHE_DIR set
     reuses the first process's on-disk executable (observed via JAX's
     cache-hit event), instead of recompiling."""
     cache_dir = str(tmp_path / 'xla_cache')
     mod_path = os.path.join(os.path.dirname(__file__), '..',
                             'timm_tpu', 'utils', 'compile_cache.py')
-    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    env = dict(os.environ, JAX_PLATFORMS='cpu', JAX_COMPILATION_CACHE_DIR=cache_dir)
     env.pop('XLA_FLAGS', None)  # keep the probe processes single-device/cheap
 
     def run():
-        r = subprocess.run([sys.executable, '-c', _CACHE_PROBE, mod_path, cache_dir],
+        r = subprocess.run([sys.executable, '-c', _CACHE_PROBE, mod_path],
                            capture_output=True, text=True, timeout=240, env=env)
         assert r.returncode == 0, r.stderr[-2000:]
         return int(r.stdout.strip().splitlines()[-1].split()[-1])
@@ -300,28 +298,35 @@ def test_compile_cache_survives_processes(tmp_path):
 
 
 @pytest.mark.compilecache
-def test_compile_cache_env_resolution(monkeypatch):
+def test_compile_cache_thresholds_and_nested_collectors():
+    """configure_compile_cache sets the persistence thresholds it is given,
+    and nested collectors each count the compiles inside their own block."""
     from timm_tpu.utils import compile_cache as cc
-    monkeypatch.setenv('TIMM_TPU_COMPILE_CACHE', '/tmp/somewhere')
-    assert cc.resolve_cache_dir() == '/tmp/somewhere'
-    monkeypatch.setenv('TIMM_TPU_COMPILE_CACHE', 'off')
-    assert cc.resolve_cache_dir() is None
-    assert cc.configure_compile_cache() is None  # disabled == no-op
-    monkeypatch.delenv('TIMM_TPU_COMPILE_CACHE')
-    monkeypatch.setenv('TIMM_TPU_XLA_CACHE', '/tmp/legacy')  # legacy spelling
-    assert cc.resolve_cache_dir() == '/tmp/legacy'
-    monkeypatch.delenv('TIMM_TPU_XLA_CACHE')
-    assert cc.resolve_cache_dir() == cc.DEFAULT_CACHE_DIR
-    assert cc.resolve_cache_dir('') is None
+    before = (jax.config.jax_persistent_cache_min_entry_size_bytes,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        cc.configure_compile_cache(min_entry_size_bytes=7, min_compile_time_secs=0.25)
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == 7
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.25
+        with cc.collect_cache_events() as outer:
+            with cc.collect_cache_events() as inner:
+                jax.jit(lambda a: a * 3 + 1)(jnp.ones((3, 5))).block_until_ready()
+            jax.jit(lambda a: a * 5 + 2)(jnp.ones((3, 5))).block_until_ready()
+        requests = 'backend_compile_duration'
+        assert cc.cache_event_total(inner, requests) >= 1
+        assert cc.cache_event_total(outer, requests) > cc.cache_event_total(inner, requests)
+    finally:
+        cc.configure_compile_cache(*before)
 
 
 @pytest.mark.compilecache
-def test_tier1_pins_compile_cache_env():
-    """The conftest pins TIMM_TPU_COMPILE_CACHE so subprocess tests and
-    re-runs hit one deterministic warm dir (no ambient-warmth dependence)."""
-    assert os.environ.get('TIMM_TPU_COMPILE_CACHE'), \
-        'tests/conftest.py must pin TIMM_TPU_COMPILE_CACHE for tier-1'
-    assert jax.config.jax_compilation_cache_dir == os.environ['TIMM_TPU_COMPILE_CACHE']
+def test_tier1_uses_the_configured_compile_cache():
+    """The conftest goes through configure_compile_cache, so tier-1 and the
+    subprocess tests that call it hit one fixed directory: JAX's own variable
+    where it is set, the in-checkout directory otherwise."""
+    from timm_tpu.utils.compile_cache import CHECKOUT_CACHE_DIR
+    expected = os.environ.get('JAX_COMPILATION_CACHE_DIR') or CHECKOUT_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == expected
 
 
 # ---- 4. device prefetch ------------------------------------------------------
@@ -400,16 +405,23 @@ def test_shard_batch_scalar_and_nonarray_leaves():
     assert int(out['step']) == 7            # 0-d array replicated, not sharded
 
 
-# ---- 5. bench fast-fail ------------------------------------------------------
+# ---- 5. bench: one process, no fallback ---------------------------------------
 
 @pytest.mark.compilecache
-def test_bench_probe_fastfail_policy():
+def test_bench_has_no_probe_child_and_unknown_chip_is_an_error():
+    """bench.py measures in the process it starts in: the probe child, the
+    watchdog and the stale-result replay are gone, and a device kind with no
+    entry in the peak table raises instead of defaulting."""
     import importlib.util
     bench_path = os.path.join(os.path.dirname(__file__), '..', 'bench.py')
     spec = importlib.util.spec_from_file_location('bench_ff', bench_path)
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
-    assert bench._max_attempts(True) == 3
-    assert bench._max_attempts(False) == 1, \
-        'a failed probe must abort after one fresh-process retry'
-    assert bench.PROBE_TIMEOUT == int(os.environ.get('TIMM_TPU_BENCH_PROBE_TIMEOUT', '60'))
+    for gone in ('_probe_device', '_replay_self_result', '_run_child', '_arm_watchdog',
+                 '_record_abort', 'PROBE_TIMEOUT', 'TOTAL_BUDGET'):
+        assert not hasattr(bench, gone), gone
+    assert bench._chip_peak('TPU v5 lite') == bench.CHIP_PEAK['v5litepod'] == 197e12
+    with pytest.raises(KeyError, match='no peak'):
+        bench._chip_peak('TPU v99')
+    with pytest.raises(KeyError, match='no peak'):
+        bench._chip_peak('cpu')

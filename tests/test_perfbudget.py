@@ -6,12 +6,11 @@
 2. Seed budgets: probing the live code against tests/fixtures/
    perf_budgets.json stays clean; an injected block_scan=False regression
    trips the jaxpr-eqn AND trace-time budgets for the scanned config.
-3. BENCH_SELF.json v2 document: result/abort/replay round-trips, v1 upgrade,
-   bounded abort history, schema validation.
+3. BENCH_SELF.json v2 document: result/replay round-trips, v1 upgrade,
+   schema validation.
 4. `bench.py --replay --dry-run` (subprocess): the ENTIRE queued PERF.md
-   checklist completes unattended with a schema-valid BENCH_SELF.json; an
-   aborted bench round appends a structured abort record while preserving
-   the prior result.
+   checklist completes unattended with a schema-valid BENCH_SELF.json;
+   `bench.py` with no TPU exits non-zero and prints no number.
 5. Profiler: perfetto parsing + MXU vs non-MXU classification on a
    synthetic trace (deterministic; the real-trace path is exercised by the
    replay's `profile` step).
@@ -28,10 +27,10 @@ from timm_tpu.perfbudget import (
     DEFAULT_MATRIX, ProbeConfig, check_counter, check_counter_min, check_ratio_max,
     check_ratio_min, check_upper, compare_budgets, compare_config, format_violations,
     latest_trace_file, load_budgets, load_self_doc, parse_trace, probe_config,
-    record_abort, record_result, run_matrix, summarize_events, tolerance_for,
+    record_result, run_matrix, summarize_events, tolerance_for,
     update_budgets, validate_self_result,
 )
-from timm_tpu.perfbudget.replay import REPLAY_STEPS, SELF_SCHEMA, _MAX_ABORTS
+from timm_tpu.perfbudget.replay import REPLAY_STEPS, SELF_SCHEMA
 
 pytestmark = pytest.mark.perfbudget
 
@@ -214,7 +213,7 @@ def test_run_matrix_rejects_unknown_config():
 
 # ---- 3. BENCH_SELF.json v2 document -----------------------------------------
 
-def test_self_doc_roundtrip_abort_history_and_v1_upgrade(tmp_path):
+def test_self_doc_roundtrip_and_v1_upgrade(tmp_path):
     path = str(tmp_path / 'BENCH_SELF.json')
 
     # missing and corrupt files both yield a writable fresh document
@@ -229,28 +228,18 @@ def test_self_doc_roundtrip_abort_history_and_v1_upgrade(tmp_path):
     assert doc['result'] == result and doc['measured_at']
     assert validate_self_result(doc) == []
 
-    # aborts append without clobbering the result, capped at _MAX_ABORTS
-    for i in range(_MAX_ABORTS + 5):
-        record_abort(path, f'reason {i}', {'model': 'x'})
-    doc = load_self_doc(path)
-    assert doc['result'] == result
-    assert len(doc['aborts']) == _MAX_ABORTS
-    assert doc['aborts'][-1]['reason'] == f'reason {_MAX_ABORTS + 4}'
-    assert all(a['at'] and a['reason'] for a in doc['aborts'])
-    assert validate_self_result(doc) == []
-
     # pre-v2 files (bare {'measured_at', 'result'}) upgrade losslessly
     v1 = str(tmp_path / 'v1.json')
     with open(v1, 'w') as f:
         json.dump({'measured_at': '2026-01-01T00:00:00Z', 'result': result}, f)
     doc = load_self_doc(v1)
     assert doc['schema'] == SELF_SCHEMA and doc['result'] == result
-    assert doc['measured_at'] == '2026-01-01T00:00:00Z' and doc['aborts'] == []
+    assert doc['measured_at'] == '2026-01-01T00:00:00Z'
 
     # validator actually rejects malformed documents
     assert validate_self_result({'schema': 'bogus'})
     bad = load_self_doc(path)
-    bad['aborts'] = [{'reason': 'no timestamp'}]
+    bad['result'] = {'metric': 'no value'}
     assert validate_self_result(bad)
 
 
@@ -310,48 +299,21 @@ def test_replay_steps_subset_and_unknown_id(tmp_path):
     assert r.returncode != 0
 
 
-def test_aborted_round_leaves_structured_record(tmp_path):
-    """Satellite fix: a round whose probe fails no longer leaves an empty
-    file — it appends an abort record, PRESERVES the prior self-measured
-    result, and replays it clearly labelled with exit code 3."""
+def test_bench_without_a_tpu_exits_nonzero_with_no_number(tmp_path):
+    """`python bench.py` where JAX finds no TPU: non-zero exit, nothing on
+    stdout — in particular no result recorded by an earlier run."""
     self_path = str(tmp_path / 'BENCH_SELF.json')
     prior = {'metric': 'vit_tiny_patch16_224 train img/s/chip', 'value': 321.0,
              'unit': 'img/s/chip', 'vs_baseline': None}
     record_result(self_path, prior)
 
-    env = _bench_env(tmp_path, TIMM_TPU_BENCH_FORCE_PROBE_FAIL='1',
-                     BENCH_TOTAL_BUDGET='40', TIMM_TPU_BENCH_PROBE_TIMEOUT='5')
     r = subprocess.run([sys.executable, BENCH, '--fast', '--save-self'],
-                       env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode == 3, (r.returncode, r.stdout[-2000:], r.stderr[-1000:])
-    out = _last_json(r.stdout)
-    assert out['replay'] is True and out['value'] == 321.0
-    assert 'REPLAY' in out['metric']
-
-    doc = load_self_doc(self_path)
-    assert doc['result'] == prior, 'abort clobbered the prior result'
-    assert len(doc['aborts']) == 1
-    abort = doc['aborts'][0]
-    assert 'probe failed' in abort['reason'] and abort['at']
-    assert abort['model'] == 'vit_tiny_patch16_224'
-    assert validate_self_result(doc) == []
-
-
-def test_abort_only_self_file_refuses_replay(tmp_path):
-    """A v2 file holding only abort records has nothing honest to replay:
-    the fallback must exit 2 with the 'no BENCH_SELF to replay' line, not
-    fabricate a result."""
-    self_path = str(tmp_path / 'BENCH_SELF.json')
-    record_abort(self_path, 'earlier abort', {})
-
-    env = _bench_env(tmp_path, TIMM_TPU_BENCH_FORCE_PROBE_FAIL='1',
-                     BENCH_TOTAL_BUDGET='40', TIMM_TPU_BENCH_PROBE_TIMEOUT='5')
-    r = subprocess.run([sys.executable, BENCH, '--fast'],
-                       env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode == 2
-    assert 'no BENCH_SELF.json to replay' in _last_json(r.stdout)['metric']
+                       env=_bench_env(tmp_path), cwd=REPO_ROOT, capture_output=True,
+                       text=True, timeout=300)
+    assert r.returncode == 2, (r.returncode, r.stdout[-2000:], r.stderr[-1000:])
+    assert r.stdout.strip() == '', r.stdout[-2000:]
+    assert 'no accelerator' in r.stderr
+    assert load_self_doc(self_path)['result'] == prior, 'a failed run touched the record'
 
 
 # ---- 5. profiler parsing (synthetic trace, deterministic) -------------------

@@ -269,7 +269,7 @@ def test_aot_warmup_hits_compile_cache_on_second_startup(tmp_path):
     for pre-declared buckets — every bucket program comes back from the
     persistent compile cache (observed via JAX's cache-hit events)."""
     cache_dir = str(tmp_path / 'serve_xla_cache')
-    env = dict(os.environ, JAX_PLATFORMS='cpu', TIMM_TPU_COMPILE_CACHE=cache_dir)
+    env = dict(os.environ, JAX_PLATFORMS='cpu', JAX_COMPILATION_CACHE_DIR=cache_dir)
     env.pop('XLA_FLAGS', None)  # single-device probe processes, cheap compiles
 
     def startup():

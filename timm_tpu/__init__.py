@@ -6,7 +6,6 @@ for TPU hardware: NHWC layouts, bf16 compute, one jitted train step over a
 """
 __version__ = '0.1.0'
 
-from . import _compat  # noqa: F401  (must precede everything: flax shims)
 from .layers import *  # noqa: F401,F403
 from .models import (  # noqa: F401
     create_model, is_model, list_models, list_modules, list_pretrained,

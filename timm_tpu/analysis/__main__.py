@@ -9,38 +9,17 @@
 Exit codes: 0 clean / 2 violations / 3 internal error (a crashed rule is
 never evidence of a clean repo).
 
-Tier B/C rules consume programs the perfbudget probes lower, which needs
-the forced 8-virtual-CPU-device topology — set before jax is imported.
-Like perfbudget's CLI, this module re-execs itself once with the XLA flag
-exported when the device count is short (guarded so a topology that still
-comes up short fails loudly instead of looping).
+Tier B/C rules consume programs the perfbudget probes lower on 8 virtual CPU
+devices. Every verdict is CPU-provable, so this tool pins itself to that
+platform before its first JAX device call (`use_virtual_cpu_devices`): it
+takes no chip and starts no child.
 """
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
 import sys
 
 _REQUIRED_DEVICES = 8
-_REEXEC_GUARD = 'TIMM_TPU_ANALYSIS_REEXEC'
-
-
-def _maybe_reexec(argv, needed: bool) -> None:
-    import jax
-    if (not needed or jax.device_count() >= _REQUIRED_DEVICES
-            or os.environ.get(_REEXEC_GUARD)):
-        return
-    env = dict(os.environ)
-    flags = env.get('XLA_FLAGS', '')
-    if '--xla_force_host_platform_device_count' not in flags:
-        env['XLA_FLAGS'] = (
-            flags + f' --xla_force_host_platform_device_count={_REQUIRED_DEVICES}').strip()
-    env.setdefault('JAX_PLATFORMS', 'cpu')  # every verdict is CPU-provable
-    env[_REEXEC_GUARD] = '1'
-    raise SystemExit(subprocess.call(
-        [sys.executable, '-m', 'timm_tpu.analysis'] + list(argv), env=env))
 
 
 def main(argv=None) -> int:
@@ -84,8 +63,9 @@ def main(argv=None) -> int:
         print(f'analysis: {e}', file=sys.stderr)
         return EXIT_ERROR
 
-    _maybe_reexec(argv, needed=any(r.needs_programs or r.needs_devices > 1
-                                   for r in rules))
+    if any(r.needs_programs or r.needs_devices > 1 for r in rules):
+        from ..parallel import use_virtual_cpu_devices
+        use_virtual_cpu_devices(_REQUIRED_DEVICES)
 
     log = (lambda m: None) if args.quiet else (
         lambda m: print(m, file=sys.stderr, flush=True))

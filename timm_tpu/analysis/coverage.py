@@ -308,7 +308,6 @@ def diff_matrix(fixture_rows: Dict[str, Dict], live_rows: Dict[str, Dict],
 
 def main(argv=None) -> int:
     import argparse
-    import subprocess
     import sys
 
     parser = argparse.ArgumentParser(prog='python -m timm_tpu.analysis.coverage')
@@ -323,18 +322,9 @@ def main(argv=None) -> int:
                              'instead of writing; exit 2 on mismatch')
     args = parser.parse_args(argv)
 
-    import jax
-    if jax.device_count() < 8 and not os.environ.get('TIMM_TPU_COVERAGE_REEXEC'):
-        env = dict(os.environ)
-        flags = env.get('XLA_FLAGS', '')
-        if '--xla_force_host_platform_device_count' not in flags:
-            env['XLA_FLAGS'] = (
-                flags + ' --xla_force_host_platform_device_count=8').strip()
-        env.setdefault('JAX_PLATFORMS', 'cpu')
-        env['TIMM_TPU_COVERAGE_REEXEC'] = '1'
-        return subprocess.call(
-            [sys.executable, '-m', 'timm_tpu.analysis.coverage']
-            + list(sys.argv[1:] if argv is None else argv), env=env)
+    # a CPU analysis on 8 virtual devices: pinned before the first JAX call
+    from ..parallel import use_virtual_cpu_devices
+    use_virtual_cpu_devices(8)
 
     families = [f.strip() for f in args.families.split(',') if f.strip()] or None
     rows = family_coverage(families=families,

@@ -5,37 +5,17 @@
         --model-kwargs '{"num_classes": 10, "img_size": 32}' --probe-top-k 3
     python -m timm_tpu.autotune ... --table        # human table on stderr too
 
-The probe-backed tiers need the forced 8-virtual-CPU-device topology when no
-accelerator is attached (same constraint as perfbudget): re-exec once with
-XLA_FLAGS set, guarded so a topology that still comes up short fails loudly
-instead of looping. `--devices N` skips the re-exec and enumerates for a
-hypothetical topology (analytic tier only — no probing a mesh we don't have).
+The search is over the devices JAX reports in this process — the attached
+chips, or whatever `XLA_FLAGS=--xla_force_host_platform_device_count=N` with
+`JAX_PLATFORMS=cpu` gives a CPU rehearsal. `--devices N` enumerates for a
+hypothetical topology instead (analytic tier only — no probing a mesh we
+don't have).
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
-
-_REQUIRED_DEVICES = 8
-_REEXEC_GUARD = 'TIMM_TPU_AUTOTUNE_REEXEC'
-
-
-def _maybe_reexec(argv) -> None:
-    import jax
-    if jax.device_count() >= _REQUIRED_DEVICES or os.environ.get(_REEXEC_GUARD):
-        return
-    env = dict(os.environ)
-    flags = env.get('XLA_FLAGS', '')
-    if '--xla_force_host_platform_device_count' not in flags:
-        env['XLA_FLAGS'] = (
-            flags + f' --xla_force_host_platform_device_count={_REQUIRED_DEVICES}').strip()
-    env.setdefault('JAX_PLATFORMS', 'cpu')
-    env[_REEXEC_GUARD] = '1'
-    raise SystemExit(subprocess.call(
-        [sys.executable, '-m', 'timm_tpu.autotune'] + list(argv), env=env))
 
 
 def main(argv=None) -> int:
@@ -69,8 +49,6 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
 
     hypothetical = bool(args.devices)
-    if not hypothetical:
-        _maybe_reexec(argv)
 
     from .solver import AutotuneError, autotune, format_table, to_json
 

@@ -204,8 +204,8 @@ def probed_cost(metrics: Dict, point: LegalPoint, dc: DeviceClass, *,
 
 def load_correction(path: str = 'BENCH_SELF.json') -> float:
     """The fitted live-hardware correction factor the replay `autotune` step
-    persisted (predicted->measured geomean ratio); 1.0 until a healthy relay
-    window has verified the top-K."""
+    persisted (predicted->measured geomean ratio); 1.0 until a live run on
+    the chip has verified the top-K."""
     try:
         with open(path, encoding='utf-8') as f:
             doc = json.load(f)

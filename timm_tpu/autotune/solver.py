@@ -101,7 +101,7 @@ def abstract_model_info(model: str, model_kwargs: Optional[Dict] = None):
     mlp_ratio = 4.0
     blocks = getattr(abs_model, 'blocks', None)
     try:
-        fc1 = blocks[0].mlp.fc1.kernel.value.shape  # type: ignore[index]
+        fc1 = blocks[0].mlp.fc1.kernel.shape  # type: ignore[index]
         mlp_ratio = float(fc1[1]) / float(fc1[0])
     except (TypeError, AttributeError, IndexError, KeyError):
         pass

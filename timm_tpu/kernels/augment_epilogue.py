@@ -52,33 +52,39 @@ def augment_epilogue_supported(batch, re_mode: str = 'const') -> bool:
     return re_mode == 'const' and 'erase_fill' not in batch
 
 
-def _epilogue_kernel(lam_ref, cut_ref, bbox_ref, eb_ref, ebf_ref,
+def _epilogue_kernel(lam_ref, cut_ref, bbox_ref, eb_ref,
                      mean_ref, std_ref, fill_ref,
                      img_ref, flip_ref, o_ref, *,
                      channels: int, erase_k: int):
-    # blocks: img/flip/o (1, H, W*C); scalars per image in SMEM; mean/std/
-    # fill are W-tiled (1, W*C) rows shared by every grid step.
+    # blocks: img/flip/o (1, H, W*C); the per-image scalars are whole flat
+    # arrays in SMEM indexed by the grid position (the TPU lowering takes no
+    # (1, 1) SMEM blocks); mean/std/fill are W-tiled (1, W*C) rows shared by
+    # every grid step.
+    i = pl.program_id(0)
+    j = pl.num_programs(0) - 1 - i  # the mixup partner's row
     h, wc = o_ref.shape[1], o_ref.shape[2]
-    x = img_ref[0].astype(jnp.float32) / 255.0
-    xf = flip_ref[0].astype(jnp.float32) / 255.0
+    # via int32: the TPU lowering has no direct uint8 -> float32 cast
+    x = img_ref[0].astype(jnp.int32).astype(jnp.float32) / 255.0
+    xf = flip_ref[0].astype(jnp.int32).astype(jnp.float32) / 255.0
     row = jax.lax.broadcasted_iota(jnp.int32, (h, wc), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (h, wc), 1) // channels
+
+    def inside(ref, base):
+        top, left, eh, ew = (ref[base + n] for n in range(4))
+        return (row >= top) & (row < top + eh) & (col >= left) & (col < left + ew)
+
     if erase_k:
         fill = fill_ref[...]
         for k in range(erase_k):
-            top, left, eh, ew = (eb_ref[0, k, j] for j in range(4))
-            ins = (row >= top) & (row < top + eh) & (col >= left) & (col < left + ew)
-            x = jnp.where(ins, fill, x)
+            x = jnp.where(inside(eb_ref, (i * erase_k + k) * 4), fill, x)
             # the mixup partner is the ERASED flipped row -> its own boxes
-            top, left, eh, ew = (ebf_ref[0, k, j] for j in range(4))
-            ins = (row >= top) & (row < top + eh) & (col >= left) & (col < left + ew)
-            xf = jnp.where(ins, fill, xf)
-    lam = lam_ref[0, 0]
+            xf = jnp.where(inside(eb_ref, (j * erase_k + k) * 4), fill, xf)
+    lam = lam_ref[i]
     mixed = x * lam + xf * (1.0 - lam)
-    yl, yh, xl, xh = (bbox_ref[0, j] for j in range(4))
+    yl, yh, xl, xh = (bbox_ref[i * 4 + n] for n in range(4))
     ins = (row >= yl) & (row < yh) & (col >= xl) & (col < xh)
     cut = jnp.where(ins, xf, x)
-    x = jnp.where(cut_ref[0, 0] != 0, cut, mixed)
+    x = jnp.where(cut_ref[i] != 0, cut, mixed)
     x = (x - mean_ref[...]) / std_ref[...]
     o_ref[0] = x.astype(o_ref.dtype)
 
@@ -91,30 +97,29 @@ def augment_epilogue(image, lam, use_cutmix, bbox, erase_box, *,
     b, h, w, c = image.shape
     k = int(erase_box.shape[1]) if erase_box.size else 0
     img2 = image.reshape(b, h, w * c)
-    lam2 = jnp.asarray(lam, jnp.float32).reshape(b, 1)
-    cut2 = jnp.asarray(use_cutmix, jnp.int32).reshape(b, 1)
-    bbox2 = jnp.asarray(bbox, jnp.int32).reshape(b, 4)
+    lam1 = jnp.asarray(lam, jnp.float32).reshape(b)
+    cut1 = jnp.asarray(use_cutmix, jnp.int32).reshape(b)
+    bbox1 = jnp.asarray(bbox, jnp.int32).reshape(b * 4)
     if k:
-        eb2 = jnp.asarray(erase_box, jnp.int32).reshape(b, k, 4)
+        eb1 = jnp.asarray(erase_box, jnp.int32).reshape(b * k * 4)
     else:
-        eb2 = jnp.zeros((b, 1, 4), jnp.int32)
+        eb1 = jnp.zeros((b * 4,), jnp.int32)
 
     mean_row = jnp.asarray(np.tile(np.asarray(mean, np.float32), w))[None]
     std_row = jnp.asarray(np.tile(np.asarray(std, np.float32), w))[None]
     fill_row = jnp.asarray(np.tile(np.asarray(re_mean, np.float32), w))[None]
 
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     row_spec = pl.BlockSpec((1, w * c), lambda i: (0, 0))
     kern = functools.partial(_epilogue_kernel, channels=c, erase_k=k)
     out = pl.pallas_call(
         kern,
         grid=(b,),
         in_specs=[
-            smem((1, 1), lambda i: (i, 0)),                       # lam
-            smem((1, 1), lambda i: (i, 0)),                       # use_cutmix
-            smem((1, 4), lambda i: (i, 0)),                       # cutmix bbox
-            smem((1, max(k, 1), 4), lambda i: (i, 0, 0)),         # erase boxes
-            smem((1, max(k, 1), 4), lambda i: (b - 1 - i, 0, 0)),  # flipped row's
+            smem,                                                 # lam
+            smem,                                                 # use_cutmix
+            smem,                                                 # cutmix bbox
+            smem,                                                 # erase boxes
             row_spec,                                             # mean (W-tiled)
             row_spec,                                             # std
             row_spec,                                             # erase fill
@@ -124,7 +129,7 @@ def augment_epilogue(image, lam, use_cutmix, bbox, erase_box, *,
         out_specs=pl.BlockSpec((1, h, w * c), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, w * c), out_dtype),
         interpret=_interpret(),
-    )(lam2, cut2, bbox2, eb2, eb2, mean_row, std_row, fill_row, img2, img2)
+    )(lam1, cut1, bbox1, eb1, mean_row, std_row, fill_row, img2, img2)
     return out.reshape(b, h, w, c)
 
 
@@ -209,7 +214,11 @@ register(KernelSpec(
     gate='win wall-clock vs the jitted XLA augment program at the live '
          'loader shape on TPU — or delete (the XLA program stays for '
          "'pixel'/'rand' modes either way)",
-    parity_tol=1e-6,
+    # float32 outputs reach |2.7| (ulp 2.4e-7). Interpreted on the CPU the two
+    # arms agree exactly; compiled for the TPU, whose f32 divide is not
+    # correctly rounded, Mosaic and XLA differ by a few ulp through /255 and
+    # /std: 1.2e-6 measured on a v5e (chip_smoke.py, PR 21)
+    parity_tol=5e-6,
     kernel_fn=augment_image_batch_fused,
     reference_fn=_reference,
     make_inputs=_make_inputs,

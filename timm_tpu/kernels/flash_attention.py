@@ -37,10 +37,7 @@ from jax.experimental import pallas as pl
 
 
 def _supported_backend() -> bool:
-    try:
-        return jax.default_backend() == 'tpu'
-    except Exception:
-        return False
+    return jax.default_backend() == 'tpu'
 
 
 def flash_attention_supported(q, k, v, mask=None) -> bool:

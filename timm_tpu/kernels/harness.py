@@ -22,7 +22,7 @@ Three consumers, one spec table (registry.py):
    `bench.py --kernels` and the replay `kernels` step: parity failure is an
    immediate `delete` (a wrong kernel loses regardless of speed); on a
    backend outside the spec's declared `backends` the verdict is `pending`
-   (the first healthy relay window on real hardware settles it); otherwise
+   (a timed run on the claimed hardware settles it); otherwise
    the kernel must win wall-clock at EVERY declared regime case or it is
    `delete`.
 """
@@ -53,14 +53,18 @@ def parity_cases() -> List[Tuple[KernelSpec, KernelCase]]:
     return [(spec, case) for spec in registry.all_specs() for case in spec.cases]
 
 
-def parity_check(spec: KernelSpec, case: KernelCase, seed: int = 0) -> Dict:
+def parity_check(spec: KernelSpec, case: KernelCase, seed: int = 0,
+                 live: bool = False) -> Dict:
     """Max abs error between jitted kernel and jitted reference at the
-    case's dry shapes, leaf-for-leaf over the output pytree."""
+    case's dry (or, with `live`, its claimed) shapes, leaf-for-leaf over the
+    output pytree. `tpu_custom_call` says whether the compiled kernel arm
+    holds a Mosaic kernel (True on TPU) or ran interpreted (False on CPU)."""
     import jax
     import jax.numpy as jnp
 
-    inputs = spec.make_inputs(seed=seed, **case.dry)
-    out_k = _jit_arm(spec.kernel_fn, case.statics)(inputs)
+    inputs = spec.make_inputs(seed=seed, **(case.live if live else case.dry))
+    compiled_k = _jit_arm(spec.kernel_fn, case.statics).lower(inputs).compile()
+    out_k = compiled_k(inputs)
     out_r = _jit_arm(spec.reference_fn, case.statics)(inputs)
     leaves_k, leaves_r = jax.tree.leaves(out_k), jax.tree.leaves(out_r)
     assert len(leaves_k) == len(leaves_r), (
@@ -71,7 +75,8 @@ def parity_check(spec: KernelSpec, case: KernelCase, seed: int = 0) -> Dict:
         d = jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))
         err = max(err, float(d))
     return {'kernel': spec.name, 'case': case.name, 'max_abs_err': err,
-            'tol': spec.parity_tol, 'ok': err <= spec.parity_tol}
+            'tol': spec.parity_tol, 'ok': err <= spec.parity_tol,
+            'tpu_custom_call': 'tpu_custom_call' in compiled_k.as_text()}
 
 
 def lower_case(spec: KernelSpec, case: KernelCase, seed: int = 0) -> Dict:
@@ -165,8 +170,8 @@ def ab_verdict(spec: KernelSpec, *, live: bool = False, steps: int = 5,
     if backend not in spec.backends:
         rec['verdict'] = 'pending'
         rec['reason'] = (f'regime claims {"/".join(spec.backends)}; this run is '
-                         f'on {backend} (parity only) — first healthy relay '
-                         f'window on claimed hardware settles the gate')
+                         f'on {backend} (parity only) — a timed run on the '
+                         f'claimed hardware settles the gate')
         return rec
     rec['cases'] = [ab_case(spec, case, live=live, steps=steps, seed=seed)
                     for case in spec.cases]

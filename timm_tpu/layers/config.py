@@ -105,10 +105,7 @@ def use_fused_attn(experimental: bool = False) -> bool:
         return False
     # Default: fused on real TPU backends only; CPU tests use the XLA path.
     import jax
-    try:
-        return jax.default_backend() == 'tpu'
-    except Exception:
-        return False
+    return jax.default_backend() == 'tpu'
 
 
 def set_fused_attn(enable: bool = True, experimental: bool = False):

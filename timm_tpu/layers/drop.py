@@ -53,7 +53,10 @@ class DropPath(nnx.Module):
         self.drop_prob = float(drop_prob)
         self.scale_by_keep = scale_by_keep
         self.deterministic = False
-        self.rngs = rngs.fork() if rngs is not None and self.drop_prob > 0.0 else None
+        # declared as data even when None: the first block of a linear ramp has
+        # rate 0 and no stream, and must still split to the same graphdef as
+        # its neighbours for block/stage scan
+        self.rngs = nnx.data(rngs.fork() if rngs is not None and self.drop_prob > 0.0 else None)
 
     def __call__(self, x):
         # scan mode (models/_manipulate.scan_stage_stack): the merged block's
@@ -177,7 +180,10 @@ class DropBlock2d(nnx.Module):
         self.couple_channels = couple_channels
         self.scale_by_keep = scale_by_keep
         self.deterministic = False
-        self.rngs = rngs.fork() if rngs is not None and self.drop_prob > 0.0 else None
+        # declared as data even when None: the first block of a linear ramp has
+        # rate 0 and no stream, and must still split to the same graphdef as
+        # its neighbours for block/stage scan
+        self.rngs = nnx.data(rngs.fork() if rngs is not None and self.drop_prob > 0.0 else None)
 
     def __call__(self, x):
         if self.deterministic or self.drop_prob == 0.0 or self.rngs is None:

@@ -30,10 +30,8 @@ __all__ = [
 
 
 def _param_value(p):
-    # affine=False is Param(None) on older flax, plain None on newer
-    if p is None or p.value is None:
-        return None
-    return p[...]
+    # affine=False leaves the attribute None
+    return None if p is None else p[...]
 
 
 def _resolve_internal(instance_dtype):

@@ -26,10 +26,8 @@ __all__ = ['StdConv2d', 'ScaledStdConv2d', 'ScaledStdConv2dSame']
 
 
 def _bias_value(bias):
-    # use_bias=False is Param(None) on older flax, plain None on newer
-    if bias is None or bias.value is None:
-        return None
-    return bias[...]
+    # use_bias=False leaves the attribute None
+    return None if bias is None else bias[...]
 
 
 def _conv_nhwc(x, kernel, bias, strides, padding, dilation, groups):

@@ -7,9 +7,14 @@ the reference uses, matched against those names.
 """
 from __future__ import annotations
 
+import collections.abc
+import functools
 import re
+import types
 from collections import defaultdict
 from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+
+import numpy as np
 
 MATCH_PREV_GROUP = (99999,)
 
@@ -176,27 +181,85 @@ def iter_submodules(module):
     yield from _walk(module)
 
 
-_MEM_ADDR_RE = re.compile(r'0x[0-9a-fA-F]+')
+def _static_equal(a, b) -> bool:
+    """Whether two static graphdef values describe the same computation.
+    Per-block init-fn closures (`trunc_normal_.<locals>.init`) and plain
+    config objects (`Pool2d`) are distinct objects in every block, so identity
+    `==` calls homogeneous blocks different; compare them by code + captured
+    values and by fields instead. A depth-indexed float or a different
+    submodule layout still compares unequal."""
+    if a is b:
+        return True
+    if type(a) is not type(b):
+        return False
+    if isinstance(a, types.FunctionType):
+        return (a.__code__ is b.__code__
+                and _static_equal(a.__defaults__, b.__defaults__)
+                and _static_equal(a.__kwdefaults__, b.__kwdefaults__)
+                and _static_equal([c.cell_contents for c in a.__closure__ or ()],
+                                  [c.cell_contents for c in b.__closure__ or ()]))
+    if isinstance(a, functools.partial):
+        return _static_equal((a.func, a.args, a.keywords), (b.func, b.args, b.keywords))
+    if isinstance(a, (tuple, list)):
+        return len(a) == len(b) and all(_static_equal(x, y) for x, y in zip(a, b))
+    if isinstance(a, collections.abc.Mapping):
+        return a.keys() == b.keys() and all(_static_equal(a[k], b[k]) for k in a)
+    if isinstance(a, np.ndarray):
+        return np.array_equal(a, b)
+    if type(a).__eq__ is object.__eq__ and hasattr(a, '__dict__'):
+        return _static_equal(vars(a), vars(b))
+    return bool(a == b)
 
 
-def _masked_graphdef_repr(graphdef) -> str:
-    """Graphdef repr with memory addresses masked: per-block init-fn closures
-    (`trunc_normal_.<locals>.init at 0x...`) are identity-distinct but
-    computation-irrelevant, while genuinely different statics (a depth-indexed
-    lambda_init float, a different submodule layout) stay visible."""
-    return _MEM_ADDR_RE.sub('0x', repr(graphdef))
+def graphdefs_equivalent(a, b) -> bool:
+    """Structural equality of two `nnx.GraphDef`s: same node layout, same
+    attribute names and kinds, static values equal under `_static_equal`."""
+    from flax.nnx.graph import Static
+    if a.nodes != b.nodes or len(a.attributes) != len(b.attributes):
+        return False
+    for (ka, va), (kb, vb) in zip(a.attributes, b.attributes):
+        if ka != kb or type(va) is not type(vb):
+            return False
+        if isinstance(va, Static):
+            if not _static_equal(va.value, vb.value):
+                return False
+        elif va != vb:
+            return False
+    return True
+
+
+def _split_blocks(blocks):
+    """``nnx.split(b, nnx.RngState, ...)`` for each block with its DropPath
+    statics (per-layer rate float + forked stream) neutralized, so a
+    linearly-ramped stochastic-depth schedule doesn't make the graphdefs
+    heterogeneous: in scan mode the per-layer rates ride a scanned rate vector
+    and the keys are drawn eagerly outside the scan (see
+    `drop_path_scan_inputs`), so the merged blocks' DropPath modules must be
+    structural no-ops."""
+    from flax import nnx
+
+    from ..layers.drop import DropPath
+
+    dp_saved = []
+    for b in blocks:
+        for sm in iter_submodules(b):
+            if isinstance(sm, DropPath):
+                dp_saved.append((sm, sm.drop_prob, sm.rngs))
+                sm.drop_prob = 0.0
+                sm.rngs = None
+    try:
+        return [nnx.split(b, nnx.RngState, ...) for b in blocks]
+    finally:
+        for sm, p, r in dp_saved:
+            sm.drop_prob = p
+            sm.rngs = r
 
 
 def build_block_stack(blocks, validate: bool = True):
     """Split a homogeneous block list into ``(graphdef, rng_state, stacked)``
     where ``stacked`` is the blocks' non-RNG state with a leading depth axis.
 
-    DropPath statics (per-layer rate float + forked stream) are neutralized
-    before splitting so a linearly-ramped stochastic-depth schedule doesn't
-    make the graphdefs heterogeneous: in scan mode the per-layer rates ride a
-    scanned rate vector and the keys are drawn eagerly outside the scan
-    (see `drop_path_scan_inputs`), so the merged blocks' DropPath modules must
-    be structural no-ops.
+    DropPath statics are neutralized before splitting (`_split_blocks`).
 
     Raises BlockStackError when stacking is impossible or would silently
     change semantics (different block types, depth-dependent statics, live
@@ -205,8 +268,6 @@ def build_block_stack(blocks, validate: bool = True):
     import jax
     import jax.numpy as jnp
     from flax import nnx
-
-    from ..layers.drop import DropPath
 
     blocks = list(blocks)
     if len(blocks) < 2:
@@ -227,25 +288,11 @@ def build_block_stack(blocks, validate: bool = True):
                     raise BlockStackError(
                         'active inner dropout (train mode, rate>0) cannot run under scan')
 
-    dp_saved = []
-    for b in blocks:
-        for sm in iter_submodules(b):
-            if isinstance(sm, DropPath):
-                dp_saved.append((sm, sm.drop_prob, sm.rngs))
-                sm.drop_prob = 0.0
-                sm.rngs = None
-    try:
-        splits = [nnx.split(b, nnx.RngState, ...) for b in blocks]
-    finally:
-        for sm, p, r in dp_saved:
-            sm.drop_prob = p
-            sm.rngs = r
-
+    splits = _split_blocks(blocks)
     graphdef, rng_state, _ = splits[0]
     if validate:
-        ref = _masked_graphdef_repr(graphdef)
         for i, (gd, _, _) in enumerate(splits[1:], start=1):
-            if _masked_graphdef_repr(gd) != ref:
+            if not graphdefs_equivalent(gd, graphdef):
                 raise BlockStackError(
                     f'block 0 and block {i} differ in static structure '
                     '(depth-dependent statics or layout)')
@@ -365,32 +412,6 @@ def resolve_stage_scan(flag) -> bool:
     return os.environ.get('TIMM_TPU_STAGE_SCAN', '').lower() in ('1', 'true', 'yes', 'on')
 
 
-def _stage_block_reprs(blocks):
-    """Masked graphdef repr per block, with DropPath statics neutralized the
-    same way `build_block_stack` does, so a ramped stochastic-depth schedule
-    doesn't read as heterogeneity during planning."""
-    from flax import nnx
-
-    from ..layers.drop import DropPath
-
-    reprs = []
-    for b in blocks:
-        dp_saved = []
-        for sm in iter_submodules(b):
-            if isinstance(sm, DropPath):
-                dp_saved.append((sm, sm.drop_prob, sm.rngs))
-                sm.drop_prob = 0.0
-                sm.rngs = None
-        try:
-            graphdef, _, _ = nnx.split(b, nnx.RngState, ...)
-            reprs.append(_masked_graphdef_repr(graphdef))
-        finally:
-            for sm, p, r in dp_saved:
-                sm.drop_prob = p
-                sm.rngs = r
-    return reprs
-
-
 def plan_stage_stack(blocks) -> Tuple[int, int]:
     """Find ``(eager_prefix, period)`` for a stage's block list: the first
     `eager_prefix` blocks run eagerly, the rest scan with period `period`
@@ -401,7 +422,7 @@ def plan_stage_stack(blocks) -> Tuple[int, int]:
     if len(blocks) < 2:
         raise BlockStackError('need at least 2 blocks to scan')
     types = [type(b) for b in blocks]
-    reprs = _stage_block_reprs(blocks)
+    graphdefs = [gd for gd, _, _ in _split_blocks(blocks)]
     for prefix in (0, 1):
         for period in (1, 2):
             rest = len(blocks) - prefix
@@ -409,7 +430,7 @@ def plan_stage_stack(blocks) -> Tuple[int, int]:
                 continue
             cols_ok = all(
                 all(types[prefix + j + i * period] is types[prefix + j]
-                    and reprs[prefix + j + i * period] == reprs[prefix + j]
+                    and graphdefs_equivalent(graphdefs[prefix + j + i * period], graphdefs[prefix + j])
                     for i in range(rest // period))
                 for j in range(period))
             if cols_ok:

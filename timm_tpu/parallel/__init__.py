@@ -2,6 +2,7 @@ from .mesh import (
     batch_axes, create_mesh, data_sharding, get_global_mesh, mesh_process_count,
     nonmodel_batch_axes, peek_global_mesh, place_global,
     replicate_sharding, resolve_elastic_axes, set_global_mesh, shard_batch,
+    use_virtual_cpu_devices,
 )
 from .distributed import (
     all_hosts_flag, barrier_timeout_s, coordination_client, init_distributed_device,

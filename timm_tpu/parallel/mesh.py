@@ -37,7 +37,7 @@ __all__ = [
     'create_mesh', 'data_sharding', 'replicate_sharding', 'shard_batch',
     'get_global_mesh', 'set_global_mesh', 'peek_global_mesh', 'batch_axes',
     'nonmodel_batch_axes', 'resolve_elastic_axes', 'place_global',
-    'mesh_process_count',
+    'mesh_process_count', 'use_virtual_cpu_devices',
 ]
 
 _GLOBAL_MESH: Optional[Mesh] = None
@@ -48,6 +48,17 @@ def _mesh_axes_str(axes) -> str:
     items = list(axes.items() if isinstance(axes, dict) else axes)
     total = int(np.prod([s for _, s in items])) if items else 1
     return ', '.join(f'{n}={s}' for n, s in items) + f' ({total} devices)'
+
+
+def use_virtual_cpu_devices(n: int) -> None:
+    """Hold this process to `n` virtual CPU devices — the declared platform of
+    the CPU analysis tools and rehearsals (``python -m timm_tpu.analysis`` /
+    ``.perfbudget`` / ``.analysis.coverage``, ``__graft_entry__``). An explicit
+    choice that must precede the process's first JAX device call: no chip is
+    touched and no child is started. JAX raises if a backend is already up
+    with another device count."""
+    jax.config.update('jax_platforms', 'cpu')
+    jax.config.update('jax_num_cpu_devices', n)
 
 
 def create_mesh(

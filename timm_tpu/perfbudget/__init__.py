@@ -23,7 +23,7 @@ from .budgets import (
 from .probe import DEFAULT_MATRIX, ProbeConfig, donation_evidence, probe_config, run_matrix
 from .profiler import latest_trace_file, parse_trace, profile_step, summarize_events
 from .replay import (
-    REPLAY_STEPS, SELF_SCHEMA, load_self_doc, record_abort, record_result,
+    REPLAY_STEPS, SELF_SCHEMA, load_self_doc, record_result,
     run_replay, save_self_doc, validate_self_result,
 )
 
@@ -34,6 +34,6 @@ __all__ = [
     'tolerance_for', 'update_budgets',
     'DEFAULT_MATRIX', 'ProbeConfig', 'donation_evidence', 'probe_config', 'run_matrix',
     'latest_trace_file', 'parse_trace', 'profile_step', 'summarize_events',
-    'REPLAY_STEPS', 'SELF_SCHEMA', 'load_self_doc', 'record_abort', 'record_result',
+    'REPLAY_STEPS', 'SELF_SCHEMA', 'load_self_doc', 'record_result',
     'run_replay', 'save_self_doc', 'validate_self_result',
 ]

@@ -5,39 +5,17 @@
                                                       # to accept an improvement)
     python -m timm_tpu.perfbudget --configs base,fsdp4 --json
 
-The probe matrix needs the forced 8-virtual-CPU-device topology
-(`XLA_FLAGS=--xla_force_host_platform_device_count=8`), which MUST be set
-before jax is imported — but `python -m timm_tpu.perfbudget` imports the
-timm_tpu package (and therefore jax) before this module runs. When the
-device count is short, this module re-execs itself once in a subprocess
-with the flag exported (guarded by TIMM_TPU_PERFBUDGET_REEXEC so a topology
-that still comes up short fails loudly instead of looping).
+The probe matrix runs on 8 virtual CPU devices and its metrics are
+CPU-provable, so this tool pins itself to that platform before its first JAX
+device call (`use_virtual_cpu_devices`): it takes no chip and starts no child.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
 
 _REQUIRED_DEVICES = 8
-_REEXEC_GUARD = 'TIMM_TPU_PERFBUDGET_REEXEC'
-
-
-def _maybe_reexec(argv) -> None:
-    import jax
-    if jax.device_count() >= _REQUIRED_DEVICES or os.environ.get(_REEXEC_GUARD):
-        return
-    env = dict(os.environ)
-    flags = env.get('XLA_FLAGS', '')
-    if '--xla_force_host_platform_device_count' not in flags:
-        env['XLA_FLAGS'] = (
-            flags + f' --xla_force_host_platform_device_count={_REQUIRED_DEVICES}').strip()
-    env.setdefault('JAX_PLATFORMS', 'cpu')  # the probe metrics are CPU-provable
-    env[_REEXEC_GUARD] = '1'
-    raise SystemExit(subprocess.call(
-        [sys.executable, '-m', 'timm_tpu.perfbudget'] + list(argv), env=env))
 
 
 def main(argv=None) -> int:
@@ -56,7 +34,8 @@ def main(argv=None) -> int:
     parser.add_argument('--note', default='', help='note recorded on --update-budgets')
     args = parser.parse_args(argv)
 
-    _maybe_reexec(argv)
+    from ..parallel import use_virtual_cpu_devices
+    use_virtual_cpu_devices(_REQUIRED_DEVICES)
 
     from . import budgets as B
     from .probe import run_matrix

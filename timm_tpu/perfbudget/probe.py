@@ -251,7 +251,7 @@ def _model_dims(model) -> Optional[Tuple[int, int, int]]:
     blocks = getattr(model, 'blocks', None)
     if pos is None or blocks is None:
         return None
-    shape = getattr(getattr(pos, 'value', pos), 'shape', None)
+    shape = getattr(pos, 'shape', None)
     if not shape or len(shape) != 3:
         return None
     try:

@@ -1,50 +1,43 @@
 """Unattended replay of the PERF.md "next-round on-device checklist".
 
-Five bench rounds in a row aborted with zero on-device numbers because the
-checklist needed a human to type seven command families in order during a
-relay window. This module turns the whole queue into ONE scripted sequence:
+The checklist needed a human to type seven command families in order. This
+module turns the whole queue into ONE scripted sequence:
 
     python bench.py --replay [--dry-run] [--save-self]
 
 Every step is a REPLAY_STEPS entry with a `dry` spec (tiny models, CPU,
 tier-1-smoked every run) and a `live` spec (the real on-device A/B). The two
 specs run the IDENTICAL code path — only model size, batch, and step count
-differ — so the first live relay window executes a sequence that tier-1 has
-already proven end to end. Results stream into BENCH_SELF.json (schema
-``bench_self/v2``) after EVERY step, so a relay that dies mid-checklist
-still leaves everything measured so far on disk.
+differ — so a live run executes a sequence that tier-1 has already proven end
+to end. Results stream into BENCH_SELF.json (schema ``bench_self/v2``) after
+EVERY step, so a run that dies mid-checklist still leaves everything measured
+so far on disk.
 
 This module also owns the BENCH_SELF.json v2 document helpers shared with
-bench.py: the v2 file keeps the last good `result` (what `--save-self`
-records and the replay fallback reads), a bounded `aborts` history (the
-satellite fix: an aborted TPU probe now leaves a structured record instead
-of an empty round file), and the latest `replay` run. Top-level imports are
-stdlib-only so bench.py's abort paths can use the writers without paying a
-jax import.
+bench.py: the v2 file keeps the last live `result` (what `--save-self`
+records) and the latest `replay` run. It is a record only: nothing reads a
+result back to print it in place of a measurement. Top-level imports are
+stdlib-only.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import os
-import tempfile
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 __all__ = ['REPLAY_STEPS', 'run_replay', 'load_self_doc', 'save_self_doc',
-           'record_result', 'record_abort', 'validate_self_result',
-           'SELF_SCHEMA']
+           'record_result', 'validate_self_result', 'SELF_SCHEMA']
 
 SELF_SCHEMA = 'bench_self/v2'
-_MAX_ABORTS = 20
 
 
 # ---- BENCH_SELF.json v2 document ------------------------------------------
 
 def load_self_doc(path: str) -> Dict:
     """Load (and, for pre-v2 files, upgrade) the BENCH_SELF document. A
-    missing/corrupt file yields a fresh empty document — the abort recorder
-    must never itself abort."""
+    missing/corrupt file yields a fresh empty document."""
     doc: Dict = {}
     try:
         with open(path) as f:
@@ -57,9 +50,7 @@ def load_self_doc(path: str) -> Dict:
         # v1 shape was {'measured_at', 'result'}; carry both forward
         doc = {'schema': SELF_SCHEMA,
                'measured_at': doc.get('measured_at'),
-               'result': doc.get('result'),
-               'aborts': []}
-    doc.setdefault('aborts', [])
+               'result': doc.get('result')}
     doc.setdefault('result', None)
     return doc
 
@@ -78,23 +69,10 @@ def _now() -> str:
 
 def record_result(path: str, result: Dict) -> Dict:
     """`--save-self` success path: record the live measurement, preserving
-    abort history and the last replay run."""
+    the last replay run."""
     doc = load_self_doc(path)
     doc['measured_at'] = _now()
     doc['result'] = result
-    save_self_doc(path, doc)
-    return doc
-
-
-def record_abort(path: str, reason: str, context: Optional[Dict] = None) -> Dict:
-    """Satellite fix: an aborted probe/bench appends a structured record
-    instead of leaving the round file empty; the last good `result` (if any)
-    survives for the replay fallback."""
-    doc = load_self_doc(path)
-    rec = {'at': _now(), 'reason': reason}
-    if context:
-        rec.update(context)
-    doc['aborts'] = (doc['aborts'] + [rec])[-_MAX_ABORTS:]
     save_self_doc(path, doc)
     return doc
 
@@ -108,12 +86,6 @@ def validate_self_result(doc: Dict) -> List[str]:
         return ['document is not a JSON object']
     if doc.get('schema') != SELF_SCHEMA:
         errs.append(f"schema != {SELF_SCHEMA!r}: {doc.get('schema')!r}")
-    if not isinstance(doc.get('aborts', []), list):
-        errs.append('aborts is not a list')
-    else:
-        for i, a in enumerate(doc.get('aborts', [])):
-            if not isinstance(a, dict) or 'at' not in a or 'reason' not in a:
-                errs.append(f'aborts[{i}] missing at/reason')
     result = doc.get('result')
     if result is not None and (not isinstance(result, dict) or 'value' not in result):
         errs.append('result present but not a bench result object')
@@ -322,7 +294,7 @@ def _build_tiny_step(spec: Dict):
 
     def train_step(p, o):
         def loss_fn(p):
-            m = nnx.merge(graphdef, p, rest)
+            m = nnx.merge(graphdef, p, rest, copy=True)
             return batch_loss(m)
         loss, grads = jax.value_and_grad(loss_fn)(p)
         updates, o = opt.update(grads, o, p, lr=1e-3)
@@ -433,7 +405,7 @@ def _run_profile(spec: Dict, trace_dir: Optional[str]) -> Dict:
     run_one_step, _n, meta = _build_tiny_step(spec)
     loss = run_one_step()  # compile outside the trace window
     jax.block_until_ready(loss)
-    trace_dir = trace_dir or tempfile.mkdtemp(prefix='timm_tpu_replay_trace_')
+    trace_dir = trace_dir or os.path.join('output', 'replay', 'trace')
     summary = profile_step(run_one_step, trace_dir,
                            steps=int(spec.get('steps', 2)),
                            label=f"train:{spec['model']}")
@@ -587,7 +559,7 @@ def _run_kernels(spec: Dict, live: bool) -> Dict:
     """Kernel-portfolio win-or-delete A/B over the registry
     (kernels/harness.py). Parity always runs; on hardware a kernel did not
     claim (dry CPU arm for the TPU-only portfolio) its verdict is 'pending'
-    — the gate settles on the first live relay window. A 'delete' verdict
+    — the gate settles on a live run on that hardware. A 'delete' verdict
     (parity failure, or a timed loss on claimed hardware) fails the step:
     the checklist refuses to carry a losing kernel forward."""
     from ..kernels.harness import format_verdict_line, run_kernel_ab
@@ -706,11 +678,11 @@ def _run_multihost(spec: Dict) -> Dict:
     step: real 2-process cluster bring-up, SIGKILL mid-epoch, survivor KV
     consensus, crash-safe manifest commit. A failed check fails the step."""
     import shutil
-    import tempfile
 
     from ..resilience.multihost import run_kill_drill
 
-    workdir = spec.get('workdir') or tempfile.mkdtemp(prefix='bench_multihost_')
+    workdir = spec.get('workdir') or os.path.join('output', 'replay', 'multihost')
+    shutil.rmtree(workdir, ignore_errors=True)
     result = run_kill_drill(
         workdir,
         processes=int(spec.get('processes', 2)),

@@ -220,8 +220,7 @@ def run_kill_drill(workdir: str, processes: int = 2, kill_update: int = 4,
 
 
 if __name__ == '__main__':
-    import tempfile
-    wd = sys.argv[1] if len(sys.argv) > 1 else tempfile.mkdtemp(prefix='timm_tpu_multihost_')
+    wd = sys.argv[1] if len(sys.argv) > 1 else os.path.join('output', 'multihost_drill')
     result = run_kill_drill(wd, log=lambda m: print(f'[multihost] {m}', flush=True))
     print(json.dumps(result, indent=2, default=str))
     sys.exit(0 if result['ok'] else 1)

@@ -100,8 +100,7 @@ class InferenceEngine:
             # ready must be disk-bound. persist_all_programs drops the
             # min-compile-time threshold so even sub-second bucket programs
             # (small models / small buckets) persist.
-            configure_compile_cache(
-                min_compile_time_secs=0.0 if persist_all_programs else None)
+            configure_compile_cache(**({'min_compile_time_secs': 0.0} if persist_all_programs else {}))
         self.mesh = mesh if mesh is not None else create_mesh(devices=jax.devices()[:1])
         self._n_batch_shards = int(self.mesh.size)
         self.buckets = validate_buckets(buckets, divisor=self._n_batch_shards)

@@ -97,7 +97,7 @@ class TrainingTask:
         # (disable with nonfinite_guard=False or TIMM_TPU_NONFINITE_GUARD=0).
         self._nonfinite_guard = guard_enabled(nonfinite_guard)
         self.sentinel = NonFiniteSentinel(nonfinite_tolerance) if self._nonfinite_guard else None
-        self._sentinel_state = new_sentinel_state() if self._nonfinite_guard else None
+        self._sentinel_state = self._new_sentinel_state() if self._nonfinite_guard else None
         # on-device input normalization, fused into the jitted step (the
         # reference normalizes on-GPU in PrefetchLoader, loader.py:124-159)
         if mean is not None:
@@ -247,7 +247,10 @@ class TrainingTask:
         def loss_and_state(params, rest, mb):
             """Merge → loss_forward → re-split, so grads flow w.r.t. params
             while BN-stat / RNG-counter mutations are carried functionally."""
-            m = nnx.merge(graphdef, params, rest)
+            # copy=True: merge otherwise re-uses the Variables of `rest`, which
+            # were created at the enclosing jit/scan trace level and may not be
+            # mutated (RNG counters, BN stats) under value_and_grad
+            m = nnx.merge(graphdef, params, rest, copy=True)
             loss, _output = loss_forward(m, mb)
             _, _, new_rest = nnx.split(m, nnx.Param, ...)
             return loss.astype(jnp.float32), new_rest
@@ -434,10 +437,16 @@ class TrainingTask:
             params, rest, self.opt_state, ema_in, sent_in, batch,
             jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32)).compile()
 
+    def _new_sentinel_state(self):
+        """Fresh counters placed like the step's output: an unplaced array
+        has another type than the mesh-replicated one the step hands back, and
+        the second train step would trace and compile a second program."""
+        return jax.device_put(new_sentinel_state(), replicate_sharding(self.mesh))
+
     def reset_nonfinite(self):
         """Clear the consecutive-bad-step counters (after a rollback)."""
         if self._sentinel_state is not None:
-            self._sentinel_state = new_sentinel_state()
+            self._sentinel_state = self._new_sentinel_state()
         if self.sentinel is not None:
             self.sentinel.reset()
 

@@ -2,133 +2,92 @@
 
 JAX's persistent compilation cache makes compiled executables durable across
 processes: a second cold process re-loading the same program pays only a disk
-read instead of a full XLA compile. Until this module existed, the warm
-``/tmp/timm_tpu_xla_cache`` that tier-1's wall-clock budget depends on was set
-only by tests/conftest.py — entry-script runs (train/validate/inference/bench)
-recompiled everything from scratch every process.
+read instead of a full XLA compile. The directory is part of the cache key, so
+it must not move between runs:
+
+  * where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself keeps the cache
+    there and this module sets no directory;
+  * otherwise the cache lives in one fixed, git-ignored directory inside the
+    checkout (``CHECKOUT_CACHE_DIR``, resolved from this file's location, not
+    the working directory).
 
 One subtlety this module handles: JAX latches its "is the cache enabled?"
-decision at the FIRST compilation of the process (``_cache_checked`` in
-``jax._src.compilation_cache``). Setting ``jax_compilation_cache_dir`` after
+decision at the FIRST compilation of the process. Setting the directory after
 any jit has run silently does nothing. ``configure_compile_cache`` therefore
-resets the cache state after (re)configuring so late configuration still takes
+resets the cache state after configuring so late configuration still takes
 effect.
-
-Environment knobs:
-  TIMM_TPU_COMPILE_CACHE            cache dir; '', '0' or 'off' disables.
-                                    (TIMM_TPU_XLA_CACHE is honored as a
-                                    legacy fallback spelling.)
-  TIMM_TPU_COMPILE_CACHE_MIN_ENTRY_BYTES    min executable size to persist
-                                            (default 0 = everything)
-  TIMM_TPU_COMPILE_CACHE_MIN_COMPILE_SECS   min compile time to persist
-                                            (default 0.5s)
 """
 from __future__ import annotations
 
 import contextlib
-import logging
 import os
-from typing import Dict, Optional
+from typing import Dict
 
-_logger = logging.getLogger(__name__)
-
-DEFAULT_CACHE_DIR = '/tmp/timm_tpu_xla_cache'
-
-_DISABLED = ('', '0', 'off', 'false', 'none')
-
-
-def resolve_cache_dir(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Explicit arg > TIMM_TPU_COMPILE_CACHE > legacy TIMM_TPU_XLA_CACHE >
-    DEFAULT_CACHE_DIR. Returns None when disabled."""
-    if cache_dir is None:
-        cache_dir = os.environ.get(
-            'TIMM_TPU_COMPILE_CACHE',
-            os.environ.get('TIMM_TPU_XLA_CACHE', DEFAULT_CACHE_DIR))
-    if cache_dir is None or cache_dir.strip().lower() in _DISABLED:
-        return None
-    return cache_dir
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    '.jax_cache')
 
 
 def configure_compile_cache(
-        cache_dir: Optional[str] = None,
-        min_entry_size_bytes: Optional[int] = None,
-        min_compile_time_secs: Optional[float] = None,
-) -> Optional[str]:
-    """Point JAX's persistent compilation cache at a durable directory.
+        min_entry_size_bytes: int = 0,
+        min_compile_time_secs: float = 0.5,
+) -> str:
+    """Switch JAX's persistent compilation cache on for this process.
 
-    Call at process start (all four entry scripts and the tier-1 conftest do)
-    so every compile in the process is eligible. Returns the configured dir,
-    or None when disabled. Safe to call more than once and after jits have
-    already run (the cache-enabled latch is reset).
+    Call at process start (the entry scripts, the serve engine and the tier-1
+    conftest do) so every compile in the process is eligible. Returns the
+    directory in use. Safe to call more than once and after jits have already
+    run (the cache-enabled latch is reset).
     """
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    cache_dir = resolve_cache_dir(cache_dir)
-    if cache_dir is None:
-        return None
-    if min_entry_size_bytes is None:
-        min_entry_size_bytes = int(os.environ.get('TIMM_TPU_COMPILE_CACHE_MIN_ENTRY_BYTES', '0'))
-    if min_compile_time_secs is None:
-        min_compile_time_secs = float(os.environ.get('TIMM_TPU_COMPILE_CACHE_MIN_COMPILE_SECS', '0.5'))
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update('jax_compilation_cache_dir', cache_dir)
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes', min_entry_size_bytes)
-        jax.config.update('jax_persistent_cache_min_compile_time_secs', min_compile_time_secs)
-    except Exception as e:  # out-of-tree jax without these flags: degrade loudly
-        _logger.warning(f'persistent compile cache not configured: {e}')
-        return None
-    try:
-        # un-latch the once-per-process enabled check so configuration after
-        # an early jit (imports, probes) still takes effect
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception as e:
-        # best-effort: the private reset hook moves between jax versions;
-        # without it the cache still works for jits issued after configure
-        _logger.debug(f'compile-cache reset hook unavailable: {e}')
-    return cache_dir
+    if not os.environ.get('JAX_COMPILATION_CACHE_DIR'):
+        os.makedirs(CHECKOUT_CACHE_DIR, exist_ok=True)
+        jax.config.update('jax_compilation_cache_dir', CHECKOUT_CACHE_DIR)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', min_entry_size_bytes)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', min_compile_time_secs)
+    # un-latch the once-per-process enabled check so configuration after an
+    # early jit (imports) still takes effect
+    compilation_cache.reset_cache()
+    return jax.config.jax_compilation_cache_dir
 
 
-# -- compile-cache event accounting -------------------------------------------
-# JAX emits '/jax/compilation_cache/cache_hits' / 'cache_misses' monitoring
-# events on every compile with the persistent cache enabled. One module-level
-# listener fans out to whichever collectors are active, so nested measurements
-# (engine prewarm inside drill inside test) each see their own counts.
-
-_ACTIVE_COLLECTORS: list = []
-_LISTENER_INSTALLED = False
-
-
-def _install_cache_listener():
-    global _LISTENER_INSTALLED
-    if _LISTENER_INSTALLED:
-        return
-    try:
-        from jax._src import monitoring
-
-        def _on_event(event, **kwargs):
-            if '/compilation_cache/' not in event:
-                return
-            for c in list(_ACTIVE_COLLECTORS):
-                c[event] = c.get(event, 0) + 1
-
-        monitoring.register_event_listener(_on_event)
-        _LISTENER_INSTALLED = True
-    except Exception as e:  # out-of-tree jax: counts degrade to zeros
-        _logger.warning(f'compile-cache event listener unavailable: {e}')
+_BACKEND_COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
 
 
 @contextlib.contextmanager
 def collect_cache_events():
-    """Collect JAX compilation-cache events within the block into a dict."""
-    _install_cache_listener()
+    """Count JAX's compile events within the block into a dict.
+
+    Every program JAX builds — compiled afresh or read back from the
+    persistent cache — records ``/jax/core/compile/backend_compile_duration``
+    once, so its count is the number of compilations, cache or no cache. With
+    the persistent cache on, one served from disk also records
+    ``/jax/compilation_cache/cache_hits`` and one written to disk
+    ``.../cache_misses`` (a compile under the persistence thresholds is
+    neither). Each collector registers its own listeners, so nested
+    measurements (engine prewarm inside drill inside test) each see their own
+    counts."""
+    import jax
+
     counts: Dict[str, int] = {}
-    _ACTIVE_COLLECTORS.append(counts)
+
+    def on_event(event, **kwargs):
+        if '/compilation_cache/' in event:
+            counts[event] = counts.get(event, 0) + 1
+
+    def on_duration(event, duration_secs, **kwargs):
+        if event == _BACKEND_COMPILE_EVENT:
+            counts[event] = counts.get(event, 0) + 1
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
         yield counts
     finally:
-        _ACTIVE_COLLECTORS.remove(counts)
+        jax.monitoring.unregister_event_listener(on_event)
+        jax.monitoring.unregister_event_duration_listener(on_duration)
 
 
 def cache_event_total(counts: Dict[str, int], suffix: str) -> int:
@@ -136,19 +95,19 @@ def cache_event_total(counts: Dict[str, int], suffix: str) -> int:
     return sum(v for k, v in counts.items() if k.endswith(suffix))
 
 
-def count_jaxpr_eqns(jaxpr) -> int:
-    """Total equation count of a (closed) jaxpr including nested sub-jaxprs
-    (scan/while/cond bodies, remat). The proxy for trace/lowering cost: a
-    Python block loop contributes O(depth) equations, a scanned stack O(1)."""
-    jaxpr = getattr(jaxpr, 'jaxpr', jaxpr)
-    n = 0
-    for eqn in jaxpr.eqns:
-        n += 1
+def iter_jaxpr_eqns(jaxpr):
+    """Every equation of a (closed) jaxpr, nested sub-jaxprs (scan/while/cond
+    bodies, remat) included."""
+    for eqn in getattr(jaxpr, 'jaxpr', jaxpr).eqns:
+        yield eqn
         for v in eqn.params.values():
-            if hasattr(v, 'jaxpr'):
-                n += count_jaxpr_eqns(v)
-            elif isinstance(v, (list, tuple)):
-                for item in v:
-                    if hasattr(item, 'jaxpr'):
-                        n += count_jaxpr_eqns(item)
-    return n
+            for item in (v if isinstance(v, (list, tuple)) else (v,)):
+                if hasattr(item, 'jaxpr'):
+                    yield from iter_jaxpr_eqns(item)
+
+
+def count_jaxpr_eqns(jaxpr) -> int:
+    """Total equation count of a (closed) jaxpr including nested sub-jaxprs.
+    The proxy for trace/lowering cost: a Python block loop contributes
+    O(depth) equations, a scanned stack O(1)."""
+    return sum(1 for _ in iter_jaxpr_eqns(jaxpr))

@@ -141,8 +141,8 @@ def main():
                         help='(with --serve) requests per drill arm')
     parser.add_argument('--replay', action='store_true',
                         help='execute the entire queued PERF.md A/B checklist (donation, '
-                             'pad-tokens, bf16 knobs, fsdp x tp grid, flash gate, profiler '
-                             'trace, serve drill) as one scripted sequence, recording every '
+                             'pad-tokens, bf16 knobs, fsdp x tp grid, flash gate, '
+                             'serve drill) as one scripted sequence, recording every '
                              'step into BENCH_SELF.json. Combine with --dry-run for the '
                              'tier-1 CPU smoke (tiny models, same code path).')
     parser.add_argument('--replay-steps', default='', metavar='A,B',
@@ -165,19 +165,12 @@ def main():
                              'in tier-1; the full run also walks the jaxpr/HLO of every '
                              'probe program. Exit 0 clean / 2 violations / 3 analyzer '
                              'error.')
-    parser.add_argument('--profile', action='store_true',
-                        help='capture a jax.profiler trace of the train step for --model '
-                             'and print the self-parsed MXU vs non-MXU op summary '
-                             '(PERF.md checklist item 6, unattended)')
-    parser.add_argument('--profile-dir', default='', metavar='DIR',
-                        help='trace output dir (default: a fresh temp dir; TensorBoard-'
-                             'loadable for the deep-dive)')
     parser.add_argument('--save-self', action='store_true',
                         help='on success, record result to BENCH_SELF.json')
     args = parser.parse_args()
     if (args.quantize and args.bench == 'train'
             and not (args.dry_run or args.serve or args.replay
-                     or args.profile or args.compile_report)):
+                     or args.compile_report)):
         parser.error('--quantize int8 quantizes weights for the serve path; '
                      'measure it with --bench infer (or smoke with --dry-run)')
     if args.fast:
@@ -198,9 +191,6 @@ def main():
 
     if args.analysis:
         raise SystemExit(_analysis(args))
-
-    if args.profile:
-        raise SystemExit(_profile_run(args))
 
     if args.serve:
         raise SystemExit(_serve_drill(args))
@@ -395,7 +385,7 @@ def _serve_drill(args) -> int:
 
 
 def _force_cpu_topology():
-    """The fsdp x tp replay/profile steps need 8 devices; a CPU host only
+    """The fsdp x tp replay steps need 8 devices; a CPU host only
     grows them if the XLA flag is exported before jax's FIRST import (no-op
     once jax is loaded, and harmless on a real TPU backend)."""
     if 'jax' in sys.modules:
@@ -421,8 +411,7 @@ def _replay_checklist(args) -> int:
     names = [s.strip() for s in args.replay_steps.split(',') if s.strip()] or None
     _status(f'replay: PERF.md checklist ({"dry-run" if args.dry_run else "LIVE"})')
     doc, rc = run_replay(dry_run=args.dry_run, self_path=SELF_RESULT_PATH,
-                         names=names, trace_dir=args.profile_dir or None,
-                         log=lambda m: _status(m))
+                         names=names, log=lambda m: _status(m))
     errs = validate_self_result(load_self_doc(SELF_RESULT_PATH))
     statuses = ' '.join(f"{s['id']}={s['status']}" for s in doc['steps'])
     print(json.dumps({
@@ -498,38 +487,6 @@ def _analysis(args) -> int:
         'value': float(result['violations']), 'unit': 'violations',
         'vs_baseline': None}), flush=True)
     return result['exit_code']
-
-
-def _profile_run(args) -> int:
-    """Unattended profiler harness (PERF.md checklist item 6): capture a
-    jax.profiler trace of the train step for --model and print the
-    self-parsed MXU vs non-MXU op-category summary. The trace directory is
-    kept on disk (TensorBoard/XProf-loadable) for the human deep-dive."""
-    _force_cpu_topology()
-    from timm_tpu.perfbudget.replay import _run_profile
-    from timm_tpu.utils import configure_compile_cache
-
-    configure_compile_cache()
-    img = min(args.img_size, 64) if args.dry_run else args.img_size
-    spec = {'model': args.model, 'img_size': img,
-            'batch': args.batch_size or (8 if args.dry_run else 32),
-            'steps': max(1, min(args.steps, 3))}
-    if args.fsdp:
-        spec['fsdp'] = args.fsdp
-    if args.tp:
-        spec['tp'] = args.tp
-    _status(f'profile: tracing {args.model} train step ({spec["steps"]} step(s))')
-    summary = _run_profile(spec, args.profile_dir or None)
-    ok = summary.get('status') == 'ok'
-    mxu = summary.get('mxu_frac')
-    print(json.dumps({
-        'metric': (f"profiler trace {args.model}: {summary.get('total_events', 0)} device-op "
-                   f"events, MXU {summary.get('mxu_us', 0.0):.0f}us vs other "
-                   f"{summary.get('non_mxu_us', 0.0):.0f}us -> {summary.get('trace_dir', '?')}"),
-        'value': round(mxu, 4) if mxu is not None else 0.0,
-        'unit': 'MXU time fraction', 'vs_baseline': None,
-        'summary': summary}), flush=True)
-    return 0 if ok else 2
 
 
 def _compile_child(args) -> int:

@@ -377,7 +377,7 @@ def test_probe_matrix_and_budgets_carry_autotune_config():
 def test_replay_checklist_has_autotune_step():
     from timm_tpu.perfbudget.replay import REPLAY_STEPS
 
-    assert len(REPLAY_STEPS) == 22
+    assert len(REPLAY_STEPS) == 21
     step = next(s for s in REPLAY_STEPS if s['id'] == 'autotune')
     assert step['kind'] == 'autotune'
     assert step['dry']['top_k'] >= 2 and step['live']['top_k'] == 3

@@ -11,11 +11,7 @@
 4. `bench.py --replay --dry-run` (subprocess): the ENTIRE queued PERF.md
    checklist completes unattended with a schema-valid BENCH_SELF.json;
    `bench.py` with no TPU exits non-zero and prints no number.
-5. Profiler: perfetto parsing + MXU vs non-MXU classification on a
-   synthetic trace (deterministic; the real-trace path is exercised by the
-   replay's `profile` step).
 """
-import gzip
 import json
 import os
 import subprocess
@@ -26,8 +22,8 @@ import pytest
 from timm_tpu.perfbudget import (
     DEFAULT_MATRIX, ProbeConfig, check_counter, check_counter_min, check_ratio_max,
     check_ratio_min, check_upper, compare_budgets, compare_config, format_violations,
-    latest_trace_file, load_budgets, load_self_doc, parse_trace, probe_config,
-    record_result, run_matrix, summarize_events, tolerance_for,
+    load_budgets, load_self_doc, probe_config,
+    record_result, run_matrix, tolerance_for,
     update_budgets, validate_self_result,
 )
 from timm_tpu.perfbudget.replay import REPLAY_STEPS, SELF_SCHEMA
@@ -276,9 +272,6 @@ def test_replay_dry_run_completes_full_checklist(tmp_path):
     assert set(ran) == {s['id'] for s in REPLAY_STEPS}
     assert set(ran.values()) == {'ok'}, ran
     assert out['value'] == float(replay['completed']) == float(len(REPLAY_STEPS))
-    # the profiler step actually parsed device ops out of its own trace
-    prof = next(s for s in replay['steps'] if s['id'] == 'profile')
-    assert prof['result']['total_events'] > 0
 
 
 def test_replay_steps_subset_and_unknown_id(tmp_path):
@@ -314,48 +307,3 @@ def test_bench_without_a_tpu_exits_nonzero_with_no_number(tmp_path):
     assert r.stdout.strip() == '', r.stdout[-2000:]
     assert 'no accelerator' in r.stderr
     assert load_self_doc(self_path)['result'] == prior, 'a failed run touched the record'
-
-
-# ---- 5. profiler parsing (synthetic trace, deterministic) -------------------
-
-def _write_trace(tmp_path, events):
-    run_dir = tmp_path / 'plugins' / 'profile' / 'run1'
-    run_dir.mkdir(parents=True)
-    path = run_dir / 'host.trace.json.gz'
-    with gzip.open(path, 'wt') as f:
-        json.dump({'traceEvents': events}, f)
-    return str(tmp_path)
-
-
-def test_profiler_classifies_mxu_vs_other(tmp_path):
-    trace_dir = _write_trace(tmp_path, [
-        {'ph': 'M', 'name': 'thread_name', 'pid': 1, 'tid': 1,
-         'args': {'name': 'tf_XLAEigen/1'}},
-        {'ph': 'M', 'name': 'thread_name', 'pid': 1, 'tid': 2,
-         'args': {'name': 'python'}},
-        {'ph': 'M', 'name': 'thread_name', 'pid': 1, 'tid': 3,
-         'args': {'name': 'main'}},
-        # device ops: one MXU-class (dot), one not (fusion)
-        {'ph': 'X', 'name': 'dot.3', 'pid': 1, 'tid': 1, 'ts': 0, 'dur': 100},
-        {'ph': 'X', 'name': 'fusion.7', 'pid': 1, 'tid': 1, 'ts': 100, 'dur': 50},
-        # noise that must NOT count: python frame, compile event, class name
-        {'ph': 'X', 'name': 'loss_fn', 'pid': 1, 'tid': 2, 'ts': 0, 'dur': 999},
-        {'ph': 'X', 'name': 'backend_compile', 'pid': 1, 'tid': 3, 'ts': 0, 'dur': 500},
-        {'ph': 'X', 'name': 'TfrtCpuClient::Compile', 'pid': 1, 'tid': 3, 'ts': 0, 'dur': 500},
-    ])
-    path = latest_trace_file(trace_dir)
-    assert path and path.endswith('.trace.json.gz')
-    ops = parse_trace(path)
-    assert sorted(ev['name'] for ev in ops) == ['dot.3', 'fusion.7']
-    s = summarize_events(ops)
-    assert s['total_events'] == 2
-    assert s['mxu_us'] == 100.0 and s['non_mxu_us'] == 50.0
-    assert abs(s['mxu_frac'] - 100.0 / 150.0) < 1e-3
-    assert s['top_ops'][0]['op'] == 'dot'
-
-
-def test_profiler_empty_trace_dir(tmp_path):
-    assert latest_trace_file(str(tmp_path)) is None
-    assert summarize_events([]) == {'total_events': 0, 'mxu_us': 0.0,
-                                    'non_mxu_us': 0.0, 'mxu_frac': 0.0,
-                                    'top_ops': []}

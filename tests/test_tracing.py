@@ -1,0 +1,227 @@
+"""`timm_tpu/utils/tracing.py`: the program's one tracing mechanism.
+
+The ring is process-wide and other tests of the same worker write to it, so
+each case reads only what it recorded itself (spans that started after its own
+mark, names nothing else uses at that moment).
+"""
+import inspect
+import os
+import re
+import sys
+import threading
+
+import pytest
+
+from timm_tpu.utils import tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _since(mark_ns, name=None):
+    return [s for s in tracing.snapshot()['spans'] if s.start_ns >= mark_ns and (name is None or s.name == name)]
+
+
+def test_nesting_and_parent_links_across_two_threads():
+    mark = tracing.now_ns()
+    seen = {}
+
+    def worker():
+        with tracing.span('loader.h2d') as outer:
+            with tracing.span('loader.sample_params') as inner:
+                seen['thread'] = (outer.id, inner.id)
+
+    with tracing.span('train.step', step=7) as root:
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        with tracing.span('task.train_step') as call:
+            with tracing.span('task.step_call') as leaf:
+                pass
+    by_id = {s.id: s for s in _since(mark)}
+    assert by_id[leaf.id].parent == call.id and by_id[call.id].parent == root.id and by_id[root.id].parent == 0
+    outer_id, inner_id = seen['thread']
+    # the other thread has its own stack: its outer span has no parent and no step, whatever the main thread has open
+    assert by_id[outer_id].parent == 0 and by_id[inner_id].parent == outer_id
+    assert by_id[outer_id].thread == by_id[inner_id].thread != by_id[root.id].thread
+    assert by_id[outer_id].step is None and by_id[leaf.id].step == 7
+    # a child lies inside its parent on both clocks
+    for child, parent in ((leaf, call), (call, root)):
+        c, p = by_id[child.id], by_id[parent.id]
+        assert p.start_ns <= c.start_ns <= c.end_ns <= p.end_ns and p.cpu_start_ns <= c.cpu_start_ns <= c.cpu_end_ns <= p.cpu_end_ns
+
+
+def test_a_step_root_hands_its_step_down_and_marks_the_counters():
+    mark = tracing.now_ns()
+    for step in (41, 42):
+        with tracing.span('train.step', step=step):
+            tracing.count('loader.batches', 3)
+            with tracing.span('train.loader_next'):
+                with tracing.span('loader.batch_wait'):
+                    pass
+    with tracing.span('train.bookkeeping'):   # outside any root: no step
+        pass
+    assert [s.step for s in _since(mark, 'loader.batch_wait')] == [41, 42]
+    assert [s.step for s in _since(mark, 'train.bookkeeping')] == [None]
+    marks = [c for t, c in tracing.snapshot()['marks'] if t >= mark]
+    assert len(marks) == 2 and marks[1]['loader.batches'] - marks[0]['loader.batches'] == 3
+
+
+def test_a_span_is_recorded_when_its_block_raises():
+    mark = tracing.now_ns()
+
+    class Closed(Exception):
+        pass
+
+    with pytest.raises(Closed):
+        with tracing.span('train.step', step=1):
+            with tracing.span('task.train_step'):
+                raise Closed()
+    got = {s.name: s for s in _since(mark)}
+    assert got['task.train_step'].failed and got['train.step'].failed
+    assert got['task.train_step'].parent == got['train.step'].id
+    with tracing.span('task.scalars_put') as after:   # the stack was unwound: nothing is left open
+        pass
+    assert _since(mark, 'task.scalars_put')[0].parent == 0 and not _since(mark, 'task.scalars_put')[0].failed
+    assert after.step is None
+
+
+def test_the_ring_is_bounded_and_large_enough():
+    assert tracing.RING >= 16384
+    for _ in range(tracing.RING + 100):
+        with tracing.span('train.log_sync'):
+            pass
+    spans = tracing.snapshot()['spans']
+    assert len(spans) == tracing.RING and spans[-1].id - spans[0].id >= tracing.RING - 1
+
+
+@pytest.mark.parametrize('record', [lambda n: tracing.span(n), lambda n: tracing.count(n), lambda n: tracing.busy(n),
+                                    lambda n: tracing.gauge(n, 1)], ids=['span', 'count', 'busy', 'gauge'])
+def test_a_name_that_is_not_declared_is_refused(record):
+    with pytest.raises(KeyError, match='not declared'):
+        record('task.made_up')
+
+
+def test_every_compilation_is_a_span_under_whatever_was_open():
+    import jax
+    import jax.numpy as jnp
+    mark = tracing.now_ns()
+    x, k = jnp.ones(3), mark % 977   # a constant no cached program holds
+    with tracing.span('train.step', step=5):
+        with tracing.span('task.step_call') as call:
+            jax.jit(lambda x: x * 3 + k)(x).block_until_ready()
+    built = _since(0, 'xla.backend_compile')
+    mine = [s for s in built if s.parent == call.id]
+    assert len(mine) == 1 and mine[0].step == 5 and mine[0].end_ns > mine[0].start_ns >= mark
+    jax.jit(lambda x: x * 5 + k)(x).block_until_ready()
+    outside = [s for s in _since(0, 'xla.backend_compile') if s.id > mine[0].id]
+    assert outside and outside[-1].parent == 0 and outside[-1].step is None
+
+
+def test_summary_gives_medians_and_sums_per_name():
+    S = tracing.Span
+    ms = 1_000_000
+    spans = [S(1, 0, 'task.step_call', 1, 0, 0, 10 * ms, 0, 1 * ms, False),
+             S(2, 0, 'task.step_call', 1, 1, 20 * ms, 50 * ms, 0, 3 * ms, False),
+             S(3, 0, 'task.step_call', 1, 2, 60 * ms, 80 * ms, 0, 8 * ms, False),
+             S(4, 0, 'task.state_split', 1, 2, 90 * ms, 94 * ms, 0, 4 * ms, False)]
+    got = tracing.summary(spans=spans)
+    assert got['task.step_call'] == {'n': 3, 'wall_ms_median': 20.0, 'wall_ms_sum': 60.0,
+                                     'cpu_ms_median': 3.0, 'cpu_ms_sum': 12.0}
+    assert tracing.summary(since_ns=20 * ms, spans=spans)['task.step_call']['n'] == 2
+    assert set(tracing.summary(since_ns=85 * ms, spans=spans)) == {'task.state_split'}
+    mark = tracing.now_ns()
+    with tracing.span('task.state_update'):
+        pass
+    assert tracing.summary(mark)['task.state_update']['n'] == 1
+
+
+def test_counters_lose_no_update_under_contending_threads():
+    """More threads than cores, a short switch interval: a read-modify-write
+    without the lock would lose adds."""
+    threads, adds = 4 * (os.cpu_count() or 4), 2000
+    before = tracing.snapshot()['counters'].get('loader.samples', 0)
+    busy_before = tracing.snapshot()['counters'].get('loader.decode_busy_ns', 0)
+
+    def worker():
+        for _ in range(adds):
+            with tracing.busy('loader.decode_busy_ns'):
+                tracing.count('loader.samples')
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        pool = [threading.Thread(target=worker) for _ in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in pool)
+    after = tracing.snapshot()['counters']
+    assert after['loader.samples'] - before == threads * adds
+    assert after['loader.decode_busy_ns'] > busy_before
+
+
+def test_a_gauge_keeps_a_bounded_series_on_the_rings_clock():
+    mark = tracing.now_ns()
+    for depth in (2, 0, 1):
+        tracing.gauge('loader.batch_q_depth', depth)
+    series = [(t, v) for t, v in tracing.snapshot()['gauges']['loader.batch_q_depth'] if t >= mark]
+    assert [v for _, v in series] == [2, 0, 1] and series[0][0] <= series[-1][0] <= tracing.now_ns()
+    for _ in range(tracing.SERIES + 10):
+        tracing.gauge('loader.batch_q_depth', 1)
+    assert len(tracing.snapshot()['gauges']['loader.batch_q_depth']) == tracing.SERIES
+
+
+# -- the lint: names are the contract ------------------------------------------------------
+
+CALL = re.compile(r"tracing\.(?:span|count|busy|gauge)\(\s*(['\"])([^'\"]+)\1")
+
+
+def _program_sources():
+    paths = [os.path.join(ROOT, 'train.py')]
+    for folder, _, files in os.walk(os.path.join(ROOT, 'timm_tpu')):
+        paths += [os.path.join(folder, f) for f in files if f.endswith('.py')]
+    return {p: open(p).read() for p in paths}
+
+
+def test_every_recorded_name_is_declared_and_every_declared_name_is_recorded_and_read():
+    import train
+    sources = _program_sources()
+    sites = {}
+    for path, text in sources.items():
+        for _, name in CALL.findall(text):
+            sites.setdefault(name, []).append(os.path.relpath(path, ROOT))
+    sites.setdefault('xla.backend_compile', []).append('timm_tpu/utils/tracing.py')   # the listener's own record
+    assert "'xla.backend_compile'" in sources[os.path.join(ROOT, 'timm_tpu', 'utils', 'tracing.py')]
+    assert set(sites) == set(tracing.SPANS), (set(sites) ^ set(tracing.SPANS))
+    # nothing records under a computed name, and only tracing.py knows TraceAnnotation
+    for path, text in sources.items():
+        if not path.endswith(os.path.join('utils', 'tracing.py')):
+            assert 'TraceAnnotation' not in text, path
+            for call in re.findall(r'tracing\.(?:span|count|busy|gauge)\(([^)]*)', text):
+                assert call.lstrip()[:1] in ('\'', '"'), (path, call)
+    # each name has a reader: a per-layer metric, the reduction behind them, or train.py's two log lines
+    bench = os.path.join(ROOT, 'benchmarks')
+    readers = [open(os.path.join(bench, 'layer_metrics', f)).read() for f in os.listdir(os.path.join(bench, 'layer_metrics'))]
+    readers += [open(os.path.join(bench, 'harness', 'program_spans.py')).read(),
+                inspect.getsource(train._host_line), inspect.getsource(train._setup_line)]
+    unread = [name for name in tracing.SPANS if not any(f"'{name}'" in text for text in readers)]
+    assert not unread, unread
+    # the layers are the ones PERF.md section 3 and BENCHMARK.json name
+    assert {layer for layer, _ in tracing.SPANS.values()} == {'entry and compile cache', 'input', 'step'}
+
+
+def test_no_loader_worker_thread_opens_a_span():
+    """Spans in the loader sit on the consuming (main) thread only; the worker
+    and collator functions use counters."""
+    from timm_tpu.data.loader import ThreadedLoader
+    text = inspect.getsource(ThreadedLoader.__iter__)
+    for fn in ('def worker(', 'def collator('):
+        start = text.index(fn)
+        body = text[start:text.index('\n        def ', start + 1) if '\n        def ' in text[start + 1:] else None]
+        body = body.split('\n        ct = threading.Thread')[0].split('\n        used = ')[0]
+        assert 'tracing.span(' not in body, fn
+        assert 'tracing.count(' in body or 'tracing.busy(' in body, fn

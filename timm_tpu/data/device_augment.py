@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from timm_tpu.parallel.mesh import shard_batch
+from timm_tpu.utils import tracing
 
 __all__ = [
     'mixup_images', 'mixup_targets', 'erase_images', 'augment_image_batch',
@@ -322,14 +323,17 @@ class DeviceAugmentStage:
     def __iter__(self):
         for step, (x, t) in enumerate(self.loader):
             batch = {'image': x, 'target': t}
-            if self.random_erasing is not None:
-                batch.update(self.random_erasing.sample_params(x.shape))
-                if self.re_mode == 'pixel':
-                    batch['noise_epoch'] = np.uint32(self._epoch)
-                    batch['noise_step'] = np.uint32(step)
-            if self.mixup is not None:
-                batch.update(self.mixup.sample_params(x.shape))
-            yield self._augment(shard_batch(batch, self._mesh))
+            with tracing.span('loader.sample_params'):
+                if self.random_erasing is not None:
+                    batch.update(self.random_erasing.sample_params(x.shape))
+                    if self.re_mode == 'pixel':
+                        batch['noise_epoch'] = np.uint32(self._epoch)
+                        batch['noise_step'] = np.uint32(step)
+                if self.mixup is not None:
+                    batch.update(self.mixup.sample_params(x.shape))
+            with tracing.span('loader.augment_call'):
+                out = self._augment(shard_batch(batch, self._mesh))
+            yield out
 
 
 class NaFlexDeviceAugment:

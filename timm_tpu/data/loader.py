@@ -21,6 +21,7 @@ from typing import Callable, Optional, Tuple
 import numpy as np
 
 from ..resilience import SkipBudget, TooManyBadSamples, get_fault_injector, retry_io
+from ..utils import tracing
 from .constants import IMAGENET_DEFAULT_MEAN, IMAGENET_DEFAULT_STD
 from .random_erasing import RandomErasing
 from .transforms_factory import create_transform
@@ -256,16 +257,22 @@ class DevicePrefetcher:
 
         buf = collections.deque()
         it = iter(self.loader)
+
+        def put_next():
+            batch = next(it)
+            with tracing.span('loader.h2d'):
+                buf.append(shard_batch(batch))
+
         try:
             while len(buf) < self.size:
                 try:
-                    buf.append(shard_batch(next(it)))
+                    put_next()
                 except StopIteration:
                     break
             while buf:
                 out = buf.popleft()
                 try:
-                    buf.append(shard_batch(next(it)))
+                    put_next()
                 except StopIteration:
                     pass
                 yield out
@@ -410,17 +417,21 @@ class ThreadedLoader:
             for idx in worker_indices:
                 if stop.is_set():
                     return
-                try:
-                    # transient I/O faults (OSError) ride through jittered
-                    # exponential backoff; anything still failing is poison
-                    sample = retry_io(lambda: _read(idx), retries=3, base_delay=0.05,
-                                      desc=f'sample {int(idx)}')
-                except Exception as e:
+                # counters only on these threads: a span per sample would cost
+                # the interpreter they are suspected of starving the main thread of
+                with tracing.busy('loader.decode_busy_ns'):
                     try:
-                        skip_budget.record(e, f'sample index {int(idx)}')
-                        sample = _SKIPPED
-                    except TooManyBadSamples as fatal:
-                        sample = fatal  # budget exhausted: fail the epoch loudly
+                        # transient I/O faults (OSError) ride through jittered
+                        # exponential backoff; anything still failing is poison
+                        sample = retry_io(lambda: _read(idx), retries=3, base_delay=0.05,
+                                          desc=f'sample {int(idx)}')
+                    except Exception as e:
+                        try:
+                            skip_budget.record(e, f'sample index {int(idx)}')
+                            sample = _SKIPPED
+                        except TooManyBadSamples as fatal:
+                            sample = fatal  # budget exhausted: fail the epoch loudly
+                tracing.count('loader.samples')
                 if not _put(sample_q, (int(idx), sample)):
                     return
 
@@ -452,6 +463,7 @@ class ThreadedLoader:
                     if self.random_erasing is not None:
                         x = self.random_erasing(x)
                     ok = _put(batch_q, (x, t))
+                    tracing.count('loader.batches')
                     batch_imgs, batch_targets = [], []
                     return ok
                 return True
@@ -493,7 +505,9 @@ class ThreadedLoader:
 
         try:
             while True:
-                item = batch_q.get()
+                tracing.gauge('loader.batch_q_depth', batch_q.qsize())
+                with tracing.span('loader.batch_wait'):
+                    item = batch_q.get()
                 if item is None:
                     break
                 if isinstance(item, Exception):

@@ -1,14 +1,12 @@
-"""Hardware-independent perf-regression suite + profiler harness + replay.
+"""Hardware-independent perf-regression suite + replay.
 
-Four pieces (see each module's docstring):
+Three pieces (see each module's docstring):
   * probe    — lower the real train/serve programs, extract XLA cost
                analysis, jaxpr size, per-device bytes, donation/sharding
                legality for a config matrix;
   * budgets  — checked-in seed budgets + the one tolerance policy (fails on
                regression AND on silent improvement; re-baseline via
                ``python -m timm_tpu.perfbudget --update-budgets``);
-  * profiler — `jax.profiler.trace` harness with a self-parsed MXU vs
-               non-MXU op summary (`bench.py --profile`);
   * replay   — the PERF.md on-device checklist as one scripted sequence
                writing BENCH_SELF.json (`bench.py --replay [--dry-run]`).
 
@@ -21,7 +19,6 @@ from .budgets import (
     format_violations, load_budgets, tolerance_for, update_budgets,
 )
 from .probe import DEFAULT_MATRIX, ProbeConfig, donation_evidence, probe_config, run_matrix
-from .profiler import latest_trace_file, parse_trace, profile_step, summarize_events
 from .replay import (
     REPLAY_STEPS, SELF_SCHEMA, load_self_doc, record_result,
     run_replay, save_self_doc, validate_self_result,
@@ -33,7 +30,6 @@ __all__ = [
     'compare_budgets', 'compare_config', 'format_violations', 'load_budgets',
     'tolerance_for', 'update_budgets',
     'DEFAULT_MATRIX', 'ProbeConfig', 'donation_evidence', 'probe_config', 'run_matrix',
-    'latest_trace_file', 'parse_trace', 'profile_step', 'summarize_events',
     'REPLAY_STEPS', 'SELF_SCHEMA', 'load_self_doc', 'record_result',
     'run_replay', 'save_self_doc', 'validate_self_result',
 ]

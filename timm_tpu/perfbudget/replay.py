@@ -161,9 +161,6 @@ REPLAY_STEPS: Tuple[Dict, ...] = (
                   pad_tokens=256),
          live=dict(model='naflexvit_base_patch16_gap', img_size=224, batch=32,
                    pad_tokens=784, pallas=True)),
-    dict(id='profile', item=6, kind='profile',
-         title='jax.profiler trace of the train step + MXU/non-MXU op summary',
-         dry=dict(_TINY, steps=2), live=dict(_VITB, steps=3)),
     dict(id='grid_8x1', item=7, kind='train',
          title='fsdp x tp grid: (8,1)',
          dry=dict(_TINY, fsdp=8), live=dict(_VITB, batch=1024, fsdp=8)),
@@ -395,23 +392,6 @@ def _run_flash(spec: Dict) -> Dict:
             'logits_finite': finite, 'pallas_kernel_importable': kernel_available,
             'pallas_env_gate': os.environ.get('TIMM_TPU_PALLAS_ATTN', ''),
             'live_needs': 'TIMM_TPU_PALLAS_ATTN=1 at masked N in {576, 784, 1024}'}
-
-
-def _run_profile(spec: Dict, trace_dir: Optional[str]) -> Dict:
-    import jax
-
-    from .profiler import profile_step
-
-    run_one_step, _n, meta = _build_tiny_step(spec)
-    loss = run_one_step()  # compile outside the trace window
-    jax.block_until_ready(loss)
-    trace_dir = trace_dir or os.path.join('output', 'replay', 'trace')
-    summary = profile_step(run_one_step, trace_dir,
-                           steps=int(spec.get('steps', 2)),
-                           label=f"train:{spec['model']}")
-    summary.update(meta)
-    summary['status'] = 'ok' if summary.get('total_events', 0) > 0 else 'failed'
-    return summary
 
 
 def _run_naflex(spec: Dict) -> Dict:
@@ -724,7 +704,7 @@ def _run_family_sweep(spec: Dict) -> Dict:
                                 if r['stage_or_block_scan'])}
 
 
-def _run_step(step: Dict, dry_run: bool, trace_dir: Optional[str]) -> Dict:
+def _run_step(step: Dict, dry_run: bool) -> Dict:
     spec = step['dry'] if dry_run else step['live']
     if step['kind'] == 'analysis':
         return _run_analysis(spec)
@@ -734,8 +714,6 @@ def _run_step(step: Dict, dry_run: bool, trace_dir: Optional[str]) -> Dict:
         return _run_train(spec)
     if step['kind'] == 'flash':
         return _run_flash(spec)
-    if step['kind'] == 'profile':
-        return _run_profile(spec, trace_dir)
     if step['kind'] == 'serve':
         return _run_serve(spec)
     if step['kind'] == 'quant_serve':
@@ -753,7 +731,7 @@ def _run_step(step: Dict, dry_run: bool, trace_dir: Optional[str]) -> Dict:
 
 def run_replay(dry_run: bool = True, self_path: Optional[str] = None,
                names: Optional[Sequence[str]] = None,
-               trace_dir: Optional[str] = None, log=None) -> Tuple[Dict, int]:
+               log=None) -> Tuple[Dict, int]:
     """Execute the checklist (all steps, or the `names` subset) and persist
     the run into BENCH_SELF.json after EVERY step. Returns (replay_doc,
     exit_code); exit_code is 0 iff no step failed."""
@@ -789,7 +767,7 @@ def run_replay(dry_run: bool = True, self_path: Optional[str] = None,
             t0 = time.perf_counter()
             rec: Dict = {'id': step['id'], 'item': step['item'], 'title': step['title']}
             try:
-                result = _run_step(step, dry_run, trace_dir)
+                result = _run_step(step, dry_run)
                 autotune_doc.update(result.pop('_autotune_doc', {}))
                 rec['status'] = result.pop('status', 'ok')
                 key = 'reason' if rec['status'] == 'skipped' else 'result'

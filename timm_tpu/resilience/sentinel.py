@@ -28,6 +28,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..utils import tracing
+
 _logger = logging.getLogger(__name__)
 
 __all__ = ['NonFiniteError', 'NonFiniteSentinel', 'tree_all_finite',
@@ -103,6 +105,7 @@ class NonFiniteSentinel:
         self._calls += 1
         if self._calls % self.check_every != 0:
             return False
+        tracing.count('task.sentinel_polls')
         counts = jax.device_get(sentinel_state)
         consecutive, total = int(counts[0]), int(counts[1])
         newly_bad = total - self.total

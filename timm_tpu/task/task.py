@@ -54,6 +54,7 @@ from ..resilience import (
     NonFiniteSentinel, guard_enabled, new_sentinel_state, tree_all_finite,
     update_sentinel_state,
 )
+from ..utils import tracing
 from ..utils.clip_grad import dispatch_clip_grad, global_grad_norm
 from ..utils.model_ema import ModelEmaV3, ema_update
 from ..utils.serialization import flatten_pytree, unflatten_into
@@ -379,28 +380,35 @@ class TrainingTask:
     # -- public step API -------------------------------------------------------
     def train_step(self, batch: Dict[str, Any], lr: float, step: int = 0):
         """One optimization step; `batch['input']` is NHWC, batch dim sharded
-        over the mesh (use parallel.shard_batch)."""
-        if self._train_step is None:
-            self._train_step = self._build_train_step()
-        self.model.train()
-        _, params, rest = self._split_model()
-        ema_decay = self.ema.get_decay(step) if self.ema is not None else 0.0
-        ema_in = self.ema_params if self.ema_params is not None else ()
-        sent_in = self._sentinel_state if self._sentinel_state is not None else ()
-        params, rest, self.opt_state, ema_out, sent_out, metrics = self._train_step(
-            params, rest, self.opt_state, ema_in, sent_in, batch,
-            jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32))
-        nnx.update(self.model, params, rest)
-        if self.ema_params is not None:
-            self.ema_params = ema_out
-        if self._sentinel_state is not None:
-            self._sentinel_state = sent_out
-            metrics['nonfinite_count'] = sent_out[0]
-            metrics['nonfinite_total'] = sent_out[1]
-            if self.sentinel is not None:
+        over the mesh (use parallel.shard_batch). The spans split the call's
+        host time by part (utils/tracing.py; PERF.md section 3)."""
+        with tracing.span('task.train_step'):
+            if self._train_step is None:
+                self._train_step = self._build_train_step()
+            with tracing.span('task.state_split'):
+                self.model.train()
+                _, params, rest = self._split_model()
+            ema_decay = self.ema.get_decay(step) if self.ema is not None else 0.0
+            ema_in = self.ema_params if self.ema_params is not None else ()
+            sent_in = self._sentinel_state if self._sentinel_state is not None else ()
+            with tracing.span('task.scalars_put'):
+                lr_in, ema_decay_in = jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32)
+            with tracing.span('task.step_call'):
+                params, rest, self.opt_state, ema_out, sent_out, metrics = self._train_step(
+                    params, rest, self.opt_state, ema_in, sent_in, batch, lr_in, ema_decay_in)
+            with tracing.span('task.state_update'):
+                nnx.update(self.model, params, rest)
+                if self.ema_params is not None:
+                    self.ema_params = ema_out
+                if self._sentinel_state is not None:
+                    self._sentinel_state = sent_out
+                    metrics['nonfinite_count'] = sent_out[0]
+                    metrics['nonfinite_total'] = sent_out[1]
+            if self._sentinel_state is not None and self.sentinel is not None:
                 # polls the device counters (every TIMM_TPU_NONFINITE_CHECK_EVERY
                 # steps) and raises NonFiniteError after K consecutive bad steps
-                self.sentinel.observe(sent_out, step=step)
+                with tracing.span('task.sentinel_poll'):
+                    self.sentinel.observe(sent_out, step=step)
         return metrics
 
     def trace_train_step(self, batch: Dict[str, Any], lr: float = 0.1, step: int = 0):

@@ -1,0 +1,206 @@
+"""The program's one tracing mechanism: host spans and counters at the
+boundaries of the training path, always on, in one bounded ring.
+
+`span(name, step=...)` records wall and thread-CPU time of a block, the span
+that encloses it and the step it belongs to, and enters a
+`jax.profiler.TraceAnnotation` for the same interval: whenever a profiler
+session runs, the span is in the trace's host plane, on the clock the device
+planes use. `count`, `busy` and `gauge` keep integers and sampled values at the
+same boundaries; every program XLA builds is recorded as an
+`xla.backend_compile` span under whatever span was open when it was built.
+
+Nothing is written out and nothing switches it off: readers (`train.py`'s log
+line, `benchmarks/harness/program_spans.py`, tests) take `snapshot()` or
+`summary()`. Every name is declared in `SPANS`; `PERF.md` section 3 says which
+metric reads which.
+"""
+from __future__ import annotations
+
+import collections
+import itertools
+import statistics
+import threading
+import time
+from typing import NamedTuple, Optional
+
+import jax
+
+RING = 32768          # spans kept: set-up plus minutes of steps at ~15 spans a step
+SERIES = 4096         # samples kept per gauge, and counter marks (one per step root)
+
+# name -> (layer as PERF.md section 3 has it, what it covers)
+SPANS = {
+    'train.step': ('entry and compile cache', 'one pass of the loop for one update: fetch, place, step, bookkeeping'),
+    'train.loader_next': ('input', "the loop's next() on the loader: all the main thread does to get a batch"),
+    'train.batch_to_device': ('input', 'jnp.asarray + shard_batch in the loop (a no-op for device batches)'),
+    'train.bookkeeping': ('entry and compile cache', 'scheduler update, recovery-interval check, fault and shutdown poll'),
+    'train.log_sync': ('entry and compile cache', "float(metrics['loss']) at --log-interval: a deliberate sync"),
+    'setup.model_build': ('entry and compile cache', 'create_model, sharded init included'),
+    'setup.task_build': ('entry and compile cache', 'optimizer, task, loss, EMA'),
+    'setup.data_build': ('entry and compile cache', 'datasets and both loaders'),
+    'xla.backend_compile': ('entry and compile cache', 'one program built or read from the cache (jax.monitoring)'),
+    'loader.batch_wait': ('input', "main thread blocked on the collator's queue"),
+    'loader.batch_q_depth': ('input', 'gauge: collated batches waiting when the main thread asks; 0 = loader behind'),
+    'loader.h2d': ('input', 'host-to-device put of the next uint8 batch'),
+    'loader.sample_params': ('input', 'erase / mixup parameter draws, numpy on the main thread'),
+    'loader.augment_call': ('input', 'placing the parameters and dispatching the augment program'),
+    'loader.samples': ('input', 'counter: samples read, decoded and transformed by the worker threads'),
+    'loader.decode_busy_ns': ('input', 'counter: wall ns the worker threads spent on those samples'),
+    'loader.batches': ('input', 'counter: batches the collator thread handed over'),
+    'task.train_step': ('step', 'the whole TrainingTask.train_step call'),
+    'task.state_split': ('step', 'model.train() + nnx.split of the live model'),
+    'task.scalars_put': ('step', 'the two jnp.asarray scalar transfers (lr, ema decay)'),
+    'task.step_call': ('step', 'the jitted call: flatten, dispatch, any wait; first call also trace + compile'),
+    'task.state_update': ('step', 'nnx.update + EMA / sentinel state written back'),
+    'task.sentinel_poll': ('step', "sentinel.observe(): the device_get of the step's counters"),
+    'task.sentinel_polls': ('step', 'counter: observe() calls that read the device'),
+}
+
+
+class Span(NamedTuple):
+    id: int
+    parent: int               # id of the enclosing span on the same thread, 0 for none
+    name: str
+    thread: int
+    step: Optional[int]
+    start_ns: int             # time.perf_counter_ns
+    end_ns: int
+    cpu_start_ns: int         # time.thread_time_ns
+    cpu_end_ns: int
+    failed: bool              # an exception passed through
+
+
+_ring: collections.deque = collections.deque(maxlen=RING)
+_ids = itertools.count(1)
+_local = threading.local()
+_lock = threading.Lock()
+_counters: dict = {}
+_marks: collections.deque = collections.deque(maxlen=SERIES)   # (end_ns of a step root, the counters then)
+_gauges: dict = {}
+
+
+now_ns = time.perf_counter_ns   # the ring's clock, for a reader's `since_ns`
+
+
+def _stack() -> list:
+    try:
+        return _local.stack
+    except AttributeError:
+        _local.stack = []
+        return _local.stack
+
+
+def _known(name: str) -> str:
+    if name not in SPANS:
+        raise KeyError(f'{name!r} is not declared in tracing.SPANS')
+    return name
+
+
+class span:
+    """Context manager: one record in the ring when the block ends (also when
+    it raises) and one `TraceAnnotation` around it. `step=` makes this span the
+    root of a step: spans opened inside inherit it, and the counters' totals
+    are marked when it ends, so that a reader can take their change over any
+    run of steps."""
+    __slots__ = ('name', 'step', 'root', 'id', 'parent', 'stack', 'start_ns', 'cpu_start_ns', 'annotation')
+
+    def __init__(self, name: str, **ids):
+        self.name = _known(name)
+        self.step = ids.get('step')
+        self.root = self.step is not None
+        self.annotation = jax.profiler.TraceAnnotation(name, **ids)
+
+    def __enter__(self):
+        stack = self.stack = _stack()
+        self.parent, inherited = stack[-1] if stack else (0, None)
+        if self.step is None:
+            self.step = inherited
+        self.id = next(_ids)
+        stack.append((self.id, self.step))
+        self.annotation.__enter__()
+        self.cpu_start_ns = time.thread_time_ns()
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        end_ns = time.perf_counter_ns()
+        cpu_end_ns = time.thread_time_ns()
+        self.annotation.__exit__(exc_type, exc, tb)
+        self.stack.pop()
+        _ring.append(tuple.__new__(Span, (  # the generated Span.__new__ costs twice this
+            self.id, self.parent, self.name, threading.get_ident(), self.step,
+            self.start_ns, end_ns, self.cpu_start_ns, cpu_end_ns, exc_type is not None)))
+        if self.root:
+            with _lock:
+                _marks.append((end_ns, dict(_counters)))
+        return False
+
+
+def count(name: str, n: int = 1) -> None:
+    _known(name)
+    with _lock:
+        _counters[name] = _counters.get(name, 0) + n
+
+
+class busy:
+    """Context manager: adds the wall nanoseconds of the block to counter
+    `name`. No ring record: this is what a loader worker may do per sample."""
+    __slots__ = ('name', 'start_ns')
+
+    def __init__(self, name: str):
+        self.name = _known(name)
+
+    def __enter__(self):
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        count(self.name, time.perf_counter_ns() - self.start_ns)
+        return False
+
+
+def gauge(name: str, value) -> None:
+    _known(name)
+    with _lock:
+        series = _gauges.get(name)
+        if series is None:
+            series = _gauges[name] = collections.deque(maxlen=SERIES)
+    series.append((time.perf_counter_ns(), value))
+
+
+def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
+    """Every program JAX builds, compiled afresh or read from the persistent
+    cache, reports this event once (`utils/compile_cache.py`), on the thread
+    that asked for it, as it ends."""
+    if event != '/jax/core/compile/backend_compile_duration':
+        return
+    end_ns = time.perf_counter_ns()
+    cpu_ns = time.thread_time_ns()
+    stack = _stack()
+    parent, step = stack[-1] if stack else (0, None)
+    _ring.append(Span(next(_ids), parent, 'xla.backend_compile', threading.get_ident(), step,
+                      end_ns - int(duration_secs * 1e9), end_ns, cpu_ns, cpu_ns, False))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def snapshot() -> dict:
+    """The ring's spans (oldest first), the counters, their marks at the end
+    of each step root, and each gauge's (perf_counter_ns, value) series, copied."""
+    with _lock:
+        return {'spans': list(_ring), 'counters': dict(_counters), 'marks': list(_marks),
+                'gauges': {k: list(v) for k, v in _gauges.items()}}
+
+
+def summary(since_ns: int = 0, spans=None) -> dict:
+    """name -> n, median and sum of wall ms and of thread-CPU ms, over the
+    ring's spans (or `spans`) that started at `since_ns` or later."""
+    by_name: dict = {}
+    for s in (list(_ring) if spans is None else spans):
+        if s.start_ns >= since_ns:
+            by_name.setdefault(s.name, []).append(((s.end_ns - s.start_ns) / 1e6, (s.cpu_end_ns - s.cpu_start_ns) / 1e6))
+    return {name: {'n': len(rows),
+                   'wall_ms_median': statistics.median(w for w, _ in rows), 'wall_ms_sum': sum(w for w, _ in rows),
+                   'cpu_ms_median': statistics.median(c for _, c in rows), 'cpu_ms_sum': sum(c for _, c in rows)}
+            for name, rows in by_name.items()}

@@ -386,7 +386,7 @@ def main(argv=None):
     from timm_tpu.task import ClassificationTask
     from timm_tpu.utils import (
         AverageMeter, CheckpointSaver, accuracy, get_outdir, random_seed,
-        setup_default_logging, update_summary,
+        setup_default_logging, tracing, update_summary,
     )
 
     from timm_tpu.resilience import (
@@ -477,16 +477,17 @@ def main(argv=None):
                     raise
         return create_model(args.model, **factory_kwargs, **model_kwargs)
 
-    if 'fsdp' in mesh.axis_names or 'model' in mesh.axis_names:
-        # abstract init: nnx.eval_shape resolves the partition rules against
-        # the abstract param shapes and a jitted constructor materializes each
-        # shard on its owning devices — a replicated full-model copy never
-        # exists (falls back to eager build + reshard for non-traceable
-        # constructors, e.g. pretrained-weight loading)
-        from timm_tpu.parallel import create_sharded_model
-        model = create_sharded_model(_build_model, mesh)
-    else:
-        model = _build_model()
+    with tracing.span('setup.model_build'):
+        if 'fsdp' in mesh.axis_names or 'model' in mesh.axis_names:
+            # abstract init: nnx.eval_shape resolves the partition rules against
+            # the abstract param shapes and a jitted constructor materializes each
+            # shard on its owning devices — a replicated full-model copy never
+            # exists (falls back to eager build + reshard for non-traceable
+            # constructors, e.g. pretrained-weight loading)
+            from timm_tpu.parallel import create_sharded_model
+            model = create_sharded_model(_build_model, mesh)
+        else:
+            model = _build_model()
     if args.num_classes is None:
         args.num_classes = model.num_classes
     if args.grad_checkpointing:
@@ -553,229 +554,231 @@ def main(argv=None):
             + (f"T={distill['temperature']}" if distill['kind'] == 'logit'
                else f"feat_loss={distill['feat_loss']}") + ')')
 
-    optimizer = create_optimizer_v2(model, **optimizer_kwargs(args))
-    norm_mean = data_config['mean']
-    norm_std = data_config['std']
-    if args.naflex_loader:
-        from timm_tpu.task import NaFlexClassificationTask
-        task_cls = NaFlexClassificationTask
-        # NaFlex batches are normalized host-side by the loader
-        norm_mean = norm_std = None
-    else:
-        task_cls = ClassificationTask
-    if distill is not None:
-        task_cls = (LogitDistillationTask if distill['kind'] == 'logit'
-                    else FeatureDistillationTask)
-    if args.device_augment:
-        if args.grad_accum_steps != 1:
-            raise ValueError(
-                '--device-augment yields device-resident batches; the host-side '
-                'micro-batch concatenation of --grad-accum-steps > 1 would bounce '
-                'them back to host. Use --grad-accum-steps 1')
-        if num_aug_splits > 1:
-            raise ValueError('--device-augment does not compose with --aug-splits '
-                             '(split-batch augmentation collates on host)')
-        if not args.naflex_loader and (args.synthetic_data or not args.data_dir):
-            raise ValueError('--device-augment needs a real dataset pipeline; '
-                             'pass --data-dir (synthetic batches are already device floats)')
-        # the on-device augment stage normalizes; the task must not re-normalize
-        norm_mean = norm_std = None
-    task_kwargs = {}
-    if args.naflex_loader and (args.mixup > 0 or args.cutmix > 0):
-        # smoothing folds into the soft mixed targets (reference mixup_target)
-        task_kwargs['mixup_label_smoothing'] = args.smoothing
-    if distill is not None:
-        task_kwargs['teacher'] = teacher
-        task_kwargs['distill_alpha'] = distill['alpha']
-        if distill['kind'] == 'logit':
-            task_kwargs['distill_temperature'] = distill['temperature']
+    with tracing.span('setup.task_build'):
+        optimizer = create_optimizer_v2(model, **optimizer_kwargs(args))
+        norm_mean = data_config['mean']
+        norm_std = data_config['std']
+        if args.naflex_loader:
+            from timm_tpu.task import NaFlexClassificationTask
+            task_cls = NaFlexClassificationTask
+            # NaFlex batches are normalized host-side by the loader
+            norm_mean = norm_std = None
         else:
-            task_kwargs['feat_loss'] = distill['feat_loss']
-    task = task_cls(
-        model,
-        optimizer=optimizer,
-        mesh=mesh,
-        grad_accum_steps=args.grad_accum_steps,
-        clip_grad=args.clip_grad,
-        clip_mode=args.clip_mode,
-        mean=norm_mean,
-        std=norm_std,
-        nonfinite_guard=False if args.no_nonfinite_guard else None,
-        nonfinite_tolerance=args.nonfinite_tolerance,
-        fused_update=args.fused_update,
-        **task_kwargs,
-    )
+            task_cls = ClassificationTask
+        if distill is not None:
+            task_cls = (LogitDistillationTask if distill['kind'] == 'logit'
+                        else FeatureDistillationTask)
+        if args.device_augment:
+            if args.grad_accum_steps != 1:
+                raise ValueError(
+                    '--device-augment yields device-resident batches; the host-side '
+                    'micro-batch concatenation of --grad-accum-steps > 1 would bounce '
+                    'them back to host. Use --grad-accum-steps 1')
+            if num_aug_splits > 1:
+                raise ValueError('--device-augment does not compose with --aug-splits '
+                                 '(split-batch augmentation collates on host)')
+            if not args.naflex_loader and (args.synthetic_data or not args.data_dir):
+                raise ValueError('--device-augment needs a real dataset pipeline; '
+                                 'pass --data-dir (synthetic batches are already device floats)')
+            # the on-device augment stage normalizes; the task must not re-normalize
+            norm_mean = norm_std = None
+        task_kwargs = {}
+        if args.naflex_loader and (args.mixup > 0 or args.cutmix > 0):
+            # smoothing folds into the soft mixed targets (reference mixup_target)
+            task_kwargs['mixup_label_smoothing'] = args.smoothing
+        if distill is not None:
+            task_kwargs['teacher'] = teacher
+            task_kwargs['distill_alpha'] = distill['alpha']
+            if distill['kind'] == 'logit':
+                task_kwargs['distill_temperature'] = distill['temperature']
+            else:
+                task_kwargs['feat_loss'] = distill['feat_loss']
+        task = task_cls(
+            model,
+            optimizer=optimizer,
+            mesh=mesh,
+            grad_accum_steps=args.grad_accum_steps,
+            clip_grad=args.clip_grad,
+            clip_mode=args.clip_mode,
+            mean=norm_mean,
+            std=norm_std,
+            nonfinite_guard=False if args.no_nonfinite_guard else None,
+            nonfinite_tolerance=args.nonfinite_tolerance,
+            fused_update=args.fused_update,
+            **task_kwargs,
+        )
 
-    if 'fsdp' in mesh.axis_names or 'model' in mesh.axis_names:
-        from flax import nnx
-        from timm_tpu.parallel import activation_bytes_per_device, param_bytes_per_device
-        rep_b, shard_b = param_bytes_per_device(nnx.state(model, nnx.Param), mesh)
-        axes_str = ' x '.join(f'{a}={mesh.shape[a]}' for a in mesh.axis_names)
-        _logger.info(
-            f'Sharded mesh ({axes_str}): params per device '
-            f'{shard_b / 1e6:.1f} MB (vs {rep_b / 1e6:.1f} MB replicated); optimizer '
-            f'm/v shard identically (parallel/sharding.py rules)')
-        width = getattr(model, 'embed_dim', None)
-        depth = len(getattr(model, 'blocks', None) or ())
-        seq_len = getattr(getattr(model, 'patch_embed', None), 'num_patches', None)
-        if width and depth and seq_len:
-            act_u, act_c = activation_bytes_per_device(
-                mesh, batch_size=args.batch_size, seq_len=seq_len, width=width, depth=depth)
+        if 'fsdp' in mesh.axis_names or 'model' in mesh.axis_names:
+            from flax import nnx
+            from timm_tpu.parallel import activation_bytes_per_device, param_bytes_per_device
+            rep_b, shard_b = param_bytes_per_device(nnx.state(model, nnx.Param), mesh)
+            axes_str = ' x '.join(f'{a}={mesh.shape[a]}' for a in mesh.axis_names)
             _logger.info(
-                f'Estimated block activations per device: {act_c / 1e6:.1f} MB with '
-                f'activation sharding constraints (vs {act_u / 1e6:.1f} MB without)')
+                f'Sharded mesh ({axes_str}): params per device '
+                f'{shard_b / 1e6:.1f} MB (vs {rep_b / 1e6:.1f} MB replicated); optimizer '
+                f'm/v shard identically (parallel/sharding.py rules)')
+            width = getattr(model, 'embed_dim', None)
+            depth = len(getattr(model, 'blocks', None) or ())
+            seq_len = getattr(getattr(model, 'patch_embed', None), 'num_patches', None)
+            if width and depth and seq_len:
+                act_u, act_c = activation_bytes_per_device(
+                    mesh, batch_size=args.batch_size, seq_len=seq_len, width=width, depth=depth)
+                _logger.info(
+                    f'Estimated block activations per device: {act_c / 1e6:.1f} MB with '
+                    f'activation sharding constraints (vs {act_u / 1e6:.1f} MB without)')
 
-    # loss selection (ref train.py:886-913)
-    if args.jsd_loss:
-        assert num_aug_splits > 1, '--jsd-loss requires --aug-splits > 1'
-        from timm_tpu.loss import JsdCrossEntropy
-        train_loss = JsdCrossEntropy(num_splits=num_aug_splits, smoothing=args.smoothing)
-    elif args.mixup > 0 or args.cutmix > 0:
-        train_loss = BinaryCrossEntropy(
-            smoothing=0.0, target_threshold=args.bce_target_thresh, sum_classes=args.bce_sum,
-        ) if args.bce_loss else SoftTargetCrossEntropy()
-    elif args.smoothing:
-        train_loss = BinaryCrossEntropy(
-            smoothing=args.smoothing, target_threshold=args.bce_target_thresh, sum_classes=args.bce_sum,
-        ) if args.bce_loss else LabelSmoothingCrossEntropy(smoothing=args.smoothing)
-    else:
-        train_loss = LabelSmoothingCrossEntropy(0.0)
-    task.train_loss_fn = train_loss
+        # loss selection (ref train.py:886-913)
+        if args.jsd_loss:
+            assert num_aug_splits > 1, '--jsd-loss requires --aug-splits > 1'
+            from timm_tpu.loss import JsdCrossEntropy
+            train_loss = JsdCrossEntropy(num_splits=num_aug_splits, smoothing=args.smoothing)
+        elif args.mixup > 0 or args.cutmix > 0:
+            train_loss = BinaryCrossEntropy(
+                smoothing=0.0, target_threshold=args.bce_target_thresh, sum_classes=args.bce_sum,
+            ) if args.bce_loss else SoftTargetCrossEntropy()
+        elif args.smoothing:
+            train_loss = BinaryCrossEntropy(
+                smoothing=args.smoothing, target_threshold=args.bce_target_thresh, sum_classes=args.bce_sum,
+            ) if args.bce_loss else LabelSmoothingCrossEntropy(smoothing=args.smoothing)
+        else:
+            train_loss = LabelSmoothingCrossEntropy(0.0)
+        task.train_loss_fn = train_loss
 
-    if args.model_ema:
-        task.setup_ema(decay=args.model_ema_decay, warmup=args.model_ema_warmup)
+        if args.model_ema:
+            task.setup_ema(decay=args.model_ema_decay, warmup=args.model_ema_warmup)
 
     # data
-    if args.naflex_loader:
-        if not args.data_dir:
-            raise ValueError('--naflex-loader requires --data-dir')
-        from timm_tpu.data import create_dataset
-        from timm_tpu.data.naflex_loader import create_naflex_loader
-        patch_size = getattr(model.embeds, 'patch_size', 16) if hasattr(model, 'embeds') else 16
-        dataset_train = create_dataset(
-            args.dataset, root=args.data_dir, split=args.train_split, is_training=True,
-            class_map=args.class_map)
-        dataset_eval = create_dataset(
-            args.dataset, root=args.data_dir, split=args.val_split, class_map=args.class_map)
-        loader_train = create_naflex_loader(
-            dataset_train, patch_size=patch_size,
-            patch_size_choices=tuple(args.naflex_patch_sizes) if args.naflex_patch_sizes else None,
-            train_seq_lens=tuple(args.naflex_train_seq_lens),
-            max_seq_len=args.naflex_max_seq_len,
-            batch_size=args.batch_size, is_training=True,
-            mean=data_config['mean'], std=data_config['std'],
-            interpolation=data_config['interpolation'], hflip=args.hflip,
-            mixup_alpha=args.mixup, cutmix_alpha=args.cutmix,
-            mixup_prob=args.mixup_prob, mixup_switch_prob=args.mixup_switch_prob,
-            re_prob=args.reprob, re_mode='pixel' if args.remode == 'pixel' else 'const',
-            seed=args.seed, grad_accum_steps=args.grad_accum_steps,
-            device_augment=args.device_augment,
-            bucket_mode=args.naflex_bucket_mode,
-            device_prefetch=args.device_prefetch if args.device_augment else 0)
-        loader_eval = create_naflex_loader(
-            dataset_eval, patch_size=patch_size,
-            max_seq_len=args.naflex_max_seq_len,
-            batch_size=args.validation_batch_size or args.batch_size,
-            mean=data_config['mean'], std=data_config['std'],
-            interpolation=data_config['interpolation'], seed=args.seed)
-        mixup_fn = None
-    elif args.synthetic_data or not args.data_dir:
-        _logger.info('Using synthetic data')
-        loader_train = SyntheticLoader(args.synthetic_len, args.batch_size, img_size,
-                                       args.num_classes, args.seed,
-                                       process_index=rank, process_count=world_size)
-        loader_eval = SyntheticLoader(max(args.synthetic_len // 4, args.batch_size),
-                                      args.validation_batch_size or args.batch_size,
-                                      img_size, args.num_classes, args.seed + 1,
-                                      process_index=rank, process_count=world_size)
-        mixup_fn = 'auto'
-    else:
-        from timm_tpu.data import create_dataset, create_loader
-        dataset_train = create_dataset(
-            args.dataset, root=args.data_dir, split=args.train_split, is_training=True,
-            class_map=args.class_map, num_classes=args.num_classes)
-        dataset_eval = create_dataset(
-            args.dataset, root=args.data_dir, split=args.val_split, is_training=False,
-            class_map=args.class_map, num_classes=args.num_classes)
-        if num_aug_splits > 1:
-            if not hasattr(dataset_train, '__getitem__'):
-                raise ValueError(
-                    '--aug-splits requires a map-style dataset (folder/tar/hfds); '
-                    'streaming schemes (wds/tfds/hfids) are not supported')
-            from timm_tpu.data.dataset import AugMixDataset
-            dataset_train = AugMixDataset(dataset_train, num_splits=num_aug_splits)
-        train_mixup = None
-        if args.device_augment and (args.mixup > 0 or args.cutmix > 0):
-            # parameter sampler only — the pixel/target math runs in the
-            # loader's jitted on-device program (data/device_augment.py)
-            from timm_tpu.data.mixup import Mixup
-            train_mixup = Mixup(
-                mixup_alpha=args.mixup, cutmix_alpha=args.cutmix, cutmix_minmax=args.cutmix_minmax,
-                prob=args.mixup_prob, switch_prob=args.mixup_switch_prob, mode=args.mixup_mode,
-                label_smoothing=args.smoothing, num_classes=args.num_classes, seed=args.seed)
-        loader_train = create_loader(
-            dataset_train,
-            input_size=data_config['input_size'],
-            batch_size=args.batch_size,
-            is_training=True,
-            no_aug=args.no_aug,
-            scale=args.scale,
-            ratio=args.ratio,
-            hflip=args.hflip,
-            vflip=args.vflip,
-            color_jitter=args.color_jitter,
-            auto_augment=args.aa,
-            re_prob=args.reprob,
-            re_mode=args.remode,
-            re_count=args.recount,
-            num_aug_splits=num_aug_splits,
-            interpolation=args.train_interpolation,
-            mean=data_config['mean'],
-            std=data_config['std'],
-            num_workers=args.workers,
-            seed=args.seed,
-            device_augment=args.device_augment,
-            mixup=train_mixup,
-            device_prefetch=args.device_prefetch if args.device_augment else 0,
-        )
-        loader_eval = create_loader(
-            dataset_eval,
-            input_size=data_config['input_size'],
-            batch_size=args.validation_batch_size or args.batch_size,
-            is_training=False,
-            interpolation=data_config['interpolation'],
-            mean=data_config['mean'],
-            std=data_config['std'],
-            num_workers=args.workers,
-            crop_pct=data_config['crop_pct'],
-        )
-        # device_augment folds mixup into the loader's on-device program
-        mixup_fn = None if args.device_augment else 'auto'
-
-    # mixup applies to any (input, target)-tuple loader; naflex handles its own
-    if mixup_fn == 'auto':
-        from timm_tpu.data.mixup import Mixup
-        mixup_fn = None
-        if args.mixup > 0 or args.cutmix > 0:
-            mixup_fn = Mixup(
-                mixup_alpha=args.mixup, cutmix_alpha=args.cutmix, cutmix_minmax=args.cutmix_minmax,
-                prob=args.mixup_prob, switch_prob=args.mixup_switch_prob, mode=args.mixup_mode,
-                label_smoothing=args.smoothing, num_classes=args.num_classes)
-
-    if args.device_prefetch:
-        from timm_tpu.data.loader import DevicePrefetcher
-        loader_eval = DevicePrefetcher(loader_eval, size=args.device_prefetch)
-        if args.device_augment:
-            # create_loader / create_naflex_loader already prefetch inside
-            # the device-augment stack; batches here are device-resident
-            pass
-        elif mixup_fn is None and args.grad_accum_steps == 1:
-            loader_train = DevicePrefetcher(loader_train, size=args.device_prefetch)
+    with tracing.span('setup.data_build'):
+        if args.naflex_loader:
+            if not args.data_dir:
+                raise ValueError('--naflex-loader requires --data-dir')
+            from timm_tpu.data import create_dataset
+            from timm_tpu.data.naflex_loader import create_naflex_loader
+            patch_size = getattr(model.embeds, 'patch_size', 16) if hasattr(model, 'embeds') else 16
+            dataset_train = create_dataset(
+                args.dataset, root=args.data_dir, split=args.train_split, is_training=True,
+                class_map=args.class_map)
+            dataset_eval = create_dataset(
+                args.dataset, root=args.data_dir, split=args.val_split, class_map=args.class_map)
+            loader_train = create_naflex_loader(
+                dataset_train, patch_size=patch_size,
+                patch_size_choices=tuple(args.naflex_patch_sizes) if args.naflex_patch_sizes else None,
+                train_seq_lens=tuple(args.naflex_train_seq_lens),
+                max_seq_len=args.naflex_max_seq_len,
+                batch_size=args.batch_size, is_training=True,
+                mean=data_config['mean'], std=data_config['std'],
+                interpolation=data_config['interpolation'], hflip=args.hflip,
+                mixup_alpha=args.mixup, cutmix_alpha=args.cutmix,
+                mixup_prob=args.mixup_prob, mixup_switch_prob=args.mixup_switch_prob,
+                re_prob=args.reprob, re_mode='pixel' if args.remode == 'pixel' else 'const',
+                seed=args.seed, grad_accum_steps=args.grad_accum_steps,
+                device_augment=args.device_augment,
+                bucket_mode=args.naflex_bucket_mode,
+                device_prefetch=args.device_prefetch if args.device_augment else 0)
+            loader_eval = create_naflex_loader(
+                dataset_eval, patch_size=patch_size,
+                max_seq_len=args.naflex_max_seq_len,
+                batch_size=args.validation_batch_size or args.batch_size,
+                mean=data_config['mean'], std=data_config['std'],
+                interpolation=data_config['interpolation'], seed=args.seed)
+            mixup_fn = None
+        elif args.synthetic_data or not args.data_dir:
+            _logger.info('Using synthetic data')
+            loader_train = SyntheticLoader(args.synthetic_len, args.batch_size, img_size,
+                                           args.num_classes, args.seed,
+                                           process_index=rank, process_count=world_size)
+            loader_eval = SyntheticLoader(max(args.synthetic_len // 4, args.batch_size),
+                                          args.validation_batch_size or args.batch_size,
+                                          img_size, args.num_classes, args.seed + 1,
+                                          process_index=rank, process_count=world_size)
+            mixup_fn = 'auto'
         else:
-            # mixup / grad-accum concatenation still mutate batches on host;
-            # prefetching to device first would bounce them straight back
-            _logger.info('--device-prefetch: train loader stays on host '
-                         '(mixup or --grad-accum-steps > 1 active); eval loader prefetches')
+            from timm_tpu.data import create_dataset, create_loader
+            dataset_train = create_dataset(
+                args.dataset, root=args.data_dir, split=args.train_split, is_training=True,
+                class_map=args.class_map, num_classes=args.num_classes)
+            dataset_eval = create_dataset(
+                args.dataset, root=args.data_dir, split=args.val_split, is_training=False,
+                class_map=args.class_map, num_classes=args.num_classes)
+            if num_aug_splits > 1:
+                if not hasattr(dataset_train, '__getitem__'):
+                    raise ValueError(
+                        '--aug-splits requires a map-style dataset (folder/tar/hfds); '
+                        'streaming schemes (wds/tfds/hfids) are not supported')
+                from timm_tpu.data.dataset import AugMixDataset
+                dataset_train = AugMixDataset(dataset_train, num_splits=num_aug_splits)
+            train_mixup = None
+            if args.device_augment and (args.mixup > 0 or args.cutmix > 0):
+                # parameter sampler only — the pixel/target math runs in the
+                # loader's jitted on-device program (data/device_augment.py)
+                from timm_tpu.data.mixup import Mixup
+                train_mixup = Mixup(
+                    mixup_alpha=args.mixup, cutmix_alpha=args.cutmix, cutmix_minmax=args.cutmix_minmax,
+                    prob=args.mixup_prob, switch_prob=args.mixup_switch_prob, mode=args.mixup_mode,
+                    label_smoothing=args.smoothing, num_classes=args.num_classes, seed=args.seed)
+            loader_train = create_loader(
+                dataset_train,
+                input_size=data_config['input_size'],
+                batch_size=args.batch_size,
+                is_training=True,
+                no_aug=args.no_aug,
+                scale=args.scale,
+                ratio=args.ratio,
+                hflip=args.hflip,
+                vflip=args.vflip,
+                color_jitter=args.color_jitter,
+                auto_augment=args.aa,
+                re_prob=args.reprob,
+                re_mode=args.remode,
+                re_count=args.recount,
+                num_aug_splits=num_aug_splits,
+                interpolation=args.train_interpolation,
+                mean=data_config['mean'],
+                std=data_config['std'],
+                num_workers=args.workers,
+                seed=args.seed,
+                device_augment=args.device_augment,
+                mixup=train_mixup,
+                device_prefetch=args.device_prefetch if args.device_augment else 0,
+            )
+            loader_eval = create_loader(
+                dataset_eval,
+                input_size=data_config['input_size'],
+                batch_size=args.validation_batch_size or args.batch_size,
+                is_training=False,
+                interpolation=data_config['interpolation'],
+                mean=data_config['mean'],
+                std=data_config['std'],
+                num_workers=args.workers,
+                crop_pct=data_config['crop_pct'],
+            )
+            # device_augment folds mixup into the loader's on-device program
+            mixup_fn = None if args.device_augment else 'auto'
+
+        # mixup applies to any (input, target)-tuple loader; naflex handles its own
+        if mixup_fn == 'auto':
+            from timm_tpu.data.mixup import Mixup
+            mixup_fn = None
+            if args.mixup > 0 or args.cutmix > 0:
+                mixup_fn = Mixup(
+                    mixup_alpha=args.mixup, cutmix_alpha=args.cutmix, cutmix_minmax=args.cutmix_minmax,
+                    prob=args.mixup_prob, switch_prob=args.mixup_switch_prob, mode=args.mixup_mode,
+                    label_smoothing=args.smoothing, num_classes=args.num_classes)
+
+        if args.device_prefetch:
+            from timm_tpu.data.loader import DevicePrefetcher
+            loader_eval = DevicePrefetcher(loader_eval, size=args.device_prefetch)
+            if args.device_augment:
+                # create_loader / create_naflex_loader already prefetch inside
+                # the device-augment stack; batches here are device-resident
+                pass
+            elif mixup_fn is None and args.grad_accum_steps == 1:
+                loader_train = DevicePrefetcher(loader_train, size=args.device_prefetch)
+            else:
+                # mixup / grad-accum concatenation still mutate batches on host;
+                # prefetching to device first would bounce them straight back
+                _logger.info('--device-prefetch: train loader stays on host '
+                             '(mixup or --grad-accum-steps > 1 active); eval loader prefetches')
 
     # scheduler
     try:
@@ -1015,11 +1018,71 @@ def _resilient_train_step(task, batch, lr, step, args, saver, rollback_budget):
         return None
 
 
+def _traced_batches(loader, num_updates):
+    """(batch index, batch) from `loader`, under one `train.step` root span per
+    update: the root opens before the fetch and stays open, over as many
+    fetches as the update takes (accumulation, resume skips, a rolled-back
+    step), until the loop body comes back with `num_updates()` advanced.
+    Closing the generator closes the open root."""
+    from timm_tpu.utils import tracing
+    batches = enumerate(loader)
+    while True:
+        step = num_updates()
+        with tracing.span('train.step', step=step):
+            while num_updates() == step:
+                with tracing.span('train.loader_next'):
+                    item = next(batches, None)
+                if item is None:
+                    return
+                yield item
+
+
+def _batch_to_device(arrays, mesh, shard_batch):
+    from timm_tpu.utils import tracing
+    with tracing.span('train.batch_to_device'):
+        return shard_batch({k: jnp.asarray(v) for k, v in arrays.items()}, mesh)
+
+
+def _host_line(since_ns, counters_before):
+    """The log line's host breakdown since the previous line (README
+    "Reading the host breakdown"): mean wall ms per update of each part of the
+    loop from the program's spans, and what the loader's threads did.
+    -> (text, the counters now)."""
+    from timm_tpu.utils import tracing
+    snap = tracing.snapshot()
+    rows = tracing.summary(since_ns, spans=snap['spans'])
+    steps = max(rows.get('task.train_step', {}).get('n', 0), 1)
+    ms = lambda *names: sum(rows[k]['wall_ms_sum'] for k in names if k in rows) / steps  # noqa: E731
+    did = {k: v - counters_before.get(k, 0) for k, v in snap['counters'].items()}
+    depths = [v for t, v in snap['gauges'].get('loader.batch_q_depth', ()) if t >= since_ns]
+    text = (f"host ms/step: next {ms('train.loader_next'):.1f} split {ms('task.state_split'):.1f} "
+            f"put {ms('task.scalars_put', 'train.batch_to_device'):.1f} call {ms('task.step_call'):.1f} "
+            f"update {ms('task.state_update'):.1f} poll {ms('task.sentinel_poll'):.1f} "
+            f"loop {ms('train.bookkeeping', 'train.log_sync'):.1f}")
+    if did.get('loader.samples') and did.get('loader.batches'):
+        text += (f" | loader q {sum(depths) / max(len(depths), 1):.1f} "
+                 f"decode {did['loader.decode_busy_ns'] / did['loader.samples'] / 1e6:.1f} ms/img "
+                 f"{did['loader.decode_busy_ns'] / did['loader.batches'] / 1e6:.0f} ms/batch "
+                 f"polls {did.get('task.sentinel_polls', 0)}")
+    return text, snap['counters']
+
+
+def _setup_line():
+    """Where set-up went, once, after the process's first step."""
+    from timm_tpu.utils import tracing
+    rows = tracing.summary()
+    s = lambda name: rows.get(name, {}).get('wall_ms_sum', 0.0) / 1e3  # noqa: E731
+    built = rows.get('xla.backend_compile', {})
+    return (f"setup s: model {s('setup.model_build'):.1f} task {s('setup.task_build'):.1f} "
+            f"data {s('setup.data_build'):.1f} first step {s('task.step_call'):.1f} "
+            f"compiles {built.get('n', 0)} ({s('xla.backend_compile'):.1f} s)")
+
+
 def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
                     updates_per_epoch, saver=None, mixup_fn=None, shutdown=None,
                     skip_batches=0, start_updates=None, rollback_budget=None):
     from timm_tpu.resilience import TrainingPreempted, get_fault_injector
-    from timm_tpu.utils import AverageMeter
+    from timm_tpu.utils import AverageMeter, tracing
     loss_m = AverageMeter()
     accum = args.grad_accum_steps
     num_updates = start_updates if start_updates is not None else epoch * updates_per_epoch
@@ -1061,87 +1124,81 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
     update_idx = skip_batches // accum  # display/recovery cadence continuity on resume
     samples_since_log = 0
     log_t0 = time.time()
-    for batch_idx, batch_data in enumerate(loader):
-        if batch_idx < skip_batches:
-            continue  # mid-epoch resume: already consumed before preemption
-        if isinstance(batch_data, dict):
-            # NaFlex dict batch; scalar metadata (seq_len/patch_size) stays on
-            # host — the model derives the patch size from the patch dim shape
-            n = batch_data['patches'].shape[0]
-            if injector is not None and injector.nan_at(num_updates):
-                _logger.warning(f'[fault-inject] NaN batch at update {num_updates}')
-                batch_data = dict(batch_data, patches=np.asarray(batch_data['patches']) * np.nan)
-            batch = shard_batch(
-                {k: jnp.asarray(v) for k, v in batch_data.items()
-                 if k not in ('seq_len', 'patch_size')}, mesh)
+    log_since_ns, ring = tracing.now_ns(), tracing.snapshot()
+    log_counters = ring['counters']
+    log_setup = not any(s.name == 'task.step_call' for s in ring['spans'])  # the process's first step is still to come
+    del ring
+    batches = _traced_batches(loader, lambda: num_updates)
+    try:
+        for batch_idx, batch_data in batches:
+            if batch_idx < skip_batches:
+                continue  # mid-epoch resume: already consumed before preemption
+            if isinstance(batch_data, dict):
+                # NaFlex dict batch; scalar metadata (seq_len/patch_size) stays on
+                # host — the model derives the patch size from the patch dim shape
+                n = batch_data['patches'].shape[0]
+                if injector is not None and injector.nan_at(num_updates):
+                    _logger.warning(f'[fault-inject] NaN batch at update {num_updates}')
+                    batch_data = dict(batch_data, patches=np.asarray(batch_data['patches']) * np.nan)
+                arrays = {k: v for k, v in batch_data.items() if k not in ('seq_len', 'patch_size')}
+                seq = f'seq: {batch_data["seq_len"]} '
+            else:
+                input_np, target_np = batch_data
+                if mixup_fn is not None:
+                    input_np, target_np = mixup_fn(input_np, target_np)
+                micro_inputs.append(input_np)
+                micro_targets.append(target_np)
+                if len(micro_inputs) < accum:
+                    continue  # accumulate across loader batches (ref train.py:1266-1281)
+                if accum > 1:
+                    input_all = np.concatenate(micro_inputs, axis=0)
+                    target_all = np.concatenate(micro_targets, axis=0)
+                else:
+                    input_all, target_all = micro_inputs[0], micro_targets[0]
+                micro_inputs, micro_targets = [], []
+                if injector is not None and injector.nan_at(num_updates):
+                    _logger.warning(f'[fault-inject] NaN batch at update {num_updates}')
+                    input_all = np.asarray(input_all) * np.nan
+                n = input_all.shape[0]
+                arrays = {'input': input_all, 'target': target_all}
+                seq = ''
+            batch = _batch_to_device(arrays, mesh, shard_batch)
             metrics = _resilient_train_step(task, batch, lr, num_updates, args, saver, rollback_budget)
             if metrics is None:
                 update_idx += 1
                 continue
             num_updates += 1
             samples_since_log += n
-            if lr_scheduler is not None:
-                lr = lr_scheduler.step_update(num_updates)[0]
+            if log_setup:
+                _logger.info(_setup_line())
+                log_setup = False
+            with tracing.span('train.bookkeeping'):
+                if lr_scheduler is not None:
+                    lr = lr_scheduler.step_update(num_updates)[0]
             if update_idx % args.log_interval == 0:
-                loss_val = float(metrics['loss'])
-                if np.isfinite(loss_val):
+                with tracing.span('train.log_sync'):
+                    loss_val = float(metrics['loss'])  # sync point
+                if np.isfinite(loss_val):  # a skipped non-finite step must not poison the meter
                     loss_m.update(loss_val, n=n)
                 elapsed = time.time() - log_t0
+                ips = samples_since_log / max(elapsed, 1e-9)
+                samples_since_log = 0
+                log_t0 = time.time()
+                nf = int(metrics['nonfinite_total']) if 'nonfinite_total' in metrics else 0
+                host, log_counters = _host_line(log_since_ns, log_counters)
+                log_since_ns = tracing.now_ns()
                 _logger.info(
                     f'Train: {epoch} [{update_idx:>4d}/{updates_per_epoch}] '
                     f'Loss: {loss_m.val:#.3g} ({loss_m.avg:#.3g}) LR: {lr:.3e} '
-                    f'seq: {batch_data["seq_len"]} {samples_since_log / max(elapsed, 1e-9):.1f} img/s')
-                samples_since_log = 0
-                log_t0 = time.time()
-            if saver is not None and args.recovery_interval and (update_idx + 1) % args.recovery_interval == 0:
-                saver.save_recovery(epoch, update_idx,
-                                    extra_state=_recovery_extras(batch_idx + 1, num_updates, args))
-            poll_faults_and_shutdown(batch_idx, update_idx)
+                    f'{seq}{ips:.1f} img/s' + (f' NaN-skipped: {nf}' if nf else '') + f' {host}')
+            with tracing.span('train.bookkeeping'):
+                if saver is not None and args.recovery_interval and (update_idx + 1) % args.recovery_interval == 0:
+                    saver.save_recovery(epoch, update_idx,
+                                        extra_state=_recovery_extras(batch_idx + 1, num_updates, args))
+                poll_faults_and_shutdown(batch_idx, update_idx)
             update_idx += 1
-            continue
-        input_np, target_np = batch_data
-        if mixup_fn is not None:
-            input_np, target_np = mixup_fn(input_np, target_np)
-        micro_inputs.append(input_np)
-        micro_targets.append(target_np)
-        if len(micro_inputs) < accum:
-            continue  # accumulate across loader batches (ref train.py:1266-1281)
-        if accum > 1:
-            input_all = np.concatenate(micro_inputs, axis=0)
-            target_all = np.concatenate(micro_targets, axis=0)
-        else:
-            input_all, target_all = micro_inputs[0], micro_targets[0]
-        micro_inputs, micro_targets = [], []
-        if injector is not None and injector.nan_at(num_updates):
-            _logger.warning(f'[fault-inject] NaN batch at update {num_updates}')
-            input_all = np.asarray(input_all) * np.nan
-        batch = shard_batch({'input': jnp.asarray(input_all), 'target': jnp.asarray(target_all)}, mesh)
-        metrics = _resilient_train_step(task, batch, lr, num_updates, args, saver, rollback_budget)
-        if metrics is None:
-            update_idx += 1
-            continue
-        num_updates += 1
-        samples_since_log += input_all.shape[0]
-        if lr_scheduler is not None:
-            lr = lr_scheduler.step_update(num_updates)[0]
-        if update_idx % args.log_interval == 0:
-            loss_val = float(metrics['loss'])  # sync point
-            if np.isfinite(loss_val):  # a skipped non-finite step must not poison the meter
-                loss_m.update(loss_val, n=input_all.shape[0])
-            elapsed = time.time() - log_t0
-            ips = samples_since_log / max(elapsed, 1e-9)
-            samples_since_log = 0
-            log_t0 = time.time()
-            nf = int(metrics['nonfinite_total']) if 'nonfinite_total' in metrics else 0
-            _logger.info(
-                f'Train: {epoch} [{update_idx:>4d}/{updates_per_epoch}] '
-                f'Loss: {loss_m.val:#.3g} ({loss_m.avg:#.3g}) LR: {lr:.3e} '
-                f'{ips:.1f} img/s' + (f' NaN-skipped: {nf}' if nf else ''))
-        if saver is not None and args.recovery_interval and (update_idx + 1) % args.recovery_interval == 0:
-            saver.save_recovery(epoch, update_idx,
-                                extra_state=_recovery_extras(batch_idx + 1, num_updates, args))
-        poll_faults_and_shutdown(batch_idx, update_idx)
-        update_idx += 1
+    finally:
+        batches.close()  # the open `train.step` root ends here when an exception ends the epoch
     if micro_inputs:
         # flush trailing partial accumulation group: pad by wrapping samples so
         # the step shape stays static (slight duplicate weighting on the tail)
@@ -1152,7 +1209,7 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
             reps = -(-need // input_all.shape[0])
             input_all = np.concatenate([input_all] + [input_all] * reps, axis=0)[:accum * micro_inputs[0].shape[0]]
             target_all = np.concatenate([target_all] + [target_all] * reps, axis=0)[:accum * micro_inputs[0].shape[0]]
-        batch = shard_batch({'input': jnp.asarray(input_all), 'target': jnp.asarray(target_all)}, mesh)
+        batch = _batch_to_device({'input': input_all, 'target': target_all}, mesh, shard_batch)
         metrics = _resilient_train_step(task, batch, lr, num_updates, args, saver, rollback_budget)
         if metrics is not None:
             num_updates += 1

@@ -201,3 +201,48 @@ def test_augmix_jsd_splitbn_pipeline(tmp_path):
 # The silent-exception-swallow lint is now the analysis rule `silent-except`
 # (timm_tpu/analysis/source_rules.py) — widened from timm_tpu/data to the
 # whole package plus the top-level scripts, enforced by tests/test_analysis.py.
+
+
+class _Numbered:
+    """A dataset whose sample IS its index: what the loader delivers can be counted."""
+
+    def __init__(self, n):
+        self.n = n
+
+    def __len__(self):
+        return self.n
+
+    def __getitem__(self, i):
+        return np.full((2, 2, 3), i % 251, np.uint8), int(i)
+
+
+@pytest.mark.parametrize('n,batch,workers,training', [
+    (37, 4, 3, False),     # nothing fills a chunk evenly: every worker's last hand-over is a partial one
+    (37, 4, 3, True),
+    (5, 2, 7, False),      # more workers than samples: most hand over nothing at all
+    (64, 8, 2, True),      # whole chunks only
+])
+def test_samples_travel_in_chunks_and_every_one_arrives_once(n, batch, workers, training):
+    """The decode threads hand the collator `_CHUNK` samples at a time and the
+    rest when their indices end: no sample is lost or doubled, evaluation
+    keeps index order, training drops only the incomplete last batch."""
+    from timm_tpu.data import loader as loader_mod
+    assert loader_mod._CHUNK > 1
+    loader = loader_mod.ThreadedLoader(_Numbered(n), batch_size=batch, is_training=training, num_workers=workers, seed=3)
+    for _ in range(2):                                   # a second epoch starts from a clean slate
+        targets = np.concatenate([t for _, t in loader])
+        if training:
+            assert len(targets) == n // batch * batch and len(set(targets.tolist())) == len(targets)
+        else:
+            assert targets.tolist() == list(range(n))
+
+
+def test_the_folder_reader_hands_over_the_whole_file_in_memory(image_root):
+    """One read a file, closed before the decoder sees it: nothing of the
+    sample's decode goes back to the file system."""
+    import io
+    ds = create_dataset('', root=image_root, split='train')
+    fobj, target = ds.reader[3]
+    path = ds.reader.samples[3][0]
+    assert isinstance(fobj, io.BytesIO) and fobj.getvalue() == open(path, 'rb').read()
+    assert Image.open(fobj).size == (56, 48) and target == ds.reader.samples[3][1]

@@ -283,6 +283,9 @@ class DevicePrefetcher:
                 close()
 
 
+_CHUNK = 8   # samples a decode thread hands the collator at once
+
+
 def _collate_arrays(imgs, targets):
     """Stack a list of samples; AugMix tuple samples (clean, aug1..augN) are
     concatenated split-major along batch with targets repeated per split
@@ -391,7 +394,10 @@ class ThreadedLoader:
         num_batches = len(indices) // self.batch_size if self.drop_last \
             else -(-len(indices) // self.batch_size)
 
-        sample_q: 'queue.Queue' = queue.Queue(maxsize=self.prefetch * self.batch_size)
+        # samples travel in chunks: every hand-over wakes the collator and costs both
+        # threads the interpreter lock, which the decode threads are short of
+        # (PERF.md section 6, PR 25); the bound stays `prefetch` batches of samples
+        sample_q: 'queue.Queue' = queue.Queue(maxsize=max(1, self.prefetch * self.batch_size // _CHUNK))
         batch_q: 'queue.Queue' = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
 
@@ -414,6 +420,7 @@ class ThreadedLoader:
             return self.dataset[int(idx)]
 
         def worker(worker_indices):
+            chunk = []
             for idx in worker_indices:
                 if stop.is_set():
                     return
@@ -432,8 +439,13 @@ class ThreadedLoader:
                         except TooManyBadSamples as fatal:
                             sample = fatal  # budget exhausted: fail the epoch loudly
                 tracing.count('loader.samples')
-                if not _put(sample_q, (int(idx), sample)):
-                    return
+                chunk.append((int(idx), sample))
+                if len(chunk) == _CHUNK:
+                    if not _put(sample_q, chunk):
+                        return
+                    chunk = []
+            if chunk:
+                _put(sample_q, chunk)
 
         used = indices[:num_batches * self.batch_size] if self.drop_last else indices
         workers = []
@@ -471,30 +483,31 @@ class ThreadedLoader:
             try:
                 while consumed < len(order) and not stop.is_set():
                     try:
-                        idx, sample = sample_q.get(timeout=0.1)
+                        chunk = sample_q.get(timeout=0.1)
                     except queue.Empty:
                         continue
-                    consumed += 1
-                    if isinstance(sample, Exception):
-                        raise sample
-                    if ordered:
-                        pending[idx] = sample
-                        while pos < len(order) and int(order[pos]) in pending:
-                            s = pending.pop(int(order[pos]))
-                            pos += 1
-                            if s is not _SKIPPED:
-                                img, target = s
+                    for idx, sample in chunk:
+                        consumed += 1
+                        if isinstance(sample, Exception):
+                            raise sample
+                        if ordered:
+                            pending[idx] = sample
+                            while pos < len(order) and int(order[pos]) in pending:
+                                s = pending.pop(int(order[pos]))
+                                pos += 1
+                                if s is not _SKIPPED:
+                                    img, target = s
+                                    batch_imgs.append(img)
+                                    batch_targets.append(target)
+                                if not emit(force_last=pos == len(order)):
+                                    return
+                        else:
+                            if sample is not _SKIPPED:
+                                img, target = sample
                                 batch_imgs.append(img)
                                 batch_targets.append(target)
-                            if not emit(force_last=pos == len(order)):
+                            if not emit(force_last=consumed == len(order)):
                                 return
-                    else:
-                        if sample is not _SKIPPED:
-                            img, target = sample
-                            batch_imgs.append(img)
-                            batch_targets.append(target)
-                        if not emit(force_last=consumed == len(order)):
-                            return
             except Exception as e:
                 _put(batch_q, e)
             finally:

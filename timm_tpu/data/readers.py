@@ -2,6 +2,7 @@
 reader_image_folder.py:59, class-map handling, factory)."""
 from __future__ import annotations
 
+import io
 import os
 from typing import Dict, List, Optional, Tuple
 
@@ -76,8 +77,12 @@ class ReaderImageFolder:
                 f'Found 0 images in subfolders of {root}. Supported extensions: {", ".join(IMG_EXTENSIONS)}')
 
     def __getitem__(self, index: int):
+        # the whole file in one read, closed here: a loader's decode threads pay
+        # the interpreter lock once per system call (PERF.md section 6, PR 25),
+        # and a decoder fed from a file object makes ten of them a file
         path, target = self.samples[index]
-        return open(path, 'rb'), target
+        with open(path, 'rb', buffering=0) as f:
+            return io.BytesIO(f.read()), target
 
     def __len__(self):
         return len(self.samples)

@@ -344,7 +344,11 @@ class TrainingTask:
                 ema_params = new_ema
             metrics = {'loss': loss, 'grad_norm': grad_norm}
             if guard:
+                # the counters as outputs of their own (the state itself is donated to the
+                # next step): no eager slice on the main thread after the call
                 metrics['nonfinite'] = sentinel_state[0] > 0
+                metrics['nonfinite_count'] = sentinel_state[0]
+                metrics['nonfinite_total'] = sentinel_state[1]
             return new_params, new_rest, new_opt_state, ema_params, sentinel_state, metrics
 
         # donation + matching in/out shardings let XLA alias every state
@@ -402,8 +406,6 @@ class TrainingTask:
                     self.ema_params = ema_out
                 if self._sentinel_state is not None:
                     self._sentinel_state = sent_out
-                    metrics['nonfinite_count'] = sent_out[0]
-                    metrics['nonfinite_total'] = sent_out[1]
             if self._sentinel_state is not None and self.sentinel is not None:
                 # polls the device counters (every TIMM_TPU_NONFINITE_CHECK_EVERY
                 # steps) and raises NonFiniteError after K consecutive bad steps

@@ -9,6 +9,7 @@ Flag names mirror the reference where the concept carries over
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import logging
 import os
@@ -967,6 +968,7 @@ def main(argv=None):
                 async_writer.close()
         finally:
             shutdown.uninstall()
+            _thaw()
 
     if best_metric is not None:
         _logger.info(f'*** Best metric: {best_metric} (epoch {best_epoch})')
@@ -993,6 +995,31 @@ def _recovery_extras(batches_consumed, num_updates, args=None):
         extras['_resume.process_count'] = np.asarray(jax.process_count())
     extras.update(capture_host_rng())
     return extras
+
+
+_frozen = False  # gc.freeze() is process-wide and so is this: whether the run in progress has frozen
+
+
+def _freeze_once():
+    """Once a run, after its first step: what set-up built (the model, the compiled
+    step, JAX's caches: hundreds of thousands of containers) lives as long as the
+    run. Out of the cyclic collector's reach until `main` returns, or every few
+    seconds a full collection walks all of it, finds nothing, and holds the
+    interpreter lock for over 100 ms (PERF.md section 6, PR 25)."""
+    global _frozen
+    if not _frozen:
+        gc.collect()
+        gc.freeze()
+        _frozen = True
+
+
+def _thaw():
+    """What `_freeze_once` froze is collectable again: a task sits in a cycle with
+    its jitted step, and frozen it would keep its device state for the process's life."""
+    global _frozen
+    if _frozen:
+        gc.unfreeze()
+        _frozen = False
 
 
 def _resilient_train_step(task, batch, lr, step, args, saver, rollback_budget):
@@ -1172,6 +1199,7 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
             if log_setup:
                 _logger.info(_setup_line())
                 log_setup = False
+            _freeze_once()
             with tracing.span('train.bookkeeping'):
                 if lr_scheduler is not None:
                     lr = lr_scheduler.step_update(num_updates)[0]

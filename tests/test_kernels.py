@@ -81,7 +81,7 @@ def test_flash_grads_match():
 
 def test_registry_portfolio_and_dup_rejection():
     assert registry.kernel_names() == (
-        'augment_epilogue', 'flash_attention', 'fused_adamw')
+        'augment_epilogue', 'causal_flash_attention', 'flash_attention', 'fused_adamw')
     with pytest.raises(ValueError, match='already registered'):
         registry.register(registry.get('fused_adamw'))
     with pytest.raises(ValueError, match='regime is empty'):

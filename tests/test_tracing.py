@@ -177,7 +177,7 @@ def test_a_gauge_keeps_a_bounded_series_on_the_rings_clock():
 
 # -- the lint: names are the contract ------------------------------------------------------
 
-CALL = re.compile(r"tracing\.(?:span|count|busy|gauge)\(\s*(['\"])([^'\"]+)\1")
+CALL = re.compile(r"tracing\.(?:span|count|busy|gauge|scope|device_counter)\(\s*(['\"])([^'\"]+)\1")
 
 
 def _program_sources():
@@ -201,17 +201,50 @@ def test_every_recorded_name_is_declared_and_every_declared_name_is_recorded_and
     for path, text in sources.items():
         if not path.endswith(os.path.join('utils', 'tracing.py')):
             assert 'TraceAnnotation' not in text, path
-            for call in re.findall(r'tracing\.(?:span|count|busy|gauge)\(([^)]*)', text):
+            for call in re.findall(r'tracing\.(?:span|count|busy|gauge|scope|device_counter)\(([^)]*)', text):
                 assert call.lstrip()[:1] in ('\'', '"'), (path, call)
     # each name has a reader: a per-layer metric, the reduction behind them, or train.py's two log lines
     bench = os.path.join(ROOT, 'benchmarks')
     readers = [open(os.path.join(bench, 'layer_metrics', f)).read() for f in os.listdir(os.path.join(bench, 'layer_metrics'))]
     readers += [open(os.path.join(bench, 'harness', 'program_spans.py')).read(),
+                open(os.path.join(bench, 'harness', 'device_scopes.py')).read(),     # device time by scope
+                open(os.path.join(bench, 'harness', 'lm_readers.py')).read(),        # the LM cell's readings
+                open(os.path.join(bench, 'harness', 'lm_train_runner.py')).read(),   # its `correct`: `moe.dropped_slots`
                 inspect.getsource(train._host_line), inspect.getsource(train._setup_line)]
     unread = [name for name in tracing.SPANS if not any(f"'{name}'" in text for text in readers)]
     assert not unread, unread
     # the layers are the ones PERF.md section 3 and BENCHMARK.json name
-    assert {layer for layer, _ in tracing.SPANS.values()} == {'entry and compile cache', 'input', 'step'}
+    assert {layer for layer, _ in tracing.SPANS.values()} == {'entry and compile cache', 'input', 'step', 'attention', 'experts'}
+
+
+@pytest.mark.parametrize('record', [lambda n: tracing.scope(n), lambda n: tracing.device_counter(n, 1)], ids=['scope', 'device_counter'])
+def test_a_device_name_that_is_not_declared_is_refused(record):
+    with pytest.raises(KeyError, match='not declared'):
+        record('glm.made_up')
+
+
+def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_the_steps_output():
+    """Declared, recorded, read: the scope is in the compiled program's op names, forward and backward (where
+    `benchmarks/harness/device_scopes.py` reads it); the counter is a value of the program, not of the host."""
+    import jax
+    import jax.numpy as jnp
+    before = tracing.snapshot()
+
+    def loss(w, x):
+        with tracing.scope('glm.dense_ffn'):
+            y = jnp.tanh(x @ w)
+        return y.sum(), {'lm.tokens': tracing.device_counter('lm.tokens', jnp.int32(x.shape[0]))}
+
+    step = jax.jit(jax.value_and_grad(loss, has_aux=True))
+    w, x = jnp.ones((4, 4)), jnp.ones((3, 4))
+    text = step.lower(w, x).as_text(debug_info=True)
+    assert 'glm.dense_ffn' in text and 'transpose(jvp(glm.dense_ffn))' in text
+    (_, counters), _ = step(w, x)
+    assert int(counters['lm.tokens']) == 3
+    after = tracing.snapshot()
+    assert len(after['spans']) - len(before['spans']) <= 1 and after['counters'] == before['counters']   # the ring is not theirs
+    kinds = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('glm.', 'moe.', 'lm.'))}
+    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 13
 
 
 def test_no_loader_worker_thread_opens_a_span():

@@ -234,9 +234,11 @@ def family_coverage(families: Optional[Sequence[str]] = None,
                     'under XLA_FLAGS=--xla_force_host_platform_device_count=8 '
                     'or pass deep=False')
             row: Dict[str, object] = {
-                'model': name, 'img_size': size, 'deep': run_deep,
+                'model': name, 'img_size': z['img_size'], 'deep': run_deep,
                 'abstract_trace': bool(z['ok']),
             }
+            if 'seq_len' in z:      # a token model: the sweep fed it ids, not an image
+                row['seq_len'] = z['seq_len']
             if not z['ok']:
                 row['abstract_trace_error'] = z.get('error', 'failed')
             ok, err = _abstract_scan_check(name)

@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from .registry import AnalysisContext, rule
 from .report import Finding
 
-__all__ = ['family_representative', 'sweep', 'SMOKE_FAMILIES',
+__all__ = ['family_representative', 'abstract_batch', 'sweep', 'SMOKE_FAMILIES',
            'SIZE_OVERRIDES']
 
 # families cheap enough for the tier-1 smoke (full sweep: CLI + -m slow);
@@ -38,6 +38,18 @@ SIZE_OVERRIDES: Dict[str, int] = {
 
 _NUM_CLASSES = 10
 _BATCH = 2
+_SEQ_LEN = 128     # tokens a sequence, for a model whose input is token ids
+
+
+def abstract_batch(model, size: int):
+    """(abstract input, output shape owed) for a model, by what the model says it is: a `task_kind` of
+    'causal_lm' takes token ids (B, S) and owes logits over `model.num_classes`; every other model is an image
+    classifier built with `_NUM_CLASSES`. No list of families: a new token family is swept as it registers."""
+    import jax
+    import jax.numpy as jnp
+    if getattr(model, 'task_kind', None) == 'causal_lm':
+        return jax.ShapeDtypeStruct((_BATCH, _SEQ_LEN), jnp.int32), (_BATCH, _SEQ_LEN, model.num_classes)
+    return jax.ShapeDtypeStruct((_BATCH, size, size, 3), jnp.float32), (_BATCH, _NUM_CLASSES)
 
 
 def family_representative(module: str) -> Tuple[str, int]:
@@ -63,7 +75,6 @@ def sweep(families: Optional[Sequence[str]] = None,
     """Abstract-trace every family -> [{'module', 'model', 'img_size',
     'ok', 'out_shape' | 'error'}]. No arrays, no compiles."""
     import jax
-    import jax.numpy as jnp
     from flax import nnx
 
     import timm_tpu
@@ -77,21 +88,21 @@ def sweep(families: Optional[Sequence[str]] = None,
                 lambda n=name: timm_tpu.create_model(n, num_classes=_NUM_CLASSES))
             model.eval()
             graphdef, state = nnx.split(model)
-            out = jax.eval_shape(
-                lambda s, x: nnx.merge(graphdef, s)(x), state,
-                jax.ShapeDtypeStruct((_BATCH, size, size, 3), jnp.float32))
+            batch, owed = abstract_batch(model, size)
+            if batch.ndim == 2:
+                rec.update(img_size=None, seq_len=_SEQ_LEN)
+            out = jax.eval_shape(lambda s, x: nnx.merge(graphdef, s)(x), state, batch)
             rec['out_shape'] = tuple(out.shape)
-            rec['ok'] = tuple(out.shape) == (_BATCH, _NUM_CLASSES)
+            rec['ok'] = tuple(out.shape) == owed
             if not rec['ok']:
-                rec['error'] = (f'abstract forward returned {rec["out_shape"]}, '
-                                f'expected ({_BATCH}, {_NUM_CLASSES})')
+                rec['error'] = f'abstract forward returned {rec["out_shape"]}, expected {owed}'
         except Exception as e:  # noqa: BLE001 - each family reports its own failure
             rec['ok'] = False
             rec['error'] = f'{type(e).__name__}: {e}'
         records.append(rec)
         if log is not None:
             status = 'ok' if rec['ok'] else f'FAIL {rec["error"]}'
-            log(f'zoo {module}: {name}@{size} {status}')
+            log(f'zoo {module}: {name}@{rec.get("seq_len", size)} {status}')
     return records
 
 

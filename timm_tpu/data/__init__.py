@@ -7,7 +7,7 @@ from .constants import (
     DEFAULT_CROP_MODE, DEFAULT_CROP_PCT, IMAGENET_DEFAULT_MEAN, IMAGENET_DEFAULT_STD,
     IMAGENET_INCEPTION_MEAN, IMAGENET_INCEPTION_STD, OPENAI_CLIP_MEAN, OPENAI_CLIP_STD,
 )
-from .dataset import AugMixDataset, ImageDataset
+from .dataset import AugMixDataset, ImageDataset, TokenWindows
 from .dataset_factory import create_dataset
 from .device_augment import (
     DeviceAugment, DeviceAugmentStage, NaFlexDeviceAugment,

@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import io
 import logging
+import os
 from typing import Callable, Optional
 
 import numpy as np
@@ -140,3 +141,33 @@ class AugMixDataset:
 
     def __len__(self):
         return len(self.dataset)
+
+
+class TokenWindows:
+    """A flat file of token ids (raw little-endian int32, memory-mapped) cut
+    into consecutive windows of `seq_len`: sample i is `(ids[i*S:(i+1)*S],
+    target)` with `target[j]` the id after `ids[j]` and -1 (the causal-LM
+    task's IGNORE) at the window's last position. Windows do not overlap, so
+    an epoch gives no sequence twice; causal attention runs over the whole
+    window (no document boundaries are marked in a flat stream)."""
+
+    def __init__(self, path: str, seq_len: int, vocab_size: Optional[int] = None):
+        if not os.path.isfile(path):
+            raise FileNotFoundError(f'no token file {path} (raw int32 ids)')
+        self.ids = np.memmap(path, dtype='<i4', mode='r')
+        self.seq_len = int(seq_len)
+        if len(self.ids) < self.seq_len:
+            raise ValueError(f'{path} holds {len(self.ids)} ids, fewer than one window of {self.seq_len}')
+        self.vocab_size = vocab_size
+
+    def __len__(self):
+        return len(self.ids) // self.seq_len
+
+    def __getitem__(self, index):
+        start = int(index) * self.seq_len
+        ids = np.array(self.ids[start:start + self.seq_len], np.int32)
+        if self.vocab_size is not None and (ids.min() < 0 or ids.max() >= self.vocab_size):
+            raise ValueError(f'window {index}: ids outside [0, {self.vocab_size})')
+        target = np.empty_like(ids)
+        target[:-1], target[-1] = ids[1:], -1
+        return ids, target

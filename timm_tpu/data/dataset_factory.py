@@ -76,6 +76,16 @@ def create_dataset(
     """(reference dataset_factory.py:63)."""
     kwargs = {k: v for k, v in kwargs.items() if v is not None}
     name = name or ''
+    if name == 'tokens':
+        # one flat file of ids a split: <root>/<split>.bin
+        from .dataset import TokenWindows
+        split_name = split.split('[')[0]
+        names = {'train': ('train', 'training'), 'validation': ('validation', 'val', 'eval', 'test')}
+        found = [p for p in (os.path.join(root or '', n + '.bin') for n in names.get(split_name, (split_name,)))
+                 if os.path.isfile(p)]
+        if not found:
+            raise FileNotFoundError(f'no {split_name}.bin token file under {root}')
+        return TokenWindows(found[0], seq_len=kwargs['seq_len'], vocab_size=num_classes)
     if name.startswith('hfds/'):
         return HfdsWrapper(name[5:], root, split, **{k: kwargs[k] for k in ('input_key', 'target_key') if k in kwargs})
     if name.startswith('wds/'):

@@ -15,15 +15,20 @@ Portfolio:
   `TrainingTask(fused_update=True)` path; optax stays default + oracle.
 - `augment_epilogue` — one-pass uint8->erase->mix->normalize epilogue for
   the PR-9 `DeviceAugment` program ('const' erase regime).
+- `causal_attention` — causal flash attention for long token sequences (JAX's
+  Pallas splash-attention kernel, wrapped); the default core of
+  `layers/latent_attention.py` wherever its shapes apply.
 """
 from .flash_attention import flash_attention, flash_attention_supported
 from .fused_adamw import fused_adamw_apply, fused_adamw_step
 from .augment_epilogue import augment_epilogue_supported, augment_image_batch_fused
+from .causal_attention import causal_flash_attention, causal_flash_supported
 from .registry import KernelCase, KernelSpec, all_specs, ensure_registered
 
 __all__ = [
     'flash_attention', 'flash_attention_supported',
     'fused_adamw_apply', 'fused_adamw_step',
     'augment_epilogue_supported', 'augment_image_batch_fused',
+    'causal_flash_attention', 'causal_flash_supported',
     'KernelCase', 'KernelSpec', 'all_specs', 'ensure_registered',
 ]

@@ -26,7 +26,7 @@ __all__ = ['KernelCase', 'KernelSpec', 'register', 'unregister', 'get',
            'all_specs', 'kernel_names', 'ensure_registered', 'default_io_bytes']
 
 # modules whose import populates the registry (the portfolio)
-_PORTFOLIO = ('flash_attention', 'fused_adamw', 'augment_epilogue')
+_PORTFOLIO = ('flash_attention', 'fused_adamw', 'augment_epilogue', 'causal_attention')
 
 
 @dataclasses.dataclass(frozen=True)

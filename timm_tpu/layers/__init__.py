@@ -50,9 +50,11 @@ from .split_batchnorm import SplitBatchNorm2d, SplitBatchNormAct2d, convert_spli
 from .test_time_pool import TestTimePoolHead, apply_test_time_pool
 from .pos_embed_sincos import (
     RotaryEmbeddingCat, RotaryEmbeddingDinoV3, RotaryEmbeddingMixed,
-    build_fourier_pos_embed, build_rotary_pos_embed,
+    build_fourier_pos_embed, build_rotary_pos_embed, build_rotary_pos_embed_1d,
     build_sincos2d_pos_embed, create_rope_embed, freq_bands, pixel_freq_bands,
 )
 from .squeeze_excite import EffectiveSEModule, SEModule, SqueezeExcite
 from .weight_init import lecun_normal_, ones_, trunc_normal_, trunc_normal_tf_, variance_scaling_, zeros_
 from .hybrid_embed import HybridEmbed
+from .latent_attention import LatentAttention, causal_attention
+from .moe import SparseMoe

@@ -1,0 +1,131 @@
+"""A sparse mixture-of-experts layer that is told which experts it holds.
+
+Routing is DeepSeek-V3's `noaux_tc` (arXiv:2412.19437 section 2.1.2) as
+GLM-4.7-Flash `glm4_moe_lite` configures it: sigmoid scores over ALL
+`num_experts`, the `top_k` largest of score + bias chosen, weights = the chosen
+scores normalised over all `top_k` chosen and scaled. The layer holds experts
+[expert_offset, expert_offset + experts_held) — one expert-parallel rank's
+share — and computes their part of the result; what experts held elsewhere
+would add is not computed here and nothing stands in for it (on several chips
+the exchange in `parallel/` would bring it; ROADMAP "Reach"). With
+`experts_held == num_experts` it is the whole layer.
+
+No token is dropped under any routing: the (token, choice) slots are sorted by
+held expert into a buffer of T * top_k rows — the worst case, every choice of
+every token on an expert held here — and the three SwiGLU products run as
+grouped products over the experts held (`jax.lax.ragged_dot`, group sizes =
+slots an expert). `moe.dropped_slots` counts local slots the buffer left out:
+0 by construction, and a counter so that a later bound has to prove it.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from flax import nnx
+
+from ..utils import tracing
+from .mlp import SwiGLU
+from .weight_init import trunc_normal_
+
+__all__ = ['SparseMoe', 'route']
+
+
+def route(scores_in, router_kernel, bias, top_k: int, scaling: float):
+    """x (T, d) -> chosen expert ids (T, k) and weights (T, k), float32.
+    The scores, their choice and the weights are float32 at full precision:
+    a top-k choice flips on the last bits."""
+    logits = jnp.matmul(scores_in.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
+
+
+class SparseMoe(nnx.Module):
+    """x (B, S, dim) -> (y (B, S, dim), counters). Every expert is a SwiGLU of
+    width `hidden`, without biases; `n_shared` shared experts are one SwiGLU of
+    width `n_shared * hidden` every token passes through."""
+
+    def __init__(
+            self,
+            dim: int,
+            hidden: int,
+            num_experts: int,
+            top_k: int,
+            experts_held: int = None,
+            expert_offset: int = 0,
+            n_shared: int = 1,
+            routed_scaling_factor: float = 1.0,
+            *,
+            dtype=None,
+            param_dtype=jnp.float32,
+            rngs: nnx.Rngs,
+    ):
+        held = num_experts if experts_held is None else experts_held
+        if not 0 <= expert_offset <= expert_offset + held <= num_experts:
+            raise ValueError(f'experts [{expert_offset}, {expert_offset + held}) are not among {num_experts}')
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held, self.expert_offset = held, expert_offset
+        self.scaling = routed_scaling_factor
+        self.dtype = dtype
+        init = trunc_normal_(std=0.02)
+        key = rngs.params
+        self.router = nnx.Param(init(key(), (dim, num_experts), param_dtype))
+        # `e_score_correction_bias`: steers the choice, not the weights; a buffer without gradient. Its
+        # update from the experts' load is not part of the step (the rate is not in the public config).
+        self.score_bias = nnx.Variable(jnp.zeros((num_experts,), jnp.float32))
+        self.w_gate = nnx.Param(init(key(), (held, dim, hidden), param_dtype))
+        self.w_up = nnx.Param(init(key(), (held, dim, hidden), param_dtype))
+        self.w_down = nnx.Param(init(key(), (held, hidden, dim), param_dtype))
+        self.shared = SwiGLU(dim, hidden * n_shared, bias=False, dtype=dtype, param_dtype=param_dtype,
+                             rngs=rngs) if n_shared else None
+
+    def choose(self, x):
+        """Chosen expert ids (..., top_k) of tokens x (..., dim), of all `num_experts`."""
+        return route(x, self.router[...], self.score_bias[...], self.top_k, self.scaling)[0]
+
+    def routed(self, x):
+        """The held experts' part of the result for tokens x (T, dim), and the counters."""
+        T, dim = x.shape
+        held, k = self.experts_held, self.top_k
+        dt = self.dtype or x.dtype
+        with tracing.scope('glm.moe.route'):
+            idx, weights = route(x, self.router[...], jax.lax.stop_gradient(self.score_bias[...]), k, self.scaling)
+            local = (idx >= self.expert_offset) & (idx < self.expert_offset + held)
+            slot_expert = jnp.where(local, idx - self.expert_offset, held).reshape(-1)   # `held` = held elsewhere
+            order = jnp.argsort(slot_expert, stable=True)          # local slots first, by expert
+            group_sizes = jnp.bincount(slot_expert, length=held + 1)[:held].astype(jnp.int32)
+            rows = T * k                                           # the worst case: every slot local
+            covered = jnp.minimum(group_sizes.sum(), rows)
+            token = order // k
+            # rows past the groups hold nothing defined, in a grouped product's result and in its cotangent
+            # alike (the TPU kernel skips their tiles): every operand and result is masked to the live rows,
+            # so that nothing undefined reaches a token, forward or backward
+            live = (jnp.arange(rows) < covered)[:, None]
+            xs = jnp.where(live, x[token].astype(dt), 0)
+        with tracing.scope('glm.moe.experts'):
+            gate = jax.lax.ragged_dot(xs, self.w_gate[...].astype(dt), group_sizes)
+            up = jax.lax.ragged_dot(xs, self.w_up[...].astype(dt), group_sizes)
+            hidden = jnp.where(live, jax.nn.silu(gate) * up, 0)
+            ys = jax.lax.ragged_dot(hidden, self.w_down[...].astype(dt), group_sizes)
+        with tracing.scope('glm.moe.route'):
+            slot_weight = jnp.where(local, weights, 0.0).reshape(-1)[order]
+            ys = jnp.where(live, ys * slot_weight[:, None].astype(ys.dtype), 0)
+            y = jnp.zeros((T, dim), ys.dtype).at[token].add(ys)
+            local_slots = local.sum().astype(jnp.int32)
+            counters = {
+                'moe.local_slots': tracing.device_counter('moe.local_slots', local_slots),
+                'moe.load_max': tracing.device_counter('moe.load_max', group_sizes.max()),
+                'moe.dropped_slots': tracing.device_counter('moe.dropped_slots', local_slots - covered),
+            }
+        return y, counters
+
+    def __call__(self, x):
+        B, S, dim = x.shape
+        y, counters = self.routed(x.reshape(B * S, dim))
+        y = y.reshape(B, S, dim).astype(x.dtype)
+        if self.shared is not None:
+            with tracing.scope('glm.moe.shared'):
+                y = y + self.shared(x)
+        return y, counters

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from flax import nnx
 
 __all__ = [
-    'build_sincos2d_pos_embed', 'build_fourier_pos_embed', 'build_rotary_pos_embed',
+    'build_sincos2d_pos_embed', 'build_fourier_pos_embed', 'build_rotary_pos_embed', 'build_rotary_pos_embed_1d',
     'RotaryEmbeddingCat', 'RotaryEmbeddingMixed', 'RotaryEmbeddingDinoV3',
     'create_rope_embed', 'freq_bands', 'pixel_freq_bands',
 ]
@@ -353,3 +353,14 @@ def create_rope_embed(rope_type: str = 'cat', dim: int = 768, num_heads: int = 1
         kwargs.pop('ref_feat_shape', None)
         return RotaryEmbeddingDinoV3(dim=dim // num_heads, rngs=rngs, **kwargs)
     raise ValueError(f'Unknown RoPE type: {rope_type}')
+
+
+def build_rotary_pos_embed_1d(seq_len: int, dim: int, theta: float = 10000.0, dtype=jnp.float32) -> jnp.ndarray:
+    """1-D rotary table for token sequences: (seq_len, 2 * dim), sin then cos
+    concatenated as `apply_rot_embed_cat` takes them, in the *half* layout
+    (frequency j turns dimensions j and j + dim/2; `half=True` there).
+    Frequencies theta^(-2j/dim), positions 0..seq_len-1, no scaling."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq[None, :]      # (S, dim/2)
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.concatenate([jnp.sin(angles), jnp.cos(angles)], axis=-1).astype(dtype)

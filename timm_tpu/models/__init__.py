@@ -64,3 +64,4 @@ from .repghost import RepGhostNet
 from .vovnet import VovNet
 from .pit import PoolingVisionTransformer
 from .inception_v4 import InceptionV4
+from .glm4_moe_lite import Glm4MoeLite

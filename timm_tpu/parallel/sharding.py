@@ -105,7 +105,9 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
       6. biases                           -> replicate
       7. norm scales / LayerScale gammas  -> replicate
       8. tokens & position embeddings     -> replicate
-      9. everything else                  -> replicate (catch-all)
+      9. stacked expert kernels (E, in, out) -> 'fsdp' on the output dim
+     10. a router's kernel                -> replicate (every chip scores alike)
+     11. everything else                  -> replicate (catch-all)
 
     Rules 1-4 fall back to 'fsdp_largest' placement when the mesh has no
     'model' axis, so tp=1 reproduces the 2-axis table exactly.
@@ -129,6 +131,9 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
                       r'(?:cls_token|reg_token|dist_token|pos_embed|pos_embed_win|pos_embed_x|pos_embed_y|'
                       r'relative_position_bias_table|rel_pos_w|rel_pos_h|embedding|latent|probe|mask_token)($|\.)',
                       'replicate', name='token-embed'),
+        # `layers/moe.py` holds its experts as three bare stacks, not as Linears, and its router as a bare kernel
+        PartitionRule(r'\.mlp\.(?:w_gate|w_up|w_down)$', 'fsdp_largest', name='expert-stack'),
+        PartitionRule(r'\.mlp\.router$', 'replicate', name='router'),
         PartitionRule(r'.*', 'replicate', name='catch-all'),
     )
 

@@ -125,6 +125,11 @@ TOLERANCES: Dict[str, tuple] = {
     'augment_epilogue_io_bytes': ('band', 0.02),
     'augment_epilogue_ref_bytes_accessed': ('band', 0.50),
     'augment_epilogue_wins_bytes': ('bool', 0.0),
+    'causal_flash_attention_eqns': ('band', 0.10),
+    'causal_flash_attention_ref_eqns': ('band', 0.10),
+    'causal_flash_attention_io_bytes': ('band', 0.02),
+    'causal_flash_attention_ref_bytes_accessed': ('band', 0.50),
+    'causal_flash_attention_wins_bytes': ('bool', 0.0),
 }
 _DEFAULT_TOL = ('band', 0.10)
 

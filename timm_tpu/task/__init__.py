@@ -1,3 +1,4 @@
+from .causal_lm import CausalLMTask
 from .classification import ClassificationTask, NaFlexClassificationTask
 from .distillation import FeatureDistillationTask, LogitDistillationTask
 from .token_distillation import TokenDistillationTask

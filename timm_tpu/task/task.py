@@ -161,6 +161,12 @@ class TrainingTask:
     def eval_forward(self, model: nnx.Module, batch: Dict[str, Any]):
         return model(batch['input'])
 
+    def step_counters(self, output) -> Dict[str, Any]:
+        """Counters of `loss_forward`'s output that ride in the step's metrics
+        (`tracing.device_counter` names -> scalars); over accumulated
+        microbatches they add, a `*_max` takes the largest."""
+        return {}
+
     def normalize_input(self, batch: Dict[str, Any]) -> Dict[str, Any]:
         if self._norm_mean is None or 'input' not in batch:
             return batch
@@ -230,6 +236,7 @@ class TrainingTask:
             raise ValueError('fused_update=True but the optimizer carries no '
                              'fused_adamw_args (plain adamw chain required)')
         loss_forward = self.loss_forward
+        step_counters = self.step_counters
         normalize_input = self.normalize_input
 
         self.model.train()
@@ -252,9 +259,12 @@ class TrainingTask:
             # were created at the enclosing jit/scan trace level and may not be
             # mutated (RNG counters, BN stats) under value_and_grad
             m = nnx.merge(graphdef, params, rest, copy=True)
-            loss, _output = loss_forward(m, mb)
+            loss, output = loss_forward(m, mb)
             _, _, new_rest = nnx.split(m, nnx.Param, ...)
-            return loss.astype(jnp.float32), new_rest
+            return loss.astype(jnp.float32), (new_rest, step_counters(output))
+
+        def merge_counters(stacked):
+            return {k: v.max(0) if k.endswith('_max') else v.sum(0) for k, v in stacked.items()}
 
         grad_fn = jax.value_and_grad(loss_and_state, has_aux=True)
 
@@ -284,11 +294,12 @@ class TrainingTask:
 
                 def body(carry, scanned):
                     grads_acc, loss_acc, r = carry
-                    (l_i, new_r), g_i = grad_fn(params, r, rebuild(scanned))
-                    return (jax.tree.map(jnp.add, grads_acc, g_i), loss_acc + l_i, new_r), None
+                    (l_i, (new_r, c_i)), g_i = grad_fn(params, r, rebuild(scanned))
+                    return (jax.tree.map(jnp.add, grads_acc, g_i), loss_acc + l_i, new_r), c_i
 
                 init = (jax.tree.map(jnp.zeros_like, params), jnp.zeros((), jnp.float32), rest)
-                (grads, loss, new_rest), _ = jax.lax.scan(body, init, xs)
+                (grads, loss, new_rest), counters = jax.lax.scan(body, init, xs)
+                counters = merge_counters(counters)
                 loss = loss / accum
                 grads = jax.tree.map(lambda g: g / accum, grads)
             elif accum > 1:
@@ -296,18 +307,20 @@ class TrainingTask:
                 # for trace-cost A/B and scan-vs-unroll parity tests
                 microbatches = microbatch_split(batch)
                 loss = jnp.zeros((), jnp.float32)
-                grads, r = None, rest
+                grads, r, each = None, rest, []
                 for i in range(accum):
                     mb = jax.tree.map(
                         lambda x: x[i] if getattr(x, 'ndim', 0) >= 2 else x, microbatches)
-                    (l_i, r), g_i = grad_fn(params, r, mb)
+                    (l_i, (r, c_i)), g_i = grad_fn(params, r, mb)
+                    each.append(c_i)
                     loss = loss + l_i
                     grads = g_i if grads is None else jax.tree.map(jnp.add, grads, g_i)
                 new_rest = r
+                counters = merge_counters(jax.tree.map(lambda *xs: jnp.stack(xs), *each))
                 loss = loss / accum
                 grads = jax.tree.map(lambda g: g / accum, grads)
             else:
-                (loss, new_rest), grads = grad_fn(params, rest, batch)
+                (loss, (new_rest, counters)), grads = grad_fn(params, rest, batch)
 
             grad_norm = global_grad_norm(grads)
             if clip_grad is not None:
@@ -342,7 +355,7 @@ class TrainingTask:
                 if guard:
                     new_ema = jax.tree.map(select, new_ema, ema_params)
                 ema_params = new_ema
-            metrics = {'loss': loss, 'grad_norm': grad_norm}
+            metrics = {'loss': loss, 'grad_norm': grad_norm, **counters}
             if guard:
                 # the counters as outputs of their own (the state itself is donated to the
                 # next step): no eager slice on the main thread after the call

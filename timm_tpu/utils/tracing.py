@@ -9,6 +9,10 @@ planes use. `count`, `busy` and `gauge` keep integers and sampled values at the
 same boundaries; every program XLA builds is recorded as an
 `xla.backend_compile` span under whatever span was open when it was built.
 
+`scope(name)` names device ops (`jax.named_scope`) and `device_counter(name,
+value)` declares a value the step program returns in its metrics: both are
+free on the host, and read from a profiler trace / the step's metrics.
+
 Nothing is written out and nothing switches it off: readers (`train.py`'s log
 line, `benchmarks/harness/program_spans.py`, tests) take `snapshot()` or
 `summary()`. Every name is declared in `SPANS`; `PERF.md` section 3 says which
@@ -54,6 +58,21 @@ SPANS = {
     'task.state_update': ('step', 'nnx.update + EMA / sentinel state written back'),
     'task.sentinel_poll': ('step', "sentinel.observe(): the device_get of the step's counters"),
     'task.sentinel_polls': ('step', 'counter: observe() calls that read the device'),
+    # device scopes (`scope`): jax.named_scope names on the program's ops, read from a trace's XLA Ops line
+    'glm.embed': ('step', 'device scope: token embedding lookup'),
+    'glm.mla.proj': ('attention', 'device scope: latent attention projections, their two RMSNorms and the rotary turn'),
+    'glm.mla.core': ('attention', 'device scope: causal softmax(q k^T) v in query blocks, forward and backward'),
+    'glm.dense_ffn': ('step', 'device scope: the leading dense SwiGLU'),
+    'glm.moe.route': ('experts', 'device scope: router, top-k, sort, gather into expert order, weighted combine'),
+    'glm.moe.experts': ('experts', 'device scope: the grouped products over the experts held'),
+    'glm.moe.shared': ('experts', 'device scope: the shared expert'),
+    'glm.mtp': ('step', 'device scope: the multi-token-prediction module (its block carries the mla/moe scopes inside)'),
+    'glm.head_loss': ('step', 'device scope: final norm, output head and cross-entropy, in chunks'),
+    # step counters (`device_counter`): values computed inside the step program, returned in its metrics
+    'moe.local_slots': ('experts', 'step counter: (token, expert) slots routed to experts held here, all expert layers'),
+    'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),
+    'moe.dropped_slots': ('experts', 'step counter: local slots the dispatch left out; must read 0'),
+    'lm.tokens': ('step', 'step counter: tokens the step was given'),
 }
 
 
@@ -157,6 +176,20 @@ class busy:
     def __exit__(self, *exc):
         count(self.name, time.perf_counter_ns() - self.start_ns)
         return False
+
+
+def scope(name: str):
+    """A device scope: `jax.named_scope(name)` under a declared name. It costs the
+    host nothing at run time (it names the ops traced inside it); a profiler
+    trace carries the name on every device op, forward and backward."""
+    return jax.named_scope(_known(name))
+
+
+def device_counter(name: str, value):
+    """A step counter: `value` (an array computed inside the step program)
+    under a declared name, for the step's returned metrics. No host read."""
+    _known(name)
+    return value
 
 
 def gauge(name: str, value) -> None:

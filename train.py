@@ -34,6 +34,8 @@ def make_parser():
     group.add_argument('--dataset', metavar='NAME', default='', help='dataset type/scheme')
     group.add_argument('--train-split', metavar='NAME', default='train')
     group.add_argument('--val-split', metavar='NAME', default='validation')
+    group.add_argument('--seq-len', type=int, default=2048, metavar='N',
+                       help="tokens a sequence for --dataset tokens (<data-dir>/train.bin, raw int32 ids)")
     group.add_argument('--synthetic-data', action='store_true',
                        help='use an on-the-fly synthetic dataset (no --data-dir needed)')
     group.add_argument('--num-classes', type=int, default=None)
@@ -491,6 +493,11 @@ def main(argv=None):
             model = _build_model()
     if args.num_classes is None:
         args.num_classes = model.num_classes
+    # the model's kind picks the task and the feed: a language model trains on --dataset tokens
+    causal_lm = getattr(model, 'task_kind', None) == 'causal_lm'
+    if causal_lm != (args.dataset == 'tokens'):
+        raise ValueError(f'--dataset tokens and a causal language model go together: '
+                         f'{args.model} is {"one" if causal_lm else "none"}, --dataset is {args.dataset!r}')
     if args.grad_checkpointing:
         model.set_grad_checkpointing(True)
     if args.block_scan:
@@ -564,6 +571,9 @@ def main(argv=None):
             task_cls = NaFlexClassificationTask
             # NaFlex batches are normalized host-side by the loader
             norm_mean = norm_std = None
+        elif causal_lm:
+            from timm_tpu.task import CausalLMTask
+            task_cls = CausalLMTask
         else:
             task_cls = ClassificationTask
         if distill is not None:
@@ -682,6 +692,21 @@ def main(argv=None):
                 batch_size=args.validation_batch_size or args.batch_size,
                 mean=data_config['mean'], std=data_config['std'],
                 interpolation=data_config['interpolation'], seed=args.seed)
+            mixup_fn = None
+        elif causal_lm:
+            from timm_tpu.data import create_dataset
+            from timm_tpu.data.loader import ThreadedLoader
+            if not args.data_dir:
+                raise ValueError('--dataset tokens needs --data-dir (train.bin / validation.bin of raw int32 ids)')
+            splits = {True: args.train_split, False: args.val_split}
+            loader_train, loader_eval = (
+                ThreadedLoader(
+                    create_dataset('tokens', root=args.data_dir, split=splits[training], is_training=training,
+                                   num_classes=args.num_classes, seq_len=args.seq_len),
+                    batch_size=args.batch_size if training else args.validation_batch_size or args.batch_size,
+                    is_training=training, num_workers=args.workers, seed=args.seed,
+                    process_index=rank, process_count=world_size)
+                for training in (True, False))
             mixup_fn = None
         elif args.synthetic_data or not args.data_dir:
             _logger.info('Using synthetic data')
@@ -1215,6 +1240,9 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
                 nf = int(metrics['nonfinite_total']) if 'nonfinite_total' in metrics else 0
                 host, log_counters = _host_line(log_since_ns, log_counters)
                 log_since_ns = tracing.now_ns()
+                if 'lm.tokens' in metrics:
+                    # a sample is a sequence; the step's own count of its tokens gives tokens/s
+                    seq += f"{ips * int(metrics['lm.tokens']) / n:.0f} tokens/s "
                 _logger.info(
                     f'Train: {epoch} [{update_idx:>4d}/{updates_per_epoch}] '
                     f'Loss: {loss_m.val:#.3g} ({loss_m.avg:#.3g}) LR: {lr:.3e} '
@@ -1280,6 +1308,14 @@ def validate(task, loader, args, mesh, shard_batch, use_ema=False):
         else:
             input_np, target_np = batch_data
             batch = shard_batch({'input': jnp.asarray(input_np), 'target': jnp.asarray(target_np)}, mesh)
+            if getattr(task.model, 'task_kind', None) == 'causal_lm':
+                # a language-model task scores on the device: sums over the batch's valid positions
+                sums = {k: float(v) for k, v in task.eval_step(batch, use_ema=use_ema).items()}
+                n = max(sums['count'], 1.0)
+                loss_m.update(sums['loss_sum'] / n, n)
+                top1_m.update(100.0 * sums['top1'] / n, n)
+                top5_m.update(100.0 * sums['top5'] / n, n)
+                continue
             output = task.eval_step({'input': batch['input']}, use_ema=use_ema)
             target = batch['target']
         out_np = _local_rows(output).astype(np.float32)

@@ -3,8 +3,12 @@
 The task owns the model, optimizer, EMA and — unlike the torch reference —
 the **jitted train/eval step functions**. Design:
 
-  * the train step is a FUNCTIONAL `jax.jit` over explicit state pytrees
-    (params, non-param model state, optimizer state, EMA, sentinel) with
+  * the train step is a FUNCTIONAL `jax.jit` over explicit state (params,
+    non-param model state, optimizer state, EMA, sentinel), carried between
+    steps as FLAT tuples of arrays: `_build_train_step` binds the model's own
+    `Variable`s once, a step reads their arrays, calls, and writes the
+    returned arrays back; no module graph is split, rebuilt or merged per
+    step. The structure is fixed from the build on (as `graphdef` is). With
     **explicit `in_shardings`/`out_shardings` and `donate_argnums` for every
     state argument**: XLA aliases the donated input buffers to the matching
     outputs (params/AdamW m,v/EMA update in place — ~2 GB/step less HBM copy
@@ -149,7 +153,8 @@ class TrainingTask:
 
         self.ema: Optional[ModelEmaV3] = None
         self.ema_params = None
-        self._train_step = None
+        self._train_step = None  # the jitted step; its binding to the model's Variables goes with it
+        self._step_vars = None
         self._eval_step = None
         self.compiled = False  # jit is always on; flag kept for API parity
 
@@ -175,6 +180,28 @@ class TrainingTask:
         return dict(batch, input=x.astype(batch['input'].dtype)
                     if batch['input'].dtype != jnp.float32 else x)
 
+    # -- optimizer / EMA state -----------------------------------------------
+    # Between steps both live as the flat tuples of arrays the jitted step takes
+    # and returns; the structured tree is built on read and flattened on
+    # assignment (None flattens to no leaves and comes back as None).
+    @property
+    def opt_state(self):
+        return jax.tree.unflatten(self._opt_treedef, self._opt_leaves)
+
+    @opt_state.setter
+    def opt_state(self, tree):
+        leaves, self._opt_treedef = jax.tree.flatten(tree)
+        self._opt_leaves = tuple(leaves)
+
+    @property
+    def ema_params(self):
+        return jax.tree.unflatten(self._ema_treedef, self._ema_leaves)
+
+    @ema_params.setter
+    def ema_params(self, tree):
+        leaves, self._ema_treedef = jax.tree.flatten(tree)
+        self._ema_leaves = tuple(leaves)
+
     # -- setup ---------------------------------------------------------------
     def setup_ema(self, decay: float = 0.9999, warmup: bool = False, **kwargs):
         """(reference task.py:110). The EMA tree is a deep COPY placed like the
@@ -184,7 +211,7 @@ class TrainingTask:
         self.ema_params = jax.device_put(
             jax.tree.map(lambda p: jnp.array(p, copy=True), nnx.state(self.model, nnx.Param)),
             self._param_shardings)
-        self._train_step = None  # EMA presence is baked into the jitted step; rebuild
+        self._train_step = self._step_vars = None  # EMA presence is baked into the jitted step; rebuild
 
     def set_block_scan(self, enable: bool = True) -> bool:
         """Toggle scan-over-layers execution on the owned model (and its
@@ -195,7 +222,7 @@ class TrainingTask:
         if not hasattr(self.model, 'set_block_scan'):
             return False
         self.model.set_block_scan(enable)
-        self._train_step = None
+        self._train_step = self._step_vars = None
         self._eval_step = None
         return True
 
@@ -209,7 +236,7 @@ class TrainingTask:
         if steps == self.grad_accum_steps:
             return False
         self.grad_accum_steps = steps
-        self._train_step = None
+        self._train_step = self._step_vars = None
         return True
 
     def compile(self, backend: str = ''):
@@ -239,18 +266,29 @@ class TrainingTask:
         step_counters = self.step_counters
         normalize_input = self.normalize_input
 
+        # bind once: the model's own Variables in flatten order, and the
+        # treedefs that turn the step's flat tuples back into the trees the
+        # mathematics is written on (inside the trace only)
         self.model.train()
-        graphdef, _, _ = self._split_model()
+        graphdef, params, rest = self._split_model()
+        is_var = lambda x: isinstance(x, nnx.Variable)  # noqa: E731
+        self._step_vars = (jax.tree.leaves(params, is_leaf=is_var), jax.tree.leaves(rest, is_leaf=is_var))
+        tracing.count('task.state_binds')
+        defs = (jax.tree.structure(params), jax.tree.structure(rest), self._opt_treedef, self._ema_treedef)
 
         rep = replicate_sharding(self.mesh)
-        # pytree-prefix shardings: a single sharding broadcasts over a whole
-        # subtree (non-param state, metrics). The batch position is None =
+        # params / optimizer / EMA: one sharding a leaf, in the state's flatten
+        # order. Elsewhere a single sharding broadcasts over a whole subtree
+        # (non-param state, metrics). The batch position is None =
         # inherit from the argument: parallel.shard_batch is the explicit
         # placement mechanism, and eval/debug batches smaller than the mesh
         # batch-shard count stay legal (they run replicated).
-        param_sh = self._param_shardings
-        opt_sh = self._opt_shardings
+        param_sh = tuple(jax.tree.leaves(self._param_shardings))
+        opt_sh = tuple(jax.tree.leaves(self._opt_shardings))
         ema_sh = param_sh if has_ema else rep
+        if (len(self._step_vars[0]), len(self._step_vars[1]), len(param_sh), len(opt_sh)) != tuple(
+                d.num_leaves for d in (defs[0], defs[1], defs[0], defs[2])):
+            raise ValueError('the step carries one array a Variable and one sharding a leaf, in flatten order')
 
         def loss_and_state(params, rest, mb):
             """Merge → loss_forward → re-split, so grads flow w.r.t. params
@@ -275,7 +313,9 @@ class TrainingTask:
                 lambda x: x.reshape(accum, -1, *x.shape[1:]) if getattr(x, 'ndim', 0) >= 1 else x,
                 batch)
 
-        def train_step(params, rest, opt_state, ema_params, sentinel_state, batch, lr, ema_decay):
+        def train_step(param_leaves, rest_leaves, opt_leaves, ema_leaves, sentinel_state, batch, lr, ema_decay):
+            params, rest, opt_state, ema_params = map(
+                jax.tree.unflatten, defs, (param_leaves, rest_leaves, opt_leaves, ema_leaves))
             batch = normalize_input(batch)
 
             if accum > 1 and accum_scan:
@@ -362,7 +402,11 @@ class TrainingTask:
                 metrics['nonfinite'] = sentinel_state[0] > 0
                 metrics['nonfinite_count'] = sentinel_state[0]
                 metrics['nonfinite_total'] = sentinel_state[1]
-            return new_params, new_rest, new_opt_state, ema_params, sentinel_state, metrics
+            new_state = (new_params, new_rest, new_opt_state, ema_params)
+            if tuple(map(jax.tree.structure, new_state)) != defs:
+                # the leaves go back into the Variables they came from by position
+                raise ValueError('the train step changed the structure of its state')
+            return (*(tuple(jax.tree.leaves(t)) for t in new_state), sentinel_state, metrics)
 
         # donation + matching in/out shardings let XLA alias every state
         # buffer in place (params, m/v, EMA, RNG counters, sentinel); the
@@ -403,20 +447,22 @@ class TrainingTask:
             if self._train_step is None:
                 self._train_step = self._build_train_step()
             with tracing.span('task.state_split'):
-                self.model.train()
-                _, params, rest = self._split_model()
+                param_vars, rest_vars = self._step_vars
+                params = tuple(v.get_raw_value() for v in param_vars)
+                rest = tuple(v.get_raw_value() for v in rest_vars)
             ema_decay = self.ema.get_decay(step) if self.ema is not None else 0.0
-            ema_in = self.ema_params if self.ema_params is not None else ()
             sent_in = self._sentinel_state if self._sentinel_state is not None else ()
             with tracing.span('task.scalars_put'):
                 lr_in, ema_decay_in = jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32)
             with tracing.span('task.step_call'):
-                params, rest, self.opt_state, ema_out, sent_out, metrics = self._train_step(
-                    params, rest, self.opt_state, ema_in, sent_in, batch, lr_in, ema_decay_in)
+                params, rest, self._opt_leaves, ema_out, sent_out, metrics = self._train_step(
+                    params, rest, self._opt_leaves, self._ema_leaves, sent_in, batch, lr_in, ema_decay_in)
             with tracing.span('task.state_update'):
-                nnx.update(self.model, params, rest)
-                if self.ema_params is not None:
-                    self.ema_params = ema_out
+                for var, value in zip(param_vars, params):
+                    var.set_raw_value(value)
+                for var, value in zip(rest_vars, rest):
+                    var.set_raw_value(value)
+                self._ema_leaves = ema_out
                 if self._sentinel_state is not None:
                     self._sentinel_state = sent_out
             if self._sentinel_state is not None and self.sentinel is not None:
@@ -426,21 +472,23 @@ class TrainingTask:
                     self.sentinel.observe(sent_out, step=step)
         return metrics
 
+    def _train_step_args(self, batch: Dict[str, Any], lr: float, step: int):
+        """The jitted step, built if need be, and the arguments `train_step`
+        would call it with, for the two AOT entry points below."""
+        if self._train_step is None:
+            self._train_step = self._build_train_step()
+        ema_decay = self.ema.get_decay(step) if self.ema is not None else 0.0
+        sent_in = self._sentinel_state if self._sentinel_state is not None else ()
+        return self._train_step, (
+            *(tuple(v.get_raw_value() for v in vs) for vs in self._step_vars), self._opt_leaves, self._ema_leaves,
+            sent_in, batch, jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32))
+
     def trace_train_step(self, batch: Dict[str, Any], lr: float = 0.1, step: int = 0):
         """AOT-trace the jitted train step on `batch` WITHOUT executing it;
         returns the ClosedJaxpr (trace-cost regression tests count its
         equations to pin the O(1)-in-grad_accum_steps property)."""
-        if self._train_step is None:
-            self._train_step = self._build_train_step()
-        self.model.train()
-        _, params, rest = self._split_model()
-        ema_decay = self.ema.get_decay(step) if self.ema is not None else 0.0
-        ema_in = self.ema_params if self.ema_params is not None else ()
-        sent_in = self._sentinel_state if self._sentinel_state is not None else ()
-        traced = self._train_step.trace(
-            params, rest, self.opt_state, ema_in, sent_in, batch,
-            jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32))
-        return traced.jaxpr
+        step_fn, args = self._train_step_args(batch, lr, step)
+        return step_fn.trace(*args).jaxpr
 
     def lower_train_step(self, batch: Dict[str, Any], lr: float = 0.1, step: int = 0):
         """AOT-lower-and-compile the jitted train step on `batch` WITHOUT
@@ -449,16 +497,8 @@ class TrainingTask:
         `input_output_alias` header (donation legality) off it, and the
         compile goes through the persistent cache so repeated probes are
         disk-bound."""
-        if self._train_step is None:
-            self._train_step = self._build_train_step()
-        self.model.train()
-        _, params, rest = self._split_model()
-        ema_decay = self.ema.get_decay(step) if self.ema is not None else 0.0
-        ema_in = self.ema_params if self.ema_params is not None else ()
-        sent_in = self._sentinel_state if self._sentinel_state is not None else ()
-        return self._train_step.lower(
-            params, rest, self.opt_state, ema_in, sent_in, batch,
-            jnp.asarray(lr, jnp.float32), jnp.asarray(ema_decay, jnp.float32)).compile()
+        step_fn, args = self._train_step_args(batch, lr, step)
+        return step_fn.lower(*args).compile()
 
     def _new_sentinel_state(self):
         """Fresh counters placed like the step's output: an unplaced array
@@ -481,10 +521,9 @@ class TrainingTask:
             self._eval_step = self._build_eval_step()
         self.model.eval()
         _, params, rest = self._split_model()
-        if use_ema and self.ema_params is not None:
-            return self._eval_step(self.ema_params, rest, batch)
-        out = self._eval_step(params, rest, batch)
-        self.model.train()
+        ema = self.ema_params if use_ema else None
+        out = self._eval_step(params if ema is None else ema, rest, batch)
+        self.model.train()  # train_step does not set the mode: the model stays in train mode between calls
         return out
 
     # -- module sync / checkpoint ------------------------------------------------

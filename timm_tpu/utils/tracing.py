@@ -52,12 +52,13 @@ SPANS = {
     'loader.decode_busy_ns': ('input', 'counter: wall ns the worker threads spent on those samples'),
     'loader.batches': ('input', 'counter: batches the collator thread handed over'),
     'task.train_step': ('step', 'the whole TrainingTask.train_step call'),
-    'task.state_split': ('step', 'model.train() + nnx.split of the live model'),
+    'task.state_split': ('step', "the arrays of the model's bound Variables read into two flat tuples"),
     'task.scalars_put': ('step', 'the two jnp.asarray scalar transfers (lr, ema decay)'),
-    'task.step_call': ('step', 'the jitted call: flatten, dispatch, any wait; first call also trace + compile'),
-    'task.state_update': ('step', 'nnx.update + EMA / sentinel state written back'),
+    'task.step_call': ('step', 'the jitted call on flat tuples: dispatch, the drop of the donated optimizer arrays; first call also trace + compile'),
+    'task.state_update': ('step', 'the returned arrays written into the same Variables (dropping the donated ones) + EMA / sentinel leaves kept'),
     'task.sentinel_poll': ('step', "sentinel.observe(): the device_get of the step's counters"),
     'task.sentinel_polls': ('step', 'counter: observe() calls that read the device'),
+    'task.state_binds': ('step', "counter: times the step was (re)built and bound to the model's Variables; 1 a run"),
     # device scopes (`scope`): jax.named_scope names on the program's ops, read from a trace's XLA Ops line
     'glm.embed': ('step', 'device scope: token embedding lookup'),
     'glm.mla.proj': ('attention', 'device scope: latent attention projections, their two RMSNorms and the rotary turn'),

@@ -1115,7 +1115,7 @@ def _host_line(since_ns, counters_before):
         text += (f" | loader q {sum(depths) / max(len(depths), 1):.1f} "
                  f"decode {did['loader.decode_busy_ns'] / did['loader.samples'] / 1e6:.1f} ms/img "
                  f"{did['loader.decode_busy_ns'] / did['loader.batches'] / 1e6:.0f} ms/batch "
-                 f"polls {did.get('task.sentinel_polls', 0)}")
+                 f"polls {did.get('task.sentinel_polls', 0)} binds {did.get('task.state_binds', 0)}")
     return text, snap['counters']
 
 

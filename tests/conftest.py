@@ -13,8 +13,8 @@ from timm_tpu.utils.compile_cache import configure_compile_cache
 use_virtual_cpu_devices(8)
 
 # Persistent XLA compilation cache: model sweeps recompile the same tiny
-# fixture programs every run. Subprocess tests (resilience drills, bench
-# children) that call configure_compile_cache resolve the same directory.
+# fixture programs every run. Subprocess tests (resilience drills) that call
+# configure_compile_cache resolve the same directory.
 configure_compile_cache()
 
 import pytest
@@ -30,7 +30,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         'markers',
         'perfbudget: hardware-independent perf-regression budgets + profiler '
-        'harness + bench replay smoke (runs in tier-1)')
+        'harness (runs in tier-1)')
     config.addinivalue_line(
         'markers',
         'deviceaug: on-device batch augmentation + NaFlex packed bucketed '
@@ -39,7 +39,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         'markers',
         'quant: int8 post-training weight-only quantization — round-trip '
-        'bounds, golden-fixture logits tolerance, scale-spec inheritance, '
+        'bounds, seeded-weights logits tolerance, scale-spec inheritance, '
         'quantized serve parity, distill smoke (runs in tier-1)')
     config.addinivalue_line(
         'markers',

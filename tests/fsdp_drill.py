@@ -3,7 +3,7 @@
 Run with XLA_FLAGS=--xla_force_host_platform_device_count=N (the parent test
 sets it). Modes:
 
-  parity8 <dir>  — 8 virtual CPU devices: train the golden-fixture ViT 3
+  parity8 <dir>  — 8 virtual CPU devices: train the tiny ViT (seeded input) 3
                    steps under a ('data','fsdp')=(2,4) mesh AND on a single
                    device; assert param/EMA parity ≤1e-6; durably save the
                    sharded task's checkpoint twice (raw sharded jax arrays vs
@@ -13,7 +13,7 @@ sets it). Modes:
                    single-device task, compare eval logits against the ones
                    the sharded task recorded, and re-save to prove the
                    manifest is stable across a save→load→save round trip.
-  parity_tp <dir> — 8 virtual CPU devices: same golden-fixture train under a
+  parity_tp <dir> — 8 virtual CPU devices: same tiny-ViT train under a
                    full ('data','fsdp','model')=(2,2,2) mesh (tensor
                    parallelism + activation sharding constraints) vs a single
                    device; assert parity, assert the attention/MLP kernels
@@ -54,6 +54,7 @@ from flax import nnx
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import timm_tpu  # noqa: E402
+from seeded_vit import seeded_input  # noqa: E402
 from timm_tpu.loss import LabelSmoothingCrossEntropy  # noqa: E402
 from timm_tpu.optim import create_optimizer_v2  # noqa: E402
 from timm_tpu.parallel import create_mesh, shard_batch  # noqa: E402
@@ -65,14 +66,12 @@ from timm_tpu.utils.serialization import flatten_pytree  # noqa: E402
 
 configure_compile_cache()
 
-FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), 'fixtures', 'vit_tiny_img64_golden.npz')
 MODEL, IMG, CLASSES = 'vit_tiny_patch16_224', 64, 1000
 STEPS, BATCH = 3, 8
 
 
-def golden_batch(mesh):
-    with np.load(FIXTURE) as d:
-        x = np.tile(d['x'], (BATCH // d['x'].shape[0], 1, 1, 1))
+def seeded_batch(mesh):
+    x = np.tile(seeded_input(), (BATCH // 2, 1, 1, 1))
     t = np.random.RandomState(0).randint(0, CLASSES, BATCH)
     return shard_batch({'input': jnp.asarray(x), 'target': jnp.asarray(t)}, mesh)
 
@@ -90,7 +89,7 @@ def make_task(mesh):
 
 
 def train(task, mesh):
-    batch = golden_batch(mesh)
+    batch = seeded_batch(mesh)
     for i in range(STEPS):
         metrics = task.train_step(batch, lr=0.05, step=i + 1)
     assert np.isfinite(float(metrics['loss'])), metrics
@@ -119,7 +118,7 @@ def parity8(workdir):
                       {k: np.asarray(v) for k, v in flatten_pytree(task_1.ema_params).items()})
 
     # eval logits recorded for the cross-mesh reload drill
-    batch = golden_batch(mesh_fsdp)
+    batch = seeded_batch(mesh_fsdp)
     logits = np.asarray(task_f.eval_step({'input': batch['input']}))
     np.save(os.path.join(workdir, 'logits_fsdp.npy'), logits)
 
@@ -158,8 +157,7 @@ def load1(workdir):
     mesh = create_mesh()
     task = make_task(mesh)
     task.load_checkpoint_state(state)
-    with np.load(FIXTURE) as d:
-        x = np.tile(d['x'], (BATCH // d['x'].shape[0], 1, 1, 1))
+    x = np.tile(seeded_input(), (BATCH // 2, 1, 1, 1))
     logits = np.asarray(task.eval_step({'input': shard_batch(jnp.asarray(x), mesh)}))
     saved = np.load(os.path.join(workdir, 'logits_fsdp.npy'))
     eval_diff = float(np.abs(logits - saved).max())
@@ -205,7 +203,7 @@ def parity_tp(workdir):
                       {k: np.asarray(v) for k, v in flatten_pytree(task_1.ema_params).items()})
 
     set_global_mesh(mesh_tp)
-    batch = golden_batch(mesh_tp)
+    batch = seeded_batch(mesh_tp)
     logits = np.asarray(task_t.eval_step({'input': batch['input']}))
     np.save(os.path.join(workdir, 'logits_tp.npy'), logits)
 
@@ -243,8 +241,7 @@ def load1_tp(workdir):
     mesh = create_mesh()
     task = make_task(mesh)
     task.load_checkpoint_state(state)
-    with np.load(FIXTURE) as d:
-        x = np.tile(d['x'], (BATCH // d['x'].shape[0], 1, 1, 1))
+    x = np.tile(seeded_input(), (BATCH // 2, 1, 1, 1))
     logits = np.asarray(task.eval_step({'input': shard_batch(jnp.asarray(x), mesh)}))
     saved = np.load(os.path.join(workdir, 'logits_tp.npy'))
     print(json.dumps({

@@ -1,5 +1,5 @@
 """CLI for the multi-process host-loss drill (tests/test_multihost.py runs the
-same drill in tier-1; this wrapper exists for manual runs and bench replay).
+same drill in tier-1; this wrapper exists for manual runs).
 
 Launches an N-subprocess JAX cluster on CPU (one device per process, real
 `jax.distributed.initialize` over a localhost coordinator), trains the tiny

@@ -164,7 +164,7 @@ def test_tier_a_clean_at_head():
                           select(tiers=['A']))
     assert report.exit_code == EXIT_CLEAN, report.format_text()
     assert set(report.rules) >= (MIGRATED | {'host-sync', 'traced-branch',
-                                             'pragma-syntax', 'process-zero-io'})
+                                             'pragma-syntax', 'process-zero-io', 'layering'})
 
 
 @pytest.mark.slow
@@ -191,6 +191,7 @@ def _run_rule(rule_name, subdir):
     ('traced-branch', 'traced_branch.py'),
     ('fp32-softmax', 'fp32_softmax.py'),
     ('process-zero-io', 'process_zero_io.py'),
+    ('layering', 'layering.py'),
 ])
 def test_planted_source_violation_fails_and_waiver_suppresses(rule_name, filename):
     report = _run_rule(rule_name, 'source')

@@ -160,15 +160,15 @@ def test_correction_factor_scales_time_but_not_order():
         2.0 * r1.ranked[0].cost.step_ms, rel=1e-6)
 
 
-def test_load_correction_reads_bench_self(tmp_path):
-    from timm_tpu.autotune import load_correction
+def test_v5e_peaks_agree_with_the_benchmarks_table():
+    """Two peak tables remain until ROADMAP D6 falls: the cost model's and the
+    one the ledger's `step_mfu.train` divides by. They may not drift."""
+    from benchmarks.harness.peaks import peak
+    from timm_tpu.autotune.cost import DEVICE_CLASSES
 
-    path = tmp_path / 'BENCH_SELF.json'
-    assert load_correction(str(path)) == 1.0             # missing file
-    path.write_text(json.dumps({'autotune': {'correction': 1.37}}))
-    assert load_correction(str(path)) == pytest.approx(1.37)
-    path.write_text('not json')
-    assert load_correction(str(path)) == 1.0             # corrupt -> neutral
+    v5e, ledger = DEVICE_CLASSES['v5e'], peak('TPU v5 lite')
+    assert v5e.peak_flops == ledger['bf16_flops']
+    assert v5e.hbm_bw == ledger['hbm_bytes_per_s']
 
 
 # ---- estimator vs probed ----------------------------------------------------
@@ -358,29 +358,6 @@ def test_cost_analysis_logs_config_name_once(caplog):
     msgs = [r.getMessage() for r in caplog.records if 'boomcfg' in r.getMessage()]
     assert len(msgs) == 1, 'the warning must fire exactly once per config'
     assert 'RuntimeError' in msgs[0] and 'backend says no' in msgs[0]
-
-
-def test_probe_matrix_and_budgets_carry_autotune_config():
-    from timm_tpu.perfbudget.budgets import load_budgets
-    from timm_tpu.perfbudget.probe import DEFAULT_MATRIX
-
-    cfg = next(c for c in DEFAULT_MATRIX if c.name == 'autotune')
-    assert cfg.collect == 'autotune'
-    assert cfg.batch_size * cfg.grad_accum == 64
-    budgets = load_budgets()
-    entry = budgets['configs']['autotune']
-    for key in ('autotune_candidates', 'autotune_winner_fsdp',
-                'autotune_winner_legal', 'donation_ok', 'flops'):
-        assert key in entry, key
-
-
-def test_replay_checklist_has_autotune_step():
-    from timm_tpu.perfbudget.replay import REPLAY_STEPS
-
-    assert len(REPLAY_STEPS) == 21
-    step = next(s for s in REPLAY_STEPS if s['id'] == 'autotune')
-    assert step['kind'] == 'autotune'
-    assert step['dry']['top_k'] >= 2 and step['live']['top_k'] == 3
 
 
 # ---- user surfaces ----------------------------------------------------------

@@ -277,8 +277,8 @@ def test_parity_broken_kernel_deleted_without_timing():
 
 def test_portfolio_verdicts_pending_off_claimed_hardware():
     """The shipped portfolio claims TPU; in tier-1 (CPU) every verdict must
-    be `pending` with parity measured — the dry arm of the replay `kernels`
-    step and `bench.py --kernels --dry-run`."""
+    be `pending` with parity measured — `run_kernel_ab`'s parity-and-gates arm
+    (`live=False`)."""
     recs = harness.run_kernel_ab(live=False, steps=1)
     assert [r['kernel'] for r in recs] == sorted(r['kernel'] for r in recs)
     assert {r['kernel'] for r in recs} == set(registry.kernel_names())

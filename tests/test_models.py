@@ -1,181 +1,21 @@
-"""Model zoo tests (reference: tests/test_models.py — forward/backward/cfg
-consistency/features parametrized over the registry)."""
-import jax
+"""Model zoo tests (reference: tests/test_models.py): feature extraction and
+the single cases. The forward, backward and cfg sweeps are in
+`test_models_forward.py`, `test_models_backward.py` and `test_models_cfg.py`."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from flax import nnx
 
 import timm_tpu
-from timm_tpu.models import list_models, get_pretrained_cfg
+from timm_tpu.models import list_models
 
-# size-capped like the reference (_get_input_size, EXCLUDE filters :79-113);
-# the default (fast) forward sweep covers small per-family representatives,
-# the full registry sweep runs under -m slow (reference shards this across CI)
-FAST_FILTERS = [
-    'test_*', 'vit_tiny*', 'vit_small_patch32*', '*_atto', '*_femto', '*_pico',
-    'resnet18', 'resnet26', 'mixer_s32*', 'efficientnet_b0',
-]
-EXCLUDE_FILTERS = [
-    '*_large*', '*_huge*', '*so400m*', '*_384', '*_giant*', '*_gigantic*', '*_xlarge*',
-    'resnet101*', 'resnet152*', 'wide_resnet*', 'efficientnetv2_m*', 'mixer_l*',
-    '*x4_clip*', '*x16_clip*', '*x64_clip*', 'repvgg_d2se', 'repvgg_b3*',
-    'bat_*',  # BAT bilinear attn needs 256px inputs (block_size 8 divisibility)
-]
-TEST_MODELS = list_models(filter=FAST_FILTERS)
-ALL_MODELS = list_models(exclude_filters=EXCLUDE_FILTERS)
-SLOW_MODELS = [m for m in ALL_MODELS if m not in TEST_MODELS]
-FWD_SIZE = 64
-
-
-def _create_small(model_name, **kwargs):
-    cfg = get_pretrained_cfg(model_name)
-    fixed = cfg is not None and cfg.fixed_input_size
-    try:
-        return timm_tpu.create_model(model_name, img_size=FWD_SIZE, num_classes=10, **kwargs), FWD_SIZE
-    except TypeError:
-        return timm_tpu.create_model(model_name, num_classes=10, **kwargs), (cfg.input_size[-1] if cfg else 224)
-
-
-@pytest.mark.base
-@pytest.mark.parametrize('model_name', TEST_MODELS)
-def test_model_forward(model_name):
-    model, size = _create_small(model_name)
-    model.eval()
-    x = jnp.asarray(np.random.rand(2, size, size, 3), jnp.float32)
-    out = model(x)
-    assert out.shape == (2, 10)
-    assert bool(jnp.isfinite(out).all()), 'Output contains NaN/Inf'
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize('model_name', SLOW_MODELS)
-def test_model_forward_slow(model_name):
-    model, size = _create_small(model_name)
-    model.eval()
-    x = jnp.asarray(np.random.rand(1, size, size, 3), jnp.float32)
-    out = model(x)
-    assert out.shape == (1, 10)
-    assert bool(jnp.isfinite(out).all())
-
-
-# one small representative per family for gradient coverage (reference
-# tests/test_models.py:213 runs backward over every model; we cover every
-# FAMILY with its smallest member to keep CPU wall time bounded)
-FAMILY_BACKWARD_MODELS = [
-    'vit_tiny_patch16_224', 'vit_tiny_r_s16_p8_224', 'deit_tiny_distilled_patch16_224', 'eva02_tiny_patch14_336',
-    'beit_base_patch16_224', 'cait_xxs24_224', 'xcit_nano_12_p16_224',
-    'levit_128s', 'volo_d1_224', 'mvitv2_tiny', 'swin_tiny_patch4_window7_224', 'edgenext_xx_small',
-    'repvit_m0_9', 'tiny_vit_5m_224', 'efficientformer_l1', 'efficientformerv2_s0',
-    'mobilevit_xxs', 'mobilevitv2_050', 'twins_svt_small', 'mambaout_femto',
-    'swinv2_tiny_window8_256', 'coatnet_pico_rw_224', 'maxvit_pico_rw_256',
-    'mixer_s32_224', 'convnext_atto', 'resnet18', 'resnetv2_50', 'nf_resnet50',
-    'regnetx_002', 'vgg11', 'densenet121', 'efficientnet_lite0',
-    'mobilenetv3_small_100', 'mnasnet_050', 'lcnet_035', 'gernet_s',
-    'halonet26t', 'lambda_resnet26t', 'botnet26t_256',
-]
-_family_backward = FAMILY_BACKWARD_MODELS
-
-
-# halo blocked attention needs block_size (8) to divide every stage grid
-_BACKWARD_SIZE_OVERRIDES = {
-    'halonet26t': 256,
-    'efficientformer_l1': 224,  # fixed 7x7 attention-bias table in the final stage
-}
-
-
-@pytest.mark.backward
-@pytest.mark.slow
-@pytest.mark.parametrize('model_name', _family_backward)
-def test_model_backward_family(model_name):
-    """Gradient sweep, one representative per family (markers: backward+slow).
-
-    Also marked slow: each case re-traces and lowers a full-size model's
-    fwd+bwd (~30s CPU; the persistent XLA cache only skips the compile, not
-    the trace), so the 39-family sweep is a ~20-minute job that belongs in
-    the explicit `-m backward` / `-m slow` tiers, not the fast suite. Until
-    the flax-compat fixes these cases crashed at import time, which is the
-    only reason they ever looked cheap enough for the fast tier."""
-    cfg = get_pretrained_cfg(model_name)
-    want = _BACKWARD_SIZE_OVERRIDES.get(model_name, 96)
-    try:
-        model = timm_tpu.create_model(model_name, img_size=want, num_classes=5)
-        size = want
-    except TypeError:
-        model = timm_tpu.create_model(model_name, num_classes=5)
-        size = cfg.input_size[-1] if cfg else 224
-    model.train()
-    x = jnp.asarray(np.random.rand(2, size, size, 3), jnp.float32)
-    t = jnp.asarray([0, 1])
-
-    def loss_fn(model):
-        out = model(x)
-        out = out[0] if isinstance(out, tuple) else out
-        return jnp.mean((out - jax.nn.one_hot(t, out.shape[-1])) ** 2)
-
-    grads = nnx.grad(loss_fn)(model)
-    num_params = len(jax.tree.leaves(nnx.state(model, nnx.Param)))
-    num_grads = len([g for g in jax.tree.leaves(grads) if g is not None])
-    assert num_params == num_grads, 'Some params missing gradients'
-    finite = all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
-    assert finite, 'NaN/Inf gradient'
-
-
-@pytest.mark.base
-@pytest.mark.parametrize('model_name', list_models('test_*'))
-def test_model_backward(model_name):
-    model, size = _create_small(model_name)
-    model.train()
-    x = jnp.asarray(np.random.rand(2, size, size, 3), jnp.float32)
-    t = jnp.asarray([0, 1])
-
-    def loss_fn(model):
-        out = model(x)
-        return jnp.mean((out - jax.nn.one_hot(t, out.shape[-1])) ** 2)
-
-    grads = nnx.grad(loss_fn)(model)
-    num_params = len(jax.tree.leaves(nnx.state(model, nnx.Param)))
-    num_grads = len([g for g in jax.tree.leaves(grads) if g is not None])
-    assert num_params == num_grads, 'Some params missing gradients'
-    for g in jax.tree.leaves(grads):
-        assert bool(jnp.isfinite(g).all()), 'NaN/Inf gradient'
-
-
-@pytest.mark.cfg
-@pytest.mark.parametrize('model_name', ALL_MODELS)
-def test_model_default_cfg(model_name):
-    cfg = get_pretrained_cfg(model_name)
-    if cfg is None:
-        pytest.skip('no pretrained cfg')
-    # headless feature models (e.g. CLIP trunks) legitimately ship num_classes=0
-    assert cfg.num_classes >= 0
-    assert len(cfg.input_size) == 3
-    assert cfg.classifier is not None
-    assert cfg.first_conv is not None
-
-
-@pytest.mark.cfg
-@pytest.mark.parametrize('model_name', list_models('test_*'))
-def test_model_classifier_reset(model_name):
-    model, size = _create_small(model_name)
-    model.eval()
-    x = jnp.asarray(np.random.rand(1, size, size, 3), jnp.float32)
-    # pre-logits / identity head
-    model.reset_classifier(0)
-    out = model(x)
-    # heads with a pre-logits MLP keep it on reset (reference ClNormMlpClassifierHead
-    # semantics: reset() without reset_other preserves hidden layers)
-    want = {model.num_features, getattr(model, 'head_hidden_size', model.num_features)}
-    assert out.ndim == 2 and out.shape[-1] in want
-    # new head size
-    model.reset_classifier(7)
-    assert model(x).shape == (1, 7)
+from models_common import FWD_SIZE, create_small
 
 
 @pytest.mark.features
 @pytest.mark.parametrize('model_name', list_models('test_*'))
 def test_model_forward_intermediates(model_name):
-    model, size = _create_small(model_name)
+    model, size = create_small(model_name)
     model.eval()
     x = jnp.asarray(np.random.rand(1, size, size, 3), jnp.float32)
     final, intermediates = model.forward_intermediates(x, indices=(0, 1))

@@ -1,4 +1,4 @@
-"""Perf-budget suite: hardware-independent regression gates + replay smoke.
+"""Perf-budget suite: hardware-independent regression gates.
 
 1. Budget semantics: the tolerance policy fails on regression AND on silent
    improvement (re-baseline only via --update-budgets), and a metric that
@@ -6,32 +6,20 @@
 2. Seed budgets: probing the live code against tests/fixtures/
    perf_budgets.json stays clean; an injected block_scan=False regression
    trips the jaxpr-eqn AND trace-time budgets for the scanned config.
-3. BENCH_SELF.json v2 document: result/replay round-trips, v1 upgrade,
-   schema validation.
-4. `bench.py --replay --dry-run` (subprocess): the ENTIRE queued PERF.md
-   checklist completes unattended with a schema-valid BENCH_SELF.json;
-   `bench.py` with no TPU exits non-zero and prints no number.
+3. `python -m timm_tpu.perfbudget`'s `main`: exit codes and the printed
+   violation, over the session's capture.
 """
 import json
-import os
-import subprocess
-import sys
 
 import pytest
 
 from timm_tpu.perfbudget import (
     DEFAULT_MATRIX, ProbeConfig, check_counter, check_counter_min, check_ratio_max,
     check_ratio_min, check_upper, compare_budgets, compare_config, format_violations,
-    load_budgets, load_self_doc, probe_config,
-    record_result, run_matrix, tolerance_for,
-    update_budgets, validate_self_result,
+    load_budgets, probe_config, run_matrix, tolerance_for, update_budgets,
 )
-from timm_tpu.perfbudget.replay import REPLAY_STEPS, SELF_SCHEMA
 
 pytestmark = pytest.mark.perfbudget
-
-REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), '..'))
-BENCH = os.path.join(REPO_ROOT, 'bench.py')
 
 
 # ---- 1. tolerance policy (pure, no jax) -------------------------------------
@@ -207,103 +195,38 @@ def test_run_matrix_rejects_unknown_config():
         run_matrix(names=['no_such_config'])
 
 
-# ---- 3. BENCH_SELF.json v2 document -----------------------------------------
+# ---- 3. the CLI the README names --------------------------------------------
 
-def test_self_doc_roundtrip_and_v1_upgrade(tmp_path):
-    path = str(tmp_path / 'BENCH_SELF.json')
+def test_cli_compares_against_budgets_and_exits_nonzero_on_a_tampered_band(
+        tmp_path, monkeypatch, capsys, seed_budgets, analysis_programs):
+    """`python -m timm_tpu.perfbudget`: exit 0 against the checked-in budgets,
+    exit 1 with the violation printed against a `--budgets` file with one band
+    tampered. `run_matrix` is the session's capture, so nothing is lowered
+    again; `trace_ms` is taken from the budget for the reason
+    `test_seed_budgets_pass_on_live_code` gives."""
+    from timm_tpu.perfbudget import __main__ as cli
 
-    # missing and corrupt files both yield a writable fresh document
-    assert load_self_doc(path)['schema'] == SELF_SCHEMA
-    with open(path, 'w') as f:
-        f.write('{truncated')
-    assert load_self_doc(path)['schema'] == SELF_SCHEMA
+    names = list(analysis_programs['names'])
+    measured = {n: dict(analysis_programs['measured'][n]) for n in names}
+    for n, metrics in measured.items():
+        if 'trace_ms' in metrics:
+            metrics['trace_ms'] = seed_budgets['configs'][n]['trace_ms']
+    asked = []
 
-    result = {'metric': 'm', 'value': 1.0, 'unit': 'ok', 'vs_baseline': None}
-    record_result(path, result)
-    doc = load_self_doc(path)
-    assert doc['result'] == result and doc['measured_at']
-    assert validate_self_result(doc) == []
+    def captured_matrix(names=None, log=None):
+        asked.append(list(names))
+        return {n: measured[n] for n in names}
 
-    # pre-v2 files (bare {'measured_at', 'result'}) upgrade losslessly
-    v1 = str(tmp_path / 'v1.json')
-    with open(v1, 'w') as f:
-        json.dump({'measured_at': '2026-01-01T00:00:00Z', 'result': result}, f)
-    doc = load_self_doc(v1)
-    assert doc['schema'] == SELF_SCHEMA and doc['result'] == result
-    assert doc['measured_at'] == '2026-01-01T00:00:00Z'
+    monkeypatch.setattr('timm_tpu.perfbudget.probe.run_matrix', captured_matrix)
+    argv = ['--configs', ','.join(names)]
+    assert cli.main(argv) == 0, capsys.readouterr().out
+    assert 'all metrics within budget' in capsys.readouterr().out
+    assert asked == [names]
 
-    # validator actually rejects malformed documents
-    assert validate_self_result({'schema': 'bogus'})
-    bad = load_self_doc(path)
-    bad['result'] = {'metric': 'no value'}
-    assert validate_self_result(bad)
-
-
-# ---- 4. bench.py integration (subprocess) -----------------------------------
-
-def _bench_env(tmp_path, **extra):
-    env = dict(os.environ, JAX_PLATFORMS='cpu',
-               TIMM_TPU_BENCH_SELF=str(tmp_path / 'BENCH_SELF.json'))
-    env.update({k: str(v) for k, v in extra.items()})
-    return env
-
-
-def _last_json(stdout):
-    return json.loads(stdout.strip().splitlines()[-1])
-
-
-def test_replay_dry_run_completes_full_checklist(tmp_path):
-    """Acceptance: `bench.py --replay --dry-run` runs the ENTIRE queued
-    PERF.md checklist unattended and leaves a schema-valid BENCH_SELF.json
-    with a record for every step."""
-    env = _bench_env(tmp_path)
-    r = subprocess.run([sys.executable, BENCH, '--replay', '--dry-run'],
-                       env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-                       timeout=600)
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
-    out = _last_json(r.stdout)
-    assert out['unit'] == 'checklist steps ok'
-
-    doc = load_self_doc(env['TIMM_TPU_BENCH_SELF'])
-    assert validate_self_result(doc) == [], validate_self_result(doc)
-    replay = doc['replay']
-    assert replay['dry_run'] is True and replay['failed'] == 0
-    ran = {s['id']: s['status'] for s in replay['steps']}
-    assert set(ran) == {s['id'] for s in REPLAY_STEPS}
-    assert set(ran.values()) == {'ok'}, ran
-    assert out['value'] == float(replay['completed']) == float(len(REPLAY_STEPS))
-
-
-def test_replay_steps_subset_and_unknown_id(tmp_path):
-    env = _bench_env(tmp_path)
-    r = subprocess.run([sys.executable, BENCH, '--replay', '--dry-run',
-                        '--replay-steps', 'serve_drill'],
-                       env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
-    doc = load_self_doc(env['TIMM_TPU_BENCH_SELF'])
-    assert [s['id'] for s in doc['replay']['steps']] == ['serve_drill']
-    assert doc['replay']['steps'][0]['status'] == 'ok'
-
-    r = subprocess.run([sys.executable, BENCH, '--replay', '--dry-run',
-                        '--replay-steps', 'bogus_step'],
-                       env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-                       timeout=120)
-    assert r.returncode != 0
-
-
-def test_bench_without_a_tpu_exits_nonzero_with_no_number(tmp_path):
-    """`python bench.py` where JAX finds no TPU: non-zero exit, nothing on
-    stdout — in particular no result recorded by an earlier run."""
-    self_path = str(tmp_path / 'BENCH_SELF.json')
-    prior = {'metric': 'vit_tiny_patch16_224 train img/s/chip', 'value': 321.0,
-             'unit': 'img/s/chip', 'vs_baseline': None}
-    record_result(self_path, prior)
-
-    r = subprocess.run([sys.executable, BENCH, '--fast', '--save-self'],
-                       env=_bench_env(tmp_path), cwd=REPO_ROOT, capture_output=True,
-                       text=True, timeout=300)
-    assert r.returncode == 2, (r.returncode, r.stdout[-2000:], r.stderr[-1000:])
-    assert r.stdout.strip() == '', r.stdout[-2000:]
-    assert 'no accelerator' in r.stderr
-    assert load_self_doc(self_path)['result'] == prior, 'a failed run touched the record'
+    tampered = json.loads(json.dumps(seed_budgets))
+    tampered['configs']['base']['jaxpr_eqns'] *= 2
+    path = tmp_path / 'budgets.json'
+    path.write_text(json.dumps(tampered))
+    assert cli.main(argv + ['--budgets', str(path)]) == 1
+    out = capsys.readouterr().out
+    assert '1 budget violation(s)' in out and '[improvement] base.jaxpr_eqns' in out

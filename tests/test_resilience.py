@@ -254,26 +254,11 @@ def test_fault_selftest_all_checks_pass(tmp_path):
     assert result['ok'], result
 
 
-def test_bench_dry_run_fault_inject_smoke():
-    """`bench.py --dry-run --fault-inject` exercises the injection hooks in
-    tier-1 without a slow run (in-process, same idiom as
-    test_precision_policy's dry-run sweep)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        'bench_resilience', os.path.join(REPO_ROOT, 'bench.py'))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    class Args:
-        model = 'test_vit'
-        img_size = 32
-        pad_tokens = ''
-        softmax_dtype = ''
-        norm_dtype = ''
-        mu_dtype = ''
-        fault_inject = 'truncate_ckpt,io_error%2,nan_grads@1:2,sigterm@3,resize@5:4'
-
-    assert bench._dry_run(Args()) == 0
+def test_fault_selftest_full_spec_smoke():
+    """Every fault kind in one spec, the multi-update and resize forms among
+    them, through the injection hooks in tier-1 without a slow run."""
+    result = fault_selftest('truncate_ckpt,io_error%2,nan_grads@1:2,sigterm@3,resize@5:4')
+    assert result['ok'], result
 
 
 def test_resize_fault_spec():

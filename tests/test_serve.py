@@ -311,23 +311,18 @@ def test_sharded_serving_matches_single_device(tmp_path):
     assert d['logits_max_diff'] <= 1e-5, d
 
 
-# ---- 8. load-drill subprocess smoke ------------------------------------------
+# ---- 8. load-drill smoke -------------------------------------------------------
 
 @pytest.mark.serve
-def test_bench_serve_drill_smoke():
-    """`bench.py --serve --dry-run`: canonical A/B drill (two buckets, two
-    models, eviction) prints the p50/p99 summary line and a result line whose
-    value is the continuous-vs-per-request speedup (> 1.0 by acceptance)."""
-    env = dict(os.environ, JAX_PLATFORMS='cpu')
-    env.pop('XLA_FLAGS', None)  # single-device: the drill engine is one replica
-    r = subprocess.run(
-        [sys.executable, 'bench.py', '--serve', '--dry-run'],
-        env=env, cwd=REPO_ROOT, capture_output=True, text=True, timeout=300)
-    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
-    lines = r.stdout.strip().splitlines()
-    assert any(l.startswith('serve-drill:') and 'p50' in l and 'p99' in l
-               for l in lines), lines
-    result = json.loads(lines[-1])
-    assert result['unit'] == 'x img/s vs per-request'
-    assert result['value'] > 1.0, result
-    assert 'eviction' in result['metric']
+def test_serve_drill_smoke():
+    """`canonical_drill`: the A/B drill (two buckets, two models, eviction)
+    gives a continuous-vs-per-request speedup > 1.0 (its own acceptance), and
+    `summary_line` prints the p50/p99 line for both arms."""
+    from timm_tpu.serve import canonical_drill, summary_line
+
+    ab = canonical_drill()
+    assert ab['speedup'] > 1.0, ab
+    assert ab['continuous']['evictions'] >= 1, ab
+    line = summary_line(ab)
+    assert line.startswith('serve-drill:') and 'p50' in line and 'p99' in line, line
+    assert 'eviction' in line and f'{ab["speedup"]}x' in line, line

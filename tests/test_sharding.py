@@ -203,8 +203,8 @@ def test_shard_batch_2axis_and_divisibility_error(mesh8):
 
 # ---- donated jitted steps ----------------------------------------------------
 
-def _make_task(mesh, opt='sgd', **kwargs):
-    model = timm_tpu.create_model('test_vit', num_classes=10, img_size=32)
+def _make_task(mesh, opt='sgd', model_args=('test_vit', dict(num_classes=10, img_size=32)), **kwargs):
+    model = timm_tpu.create_model(model_args[0], **model_args[1])
     optimizer = create_optimizer_v2(model, opt=opt, lr=0.1, momentum=0.9)
     return ClassificationTask(model, optimizer=optimizer, mesh=mesh,
                               train_loss_fn=LabelSmoothingCrossEntropy(0.1), **kwargs)
@@ -593,14 +593,19 @@ def test_tp_constraint_in_scan_body_and_no_involuntary_remat(restore_global_mesh
     assert out.shape == (8, 1000) and bool(jnp.isfinite(out).all())
 
 
-def test_tp_task_train_eval_in_process(restore_global_mesh):
+@pytest.mark.parametrize('model_args', [
+    ('test_vit', dict(num_classes=10, img_size=32)),
+    # the tp compile smoke at a zoo model's widths: 3 heads over tp=2, 1000 classes (two blocks: depth adds compile time only)
+    ('vit_tiny_patch16_224', dict(img_size=32, depth=2)),
+], ids=['test_vit', 'vit_tiny-fsdp2-tp2'])
+def test_tp_task_train_eval_in_process(restore_global_mesh, model_args):
     """(2,2,2) task end-to-end in-process: kernels 2-D sharded, donated train
     steps run, eval finite, and loss tracks the fsdp-only task closely (fp
     reduction-order noise only — constraints change layout, not math)."""
     from timm_tpu.parallel import set_global_mesh
     mesh = _tp_mesh()
     set_global_mesh(mesh)
-    task = _make_task(mesh, opt='adamw')
+    task = _make_task(mesh, opt='adamw', model_args=model_args)
     qkv = nnx.state(task.model, nnx.Param)['blocks'][0]['attn']['qkv']['kernel'].value
     assert 'model' in tuple(qkv.sharding.spec) and 'fsdp' in tuple(qkv.sharding.spec)
     batch = _batch(mesh)
@@ -609,7 +614,7 @@ def test_tp_task_train_eval_in_process(restore_global_mesh):
     assert np.isfinite(np.asarray(out)).all()
 
     set_global_mesh(_fsdp_mesh(4))
-    task_f = _make_task(_fsdp_mesh(4), opt='adamw')
+    task_f = _make_task(_fsdp_mesh(4), opt='adamw', model_args=model_args)
     batch_f = _batch(_fsdp_mesh(4))
     losses_f = [float(task_f.train_step(batch_f, lr=1e-3, step=i + 1)['loss']) for i in range(2)]
     # step 1 runs on identical params: pure forward reduction-order noise.
@@ -617,27 +622,6 @@ def test_tp_task_train_eval_in_process(restore_global_mesh):
     # tight ≤1e-5 parity acceptance lives in the 8-device subprocess drill.
     np.testing.assert_allclose(losses_tp[0], losses_f[0], atol=1e-4)
     np.testing.assert_allclose(losses_tp[1], losses_f[1], rtol=5e-2)
-
-
-def test_bench_dry_run_tp_smoke(restore_global_mesh):
-    """`bench.py --dry-run --fsdp 2 --tp 2` compiles + runs a (2,2,2)-mesh
-    train/infer step on CPU (the tp compile smoke the on-device A/B rides on)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location('bench_tp_smoke', os.path.join(REPO_ROOT, 'bench.py'))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    class Args:
-        model = 'vit_tiny_patch16_224'
-        img_size = 32
-        pad_tokens = ''
-        softmax_dtype = ''
-        norm_dtype = ''
-        mu_dtype = ''
-        fsdp = 2
-        tp = 2
-
-    assert bench._dry_run(Args()) == 0
 
 
 @pytest.mark.slow

@@ -1,6 +1,10 @@
 """Utility tests (reference: tests/test_utils.py — freeze/EMA/AGC/unwrap; plus
 the extraction/relabel helpers)."""
 import json
+import os
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -90,3 +94,45 @@ def test_flatten_unflatten_roundtrip():
     rebuilt = unflatten_into(tree, flat, 'x')
     for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(rebuilt)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_the_program_imports_none_of_its_cpu_tools():
+    """What a benchmark cell imports is what the program is: in a fresh
+    interpreter, `import train` and a `ClassificationTask` built on `test_vit`
+    pull in none of the CPU tools (`analysis/source_rules.py`'s `layering` rule
+    reads the same from the source; this reads it from `sys.modules`)."""
+    code = '''
+import sys
+import train
+import timm_tpu
+from timm_tpu.optim import create_optimizer_v2
+from timm_tpu.task import ClassificationTask
+model = timm_tpu.create_model('test_vit', num_classes=10, img_size=32)
+ClassificationTask(model, optimizer=create_optimizer_v2(model, opt='adamw', lr=1e-3))
+tools = ('bench', 'timm_tpu.perfbudget', 'timm_tpu.analysis', 'timm_tpu.autotune')
+print('LOADED', sorted(m for m in sys.modules if m in tools or m.startswith(tuple(t + '.' for t in tools))))
+'''
+    r = subprocess.run([sys.executable, '-c', code], cwd=REPO_ROOT, capture_output=True, text=True,
+                       env=dict(os.environ, JAX_PLATFORMS='cpu'), timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert 'LOADED []' in r.stdout, r.stdout[-2000:]
+
+
+def test_readme_names_only_what_is_in_the_tree():
+    """Every top-level script, every path in backticks under `timm_tpu/`,
+    `benchmarks/` or `tests/`, and every `python -m timm_tpu.<module>` that
+    README.md names exists."""
+    with open(os.path.join(REPO_ROOT, 'README.md'), encoding='utf-8') as f:
+        readme = f.read()
+    scripts = set(re.findall(r'(?<![\w/.-])([A-Za-z_]\w*\.py)\b', readme))
+    paths = {p.split('::')[0].rstrip('.,;:)')
+             for p in re.findall(r'`((?:timm_tpu|benchmarks|tests)/[^`\s]*)', readme) if not set(p) & set('<*{')}
+    modules = {m.replace('.', '/') for m in re.findall(r'python3? -m (timm_tpu[\w.]*)', readme)}
+    assert len(scripts) >= 5 and len(paths) >= 10 and len(modules) >= 3, (scripts, paths, modules)
+    missing = sorted(p for p in scripts | paths if not os.path.exists(os.path.join(REPO_ROOT, p)))
+    missing += sorted(m for m in modules if not any(
+        os.path.exists(os.path.join(REPO_ROOT, m + tail)) for tail in ('.py', '/__main__.py')))
+    assert not missing, f'README.md names what the tree does not have: {missing}'

@@ -2,7 +2,8 @@
 
 The five lints that used to live inline in tests/ (donation-declared,
 partition-rules, kernel-registered, fp32-softmax, silent-except) plus the
-new sweeps this PR adds (host-sync, traced-branch, pragma-syntax). All of
+sweeps added since (host-sync, traced-branch, pragma-syntax, process-zero-io,
+layering). All of
 them honor the unified pragma (see pragmas.py); the first four keep their
 historical waiver spellings via the shims.
 """
@@ -40,7 +41,7 @@ def silent_except(ctx: AnalysisContext) -> List[Finding]:
     files = list(ctx.walk_files(_PACKAGE))
     pkg_dir = ctx.source_dir(_PACKAGE)
     if pkg_dir != ctx.root:
-        # top-level driver scripts ride along (bench.py, train.py, ...)
+        # top-level driver scripts ride along (train.py, validate.py, ...)
         files += [os.path.join(ctx.root, f) for f in sorted(os.listdir(ctx.root))
                   if f.endswith('.py')]
     findings = []
@@ -514,6 +515,73 @@ def process_zero_io(ctx: AnalysisContext) -> List[Finding]:
                 'file write outside an `is_primary()` / `rank == 0` guard — '
                 'every pod host would race this write; guard it or waive '
                 'with `# timm-tpu-lint: disable=process-zero-io <reason>`'))
+    return findings
+
+
+# ---- layering ---------------------------------------------------------------
+
+_TOOL_PACKAGES = ('timm_tpu.perfbudget', 'timm_tpu.analysis', 'timm_tpu.autotune')
+_PROGRAM_DIRS = ('task', 'models', 'layers', 'data', 'optim', 'loss', 'scheduler',
+                 'parallel', 'kernels', 'utils')
+_PROGRAM_SCRIPTS = ('train.py', 'validate.py', 'inference.py')
+
+
+def _import_nodes(tree: ast.Module, module_level: bool) -> Iterable[ast.stmt]:
+    """The tree's import statements; with `module_level`, only those that run
+    when the module is imported (not under a `def` or a lambda)."""
+    stack: List[ast.AST] = [tree]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, (ast.Import, ast.ImportFrom)):
+            yield node
+        elif not (module_level and isinstance(
+                node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda))):
+            stack.extend(ast.iter_child_nodes(node))
+
+
+def _imported_names(ctx: AnalysisContext, path: str, node: ast.stmt) -> List[str]:
+    """Dotted names the statement may import, a relative `from` resolved
+    against the file's package."""
+    if isinstance(node, ast.Import):
+        return [a.name for a in node.names]
+    base = node.module or ''
+    if node.level:
+        package = os.path.dirname(ctx.rel(path)).split(os.sep)
+        package = package[:len(package) - (node.level - 1)]
+        base = '.'.join(package + ([node.module] if node.module else []))
+    return [base] + [f'{base}.{a.name}' for a in node.names]
+
+
+@rule('layering', 'A',
+      'the program does not import its CPU tools: no module under timm_tpu/'
+      '{task,models,layers,data,optim,loss,scheduler,parallel,kernels,utils} '
+      'and none of train.py / validate.py / inference.py imports '
+      'timm_tpu.perfbudget, timm_tpu.analysis or timm_tpu.autotune at module '
+      'level (what a benchmark cell imports is what the program is), and '
+      'timm_tpu/perfbudget imports no timm_tpu.autotune at any level (the '
+      'arrows read autotune -> perfbudget.probe, analysis -> perfbudget.probe)')
+def layering(ctx: AnalysisContext) -> List[Finding]:
+    if ctx.source_dir(_PACKAGE) != ctx.root:
+        program = [f for d in _PROGRAM_DIRS if os.path.isdir(os.path.join(ctx.root, _PACKAGE, d))
+                   for f in ctx.walk_files(_PACKAGE, d)]
+        program += [os.path.join(ctx.root, f) for f in _PROGRAM_SCRIPTS]
+        perfbudget = ctx.walk_files(_PACKAGE, 'perfbudget')
+    else:
+        # fixture layout: the flat planted-violation directory is program code
+        program, perfbudget = ctx.walk_files(), []
+    findings = []
+    for files, module_level, banned, why in (
+            (program, True, _TOOL_PACKAGES, 'the program imports a CPU tool at module level'),
+            (perfbudget, False, ('timm_tpu.autotune',), 'perfbudget imports autotune, which imports perfbudget.probe')):
+        for path in files:
+            tree = ctx.ast_of(path)
+            if tree is None:
+                continue
+            for node in _import_nodes(tree, module_level):
+                hit = next((n for n in _imported_names(ctx, path, node) for b in banned
+                            if n == b or n.startswith(b + '.')), None)
+                if hit is not None:
+                    findings.append(ctx.finding('layering', path, node.lineno, f'`{hit}`: {why}'))
     return findings
 
 

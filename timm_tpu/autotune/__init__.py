@@ -12,7 +12,7 @@ probe machinery lazily; all heavy imports happen inside functions.
 from .buckets import ladder_cost, ladder_waste, propose_buckets
 from .cost import (
     DEVICE_CLASSES, CostEstimate, DeviceClass, analytic_cost,
-    default_hbm_budget, detect_device_class, load_correction, probed_cost,
+    default_hbm_budget, detect_device_class, probed_cost,
     roofline_ms,
 )
 from .solver import (
@@ -29,7 +29,7 @@ __all__ = [
     'DEVICE_CLASSES', 'DeviceClass', 'LegalPoint', 'RankedPoint', 'Rejection',
     'analytic_cost', 'apply_to_args', 'autotune', 'batch_splits',
     'default_hbm_budget', 'detect_device_class', 'enumerate_configs',
-    'format_table', 'ladder_cost', 'ladder_waste', 'load_correction',
+    'format_table', 'ladder_cost', 'ladder_waste',
     'mesh_axis_points', 'probed_cost', 'propose_buckets',
     'resolve_config_for_topology', 'roofline_ms', 'to_json',
 ]

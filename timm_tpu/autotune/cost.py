@@ -19,15 +19,14 @@ the one below and all three rank with the SAME roofline:
 The roofline itself (Williams et al.): predicted step time is
 ``max(flops / peak_flops, bytes / hbm_bandwidth)`` per device class, with
 trace/compile cost as a deterministic tiebreak (block_scan=False traces
-O(depth) — it can never win a tie). A fitted live-hardware correction
-factor (bench.py --replay step `autotune`, persisted in BENCH_SELF.json)
-multiplies the predicted time; rankings are invariant to it but the printed
-milliseconds become honest once hardware has answered.
+O(depth) — it can never win a tie). A caller's ``correction`` factor
+multiplies the predicted time; rankings are invariant to it, and no run on
+the chip has fit one yet (ROADMAP D6), so the printed milliseconds are the
+nameplate roofline's.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
 import os
 from typing import Dict, Optional, Tuple
 
@@ -36,7 +35,7 @@ from .space import LegalPoint, OPT_SLOTS
 __all__ = [
     'DeviceClass', 'DEVICE_CLASSES', 'detect_device_class', 'roofline_ms',
     'CostEstimate', 'analytic_flops', 'analytic_bytes', 'analytic_cost',
-    'probed_cost', 'fit_scales', 'load_correction', 'REMAT_FLOPS_FACTOR',
+    'probed_cost', 'fit_scales', 'REMAT_FLOPS_FACTOR',
 ]
 
 # Full remat re-runs ~one forward of the fwd+bwd(≈3x fwd) step: 4/3 FLOPs.
@@ -200,19 +199,6 @@ def probed_cost(metrics: Dict, point: LegalPoint, dc: DeviceClass, *,
                         memory_ms=memory_ms, bound=bound, tier='probed',
                         flops=flops, bytes=bytes_,
                         trace_penalty=float(metrics.get('trace_ms', 0.0)))
-
-
-def load_correction(path: str = 'BENCH_SELF.json') -> float:
-    """The fitted live-hardware correction factor the replay `autotune` step
-    persisted (predicted->measured geomean ratio); 1.0 until a live run on
-    the chip has verified the top-K."""
-    try:
-        with open(path, encoding='utf-8') as f:
-            doc = json.load(f)
-        c = float(doc.get('autotune', {}).get('correction', 1.0))
-        return c if c > 0 else 1.0
-    except (OSError, ValueError, TypeError):
-        return 1.0
 
 
 def default_hbm_budget(dc: DeviceClass) -> int:

@@ -1,8 +1,7 @@
 """The autotuner: enumerate -> rank -> (optionally) probe -> apply.
 
 `autotune()` is the one entry point behind every surface: `train.py
---autotune`, `python -m timm_tpu.autotune`, the replay checklist's
-`autotune` step, and the elastic re-solve
+--autotune`, `python -m timm_tpu.autotune`, and the elastic re-solve
 (:func:`resolve_config_for_topology`). It holds the global batch exactly
 constant — the same invariant elastic resume enforces — and only searches
 placement/decomposition.
@@ -22,7 +21,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from .cost import (
     CostEstimate, DeviceClass, analytic_cost, default_hbm_budget,
-    detect_device_class, fit_scales, load_correction, probed_cost,
+    detect_device_class, fit_scales, probed_cost,
 )
 from .space import CandidateConfig, LegalPoint, Rejection, enumerate_configs
 
@@ -141,7 +140,7 @@ def autotune(
         fsdp_candidates: Optional[Sequence[int]] = None,
         tp_candidates: Optional[Sequence[int]] = None,
         device_class: Optional[DeviceClass] = None,
-        correction: Optional[float] = None,
+        correction: float = 1.0,
         log=None,
 ) -> AutotuneResult:
     """Rank every legal config for `model` at a fixed global batch.
@@ -157,7 +156,7 @@ def autotune(
     n_devices = int(n_devices) if n_devices else jax.device_count()
     dc = device_class or detect_device_class()
     budget = hbm_budget_bytes if hbm_budget_bytes is not None else default_hbm_budget(dc)
-    correction = load_correction() if correction is None else float(correction)
+    correction = float(correction)
 
     params, dims, mlp_ratio = abstract_model_info(model, model_kwargs)
     if dims is None:

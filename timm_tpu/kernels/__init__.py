@@ -5,7 +5,8 @@ Every kernel module here registers a `KernelSpec` (registry.py): a declared
 regime (the shapes/dtypes/mask pattern where it claims to beat XLA), a
 reference XLA implementation, and a parity tolerance. harness.py turns those
 specs into the auto-generated CPU-interpreter parity tests, the perfbudget
-`kernels` probe, and the `bench.py --kernels` keep/delete verdicts; an
+`kernels` probe, and `run_kernel_ab`'s keep/delete verdicts (no timed run on
+the chip has been made yet: ROADMAP S6); an
 unregistered kernel module fails the lint in tests/test_kernels.py.
 
 Portfolio:

@@ -8,9 +8,9 @@ train: 867 einsum vs 786 XLA-fused vs 573 Pallas img/s/chip). The deletion
 gate — **win at masked N≥576** (NaFlex key-padding shapes, where the XLA
 path must materialize a masked N² fp32 tensor this kernel never builds)
 **or be deleted** — is no longer prose: it is the registry entry at the
-bottom of this file, whose masked 576/784/1024 regime cases `bench.py
---kernels` times against the `_sdpa` reference to emit the keep/delete
-verdict. The tile-aligned token-padding path (vision_transformer.py
+bottom of this file, whose masked 576/784/1024 regime cases
+`harness.run_kernel_ab` times against the `_sdpa` reference to emit the
+keep/delete verdict. The tile-aligned token-padding path (vision_transformer.py
 `pad_tokens_to`) threads exactly that key-padding mask here, which is the
 prerequisite for running the gate experiment on live hardware.
 

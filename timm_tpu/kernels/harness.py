@@ -19,7 +19,7 @@ Three consumers, one spec table (registry.py):
    perfbudget `kernels` probe pins these per kernel.
 
 3. **Verdicts** — `ab_verdict` produces the keep/delete/pending line for
-   `bench.py --kernels` and the replay `kernels` step: parity failure is an
+   `run_kernel_ab`: parity failure is an
    immediate `delete` (a wrong kernel loses regardless of speed); on a
    backend outside the spec's declared `backends` the verdict is `pending`
    (a timed run on the claimed hardware settles it); otherwise

@@ -9,7 +9,7 @@ pattern where it claims to win, split into a `dry` arm (tiny, CPU-interpret,
 tier-1-smoked) and a `live` arm (the claimed shapes, decided on hardware) —
 and (c) a **parity tolerance**. harness.py consumes these specs to
 auto-generate the per-kernel parity test, the perfbudget `kernels` probe
-metrics, and the `bench.py --kernels` keep/delete verdict lines; an
+metrics, and `harness.run_kernel_ab`'s keep/delete verdict lines; an
 unregistered kernel module cannot land (tests/test_kernels.py lint).
 
 Kernel modules register themselves at import time; `ensure_registered()`
@@ -32,8 +32,7 @@ _PORTFOLIO = ('flash_attention', 'fused_adamw', 'augment_epilogue', 'causal_atte
 @dataclasses.dataclass(frozen=True)
 class KernelCase:
     """One point of a kernel's declared regime. `dry` / `live` are kwargs for
-    the spec's `make_inputs` — same runner, different scale (the replay dry/
-    live pattern): dry is tiny and CPU-provable, live is the claimed shape
+    the spec's `make_inputs` — same runner, different scale: dry is tiny and CPU-provable, live is the claimed shape
     the hardware A/B decides on. `statics` are forwarded to BOTH the kernel
     and the reference (compile-time config: dtypes, masks, coefficients)."""
     name: str

@@ -19,8 +19,8 @@ Compute-precision policy (mirrors the reference's `fast_norm` global):
   computes mean/var in bf16 (PERF.md: ~25 LayerNorms upcast per ViT step).
 
 Both are seeded from ``TIMM_TPU_SOFTMAX_DTYPE`` / ``TIMM_TPU_NORM_DTYPE``
-(values: ``float32`` | ``bfloat16`` | empty = default) so bench.py can A/B
-each lever in a fresh process, and both are overridable per call/instance.
+(values: ``float32`` | ``bfloat16`` | empty = default) so each lever can be
+A/B'd in a fresh process, and both are overridable per call/instance.
 Every knob ships OFF by default with an exact-parity guarantee when disabled.
 """
 from __future__ import annotations

@@ -434,7 +434,7 @@ class SwinTransformerV2(nnx.Module):
             s.stage_scan = enable
 
     # stage scan IS this family's scan-over-layers: generic machinery that
-    # toggles `set_block_scan` (bench replay, probes) reaches it too
+    # toggles `set_block_scan` (probes, tests) reaches it too
     set_block_scan = set_stage_scan
 
     def get_classifier(self):

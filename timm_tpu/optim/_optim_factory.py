@@ -398,7 +398,7 @@ def create_optimizer_v2(
     HBM read+write traffic per step (~0.7 GB/step of ViT-B's 2.08 GB
     optimizer traffic, PERF.md §2 item 3); v stays fp32. Default None keeps
     fp32 state bit-for-bit. Seeded from TIMM_TPU_MU_DTYPE when unset so
-    bench.py can A/B it per process.
+    it can be A/B'd per process.
     """
     is_model = isinstance(model_or_params, nnx.Module)
     lr_scales = None

@@ -1,17 +1,16 @@
-"""Hardware-independent perf-regression suite + replay.
+"""Hardware-independent perf-regression suite.
 
-Three pieces (see each module's docstring):
+Two pieces (see each module's docstring):
   * probe    — lower the real train/serve programs, extract XLA cost
                analysis, jaxpr size, per-device bytes, donation/sharding
                legality for a config matrix;
   * budgets  — checked-in seed budgets + the one tolerance policy (fails on
                regression AND on silent improvement; re-baseline via
-               ``python -m timm_tpu.perfbudget --update-budgets``);
-  * replay   — the PERF.md on-device checklist as one scripted sequence
-               writing BENCH_SELF.json (`bench.py --replay [--dry-run]`).
+               ``python -m timm_tpu.perfbudget --update-budgets``).
 
-Top-level imports stay lazy-safe: importing this package does not import
-jax (bench.py's abort paths use the replay writers pre-jax-setup).
+Importing this package does not import jax, and nothing in it imports
+`timm_tpu.autotune` or `timm_tpu.analysis`: both import `probe` from here
+(the `layering` rule of `analysis/source_rules.py` holds the direction).
 """
 from .budgets import (
     BUDGETS_PATH, TOLERANCES, assert_within, check_counter, check_counter_min,
@@ -19,10 +18,6 @@ from .budgets import (
     format_violations, load_budgets, tolerance_for, update_budgets,
 )
 from .probe import DEFAULT_MATRIX, ProbeConfig, donation_evidence, probe_config, run_matrix
-from .replay import (
-    REPLAY_STEPS, SELF_SCHEMA, load_self_doc, record_result,
-    run_replay, save_self_doc, validate_self_result,
-)
 
 __all__ = [
     'BUDGETS_PATH', 'TOLERANCES', 'assert_within', 'check_counter',
@@ -30,6 +25,4 @@ __all__ = [
     'compare_budgets', 'compare_config', 'format_violations', 'load_budgets',
     'tolerance_for', 'update_budgets',
     'DEFAULT_MATRIX', 'ProbeConfig', 'donation_evidence', 'probe_config', 'run_matrix',
-    'REPLAY_STEPS', 'SELF_SCHEMA', 'load_self_doc', 'record_result',
-    'run_replay', 'save_self_doc', 'validate_self_result',
 ]

@@ -96,19 +96,6 @@ TOLERANCES: Dict[str, tuple] = {
     'elastic_global_batch_ok': ('bool', 0.0),
     'elastic_devices_from': ('band', 0.0),
     'elastic_devices_to': ('band', 0.0),
-    # autotune probe (autotune/solver.py): solver-output legality. The
-    # enumeration is deterministic given the 8-device topology and model, so
-    # candidate/rejection counts and the winning config's axes pin exactly
-    # (band 0.0); the winner's own compiled step rides the shared
-    # flops/bytes/donation tolerances above.
-    'autotune_candidates': ('band', 0.0),
-    'autotune_rejections': ('band', 0.0),
-    'autotune_winner_fsdp': ('band', 0.0),
-    'autotune_winner_tp': ('band', 0.0),
-    'autotune_winner_batch_size': ('band', 0.0),
-    'autotune_winner_grad_accum': ('band', 0.0),
-    'autotune_winner_global_batch_ok': ('bool', 0.0),
-    'autotune_winner_legal': ('bool', 0.0),
     'kernels_registered': ('band', 0.0),
     'fused_adamw_eqns': ('band', 0.10),
     'fused_adamw_ref_eqns': ('band', 0.10),

@@ -61,7 +61,7 @@ class ProbeConfig:
     block_scan: Optional[bool] = None     # None = model default
     grad_accum: int = 1
     opt: str = 'adamw'
-    collect: str = 'full'   # 'trace' | 'full' | 'fwd' | 'serve' | 'quant' | 'augment' | 'naflex' | 'kernels' | 'elastic' | 'autotune'
+    collect: str = 'full'   # 'trace' | 'full' | 'fwd' | 'serve' | 'quant' | 'augment' | 'naflex' | 'kernels' | 'elastic'
     buckets: Tuple[int, ...] = (2, 4)     # serve only
     seq_len: int = 25                     # naflex packed probe only
     fused_update: bool = False            # route the step through fused_adamw
@@ -144,15 +144,6 @@ DEFAULT_MATRIX: Tuple[ProbeConfig, ...] = (
     ProbeConfig(name='elastic_resize', model='test_vit',
                 model_kwargs=(('num_classes', 10), ('img_size', 32)),
                 batch_size=8, fsdp=4, collect='elastic'),
-    # autotune solver-output legality: the analytic tier enumerates the full
-    # {fsdp x tp x batch x accum x scan x remat} space for global batch
-    # batch_size*grad_accum (deterministic candidate/rejection counts and a
-    # deterministic winner), then the WINNING config's real train step is
-    # lowered once — its donation + sharding ride the same 'full'-collect
-    # machinery every other train probe budgets
-    ProbeConfig(name='autotune', model='test_vit',
-                model_kwargs=(('num_classes', 10), ('img_size', 32)),
-                batch_size=8, grad_accum=8, collect='autotune'),
     # hierarchical stage scan (ISSUE-20): the conv family baseline — convnext
     # sizes from the data (ctor takes no img_size; the new img_size field
     # sizes the batch), stages scanned via the set_block_scan alias
@@ -789,47 +780,13 @@ def _probe_kernels(cfg: ProbeConfig) -> Dict:
     return dict(kernel_metrics())
 
 
-def _probe_autotune(cfg: ProbeConfig) -> Dict:
-    """Pin the autotune solver's output legality: enumerate + rank the full
-    space analytically (no lowering) for global batch ``batch_size *
-    grad_accum``, then probe the WINNER's real train step through
-    `_probe_train` so its flops/bytes/donation land in the same budget file
-    every other train config uses."""
-    from ..autotune import autotune
-
-    result = autotune(cfg.model, cfg.kwargs(),
-                      global_batch=cfg.batch_size * cfg.grad_accum,
-                      probe_anchor=False, correction=1.0)
-    w = result.winner
-    metrics: Dict = {
-        'autotune_candidates': len(result.ranked),
-        'autotune_rejections': len(result.rejections),
-        'autotune_winner_fsdp': int(w.fsdp),
-        'autotune_winner_tp': int(w.tp),
-        'autotune_winner_batch_size': int(w.batch_size),
-        'autotune_winner_grad_accum': int(w.grad_accum),
-        'autotune_winner_global_batch_ok':
-            w.global_batch == cfg.batch_size * cfg.grad_accum,
-    }
-    winner_metrics = _probe_train(dataclasses.replace(
-        cfg, batch_size=w.batch_size, fsdp=w.fsdp, tp=w.tp,
-        grad_accum=w.grad_accum, block_scan=w.block_scan, collect='full'))
-    metrics.update(winner_metrics)
-    # the winner must be a config we can actually run: its real step lowered,
-    # compiled, and kept donation alive
-    metrics['autotune_winner_legal'] = bool(winner_metrics.get('donation_ok'))
-    return metrics
-
-
 def probe_config(cfg: ProbeConfig) -> Dict:
     """Probe one config; global mesh is saved/restored so probes compose with
-    whatever mesh the calling process (tests, bench) had active."""
+    whatever mesh the calling process (tests, a CLI) had active."""
     from ..parallel import mesh as mesh_mod
 
     saved = mesh_mod.peek_global_mesh()
     try:
-        if cfg.collect == 'autotune':
-            return _probe_autotune(cfg)
         if cfg.collect == 'serve':
             return _probe_serve(cfg)
         if cfg.collect == 'quant':

@@ -1,7 +1,7 @@
 """Fault-injection harness for resilience drills.
 
-Spec grammar (comma-separated, via `train.py --fault-inject`, `bench.py
---dry-run --fault-inject`, or env `TIMM_TPU_FAULT_INJECT`):
+Spec grammar (comma-separated, via `train.py --fault-inject` or env
+`TIMM_TPU_FAULT_INJECT`):
 
   truncate_ckpt     truncate the NEXT checkpoint write after commit (one-shot)
   nan_grads@N       poison the batch at global update N so loss/grads go NaN;
@@ -187,8 +187,7 @@ def set_fault_injector(spec_or_injector) -> Optional[FaultInjector]:
 def fault_selftest(spec: str = '', tmp_dir: Optional[str] = None) -> dict:
     """Exercise every injection hook + its recovery path on CPU, no model.
 
-    Used by `bench.py --dry-run --fault-inject` and tests/test_resilience.py
-    so the harness itself is covered in tier-1 without slow runs. Returns
+    Used by tests/test_resilience.py so the harness itself is covered in tier-1 without slow runs. Returns
     {'ok': bool, 'checks': {name: bool}, 'spec': parsed-spec}.
     """
     import tempfile

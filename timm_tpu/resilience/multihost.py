@@ -18,8 +18,8 @@ asserts the full recovery contract:
      host-sharded checkpoint under the live mesh and finishes the run;
   5. the final parameters match an uninterrupted single-process baseline.
 
-Used by tests/test_multihost.py (tier-1), tests/multihost_drill.py (manual /
-slow), and the `multihost` step of `bench.py --replay`.
+Used by tests/test_multihost.py (tier-1) and tests/multihost_drill.py (manual /
+slow).
 """
 from __future__ import annotations
 
@@ -96,8 +96,8 @@ def run_kill_drill(workdir: str, processes: int = 2, kill_update: int = 4,
                    timeout: int = 420, log=None) -> dict:
     """Run the host-loss drill; returns {'ok', 'checks', 'details'}.
 
-    compare=False / resume=False trims the baseline and resume legs (the
-    replay dry arm only proves bring-up + kill + consensus + commit safety).
+    compare=False / resume=False trims the baseline and resume legs (what is
+    left proves bring-up + kill + consensus + commit safety).
     """
     from .durable import load_verified, manifest_path, resolve_auto_resume, verify_checkpoint
 

@@ -6,7 +6,7 @@ closed-loop generator would throttle itself and hide it) is replayed against
 an :class:`~timm_tpu.serve.engine.InferenceEngine`, reporting p50/p99 request
 latency and sustained img/s against the offered load.
 
-``canonical_drill`` is the tier-1 A/B smoke (``bench.py --serve --dry-run``):
+``canonical_drill`` is the tier-1 A/B smoke (tests/test_serve.py calls it in process):
 the SAME arrival schedule replayed twice —
 
   * **continuous batching**: declared buckets, deadline-bounded admission,
@@ -28,7 +28,7 @@ import numpy as np
 
 from .engine import InferenceEngine
 
-__all__ = ['run_load_drill', 'canonical_drill', 'quant_residency_drill', 'summary_line']
+__all__ = ['run_load_drill', 'canonical_drill', 'summary_line']
 
 
 def _poisson_arrivals(num: int, rate_per_s: float, seed: int) -> np.ndarray:
@@ -51,14 +51,11 @@ def run_load_drill(
         mesh=None,
         persist_all_programs: bool = False,
         result_timeout: float = 300.0,
-        quantize: Optional[str] = None,
 ) -> Dict:
     """Replay one Poisson schedule against one engine configuration.
 
     ``per_request=True`` turns the engine into the baseline it replaces:
     bucket set ``(1,)``, zero admission wait, no transfer overlap.
-    ``quantize='int8'`` loads every model weight-only-quantized (the A arm of
-    the quant residency drill).
     """
     if per_request:
         buckets, max_wait_ms, transfer_depth = (1,), 0.0, 1
@@ -71,7 +68,7 @@ def run_load_drill(
 
     t_warm0 = time.perf_counter()
     for name in model_names:
-        engine.add_model(name, img_size=img_size, quantize=quantize)
+        engine.add_model(name, img_size=img_size)
     startup_ms = (time.perf_counter() - t_warm0) * 1e3
 
     arrivals = _poisson_arrivals(num_requests, rate_per_s, seed)
@@ -128,7 +125,6 @@ def run_load_drill(
         'padded_slots': stats['padded_slots'],
         'evictions': stats['pool']['evictions'],
         'resident': stats['resident'],
-        'quantize': quantize,
         'startup_ms': round(startup_ms, 1),
         'prewarm': stats['prewarm'],
     }
@@ -178,48 +174,6 @@ def canonical_drill(
         'per_request': baseline,
         'speedup': round(continuous['img_per_s'] / max(baseline['img_per_s'], 1e-9), 2),
         'hbm_budget_bytes': budget,
-    }
-
-
-def quant_residency_drill(
-        model_names: Sequence[str] = ('test_vit', 'test_vit2'),
-        buckets: Sequence[int] = (4, 16),
-        num_requests: int = 256,
-        rate_per_s: float = 2000.0,
-        img_size: int = 32,
-        seed: int = 0,
-        persist_all_programs: bool = False,
-) -> Dict:
-    """The int8 A/B residency drill: the SAME Poisson schedule and the SAME
-    one-model HBM budget replayed twice, fp32 vs weight-only int8.
-
-    Under a budget sized for 1.25x the larger fp32 model, the fp32 arm
-    thrashes — prewarm of model B evicts A, then each traffic phase change
-    reloads/evicts again (3 LRU evictions for the two-model phase-split
-    schedule) — while the int8 arm (~0.27x bytes per model) fits BOTH models
-    resident simultaneously with zero evictions. Same budget, 2x the models.
-    """
-    budget = int(1.25 * max(_param_bytes(n, img_size) for n in model_names))
-    common = dict(model_names=model_names, buckets=buckets,
-                  num_requests=num_requests, rate_per_s=rate_per_s,
-                  img_size=img_size, seed=seed, hbm_budget_bytes=budget,
-                  persist_all_programs=persist_all_programs)
-    fp32 = run_load_drill(**common)
-    int8 = run_load_drill(quantize='int8', **common)
-
-    assert fp32['evictions'] >= 1, \
-        f'HBM budget {budget} failed to force fp32 LRU evictions: {fp32}'
-    assert int8['evictions'] == 0, \
-        f'int8 arm evicted under the one-fp32-model budget {budget}: {int8}'
-    assert sorted(int8['resident']) == sorted(model_names), (
-        f'int8 arm should hold all {len(model_names)} models resident under '
-        f'the one-fp32-model budget; resident={int8["resident"]}')
-    return {
-        'fp32': fp32,
-        'int8': int8,
-        'hbm_budget_bytes': budget,
-        'fp32_evictions': fp32['evictions'],
-        'int8_resident': len(int8['resident']),
     }
 
 

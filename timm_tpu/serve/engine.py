@@ -26,8 +26,8 @@ The engine decouples request arrival from device stepping:
     (for serving, the O(1)-in-depth startup-latency win dominates and the
     re-stack HBM cost doesn't — PERF.md).
 
-CPU-runnable end to end: the load drill (serve/drill.py, ``bench.py
---serve``) exercises all of the above as a tier-1 smoke.
+CPU-runnable end to end: the load drill (serve/drill.py ``canonical_drill``)
+exercises all of the above as a tier-1 smoke (tests/test_serve.py).
 """
 from __future__ import annotations
 

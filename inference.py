@@ -125,13 +125,17 @@ def main():
 
     all_indices, all_probs = [], []
     t0 = time.time()
-    for x_np, _ in loader:
-        n = int(x_np.shape[0])
-        if n != bucket:  # partial final batch: pad up to the bucket shape
-            x_np, _valid = pad_rows(np.asarray(x_np), bucket)
-        idx, prb = strip_rows(infer_step(state, jnp.asarray(x_np)), n)
-        all_indices.append(np.asarray(idx))
-        all_probs.append(np.asarray(prb))
+    try:
+        for x_np, _ in loader:
+            n = int(x_np.shape[0])
+            if n != bucket:  # partial final batch: pad up to the bucket shape
+                x_np, _valid = pad_rows(np.asarray(x_np), bucket)
+            idx, prb = strip_rows(infer_step(state, jnp.asarray(x_np)), n)
+            all_indices.append(np.asarray(idx))
+            all_probs.append(np.asarray(prb))
+    finally:
+        getattr(loader, 'close', lambda: None)()   # the decode processes end here, on an exception too
+
     if not all_indices:
         raise RuntimeError(f'No images found for inference under {root!r} (split {args.split!r})')
     num = sum(a.shape[0] for a in all_indices)

@@ -248,13 +248,17 @@ def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_t
 
 
 def test_no_loader_worker_thread_opens_a_span():
-    """Spans in the loader sit on the consuming (main) thread only; the worker
-    and collator functions use counters."""
-    from timm_tpu.data.loader import ThreadedLoader
-    text = inspect.getsource(ThreadedLoader.__iter__)
-    for fn in ('def worker(', 'def collator('):
-        start = text.index(fn)
-        body = text[start:text.index('\n        def ', start + 1) if '\n        def ' in text[start + 1:] else None]
-        body = body.split('\n        ct = threading.Thread')[0].split('\n        used = ')[0]
+    """Spans in the loader sit on the consuming (main) thread only; what runs on
+    a decode thread, on the pool's reader thread or on the collator thread uses
+    counters, and a decode process has no ring at all (it reports numbers)."""
+    from timm_tpu.data import decode_worker
+    from timm_tpu.data.loader import ThreadedLoader, _DecodePool
+    collator = inspect.getsource(ThreadedLoader.__iter__)
+    collator = collator[collator.index('def collator('):collator.index('exhausted = False')]
+    for fn, body in (('worker', inspect.getsource(ThreadedLoader._decode_in_threads)),
+                     ('deliver', inspect.getsource(ThreadedLoader._decode_in_processes)),
+                     ('collator', collator)):
         assert 'tracing.span(' not in body, fn
         assert 'tracing.count(' in body or 'tracing.busy(' in body, fn
+    assert 'tracing.span(' not in inspect.getsource(_DecodePool)
+    assert 'tracing' not in inspect.getsource(decode_worker)

@@ -17,6 +17,8 @@ __all__ = ['ImageDataset', 'AugMixDataset']
 
 
 class ImageDataset:
+    decodes_files = True    # an item is a file opened, decoded and transformed: the loader decodes it in a worker process
+
     def __init__(
             self,
             root: str,
@@ -108,6 +110,7 @@ class IterableImageDataset:
 class AugMixDataset:
     """Returns (clean, aug1..augN) tuples for JSD training
     (reference dataset.py:170)."""
+    decodes_files = True
 
     def __init__(self, dataset: ImageDataset, num_splits: int = 2):
         self.dataset = dataset

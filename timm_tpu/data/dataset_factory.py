@@ -36,6 +36,7 @@ def _search_split(root: str, split: str) -> str:
 
 class HfdsWrapper:
     """Map-style HF datasets → (PIL, label) samples."""
+    decodes_files = True
 
     def __init__(self, name, root, split, input_key='image', target_key='label'):
         import datasets as hfds

@@ -1,9 +1,12 @@
-"""Batch loader with threaded decode + prefetch
-(reference: timm/data/loader.py:30-504).
+"""Batch loader: decode in worker processes (image files) or threads (array
+slices), ordered collation, prefetch (reference: timm/data/loader.py:30-504).
 
 TPU-native redesign of the reference's DataLoader+PrefetchLoader pair:
-  * worker threads decode/augment (PIL releases the GIL in libjpeg), a
-    bounded queue gives pipelined prefetch — replaces torch worker procs
+  * image files are decoded and augmented in worker processes with their own
+    interpreters (`decode_worker.py`; they never import JAX and end with
+    their parent), which hand raw pixels back down pipes; a bounded queue gives
+    pipelined prefetch — the main interpreter's lock is touched a few times a
+    batch, not a few thousand (PERF.md section 6, PR 30)
   * per-host sharding for multi-process (pod) runs replaces the distributed
     sampler: each host reads its `jax.process_index()` slice
   * normalization happens on device inside the consuming step (mean/std are
@@ -13,24 +16,44 @@ TPU-native redesign of the reference's DataLoader+PrefetchLoader pair:
 """
 from __future__ import annotations
 
+import fcntl
+import logging
+import os
+import pickle
 import queue
-import random
+import subprocess
+import sys
 import threading
-from typing import Callable, Optional, Tuple
+import time
+import weakref
+from typing import Callable, Optional
 
 import numpy as np
 
-from ..resilience import SkipBudget, TooManyBadSamples, get_fault_injector, retry_io
+from ..resilience import SkipBudget, TooManyBadSamples, get_fault_injector
 from ..utils import tracing
+from . import decode_worker
 from .constants import IMAGENET_DEFAULT_MEAN, IMAGENET_DEFAULT_STD
 from .random_erasing import RandomErasing
 from .transforms_factory import create_transform
 
-__all__ = ['create_loader', 'DevicePrefetcher', 'StreamingLoader', 'ThreadedLoader']
+__all__ = ['create_loader', 'DecodeWorkerDied', 'DevicePrefetcher', 'StreamingLoader', 'ThreadedLoader']
+
+_logger = logging.getLogger(__name__)
 
 # marker a worker emits for a sample dropped against the poison budget, so the
 # collator keeps its consumed-count bookkeeping without padding the batch
 _SKIPPED = object()
+
+
+def _skip_or_fatal(skip_budget: SkipBudget, exc: Exception, idx: int):
+    """A poisoned sample against the epoch's budget: the skip marker, or, with
+    the budget exhausted, the error that fails the epoch loudly."""
+    try:
+        skip_budget.record(exc, f'sample index {idx}')
+        return _SKIPPED
+    except TooManyBadSamples as fatal:
+        return fatal
 
 
 class StreamingLoader:
@@ -283,7 +306,7 @@ class DevicePrefetcher:
                 close()
 
 
-_CHUNK = 8   # samples a decode thread hands the collator at once
+_CHUNK = 8   # samples a decode thread or process hands over at once
 
 
 def _collate_arrays(imgs, targets):
@@ -298,7 +321,134 @@ def _collate_arrays(imgs, targets):
     return np.stack(imgs), np.asarray(targets)
 
 
+class DecodeWorkerDied(RuntimeError):
+    """A decode process ended before the loader closed it: the epoch fails, as
+    with an exhausted skip budget; nothing restarts the worker silently."""
+
+
+class _DecodePool:
+    """`num_workers` decode processes (`decode_worker.py`), each with a copy of
+    the dataset, kept across epochs. An epoch's chunks of indices are dealt round
+    robin and read back in the same order, so the samples arrive in index order
+    whatever each worker's pace. A worker can be one chunk ahead in its pipe
+    and one in its hands; then its write blocks until the reader comes round."""
+
+    def __init__(self, dataset, num_workers: int):
+        self.dataset = dataset
+        self.num_workers = num_workers
+        self.procs: list = []
+        self.results: list = []           # unbuffered read ends of the workers' result pipes
+        self.started_at: Optional[float] = None         # perf_counter, until the first batch has been reported
+        self._reader: Optional[threading.Thread] = None
+        self._stop: Optional[threading.Event] = None    # of the pass the reader serves
+
+    def start(self):
+        """Start the workers if they are not running (a no-op between epochs)."""
+        if self.procs:
+            return
+        self.started_at = time.perf_counter()
+        injector = get_fault_injector()
+        # the dataset is pickled once, and travels as bytes inside each worker's first message
+        init = dict(dataset=pickle.dumps(self.dataset, pickle.HIGHEST_PROTOCOL),
+                    fault_spec=injector.spec if injector else '')
+        try:
+            for worker in range(self.num_workers):
+                read_fd, write_fd = os.pipe()
+                self.results.append(os.fdopen(read_fd, 'rb', buffering=0))
+                try:
+                    fcntl.fcntl(read_fd, fcntl.F_SETPIPE_SZ, 1 << 20)   # a chunk of 8 x 224 x 224 x 3 in few writes
+                except OSError:
+                    pass                                                  # the default size works, in more of them
+                try:
+                    self.procs.append(subprocess.Popen([sys.executable, decode_worker.__file__, str(write_fd)],
+                                                       stdin=subprocess.PIPE, pass_fds=(write_fd,)))
+                finally:
+                    os.close(write_fd)      # the worker's end: when it dies the reader sees end of file
+            for worker in range(self.num_workers):
+                self._send(worker, dict(init, worker=worker))
+        except BaseException:
+            self.close()
+            raise
+
+    def _send(self, worker: int, message):
+        try:
+            pickle.dump(message, self.procs[worker].stdin, pickle.HIGHEST_PROTOCOL)
+            self.procs[worker].stdin.flush()
+        except BrokenPipeError:
+            raise self._died(worker) from None
+
+    def _died(self, worker: int) -> DecodeWorkerDied:
+        tracing.count('loader.worker_exits')
+        proc = self.procs[worker]
+        try:
+            code = proc.wait(timeout=2)
+        except subprocess.TimeoutExpired:
+            code = 'still running'
+        return DecodeWorkerDied(f'decode worker {worker} (pid {proc.pid}) ended before the loader closed it '
+                                f'(exit code {code}): the epoch cannot be completed')
+
+    def alive(self) -> int:
+        return sum(proc.poll() is None for proc in self.procs)
+
+    def run_epoch(self, seed: int, epoch: int, chunks: list, deliver: Callable, stop: threading.Event):
+        """Give every worker its chunks of the epoch and start the thread that
+        reads their results in chunk order: `deliver(idxs, head, pixels)` for each
+        (False stops it), `deliver(None, error, None)` when a worker has died."""
+        self._stop = stop
+        for worker in range(self.num_workers):
+            self._send(worker, (seed, epoch, chunks[worker::self.num_workers]))
+
+        def read():
+            for c, idxs in enumerate(chunks):
+                frame = decode_worker.read_frame(self.results[c % self.num_workers])
+                if stop.is_set():
+                    return
+                if frame is None:
+                    deliver(None, self._died(c % self.num_workers), None)
+                    return
+                if not deliver(idxs, *frame):
+                    return
+
+        self._reader = threading.Thread(target=read, daemon=True)
+        self._reader.start()
+
+    def close(self):
+        """End the workers (each leaves when its stdin closes; one that does not
+        within 2 s is killed), then the reader they fed and with it the pass it
+        served, then free the pipes."""
+        if self._stop is not None:
+            self._stop.set()
+        for proc in self.procs:
+            try:
+                proc.stdin.close()
+            except BrokenPipeError:
+                pass
+        for proc in self.procs:
+            try:
+                proc.wait(timeout=2)
+            except subprocess.TimeoutExpired:
+                proc.kill()
+                proc.wait()
+        if self._reader is not None:
+            self._reader.join()
+            self._reader = self._stop = None
+        for result in self.results:
+            result.close()
+        self.procs, self.results = [], []
+
+
 class ThreadedLoader:
+    """Batches of a map-style dataset: index sharding, epoch shuffle, ordered
+    collation for evaluation, the poison-skip budget and `prefetch` batches of
+    back-pressure, over one of two decode stages. Which one falls on what the
+    dataset says its items are: decoded files (`dataset.decodes_files`: open, JPEG
+    decode, PIL augmentation, milliseconds of C sections that release and retake
+    the interpreter lock some 25 times a sample) are decoded in worker PROCESSES
+    that stay for the loader's life; array slices (`TokenWindows`: microseconds,
+    no lock traffic, 128 KB an item to ship) in THREADS that live for one epoch.
+    `close()` ends the processes; so does the end of this process, however it ends.
+    """
+
     def __init__(
             self,
             dataset,
@@ -337,8 +487,28 @@ class ThreadedLoader:
         self.process_index = process_index
         self.process_count = process_count
         self.num_aug_repeats = num_aug_repeats if is_training else 0
+        self._pool = None
+        if getattr(dataset, 'decodes_files', False):
+            self._pool = _DecodePool(dataset, self.num_workers)
+            weakref.finalize(self, self._pool.close)    # a loader dropped without close() keeps no process
+        self._halt: Optional[Callable] = None    # ends the pass in progress
 
         self._local_indices = self._shard_indices(shuffled=False)
+
+    def start(self):
+        """Start the decode processes ahead of the first `iter()` (which starts
+        them otherwise), so that their imports overlap the caller's own set-up."""
+        if self._pool is not None:
+            self._pool.start()
+
+    def close(self):
+        """End the pass in progress, if any, and the decode processes. Idempotent;
+        a later `iter()` starts new ones."""
+        if self._halt is not None:
+            self._halt()
+        if self._pool is not None:
+            self._pool.close()
+            tracing.gauge('loader.decode_procs', 0)
 
     def _repeat_aug_indices(self, rng) -> np.ndarray:
         """Repeated-augmentation sampling (reference distributed_sampler.py:54
@@ -389,14 +559,68 @@ class ThreadedLoader:
             return n // self.batch_size
         return -(-n // self.batch_size)
 
+    def _decode_in_threads(self, used, hand_over: Callable, skip_budget: SkipBudget, stop: threading.Event):
+        """The decode stage for items that are array slices: `num_workers`
+        threads of this interpreter, each over a stride of the epoch's indices."""
+        def worker(worker_indices):
+            chunk = []
+            for idx in worker_indices:
+                if stop.is_set():
+                    return
+                # counters only on these threads: a span per sample would cost
+                # the interpreter lock the main thread needs
+                with tracing.busy('loader.decode_busy_ns'):
+                    try:
+                        sample = decode_worker.read_sample(self.dataset, int(idx))
+                    except Exception as e:
+                        sample = _skip_or_fatal(skip_budget, e, int(idx))
+                tracing.count('loader.samples')
+                chunk.append((int(idx), sample))
+                if len(chunk) == _CHUNK:
+                    if not hand_over(chunk):
+                        return
+                    chunk = []
+            if chunk:
+                hand_over(chunk)
+
+        for w in range(self.num_workers):
+            threading.Thread(target=worker, args=(used[w::self.num_workers],), daemon=True).start()
+
+    def _decode_in_processes(self, used, hand_over: Callable, skip_budget: SkipBudget, stop: threading.Event):
+        """The decode stage for items that are decoded files: the pool's workers
+        decode chunks of the epoch's indices; here, on the pool's reader thread,
+        their reports are added to the main process's counters, their poisoned
+        samples put to the skip budget, and their pixels handed on as views."""
+        def deliver(idxs, head, pixels) -> bool:
+            if idxs is None:
+                return hand_over([(-1, head)])      # a worker died: `head` is the error
+            tracing.count('loader.samples', len(idxs))
+            tracing.count('loader.decode_busy_ns', head['busy_ns'])
+            bad = dict(head['bad'])
+            good = iter(zip(pixels if pixels is not None else (), head['targets']))
+            chunk = []
+            for pos, idx in enumerate(idxs):
+                if pos in bad:
+                    chunk.append((idx, _skip_or_fatal(skip_budget, bad[pos], idx)))
+                else:
+                    img, target = next(good)
+                    chunk.append((idx, (tuple(img) if head['splits'] else img, target)))
+            return hand_over(chunk)
+
+        used = [int(i) for i in used]
+        chunks = [used[i:i + _CHUNK] for i in range(0, len(used), _CHUNK)]
+        self._pool.run_epoch(self.seed, self.epoch, chunks, deliver, stop)
+
     def __iter__(self):
+        if self._halt is not None:
+            self._halt()    # the pool serves one pass at a time
         indices = self._shard_indices(shuffled=self.shuffle)
         num_batches = len(indices) // self.batch_size if self.drop_last \
             else -(-len(indices) // self.batch_size)
 
         # samples travel in chunks: every hand-over wakes the collator and costs both
-        # threads the interpreter lock, which the decode threads are short of
-        # (PERF.md section 6, PR 25); the bound stays `prefetch` batches of samples
+        # threads the interpreter lock (PERF.md section 6, PR 25); the bound stays
+        # `prefetch` batches of samples
         sample_q: 'queue.Queue' = queue.Queue(maxsize=max(1, self.prefetch * self.batch_size // _CHUNK))
         batch_q: 'queue.Queue' = queue.Queue(maxsize=self.prefetch)
         stop = threading.Event()
@@ -411,48 +635,7 @@ class ThreadedLoader:
                     continue
             return False
 
-        skip_budget = SkipBudget()
-
-        def _read(idx):
-            injector = get_fault_injector()
-            if injector is not None and injector.io_error_tick():
-                raise IOError(f'[fault-inject] sample read {idx}')
-            return self.dataset[int(idx)]
-
-        def worker(worker_indices):
-            chunk = []
-            for idx in worker_indices:
-                if stop.is_set():
-                    return
-                # counters only on these threads: a span per sample would cost
-                # the interpreter they are suspected of starving the main thread of
-                with tracing.busy('loader.decode_busy_ns'):
-                    try:
-                        # transient I/O faults (OSError) ride through jittered
-                        # exponential backoff; anything still failing is poison
-                        sample = retry_io(lambda: _read(idx), retries=3, base_delay=0.05,
-                                          desc=f'sample {int(idx)}')
-                    except Exception as e:
-                        try:
-                            skip_budget.record(e, f'sample index {int(idx)}')
-                            sample = _SKIPPED
-                        except TooManyBadSamples as fatal:
-                            sample = fatal  # budget exhausted: fail the epoch loudly
-                tracing.count('loader.samples')
-                chunk.append((int(idx), sample))
-                if len(chunk) == _CHUNK:
-                    if not _put(sample_q, chunk):
-                        return
-                    chunk = []
-            if chunk:
-                _put(sample_q, chunk)
-
         used = indices[:num_batches * self.batch_size] if self.drop_last else indices
-        workers = []
-        for w in range(self.num_workers):
-            t = threading.Thread(target=worker, args=(used[w::self.num_workers],), daemon=True)
-            t.start()
-            workers.append(t)
 
         # training batches collate in arrival order (indices are already a
         # fresh shuffle, and this keeps sample_q backpressure intact); eval
@@ -513,27 +696,49 @@ class ThreadedLoader:
             finally:
                 _put(batch_q, None)
 
-        ct = threading.Thread(target=collator, daemon=True)
-        ct.start()
+        exhausted = False
 
-        try:
-            while True:
-                tracing.gauge('loader.batch_q_depth', batch_q.qsize())
-                with tracing.span('loader.batch_wait'):
-                    item = batch_q.get()
-                if item is None:
-                    break
-                if isinstance(item, Exception):
-                    raise item
-                yield item
-        finally:
+        def halt():
+            """Leave nothing blocked: the threads see `stop`; decode processes in
+            the middle of an epoch are ended, not drained (a later pass starts
+            new ones); after a whole epoch they stay for the next."""
+            self._halt = None
             stop.set()
-            # drain so blocked threads can observe stop and exit
+            if self._pool is not None and not exhausted:
+                self._pool.close()
             try:
                 while True:
                     batch_q.get_nowait()
             except queue.Empty:
                 pass
+            if not exhausted:   # for whoever resumes this pass after a close() or a newer pass ended it
+                batch_q.put_nowait(RuntimeError('this pass over the loader was ended before its epoch was'))
+
+        self._halt = halt
+        try:
+            self.start()
+            decode = self._decode_in_threads if self._pool is None else self._decode_in_processes
+            decode(used, lambda chunk: _put(sample_q, chunk), SkipBudget(), stop)
+            threading.Thread(target=collator, daemon=True).start()
+            while True:
+                tracing.gauge('loader.batch_q_depth', batch_q.qsize())
+                if self._pool is not None:
+                    tracing.gauge('loader.decode_procs', self._pool.alive())
+                with tracing.span('loader.batch_wait'):
+                    item = batch_q.get()
+                if item is None:
+                    exhausted = True
+                    break
+                if isinstance(item, Exception):
+                    raise item
+                if self._pool is not None and self._pool.started_at is not None:
+                    _logger.info(f'loader: {self._pool.alive()} decode processes, first batch '
+                                 f'{time.perf_counter() - self._pool.started_at:.2f} s after their start')
+                    self._pool.started_at = None
+                yield item
+        finally:
+            if self._halt is halt:
+                halt()
 
     @property
     def sampler(self):

@@ -114,6 +114,11 @@ class ReaderImageInTar:
         import threading
         self._tls = threading.local()
 
+    def __getstate__(self):
+        # open handles stay behind: a decode process opens its own
+        import threading
+        return dict(self.__dict__, _tls=threading.local())
+
     def _tar(self, path):
         cache = getattr(self._tls, 'tars', None)
         if cache is None:

@@ -48,9 +48,11 @@ SPANS = {
     'loader.h2d': ('input', 'host-to-device put of the next uint8 batch'),
     'loader.sample_params': ('input', 'erase / mixup parameter draws, numpy on the main thread'),
     'loader.augment_call': ('input', 'placing the parameters and dispatching the augment program'),
-    'loader.samples': ('input', 'counter: samples read, decoded and transformed by the worker threads'),
-    'loader.decode_busy_ns': ('input', 'counter: wall ns the worker threads spent on those samples'),
+    'loader.samples': ('input', "counter: samples read, decoded and transformed by the decode stage's workers (processes report theirs with each hand-over)"),
+    'loader.decode_busy_ns': ('input', 'counter: wall ns the workers spent on those samples, summed over workers'),
     'loader.batches': ('input', 'counter: batches the collator thread handed over'),
+    'loader.decode_procs': ('input', 'gauge: decode processes alive when the main thread asks for a batch; 0 after close()'),
+    'loader.worker_exits': ('input', 'counter: decode processes that ended before the loader closed them; 0 in a sound run'),
     'task.train_step': ('step', 'the whole TrainingTask.train_step call'),
     'task.state_split': ('step', "the arrays of the model's bound Variables read into two flat tuples"),
     'task.scalars_put': ('step', 'the two jnp.asarray scalar transfers (lr, ema decay)'),

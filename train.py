@@ -767,6 +767,10 @@ def main(argv=None):
                 mixup=train_mixup,
                 device_prefetch=args.device_prefetch if args.device_augment else 0,
             )
+            # the decode processes import and unpickle while the rest of set-up runs; the
+            # evaluation loader starts its own at its first use, if it has one
+            if hasattr(loader_train, 'start'):
+                loader_train.start()
             loader_eval = create_loader(
                 dataset_eval,
                 input_size=data_config['input_size'],
@@ -992,6 +996,9 @@ def main(argv=None):
             if async_writer is not None:
                 async_writer.close()
         finally:
+            # on every way out, an exception from a step included: no decode process outlives the run
+            for loader in (loader_train, loader_eval):
+                getattr(loader, 'close', lambda: None)()
             shutdown.uninstall()
             _thaw()
 
@@ -1107,6 +1114,7 @@ def _host_line(since_ns, counters_before):
     ms = lambda *names: sum(rows[k]['wall_ms_sum'] for k in names if k in rows) / steps  # noqa: E731
     did = {k: v - counters_before.get(k, 0) for k, v in snap['counters'].items()}
     depths = [v for t, v in snap['gauges'].get('loader.batch_q_depth', ()) if t >= since_ns]
+    procs = snap['gauges'].get('loader.decode_procs')
     text = (f"host ms/step: next {ms('train.loader_next'):.1f} split {ms('task.state_split'):.1f} "
             f"put {ms('task.scalars_put', 'train.batch_to_device'):.1f} call {ms('task.step_call'):.1f} "
             f"update {ms('task.state_update'):.1f} poll {ms('task.sentinel_poll'):.1f} "
@@ -1116,6 +1124,8 @@ def _host_line(since_ns, counters_before):
                  f"decode {did['loader.decode_busy_ns'] / did['loader.samples'] / 1e6:.1f} ms/img "
                  f"{did['loader.decode_busy_ns'] / did['loader.batches'] / 1e6:.0f} ms/batch "
                  f"polls {did.get('task.sentinel_polls', 0)} binds {did.get('task.state_binds', 0)}")
+    if procs:   # an image run: decode processes alive at the newest fetch, and those that ended unasked
+        text += f" procs {procs[-1][1]} exits {did.get('loader.worker_exits', 0)}"
     return text, snap['counters']
 
 

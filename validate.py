@@ -211,29 +211,32 @@ def validate(args):
     loss_m, top1_m, top5_m, time_m = AverageMeter(), AverageMeter(), AverageMeter(), AverageMeter()
     top1_fp32_m = AverageMeter()
     end = time.time()
-    for batch_idx, (x_np, t_np) in enumerate(loader):
-        n = x_np.shape[0]
-        x_np, t_np, valid_np = pad_rows(np.asarray(x_np), bucket, np.asarray(t_np))
-        batch = shard_batch({'x': jnp.asarray(x_np), 't': jnp.asarray(t_np),
-                             'v': jnp.asarray(valid_np)}, mesh)
-        loss, acc1, acc5, topk = eval_step(eval_state, batch['x'], batch['t'], batch['v'])
-        if eval_step_fp32 is not None:
-            _, ref1, _, _ = eval_step_fp32(state, batch['x'], batch['t'], batch['v'])
-            top1_fp32_m.update(float(ref1), n)
-        if real_labels is not None:
-            real_labels.add_result(np.asarray(topk)[:n], is_topk=True)  # drop pad rows
-        loss_m.update(float(loss), n)
-        top1_m.update(float(acc1), n)
-        top5_m.update(float(acc5), n)
-        time_m.update(time.time() - end)
-        end = time.time()
-        if batch_idx % args.log_freq == 0:
-            _logger.info(
-                f'Test: [{batch_idx:>4d}/{len(loader)}]  '
-                f'Time: {time_m.val:.3f}s ({n / max(time_m.avg, 1e-9):>7.1f}/s)  '
-                f'Loss: {loss_m.val:>7.4f} ({loss_m.avg:>6.4f})  '
-                f'Acc@1: {top1_m.val:>7.3f} ({top1_m.avg:>7.3f})  '
-                f'Acc@5: {top5_m.val:>7.3f} ({top5_m.avg:>7.3f})')
+    try:
+        for batch_idx, (x_np, t_np) in enumerate(loader):
+            n = x_np.shape[0]
+            x_np, t_np, valid_np = pad_rows(np.asarray(x_np), bucket, np.asarray(t_np))
+            batch = shard_batch({'x': jnp.asarray(x_np), 't': jnp.asarray(t_np),
+                                 'v': jnp.asarray(valid_np)}, mesh)
+            loss, acc1, acc5, topk = eval_step(eval_state, batch['x'], batch['t'], batch['v'])
+            if eval_step_fp32 is not None:
+                _, ref1, _, _ = eval_step_fp32(state, batch['x'], batch['t'], batch['v'])
+                top1_fp32_m.update(float(ref1), n)
+            if real_labels is not None:
+                real_labels.add_result(np.asarray(topk)[:n], is_topk=True)  # drop pad rows
+            loss_m.update(float(loss), n)
+            top1_m.update(float(acc1), n)
+            top5_m.update(float(acc5), n)
+            time_m.update(time.time() - end)
+            end = time.time()
+            if batch_idx % args.log_freq == 0:
+                _logger.info(
+                    f'Test: [{batch_idx:>4d}/{len(loader)}]  '
+                    f'Time: {time_m.val:.3f}s ({n / max(time_m.avg, 1e-9):>7.1f}/s)  '
+                    f'Loss: {loss_m.val:>7.4f} ({loss_m.avg:>6.4f})  '
+                    f'Acc@1: {top1_m.val:>7.3f} ({top1_m.avg:>7.3f})  '
+                    f'Acc@5: {top5_m.val:>7.3f} ({top5_m.avg:>7.3f})')
+    finally:
+        getattr(loader, 'close', lambda: None)()   # the decode processes end here, on an exception too
 
     if real_labels is not None:
         # replace top-1/5 with the relabeled scores (reference validate.py:418)

@@ -1,14 +1,22 @@
 """Causal flash attention on TPU for long token sequences: softmax(q k^T *
-scale + causal mask) v without the (heads, S, S) scores, forward and backward.
+scale + mask) v without the (heads, S, S) scores, forward and backward. The
+mask is causal, or causal AND within a window of the last `window` positions
+(key j is seen by query i when j <= i and i - j < window); the query heads may
+be a multiple of the key/value heads (grouped-query attention: query head g
+reads key/value head g // group).
 
 The Pallas kernel is JAX's own splash attention
 (`jax.experimental.pallas.ops.tpu.splash_attention`: blocked online softmax in
-float32, causally dead blocks skipped, Pallas backward kernels for dq and
-dk/dv); this module wraps it for (B, H, S, D) tensors, says at which shapes it
-applies, and registers it. It is on `layers/latent_attention.py`'s default
-path wherever `causal_flash_supported`; other shapes (the CPU tests' toy
-sizes) take that module's XLA query-block path, which is also the registry's
-reference.
+float32, blocks the mask leaves empty skipped forward and backward, Pallas
+backward kernels for dq and dk/dv); this module wraps it for (B, H, S, D)
+tensors, says at which shapes it applies, and registers it. With grouped heads
+it runs splash attention's multi-query form once a key/value head, so K and V
+are never repeated to the query heads in memory; the window is the kernel's own
+mask (`LocalMask`), so the blocks outside it are skipped, not multiplied and
+masked. It is on the default path of `layers/latent_attention.py` and
+`layers/grouped_attention.py` wherever `causal_flash_supported`; other shapes
+(the CPU tests' toy sizes) take those modules' XLA query-block paths, which are
+also the registry's reference.
 
 Why a kernel here: at GLM-4.7-Flash's 2 x 20 heads x 8192 positions x 256 the
 XLA path's masked row-maximum fusion runs at ~5 GB/s on a v5e and one layer
@@ -16,6 +24,8 @@ costs 578 ms forward and backward; with this kernel 64.5 ms (my chip runs,
 PR 26; PERF.md section 6).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,53 +35,86 @@ BLOCK_COMPUTE = 512    # key/value columns a kernel step multiplies at once
 RESIDUALS = 'mla_core_out'   # checkpoint_name of the kernel's output and log-sum-exp, for a remat policy
 
 
-def causal_flash_supported(q, k, v) -> bool:
-    """Shapes the kernel takes: (B, H, S, D) with one S and one D for q, k and
-    v, D a multiple of the 128 lanes, S a multiple of its block."""
-    if q.ndim != 4 or not (q.shape == k.shape == v.shape):
+def causal_flash_supported(q, k, v, window=None) -> bool:
+    """Shapes the kernel takes: q (B, H, S, D), k and v (B, H_kv, S, D) with one S and one D, H a multiple of
+    H_kv, D a multiple of the 128 lanes, S a multiple of its block; a window of at least one position."""
+    if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape or (window is not None and window < 1):
         return False
-    S, D = q.shape[2], q.shape[3]
+    (B, H, S, D), H_kv = q.shape, k.shape[1]
+    if k.shape != (B, H_kv, S, D) or H % H_kv:
+        return False
     return D % 128 == 0 and S >= 256 and S % min(BLOCK, S) == 0 and min(BLOCK, S) % 128 == 0
 
 
-def _kernel(heads: int, seq: int, interpret: bool):
+def _kernel(heads: int, seq: int, interpret: bool, window=None, grouped: bool = False):
+    """The splash kernel over `heads` query heads: one key/value head a query head, or with `grouped` one
+    key/value head for all of them (the multi-query form)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
     block, compute = min(BLOCK, seq), min(BLOCK_COMPUTE, seq)
     sizes = sk.BlockSizes(block_q=block, block_kv=block, block_kv_compute=compute,
                           block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=compute,
                           block_q_dq=block, block_kv_dq=block)
-    mask = sm.MultiHeadMask([sm.CausalMask((seq, seq))] * heads)
-    return sk.make_splash_mha(mask, block_sizes=sizes, head_shards=1, q_seq_shards=1,
-                              residual_checkpoint_name=RESIDUALS, interpret=interpret)
+    one = sm.CausalMask((seq, seq)) if window is None or window >= seq else sm.LocalMask((seq, seq), (window - 1, 0), 0)
+    make = sk.make_splash_mqa if grouped else sk.make_splash_mha
+    return make(sm.MultiHeadMask([one] * heads), block_sizes=sizes, head_shards=1, q_seq_shards=1,
+                residual_checkpoint_name=RESIDUALS, interpret=interpret)
 
 
-def causal_flash_attention(q, k, v, scale: float):
-    """(B, H, S, D) -> (B, H, S, D), causal over S; softmax in float32 inside the kernel."""
-    if not causal_flash_supported(q, k, v):
-        raise ValueError(f'causal_flash_attention does not take q {q.shape} k {k.shape} v {v.shape}')
-    kernel = _kernel(q.shape[1], q.shape[2], jax.default_backend() != 'tpu')   # CPU tests run it interpreted
-    return jax.vmap(kernel)(q * jnp.asarray(scale, q.dtype), k, v)
+def causal_flash_attention(q, k, v, scale: float, window=None, with_tiles: bool = False):
+    """q (B, H, S, D), k, v (B, H_kv, S, D) -> (B, H, S, D), causal over S and, with `window`, within the last
+    `window` positions; softmax in float32 inside the kernel. `with_tiles` also returns how many (query block,
+    key block) tiles of one sequence hold an unmasked pair, read from the kernel's own forward block map:
+    the tiles it multiplies, the rest it skips."""
+    if not causal_flash_supported(q, k, v, window):
+        raise ValueError(f'causal_flash_attention does not take q {q.shape} k {k.shape} v {v.shape} window {window}')
+    (B, H, S, D), H_kv = q.shape, k.shape[1]
+    interpret = jax.default_backend() != 'tpu'                                  # CPU tests run it interpreted
+    q = q * jnp.asarray(scale, q.dtype)
+    if H == H_kv:
+        kernel = _kernel(H, S, interpret, window)
+        out = jax.vmap(kernel)(q, k, v)
+    else:
+        # one multi-query call a key/value head: K and V stay at H_kv heads in memory
+        kernel = _kernel(H // H_kv, S, interpret, window, grouped=True)
+        out = jax.vmap(kernel)(q.reshape(B * H_kv, H // H_kv, S, D), k.reshape(B * H_kv, S, D),
+                               v.reshape(B * H_kv, S, D)).reshape(B, H, S, D)
+    return (out, _tiles(S, window)) if with_tiles else out
+
+
+@functools.lru_cache(maxsize=None)
+def _tiles(seq: int, window) -> int:
+    """(query block, key block) tiles with an unmasked pair in the forward block map of the kernel `_kernel`
+    builds for this length and window (one head's: the heads' masks are alike). Built eagerly: inside a
+    trace the kernel's own copy of the map is a traced constant."""
+    import numpy as np
+    with jax.ensure_compile_time_eval():
+        block_map = np.asarray(_kernel(1, seq, True, window).fwd_mask_info.block_mask)   # (1, query blocks, key blocks visited)
+    return int((block_map[0] != 0).sum())
 
 
 # ---------------------------------------------------------------------------
 # registry entry
 
 
-def _registry_reference(q, k, v):
+def _registry_reference(q, k, v, window=None):
+    from ..layers.grouped_attention import grouped_causal_attention
     from ..layers.latent_attention import causal_attention
-    return causal_attention(q, k, v, q.shape[-1] ** -0.5)
+    if window is None and q.shape == k.shape:
+        return causal_attention(q, k, v, q.shape[-1] ** -0.5)
+    return grouped_causal_attention(q, k, v, q.shape[-1] ** -0.5, window)
 
 
-def _registry_kernel(q, k, v):
-    return causal_flash_attention(q, k, v, q.shape[-1] ** -0.5)
+def _registry_kernel(q, k, v, window=None):
+    return causal_flash_attention(q, k, v, q.shape[-1] ** -0.5, window)
 
 
 def _registry_inputs(seed: int = 0, batch: int = 1, heads: int = 2, seq: int = 256, head_dim: int = 128,
-                     dtype: str = 'float32'):
+                     dtype: str = 'float32', kv_heads: int = None):
     import numpy as np
     rng = np.random.default_rng(seed)
-    q, k, v = (jnp.asarray(rng.standard_normal((batch, heads, seq, head_dim)) * 0.5, dtype) for _ in range(3))
+    q, k, v = (jnp.asarray(rng.standard_normal((batch, h, seq, head_dim)) * 0.5, dtype)
+               for h in (heads, kv_heads or heads, kv_heads or heads))
     return dict(q=q, k=k, v=v)
 
 
@@ -94,6 +137,23 @@ def _register():
                 dry=dict(batch=1, heads=2, seq=256, head_dim=128),
                 live=dict(batch=2, heads=20, seq=8192, head_dim=256, dtype='bfloat16'),
                 desc='GLM-4.7-Flash latent attention, 2 sequences of 8192',
+            ),
+            KernelCase(
+                name='gqa_full_s16384_d128',
+                dry=dict(batch=1, heads=4, kv_heads=2, seq=256, head_dim=128),
+                live=dict(batch=1, heads=28, kv_heads=4, seq=16384, head_dim=128, dtype='bfloat16'),
+                desc='SmallThinker-21BA3B full (position-free) layer: 28 query heads on 4 key/value heads, 16384 '
+                     'positions; v5e, one layer: forward 15.5 ms against the XLA query-block path\'s 88.1, forward and '
+                     'backward 60.3 against 230.3 (PR 31)',
+            ),
+            KernelCase(
+                name='gqa_window4096_s16384_d128',
+                dry=dict(batch=1, heads=2, kv_heads=1, seq=5120, head_dim=128),
+                live=dict(batch=1, heads=28, kv_heads=4, seq=16384, head_dim=128, dtype='bfloat16'),
+                statics=dict(window=4096),
+                desc='SmallThinker-21BA3B window layer: the same heads, keys within 4096 positions; v5e, one layer: '
+                     'forward 8.0 ms against 46.8 for the XLA path that slices keys to the window, forward and backward '
+                     '31.1 against 123.2 (PR 31)',
             ),
         ),
         backends=('tpu',),

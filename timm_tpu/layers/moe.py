@@ -1,9 +1,15 @@
 """A sparse mixture-of-experts layer that is told which experts it holds.
 
-Routing is DeepSeek-V3's `noaux_tc` (arXiv:2412.19437 section 2.1.2) as
+Routing is one of two rules a model's entry point fixes (`scoring`).
+'sigmoid_bias' is DeepSeek-V3's `noaux_tc` (arXiv:2412.19437 section 2.1.2) as
 GLM-4.7-Flash `glm4_moe_lite` configures it: sigmoid scores over ALL
 `num_experts`, the `top_k` largest of score + bias chosen, weights = the chosen
-scores normalised over all `top_k` chosen and scaled. The layer holds experts
+scores normalised over all `top_k` chosen and scaled. 'softmax_topk' is
+SmallThinker's (arXiv:2507.20984): the `top_k` largest LOGITS chosen, weights =
+a softmax over the chosen logits; no bias, no scaling. The router may read
+another tensor than the experts (`router_in`: SmallThinker routes on the
+attention's input, so a layer's routing does not wait for its attention), and
+the experts' gate activation is SiLU (SwiGLU) or ReLU (ReGLU). The layer holds experts
 [expert_offset, expert_offset + experts_held) — one expert-parallel rank's
 share — and computes their part of the result; what experts held elsewhere
 would add is not computed here and nothing stands in for it (on several chips
@@ -12,7 +18,7 @@ the exchange in `parallel/` would bring it; ROADMAP "Reach"). With
 
 No token is dropped under any routing: the (token, choice) slots are sorted by
 held expert into a buffer of T * top_k rows — the worst case, every choice of
-every token on an expert held here — and the three SwiGLU products run as
+every token on an expert held here — and the three gated-MLP products run as
 grouped products over the experts held (`jax.lax.ragged_dot`, group sizes =
 slots an expert). `moe.dropped_slots` counts local slots the buffer left out:
 0 by construction, and a counter so that a later bound has to prove it.
@@ -27,15 +33,27 @@ from ..utils import tracing
 from .mlp import SwiGLU
 from .weight_init import trunc_normal_
 
-__all__ = ['SparseMoe', 'route']
+__all__ = ['SparseMoe', 'route', 'merge_counters']
+
+ACTIVATIONS = {'silu': jax.nn.silu, 'relu': jax.nn.relu}
 
 
-def route(scores_in, router_kernel, bias, top_k: int, scaling: float):
+def merge_counters(a: dict, b: dict) -> dict:
+    """Counters of two layers as one: slots and blocks add, the largest load is the larger."""
+    if not a or not b:
+        return a or b
+    return {k: jnp.maximum(a[k], b[k]) if k.endswith('_max') else a[k] + b[k] for k in a}
+
+
+def route(scores_in, router_kernel, bias, top_k: int, scaling: float, scoring: str = 'sigmoid_bias'):
     """x (T, d) -> chosen expert ids (T, k) and weights (T, k), float32.
     The scores, their choice and the weights are float32 at full precision:
     a top-k choice flips on the last bits."""
     logits = jnp.matmul(scores_in.astype(jnp.float32), router_kernel.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
+    if scoring == 'softmax_topk':
+        chosen, idx = jax.lax.top_k(logits, top_k)
+        return idx, jax.nn.softmax(chosen, axis=-1)
     s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
@@ -43,9 +61,10 @@ def route(scores_in, router_kernel, bias, top_k: int, scaling: float):
 
 
 class SparseMoe(nnx.Module):
-    """x (B, S, dim) -> (y (B, S, dim), counters). Every expert is a SwiGLU of
-    width `hidden`, without biases; `n_shared` shared experts are one SwiGLU of
-    width `n_shared * hidden` every token passes through."""
+    """(x (B, S, dim), router_in = x) -> (y (B, S, dim), counters). Every expert is a gated MLP of width
+    `hidden`, without biases: `activation(x W_gate) * (x W_up)` through `W_down`; `n_shared` shared experts are
+    one SwiGLU of width `n_shared * hidden` every token passes through. `scoring` and `activation` are a
+    model's, fixed where its entry point builds the layer."""
 
     def __init__(
             self,
@@ -57,6 +76,8 @@ class SparseMoe(nnx.Module):
             expert_offset: int = 0,
             n_shared: int = 1,
             routed_scaling_factor: float = 1.0,
+            scoring: str = 'sigmoid_bias',
+            activation: str = 'silu',
             *,
             dtype=None,
             param_dtype=jnp.float32,
@@ -65,7 +86,10 @@ class SparseMoe(nnx.Module):
         held = num_experts if experts_held is None else experts_held
         if not 0 <= expert_offset <= expert_offset + held <= num_experts:
             raise ValueError(f'experts [{expert_offset}, {expert_offset + held}) are not among {num_experts}')
+        if scoring not in ('sigmoid_bias', 'softmax_topk') or activation not in ACTIVATIONS:
+            raise ValueError(f'scoring {scoring!r} / activation {activation!r}: not a rule this layer knows')
         self.num_experts, self.top_k = num_experts, top_k
+        self.scoring, self.activation = scoring, activation
         self.experts_held, self.expert_offset = held, expert_offset
         self.scaling = routed_scaling_factor
         self.dtype = dtype
@@ -74,24 +98,30 @@ class SparseMoe(nnx.Module):
         self.router = nnx.Param(init(key(), (dim, num_experts), param_dtype))
         # `e_score_correction_bias`: steers the choice, not the weights; a buffer without gradient. Its
         # update from the experts' load is not part of the step (the rate is not in the public config).
-        self.score_bias = nnx.Variable(jnp.zeros((num_experts,), jnp.float32))
+        # The softmax rule has none.
+        self.score_bias = nnx.Variable(jnp.zeros((num_experts,), jnp.float32)) if scoring == 'sigmoid_bias' else None
         self.w_gate = nnx.Param(init(key(), (held, dim, hidden), param_dtype))
         self.w_up = nnx.Param(init(key(), (held, dim, hidden), param_dtype))
         self.w_down = nnx.Param(init(key(), (held, hidden, dim), param_dtype))
         self.shared = SwiGLU(dim, hidden * n_shared, bias=False, dtype=dtype, param_dtype=param_dtype,
                              rngs=rngs) if n_shared else None
 
-    def choose(self, x):
-        """Chosen expert ids (..., top_k) of tokens x (..., dim), of all `num_experts`."""
-        return route(x, self.router[...], self.score_bias[...], self.top_k, self.scaling)[0]
+    def _route(self, router_in):
+        bias = None if self.score_bias is None else jax.lax.stop_gradient(self.score_bias[...])
+        return route(router_in, self.router[...], bias, self.top_k, self.scaling, self.scoring)
 
-    def routed(self, x):
-        """The held experts' part of the result for tokens x (T, dim), and the counters."""
+    def choose(self, router_in):
+        """Chosen expert ids (..., top_k) of tokens whose router input is (..., dim), of all `num_experts`."""
+        return self._route(router_in)[0]
+
+    def routed(self, x, router_in=None):
+        """The held experts' part of the result for tokens x (T, dim), routed on `router_in` (T, dim; default x),
+        and the counters."""
         T, dim = x.shape
         held, k = self.experts_held, self.top_k
         dt = self.dtype or x.dtype
         with tracing.scope('glm.moe.route'):
-            idx, weights = route(x, self.router[...], jax.lax.stop_gradient(self.score_bias[...]), k, self.scaling)
+            idx, weights = self._route(x if router_in is None else router_in)
             local = (idx >= self.expert_offset) & (idx < self.expert_offset + held)
             slot_expert = jnp.where(local, idx - self.expert_offset, held).reshape(-1)   # `held` = held elsewhere
             order = jnp.argsort(slot_expert, stable=True)          # local slots first, by expert
@@ -107,7 +137,7 @@ class SparseMoe(nnx.Module):
         with tracing.scope('glm.moe.experts'):
             gate = jax.lax.ragged_dot(xs, self.w_gate[...].astype(dt), group_sizes)
             up = jax.lax.ragged_dot(xs, self.w_up[...].astype(dt), group_sizes)
-            hidden = jnp.where(live, jax.nn.silu(gate) * up, 0)
+            hidden = jnp.where(live, ACTIVATIONS[self.activation](gate) * up, 0)
             ys = jax.lax.ragged_dot(hidden, self.w_down[...].astype(dt), group_sizes)
         with tracing.scope('glm.moe.route'):
             slot_weight = jnp.where(local, weights, 0.0).reshape(-1)[order]
@@ -121,9 +151,9 @@ class SparseMoe(nnx.Module):
             }
         return y, counters
 
-    def __call__(self, x):
+    def __call__(self, x, router_in=None):
         B, S, dim = x.shape
-        y, counters = self.routed(x.reshape(B * S, dim))
+        y, counters = self.routed(x.reshape(B * S, dim), None if router_in is None else router_in.reshape(B * S, dim))
         y = y.reshape(B, S, dim).astype(x.dtype)
         if self.shared is not None:
             with tracing.scope('glm.moe.shared'):

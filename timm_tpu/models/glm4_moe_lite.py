@@ -30,18 +30,12 @@ from flax import nnx
 
 from ..layers import LatentAttention, RmsNorm, SparseMoe, SwiGLU, build_rotary_pos_embed_1d, trunc_normal_
 from ..layers.latent_attention import CORE_OUT
+from ..layers.moe import merge_counters  # noqa: F401  (re-exported: its first home)
 from ..utils import tracing
 from ._builder import build_model_with_cfg
 from ._registry import register_model
 
 __all__ = ['Glm4MoeLite']
-
-def merge_counters(a: dict, b: dict) -> dict:
-    """Counters of two expert layers as one: slots add, the largest load is the larger."""
-    if not a or not b:
-        return a or b
-    return {k: jnp.maximum(a[k], b[k]) if k.endswith('_max') else a[k] + b[k] for k in a}
-
 
 class Glm4Block(nnx.Module):
     """(x, rope) -> (x, counters); `dense_hidden` makes the FFN a dense SwiGLU."""

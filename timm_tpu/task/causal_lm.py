@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from flax import nnx
 
-from ..models.glm4_moe_lite import merge_counters
+from ..layers.moe import merge_counters
 from ..utils import tracing
 from .task import TrainingTask
 

@@ -71,11 +71,20 @@ SPANS = {
     'glm.moe.shared': ('experts', 'device scope: the shared expert'),
     'glm.mtp': ('step', 'device scope: the multi-token-prediction module (its block carries the mla/moe scopes inside)'),
     'glm.head_loss': ('step', 'device scope: final norm, output head and cross-entropy, in chunks'),
+    # the window/full attention family's scopes. Their kind reads 'swa device scope' and not 'device scope':
+    # `benchmarks/harness/device_scopes.py` `declared_scopes()` takes the names whose kind starts with the
+    # latter, and `tests/benchmark_harness/test_lm_harness.py` holds that set equal to the nine above
+    # (PERF.md section 7); `benchmarks/harness/swa_lm_readers.py` reads both kinds
+    'swa.attn.proj': ('attention', 'swa device scope: grouped-query q/k/v/o products, the norm before them, the rotary turn'),
+    'swa.attn.core_full': ('attention', 'swa device scope: the causal core of a full (position-free) layer, forward and backward'),
+    'swa.attn.core_window': ('attention', 'swa device scope: the causal core of a window layer, forward and backward'),
     # step counters (`device_counter`): values computed inside the step program, returned in its metrics
     'moe.local_slots': ('experts', 'step counter: (token, expert) slots routed to experts held here, all expert layers'),
     'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),
     'moe.dropped_slots': ('experts', 'step counter: local slots the dispatch left out; must read 0'),
     'lm.tokens': ('step', 'step counter: tokens the step was given'),
+    'attn.full_blocks': ('attention', 'step counter: (query block, key block) tiles with an unmasked pair that the full cores multiply in the forward pass, all layers and sequences'),
+    'attn.window_blocks': ('attention', 'step counter: the same for the window cores, from the kernel\'s block map or the XLA path\'s slices'),
 }
 
 

@@ -1,7 +1,9 @@
 """The comparison that decides `correct`: every number compared is printed
 beside its limit, and one number over its limit makes the run not correct.
 The limits live in the configuration's file (`limits`), each set from chip
-readings recorded in PERF.md section 2."""
+readings recorded in PERF.md section 2. What was compared is also kept as
+data (`compared`, the `into` of `judge` and `judge_exact`): the record's
+`checks`, which `run.py` closes its result line and its stderr with."""
 from __future__ import annotations
 
 import math
@@ -68,21 +70,33 @@ def rng_numbers(before: dict, after: dict, calls: int) -> dict:
                                f'{len(before)} sites, {calls} steps' + (f', e.g. {off[0]}' if off else ''))}
 
 
-def judge_exact(numbers: dict, out=print) -> bool:
+def compared(value, limit, ok: bool, how: str = 'at most') -> dict:
+    """One number compared, as the record's `checks` keep it: the number, its limit, how the limit is meant
+    (`at most`, `at least`, `equal`) and the verdict. A number that is not finite is kept as its name: the result
+    line is strict JSON."""
+    value = float(value)
+    return {'value': value if math.isfinite(value) else str(value), 'limit': limit, 'how': how, 'ok': bool(ok)}
+
+
+def judge_exact(numbers: dict, out=print, into: dict = None) -> bool:
     ok = True
     for name, (value, limit, note) in numbers.items():
         within = value == limit
         ok = ok and within
         out(f'check {name}: {value} limit {limit} {"ok" if within else "OVER"} ({note})')
+        if into is not None:
+            into[name] = compared(value, limit, within, 'equal')
     return ok
 
 
-def judge(numbers: dict, limits: dict, out=print) -> bool:
-    """Print each number beside its limit; True when every one is within."""
+def judge(numbers: dict, limits: dict, out=print, into: dict = None) -> bool:
+    """Print each number beside its limit, and keep it in `into`; True when every one is within."""
     ok = True
     for name, (value, note) in numbers.items():
         limit = limits['loss_gap' if name.startswith('loss_gap_step') else name]
         within = value <= limit
         ok = ok and within
         out(f'check {name}: {value:.6g} limit {limit:.6g} {"ok" if within else "OVER"} ({note})')
+        if into is not None:
+            into[name] = compared(value, limit, within)
     return ok

@@ -117,37 +117,32 @@ def counter_mean(run: dict, name: str):
     return sum(rows) / len(rows) if rows else None
 
 
-def needed_macs(run: dict):
-    """`lm_flops.forward_macs` of one of the run's steps, the routed experts by the window's mean of
-    `moe.local_slots`; None where the run has no language-model sizes or counters."""
-    from . import lm_flops
-    lm, slots = run.get('lm'), counter_mean(run, 'moe.local_slots')
-    if not lm or slots is None:
-        return None
-    return lm_flops.forward_macs(run['sizes'], lm['seq_len'], lm['sequences'], slots)
-
-
-def scope_mfu(run: dict, scope: str):
-    """% of the chip's bfloat16 peak that the needed operations of `scope`'s part make over the scope's device
-    time: the part's roofline share (these parts are matrix products: compute-bound)."""
+def part_mfu(run: dict, scope: str, part: str):
+    """% of the chip's bfloat16 peak that the needed operations of one part of the step (the record's
+    `needed_macs[part]`, which the cell's runner put there from its family's operation table; forward and
+    backward) make over the device time under `scope`: the part's roofline share (these parts are matrix products:
+    compute-bound). None where the record has no such part or no time under the scope: another family's, an
+    image cell's, a parent older than the scopes."""
     from . import lm_flops, peaks
-    macs, ms = needed_macs(run), scope_ms(run, scope)
-    if macs is None or not ms or SCOPE_PARTS.get(scope) is None:
+    macs, ms = (run.get('needed_macs') or {}).get(part), scope_ms(run, scope)
+    if macs is None or not ms:
         return None
-    return 100.0 * lm_flops.train_flops(macs[SCOPE_PARTS[scope]]) / (ms / 1e3) / peaks.peak(run['device_kind'])['bf16_flops']
+    return 100.0 * lm_flops.train_flops(macs) / (ms / 1e3) / peaks.peak(run['device_kind'])['bf16_flops']
 
 
-def scope_table(run: dict) -> list:
-    """One line a scope: ms a traced step, share of busy time, roofline share of its part; then the cover."""
+def scope_table(run: dict, parts: dict = None) -> list:
+    """One line a scope of `parts` (scope -> its part of the needed operations; the GLM family's where none is
+    given): ms a traced step, share of busy time, roofline share of its part; then the cover."""
+    parts = SCOPE_PARTS if parts is None else parts
     scopes = (run.get('trace') or {}).get('scopes') or {}
     if not scopes.get('scope_s'):
         return ['device scopes: none in the trace']
     lines = []
-    for scope in SCOPE_PARTS:
-        ms, share, mfu = scope_ms(run, scope), scope_share(run, scope), scope_mfu(run, scope)
+    for scope, part in parts.items():
+        ms, share, mfu = scope_ms(run, scope), scope_share(run, scope), part_mfu(run, scope, part)
         if ms is not None:
             lines.append(f'device scope {scope}: {ms:.2f} ms a step, {share:.1f} % of busy'
                          + (f', {mfu:.1f} % of peak on its needed operations' if mfu is not None else ''))
-    lines.append(f'device scopes cover {scope_share(run, *SCOPE_PARTS):.1f} % of busy device time; outside them: '
+    lines.append(f'device scopes cover {scope_share(run, *parts):.1f} % of busy device time; outside them: '
                  + ', '.join(f'{k} {v * 1e3:.1f} ms' for k, v in scopes['unscoped'][:5]))
     return lines

@@ -13,8 +13,9 @@ what an image step needs:
     (`model.routes`), for `route_agreement` with the reference's;
   * the step's counters (`moe.*`, `lm.tokens`) are kept as device arrays and
     read after the window; a traced run also reads the step program's compiled
-    text, reduces device time by scope (`device_scopes.py`) and prints the
-    cell's own readings (`lm_readers.py`), which `BENCHMARK.json` cannot list yet.
+    text and reduces device time by scope (`device_scopes.py`) for the cell's
+    own per-layer metrics (`lm_readers.py`), and prints the scope table and the
+    two readings that are no metric (`lm_readers.PRINTED`).
 
 The reference (`reference/lm_train_step.py`) runs after the window, once the
 program's state is freed.
@@ -163,6 +164,25 @@ class LmStepWatcher(StepWatcher):
         return metrics
 
 
+def expert_layers(sizes: dict) -> int:
+    """Layers of the held model that route: all but the first dense ones, and the MTP module's."""
+    return sizes['num_hidden_layers'] - sizes['first_k_dense_replace'] + sizes['num_nextn_predict_layers']
+
+
+def needed_work(config: dict, record: dict, forward_macs=None) -> dict:
+    """What a step of this run NEEDS, for the record (`train_runner.needed_work`): `needed_macs`, the forward
+    MACs of one step by part (`lm_flops.forward_macs`, or the family's own table handed in; the routed experts by
+    the window's mean of `moe.local_slots`, the step's own count, so the work is the configuration's whatever
+    implements it), which the `*_mfu.train` readers take their part from, and `needed_step_flops`, their sum
+    x 2 x 3. Nothing where the step returned no counters."""
+    from . import device_scopes, lm_flops
+    slots, lm = device_scopes.counter_mean(record, 'moe.local_slots'), record['lm']
+    if slots is None:
+        return {}
+    macs = (forward_macs or lm_flops.forward_macs)(config['sizes'], lm['seq_len'], lm['sequences'], slots)
+    return {'needed_macs': macs, 'needed_step_flops': lm_flops.train_flops(macs)}
+
+
 def memory_peak(stats: dict, live_in_window: int, summed: int) -> int:
     """Peak device memory of the run. `peaks.memory_peak_bytes` adds the peak of live buffers to the peak the
     runtime reserved for loaded programs' temporaries; here the two peaks fall at different times (seeding
@@ -278,9 +298,10 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
         'losses_window': losses, 'reference': config['reference'], 'sizes': sizes,
         'device_kind': device.device_kind,
         'counters': {k: v for k, v in counters.items() if v},
-        'lm': {'seq_len': watcher.seq_len, 'sequences': watcher.batch_size,
+        'lm': {'seq_len': watcher.seq_len, 'sequences': watcher.batch_size, 'expert_layers': expert_layers(sizes),
                'tokens_per_s': steps * watcher.batch_size * watcher.seq_len / window_s},
     }
+    record.update(needed_work(config, record))
     # a training sample here is one sequence: `train_img_per_s` reads sequences a second
     record['end_to_end'] = {'train_img_per_s': steps * watcher.batch_size / window_s, 'setup_s': record['setup_s']}
     if trace:
@@ -310,30 +331,34 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
     # the program's state goes before the reference's comes
     followed, program_numbers = watcher.followed, watcher.program
     dropped = sum(record['counters'].get('moe.dropped_slots', [0]))
+    checks = record['checks'] = {}                       # every number `correct` compares, beside its limit
     own = check.judge_exact({**feed_numbers(followed),
                              'moe_dropped_slots': (dropped, 0, f'over the window\'s {steps} steps'),
                              'moe_counters_missing': (int('moe.dropped_slots' not in record['counters']), 0,
-                                                      'the step returns its counters')}, out=log)
+                                                      'the step returns its counters')}, out=log, into=checks)
     del watcher
     gc.collect()
 
     t_ref = time.perf_counter()
     ref_numbers = reference_follow(reference, config, make_weights, followed, 'float32')
     numbers = check.training_numbers(program_numbers, ref_numbers)
-    ok = check.judge(numbers, config['limits']['lm_train'], out=log)
+    ok = check.judge(numbers, config['limits']['lm_train'], out=log, into=checks)
     agreement = route_agreement(program_numbers['routes'], ref_numbers['routes']) if 'routes' in program_numbers else 0.0
     floor = config['limits_lm']['route_agreement_min']
     agreed = agreement >= floor
+    checks['route_agreement'] = check.compared(agreement, floor, agreed, 'at least')
     log(f'check route_agreement: {agreement:.6g} at least {floor:.6g} {"ok" if agreed else "UNDER"} '
         f'(share of the program\'s chosen (token, expert) pairs of step 1 the reference chose too)')
     log(f'reference: {FOLLOWED} steps followed in {time.perf_counter() - t_ref:.1f} s ('
         + ', '.join(f'{k} {v:.1f}' for k, v in ref_numbers['seconds'].items()) + ')')
     weight = sizes['mtp_loss_weight'] if sizes['num_nextn_predict_layers'] else 0.0
-    first = program_numbers['losses'][0] / (1.0 + weight)
-    sane = abs(first - math.log(sizes['vocab_held'])) <= 0.5
+    first, owed = program_numbers['losses'][0] / (1.0 + weight), math.log(sizes['vocab_held'])
+    sane = abs(first - owed) <= 0.5
+    checks['first_loss'] = check.compared(first, [owed - 0.5, owed + 0.5], sane, 'within')
     log(f'check first_loss: {first:.4f} (the loss over 1 + {weight:g}, the MTP term\'s weight) within '
         f'ln({sizes["vocab_held"]}) +- 0.5: {"ok" if sane else "OVER"}')
     zero_compiles = record['compiles_in_window'] == 0
+    checks['compiles_in_window'] = check.compared(record['compiles_in_window'], 0, zero_compiles, 'equal')
     log(f'check compiles_in_window: {record["compiles_in_window"]} limit 0 {"ok" if zero_compiles else "OVER"}')
     record['correct'] = bool(ok and own and agreed and sane and zero_compiles and failed == 0 and steps > 0)
     record['numbers'] = dict({k: v[0] for k, v in numbers.items()}, route_agreement=agreement)

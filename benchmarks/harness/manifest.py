@@ -4,8 +4,10 @@ A cell is `benchmarks/workloads/<name>.json`, its configuration
 `benchmarks/configs/<config>.json`, a per-layer metric's reader
 `benchmarks/layer_metrics/<metric>.py`, a reference
 `benchmarks/reference/<reference>.py`, a runner
-`benchmarks/harness/<runner>_runner.py`. Adding any of them is adding a file
-and an entry to `BENCHMARK.json`; no file that is there is edited.
+`benchmarks/harness/<runner>_runner.py` (its `run`, which gives the record, and
+`needed_work`, which says what a step of its family needs: the readers know no
+family's shapes). Adding any of them is adding a file and an entry to
+`BENCHMARK.json`; no file that is there is edited.
 """
 from __future__ import annotations
 

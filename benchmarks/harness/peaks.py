@@ -1,9 +1,8 @@
 """The one table of chip peaks, keyed by `jax.devices()[0].device_kind`.
 
 Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s in bfloat16,
-394 TOP/s in int8, 16 GB of HBM at 819 GB/s per chip (copied from
-`bench.py` `CHIP_PEAK`, which a later PR deletes). A device that is not in the
-table is an error, never a default. Beside it: how this runtime's memory
+394 TOP/s in int8, 16 GB of HBM at 819 GB/s per chip. A device that is not in
+the table is an error, never a default. Beside it: how this runtime's memory
 statistics add up to a peak.
 """
 from __future__ import annotations

@@ -2,13 +2,13 @@
 `swa_lm_train`): `lm_train_runner.py`'s run — the same window, clocks, record,
 followed steps, feed checks, route agreement, memory peak and reference follow,
 all imported from there — with what this family needs instead of what the GLM
-share needs: its own operation count (`swa_lm_flops.py`), scopes and readings
-(`swa_lm_readers.py`), the step's `attn.*` tile counters beside the `moe.*`
-ones, limits under `limits['swa_lm_train']`, and a first loss without an MTP
-term, held to what the seeded head owes (ln V + half its logits' variance).
-`lm_train_runner.run` cannot serve: traced, its scope table reads GLM's sizes
-(`lm_flops.forward_macs`: `qk_nope_head_dim`, `q_lora_rank`, ..), and that file
-is not this PR's to edit (PERF.md section 7 (g) asks for the fold).
+share needs: its own operation count (`swa_lm_flops.py`, which `needed_work`
+puts into the record), scopes and readings (`swa_lm_readers.py`), the step's
+`attn.*` tile counters beside the `moe.*` ones, limits under
+`limits['swa_lm_train']`, and a first loss without an MTP term, held to what
+the seeded head owes (ln V + half its logits' variance). PR 31 could not edit
+`lm_train_runner.run` to serve both; PERF.md section 7 (g) and (j) ask for the
+fold.
 """
 from __future__ import annotations
 
@@ -19,11 +19,18 @@ import statistics
 import time
 import traceback
 
+from . import lm_train_runner
 from .lm_train_runner import (COUNTERS, LmStepWatcher, build_argv, feed_numbers, memory_peak, reference_follow,
                               route_agreement)
 from .train_runner import FOLLOWED, WindowClosed
 
 ATTN_COUNTERS = ('attn.full_blocks', 'attn.window_blocks')
+
+
+def needed_work(config: dict, record: dict) -> dict:
+    """`lm_train_runner.needed_work` by this family's operation table (`swa_lm_flops.forward_macs`)."""
+    from . import swa_lm_flops
+    return lm_train_runner.needed_work(config, record, swa_lm_flops.forward_macs)
 
 
 def tile_counting(inner, seen: list):
@@ -48,7 +55,7 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
     from timm_tpu.task import CausalLMTask
     from timm_tpu.utils.compile_cache import collect_cache_events, configure_compile_cache
 
-    from . import check, device_scopes, lm_traffic, swa_lm_readers, weights
+    from . import check, device_scopes, lm_readers, lm_traffic, swa_lm_readers, weights
     from .manifest import reference_module
     from .peaks import memory_peak_bytes
 
@@ -110,9 +117,10 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
         'losses_window': losses, 'reference': config['reference'], 'sizes': sizes,
         'device_kind': device.device_kind,
         'counters': {k: v for k, v in counters.items() if v},
-        'lm': {'seq_len': watcher.seq_len, 'sequences': watcher.batch_size,
+        'lm': {'seq_len': watcher.seq_len, 'sequences': watcher.batch_size, 'expert_layers': sizes['num_hidden_layers'],
                'tokens_per_s': steps * watcher.batch_size * watcher.seq_len / window_s},
     }
+    record.update(needed_work(config, record))
     # a training sample here is one sequence: `train_img_per_s` reads sequences a second
     record['end_to_end'] = {'train_img_per_s': steps * watcher.batch_size / window_s, 'setup_s': record['setup_s']}
     if trace:
@@ -123,7 +131,7 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
         record['trace']['scopes'] = device_scopes.reduce_scopes(path, watcher.hlo_text or '', swa_lm_readers.declared_scopes())
         record['trace']['breakdown']['device_scopes'] = sorted(
             ([k, v] for k, v in record['trace']['scopes']['scope_s'].items()), key=lambda kv: -kv[1])
-        for line in swa_lm_readers.scope_table(record) + swa_lm_readers.lines(record):
+        for line in device_scopes.scope_table(record, swa_lm_readers.SCOPE_PARTS) + lm_readers.lines(record):
             log(line)
     log(f'window: {steps} steps of {watcher.batch_size} x {watcher.seq_len} tokens in {window_s:.3f} s = '
         f'{record["lm"]["tokens_per_s"]:.0f} tokens/s; '
@@ -141,20 +149,22 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
     followed, program_numbers = watcher.followed, watcher.program
     dropped = sum(record['counters'].get('moe.dropped_slots', [0]))
     missing = [name for name in ('moe.dropped_slots',) + ATTN_COUNTERS if name not in record['counters']]
+    checks = record['checks'] = {}                       # every number `correct` compares, beside its limit
     own = check.judge_exact({**feed_numbers(followed),
                              'moe_dropped_slots': (dropped, 0, f'over the window\'s {steps} steps'),
                              'step_counters_missing': (len(missing), 0, f'the step returns its counters {missing or ""}'.rstrip())},
-                            out=log)
+                            out=log, into=checks)
     del watcher
     gc.collect()
 
     t_ref = time.perf_counter()
     ref_numbers = reference_follow(reference, config, make_weights, followed, 'float32')
     numbers = check.training_numbers(program_numbers, ref_numbers)
-    ok = check.judge(numbers, config['limits']['swa_lm_train'], out=log)
+    ok = check.judge(numbers, config['limits']['swa_lm_train'], out=log, into=checks)
     agreement = route_agreement(program_numbers['routes'], ref_numbers['routes']) if 'routes' in program_numbers else 0.0
     floor = config['limits_lm']['route_agreement_min']
     agreed = agreement >= floor
+    checks['route_agreement'] = check.compared(agreement, floor, agreed, 'at least')
     log(f'check route_agreement: {agreement:.6g} at least {floor:.6g} {"ok" if agreed else "UNDER"} '
         f'(share of the program\'s chosen (token, expert) pairs of step 1, all {sizes["num_hidden_layers"]} layers, '
         f'the reference chose too)')
@@ -165,9 +175,11 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
     first = program_numbers['losses'][0]
     owed = math.log(sizes['vocab_held']) + sizes['hidden_size'] * weights.STD ** 2 / 2
     sane = abs(first - owed) <= 0.5
+    checks['first_loss'] = check.compared(first, [owed - 0.5, owed + 0.5], sane, 'within')
     log(f'check first_loss: {first:.4f} within ln({sizes["vocab_held"]}) + {owed - math.log(sizes["vocab_held"]):.3f} '
         f'(half the seeded logits\' variance) = {owed:.4f} +- 0.5: {"ok" if sane else "OVER"}')
     zero_compiles = record['compiles_in_window'] == 0
+    checks['compiles_in_window'] = check.compared(record['compiles_in_window'], 0, zero_compiles, 'equal')
     log(f'check compiles_in_window: {record["compiles_in_window"]} limit 0 {"ok" if zero_compiles else "OVER"}')
     record['correct'] = bool(ok and own and agreed and sane and zero_compiles and failed == 0 and steps > 0)
     record['numbers'] = dict({k: v[0] for k, v in numbers.items()}, route_agreement=agreement)

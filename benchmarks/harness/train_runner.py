@@ -38,6 +38,19 @@ class WindowClosed(Exception):
     """Raised by the wrapper when the window has closed; carries nothing."""
 
 
+def needed_work(config: dict, record: dict) -> dict:
+    """What a step of this run NEEDS, for the record: `needed_step_flops`, which `step_mfu.train` reads in every
+    training cell. Every runner has this function and knows its family's shape functions; the readers know none.
+    Here: the reference's forward MACs x 2 x 3 x the batch, nothing recomputed. The shape function is
+    `flops.py`'s, or the reference file's own `forward_macs(sizes)` where a later family brings one beside its
+    reference."""
+    from . import flops
+    from .manifest import reference_module
+    own = getattr(reference_module(config['reference']), 'forward_macs', None)
+    macs = own(config['sizes']) if own else flops.forward_macs(config['reference'], config['sizes'])
+    return {'needed_step_flops': macs * 2 * 3 * record['batch_size']}
+
+
 def loader_workers() -> int:
     return max(1, min(16, (os.cpu_count() or 4) - 2))
 
@@ -258,6 +271,7 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
         'device_kind': device.device_kind,
     }
     record['end_to_end'] = {'train_img_per_s': steps * watcher.batch_size / window_s, 'setup_s': record['setup_s']}
+    record.update(needed_work(config, record))
     if trace:
         from . import trace as trace_mod
         record['trace'] = trace_mod.reduce_trace(trace_mod.newest_xplane(trace_dir), default_gap_label='host')
@@ -268,9 +282,10 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
 
     # the program's state goes before the reference's comes
     followed, program_numbers = watcher.followed, watcher.program
+    checks = record['checks'] = {}                       # every number `correct` compares, beside its limit
     own = check.judge_exact({**check.feed_numbers(followed, config['recipe']),
                              **check.rng_numbers(watcher.rng_counts['before'], watcher.rng_counts['after'],
-                                                 watcher.calls)}, out=log)
+                                                 watcher.calls)}, out=log, into=checks)
     del watcher
     gc.collect()
 
@@ -279,12 +294,14 @@ def run(cell: dict, config: dict, *, seed: int, seconds: float, trace: bool, pro
     ref_numbers = reference_follow(reference, ref_cfg, weights.make(seed, reference.init_spec(sizes)), followed,
                                    config, precision='float32')
     numbers = check.training_numbers(program_numbers, ref_numbers)
-    ok = check.judge(numbers, config['limits']['train'], out=log)
+    ok = check.judge(numbers, config['limits']['train'], out=log, into=checks)
     log(f'reference: {FOLLOWED} steps followed in {time.perf_counter() - t_ref:.1f} s')
-    first = program_numbers['losses'][0]
-    sane = abs(first - math.log(sizes['num_classes'])) <= 0.5
+    first, owed = program_numbers['losses'][0], math.log(sizes['num_classes'])
+    sane = abs(first - owed) <= 0.5
+    checks['first_loss'] = check.compared(first, [owed - 0.5, owed + 0.5], sane, 'within')
     log(f'check first_loss: {first:.4f} within ln({sizes["num_classes"]}) +- 0.5: {"ok" if sane else "OVER"}')
     zero_compiles = record['compiles_in_window'] == 0
+    checks['compiles_in_window'] = check.compared(record['compiles_in_window'], 0, zero_compiles, 'equal')
     log(f'check compiles_in_window: {record["compiles_in_window"]} limit 0 {"ok" if zero_compiles else "OVER"}')
     record['correct'] = bool(ok and own and sane and zero_compiles and failed == 0 and steps > 0)
     record['numbers'] = {k: v[0] for k, v in numbers.items()}

@@ -6,9 +6,12 @@ started on:
 
 Earlier lines are free text (every number compared with its limit, the
 loader's workers, where set-up went); the LAST line of stdout is one
-JSON object with `correct`, `attempted`, `failed`, `metrics`, `device` and,
-when traced, `breakdown`. With `--trace 0` the metrics are the cell's
-end-to-end metrics, with `--trace 1` its per-layer metrics.
+JSON object with `correct`, `attempted`, `failed`, `metrics`, `device`,
+when traced `breakdown`, and last `checks`: every number `correct` compared,
+beside its limit, as the runner's record holds them (`check.compared`); the
+same numbers are the last lines of stderr. With `--trace 0`
+the metrics are the cell's end-to-end metrics, with `--trace 1` its per-layer
+metrics.
 
 Everything that belongs to one cell, configuration or metric is a file found
 by the name in `BENCHMARK.json` (`harness/manifest.py`). There is no CPU
@@ -67,7 +70,13 @@ def result_line(manifest, cell_name: str, record: dict, device: dict, trace: boo
     if trace:
         device.update(busy_s=record['trace']['busy_s'], window_s=record['trace']['window_s'])
         line['breakdown'] = record['trace']['breakdown']
+    line['checks'] = record.get('checks', {})
     return line
+
+
+def check_lines(checks: dict) -> list:
+    """One line a number compared, from the record's data: `check <name>: <number> <how> <limit> ok|NOT`."""
+    return [f'check {name}: {c["value"]} {c["how"]} {c["limit"]} {"ok" if c["ok"] else "NOT"}' for name, c in checks.items()]
 
 
 def main(argv=None) -> int:
@@ -87,7 +96,9 @@ def main(argv=None) -> int:
     record = runner_module(cell['runner']).run(
         cell, config, seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
         process_start=PROCESS_START, scratch=SCRATCH)
-    print(json.dumps(result_line(manifest, args.workload, record, device, bool(args.trace))), flush=True)
+    line = result_line(manifest, args.workload, record, device, bool(args.trace))
+    print('\n'.join(check_lines(line['checks'])), file=sys.stderr, flush=True)
+    print(json.dumps(line), flush=True)
     return 0
 
 

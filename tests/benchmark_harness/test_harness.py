@@ -25,7 +25,7 @@ from benchmarks.harness.manifest import BENCH_DIR, Manifest, load_json, runner_m
 
 NAME = re.compile(r'^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$')
 UNIT = re.compile(r'^[A-Za-z0-9_/%.\-]{1,16}$')
-RESULT_KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device'}
+RESULT_KEYS = {'correct', 'attempted', 'failed', 'metrics', 'device', 'checks'}
 
 TOY_SIZES = {'img_size': 160, 'patch_size': 16, 'in_chans': 3, 'embed_dim': 64, 'depth': 2, 'num_heads': 2,
              'mlp_ratio': 3, 'num_classes': 1000}
@@ -280,6 +280,35 @@ def test_a_cell_added_by_files_alone_runs_and_prints_the_contracts_line(toy, toy
     assert line['metrics']['toy_steps']['value'] == record['steps'] and 'hbm_peak_gb.train' not in line['metrics']
     assert line['metrics']['step_wall_ms.train']['value'] > line['metrics']['dispatch_host_ms.train']['value'] > 0
     assert line['metrics']['step_device_ms.train']['value'] == pytest.approx(100.0)
+
+
+def test_every_number_compared_stands_beside_its_limit_for_the_result_line(toy, toy_train):
+    """`run.py` closes its result line with `checks` and its stderr with the same: what `check.judge`,
+    `judge_exact` and the runner's own comparisons put into the record as data (`check.compared`), not what their
+    printed lines say. The control's numbers, judged without an `into`, are not the run's."""
+    from benchmarks import run as bench_run
+    record, lines = toy_train
+    checks = record['checks']
+    assert set(checks) == {l.split()[1].rstrip(':') for l in lines if l.startswith('check ')} >= {
+        'loss_gap_step1', 'loss_gap_step3', 'first_grad_norm_gap', 'param_change_norm_gap', 'ema_change_norm_gap',
+        'feed_repeated_rows', 'rng_counts_off', 'first_loss', 'compiles_in_window'}
+    assert all(set(c) == {'value', 'limit', 'how', 'ok'} and c['ok'] is True for c in checks.values())
+    limits = TOY_LIMITS['train']
+    assert checks['first_grad_norm_gap']['limit'] == limits['first_grad_norm_gap'] and checks['first_grad_norm_gap']['how'] == 'at most'
+    assert checks['loss_gap_step3']['limit'] == limits['loss_gap'] and checks['feed_repeated_rows'] == {'value': 0.0, 'limit': 0, 'how': 'equal', 'ok': True}
+    low, high = checks['first_loss']['limit']
+    assert checks['first_loss']['how'] == 'within' and low < checks['first_loss']['value'] < high == pytest.approx(math.log(1000) + 0.5)
+    # the line: `checks` comes last, and is strict JSON whatever a number reads
+    line = bench_run.result_line(toy[0], 'toy_vit_train', record, {'platform': 'cpu', 'kind': 'cpu', 'count': 1}, trace=False)
+    assert list(line)[-1] == 'checks' and line['checks'] == checks
+    said = bench_run.check_lines(checks)
+    assert len(said) == len(checks) and f'check first_grad_norm_gap: {checks["first_grad_norm_gap"]["value"]} at most {limits["first_grad_norm_gap"]} ok' in said
+    into = {}
+    assert not check.judge({'loss_gap_step1': (math.inf, ''), 'first_grad_norm_gap': (math.nan, '')}, limits, out=lambda s: None, into=into)
+    assert into['loss_gap_step1'] == {'value': 'inf', 'limit': limits['loss_gap'], 'how': 'at most', 'ok': False}
+    assert into['first_grad_norm_gap']['value'] == 'nan' and not into['first_grad_norm_gap']['ok']
+    assert json.loads(json.dumps(into, allow_nan=False)) == into and bench_run.check_lines(into)[0].endswith(' NOT')
+    assert not check.judge_exact({'x': (3, 0, 'note')}, out=lambda s: None, into=into) and into['x'] == {'value': 3.0, 'limit': 0, 'how': 'equal', 'ok': False}
 
 
 def test_the_lower_precision_control_is_not_correct(toy, toy_train):

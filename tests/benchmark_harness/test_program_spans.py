@@ -4,6 +4,7 @@ CPU. One file, like `test_harness.py`, whose toy cell it borrows.
 """
 import importlib.util
 import os
+import statistics
 import sys
 
 import pytest
@@ -27,11 +28,20 @@ RING = ['step_state_split_ms.train', 'step_scalars_put_ms.train', 'step_call_ms.
         'setup_model_build_s', 'setup_data_build_s', 'setup_step_program_s', 'setup_compile_s']
 TRACE = ['device_idle_in_step_ms.train', 'device_idle_in_input_ms.train', 'device_idle_in_loop_ms.train',
          'device_idle_attributed_share.train']
+CHILDREN = ('task.state_split', 'task.scalars_put', 'task.step_call', 'task.state_update', 'task.sentinel_poll')
+# What a step of `task.train_step` may spend under none of its five children. Between five spans in a row lie six
+# gaps of a span's exit, a line or two of Python and the next span's entry: 70-85 us a step here on an idle machine,
+# 70-145 us beside five other workers (medians of 42 toy runs; a span costs 14 us on the chip machine, PERF.md
+# section 6, PR 24). 0.3 ms is twice the worst of those. A share of the call alone cannot be the bound: the toy's
+# call is 25-30 ms here because the poll waits 20 ms for the step, and with nothing in it waiting a call can be a
+# millisecond (PR 25's lagged poll: ROADMAP S2), 5 % of which is less than the clock reads. The smallest child this
+# still misses is one shorter than the bound (`task.state_split`, 0.02 ms here).
+UNCOVERED_MS = 0.3
 
 
 def test_the_manifest_names_exactly_these_readers_beside_the_eight_it_had():
     m = Manifest()
-    assert [x['name'] for x in m.data['per_layer']][8:] == RING[:10] + TRACE + RING[10:]
+    assert [x['name'] for x in m.data['per_layer']][8:26] == RING[:10] + TRACE + RING[10:]   # later entries are appended
     for name in RING + TRACE:
         entry = m.per_layer[name]
         assert callable(m.reader(name))   # LAYER, UNIT, MOVES of the file agree with the entry
@@ -60,21 +70,43 @@ def test_train_main_fills_the_ring_and_the_reader_reads_a_number(toy, sound, nam
         assert value > toy[0].reader('step_call_ms.train')(sound) / 1e3    # the first call traces and compiles
 
 
+def _uncovered_ms(w, children):
+    """(ms of a `task.train_step` call under none of `children`, the most it may be): both by the MEDIAN step of
+    the window. Work that no child holds is there in every step; a worker taken off its core between two spans'
+    clocks (2-7 ms, one step in forty under `-n 6`) is the machine's, and a sum over the window would carry it."""
+    whole, parts = ps.per_step(w, 'task.train_step'), ps.per_step(w, *children)
+    assert all(p <= a for p, a in zip(parts, whole))                 # children lie inside their parent, every step
+    return statistics.median(a - p for a, p in zip(whole, parts)), max(UNCOVERED_MS, 0.05 * statistics.median(whole))
+
+
 def test_the_window_is_the_wrappers_and_the_children_cover_the_call(sound):
     w = ps.window(sound)
     assert len(w['roots']) == sound['steps'] and [r.step for r in w['roots']] == sorted(r.step for r in w['roots'])
     assert w['roots'][-1].failed and not w['roots'][0].failed       # the window closes by raising through the last root
-    whole = ps.per_step(w, 'task.train_step')
-    parts = ps.per_step(w, 'task.state_split', 'task.scalars_put', 'task.step_call', 'task.state_update',
-                        'task.sentinel_poll')
-    assert 0.95 * sum(whole) <= sum(parts) <= sum(whole)
+    uncovered, bound = _uncovered_ms(w, CHILDREN)
+    assert 0.0 <= uncovered <= bound, (uncovered, bound)
     # the wrapper's two clocks sit right around the program's own span
+    whole = ps.per_step(w, 'task.train_step')
     outside = [d * 1e3 for d in sound['spans']['train_step_dispatch_s']]
-    assert all(0.0 <= o - i < 5.0 for o, i in zip(outside, whole))
+    # (every step, as before PR 35, but for ONE step of the window: a worker taken off its core between the wrapper's
+    # clock and the span's loses 2-7 ms once in forty steps under `-n 6`, and a window here has a dozen)
+    gaps = [o - i for o, i in zip(outside, whole)]
+    assert all(g >= 0.0 for g in gaps) and sum(g >= 5.0 for g in gaps) <= 1, gaps
     # set-up is what ended before the window's first root, compilations and the first step among it
     names = [s.name for s in ps.setup(w)]
     assert {'setup.model_build', 'setup.task_build', 'setup.data_build', 'xla.backend_compile', 'task.step_call'} <= set(names)
     assert max(s.end_ns for s in ps.setup(w)) <= w['roots'][0].start_ns
+
+
+def test_a_call_with_a_child_span_removed_is_not_covered(sound):
+    """What the cover is there to catch, work inside `task.train_step` that no child span holds: the same window
+    read without its longest child (the poll while it waits for the step, the dispatch once it does not; of five
+    children that cover the call, at least a fifth of it) is over the bound."""
+    w = ps.window(sound)
+    longest = max(CHILDREN, key=lambda c: statistics.median(ps.per_step(w, c)))
+    assert longest in ('task.sentinel_poll', 'task.step_call')
+    uncovered, bound = _uncovered_ms(w, [c for c in CHILDREN if c != longest])
+    assert uncovered > bound, (longest, uncovered, bound)
 
 
 def test_the_traced_line_carries_the_ring_metrics_and_leaves_the_trace_ones_out(toy, sound):

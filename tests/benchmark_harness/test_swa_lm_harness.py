@@ -2,10 +2,12 @@
 `swa_lm_readers.py`, the configuration and the cell ISSUE 31 brings), at `smallthinker_toy` size on the CPU.
 One file, like its neighbours.
 
-The eleven readings are functions of `swa_lm_readers.py`, not metrics of `BENCHMARK.json`, for the reason
-`test_lm_harness.py` gives for the GLM cell's eight (the pin of `test_program_spans.py`; PERF.md section 7).
-The toy manifest below gives each its entry and its reader file, as a `benchmark` PR will; the six names both
-cells have get ONE entry that lists both, read through `swa_lm_readers.read_any`.
+Eight of the readings are metrics of `BENCHMARK.json` since PR 35 (the five `attn_*` of this family, in
+`swa_lm_readers.READERS`, and three `moe_*` of `lm_readers.READERS` that list both language-model cells under ONE
+entry: no reader asks which family a record is of); the whole step's share of the peak is `step_mfu.train`'s, and
+two stay free text (`lm_readers.PRINTED`). The toy manifest below lists its cell wherever the real cell is listed.
+What the manifest must have is held as a subset of what it has: a later PR adds cells and metrics and may not
+edit this file.
 """
 import json
 import math
@@ -26,11 +28,12 @@ from benchmarks.harness import check, device_scopes, lm_readers, swa_lm_flops, s
 from benchmarks.harness.manifest import BENCH_DIR, Manifest, load_json, runner_module  # noqa: E402
 
 CELL, GLM_CELL, CONFIG = 'smallthinker_21b_ep8_train_16k', 'glm47_flash_ep8_train_8k', 'smallthinker_21b_ep8'
-NEW = ['lm_step_mfu.train', 'attn_device_share.train', 'attn_proj_mfu.train', 'attn_full_core_mfu.train',
-       'attn_window_core_mfu.train', 'attn_window_block_fill.train', 'moe_device_share.train', 'moe_experts_mfu.train',
-       'moe_route_device_ms.train', 'moe_slots_per_expert.train', 'moe_load_max_over_mean.train']
-BOTH = [n for n in NEW if n in lm_readers.READERS]
-NOT_ITS = {'step_mfu.train', 'input_prepare_ms.train', 'input_decode_busy_share.train'}
+BOTH = ['moe_route_device_ms.train', 'moe_device_ms.train', 'moe_experts_mfu.train']     # `lm_readers.READERS`': both families'
+OWN = ['attn_device_ms.train', 'attn_proj_mfu.train', 'attn_full_core_mfu.train', 'attn_window_core_mfu.train',
+       'attn_window_block_fill.train']                                                      # `swa_lm_readers.READERS`'
+METRICS = BOTH + OWN                                                                        # the manifest's order
+COUNTED = list(lm_readers.PRINTED)
+NOT_ITS = {'input_prepare_ms.train', 'input_decode_busy_share.train', 'mla_device_ms.train', 'mla_core_mfu.train'}
 TOY_SIZES = dict(vocab_held=256, hidden_size=64, num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
                  moe_ffn_hidden_size=32, moe_num_primary_experts=8, moe_num_active_primary_experts=2, experts_held=2,
                  expert_offset=0, rope_layout=[0, 1, 1, 1], sliding_window_layout=[0, 1, 1, 1], sliding_window_size=8,
@@ -44,41 +47,32 @@ GLM_RECORD = {'runner': 'train', 'steps': 3, 'device_kind': 'TPU v5 lite', 'lm':
                             moe_intermediate_size=32, n_routed_experts=8, num_experts_per_tok=2, experts_held=2, n_shared_experts=1,
                             first_k_dense_replace=1, num_nextn_predict_layers=1),
               'counters': {'moe.local_slots': [700, 800], 'moe.load_max': [300, 310], 'moe.dropped_slots': [0, 0]},
+              'needed_macs': {'mla_core': 4e9, 'moe_experts': 1e9, 'moe_route': 1e7},
               'trace': {'busy_s': 0.5, 'window_s': 1.0, 'idle_share': 0.5, 'work': 5, 'scopes': {
                   'scope_s': {'glm.mla.core': 0.2, 'glm.moe.route': 0.03, 'glm.moe.experts': 0.04}, 'busy_s': 0.5, 'unscoped': []}}}
 
-READER_FILE = """LAYER = {layer!r}
-UNIT = {unit!r}
-MOVES = {moves!r}
-
-
-def read(run: dict):
-    from benchmarks.harness import swa_lm_readers
-    return swa_lm_readers.read_any({name!r}, run)
-"""
-
-
-def test_the_manifest_gains_the_configuration_and_the_cell_and_nothing_else_moves():
+def test_the_manifest_has_the_configuration_the_cell_and_its_readers_entries():
     m = Manifest()
     names = [x['name'] for x in m.data['per_layer']]
-    assert len(names) == 26 and not set(NEW) & set(names) and list(swa_lm_readers.READERS) == NEW   # the pin holds
-    assert [w['name'] for w in m.data['workloads']][-2:] == [GLM_CELL, CELL] and len(m.data['workloads']) == 4
-    assert [c['name'] for c in m.data['configs']][-1] == CONFIG and m.data['run_seconds'] == 20
-    # the new cell is listed wherever the GLM cell is, after it, and nowhere else
-    for metric in m.data['end_to_end'] + m.data['per_layer']:
+    assert set(swa_lm_readers.READERS) == set(OWN) and 'lm_step_mfu.train' not in names     # one share, one name
+    assert [n for n in names[26:] if n in METRICS] == METRICS and not set(COUNTED) & set(names)
+    assert [w['name'] for w in m.data['workloads']][2:4] == [GLM_CELL, CELL]                 # later cells come after
+    assert [c['name'] for c in m.data['configs']][3] == CONFIG and m.data['run_seconds'] == 20
+    # among the 26 entries older than the readings the cell is listed wherever the GLM cell is, after it; a reading
+    # lists the cells of the families that have it
+    for metric in m.data['end_to_end'] + m.data['per_layer'][:26]:
         cells = metric.get('workloads', [])
-        assert (CELL in cells) == (GLM_CELL in cells) and (CELL not in cells or cells[-2:] == [GLM_CELL, CELL])
+        assert (CELL in cells) == (GLM_CELL in cells) and (CELL not in cells or cells.index(CELL) == cells.index(GLM_CELL) + 1)
     assert not NOT_ITS & set(m.metrics_of(CELL, 'per_layer')) and m.metrics_of(CELL, 'end_to_end') == ['train_img_per_s', 'setup_s']
-    assert m.metrics_of(CELL, 'per_layer') == m.metrics_of(GLM_CELL, 'per_layer')
-    for name, r in swa_lm_readers.READERS.items():           # what their entries will say; a shared name says what GLM's says
+    assert set(OWN) <= set(m.metrics_of(CELL, 'per_layer')) - set(m.metrics_of(GLM_CELL, 'per_layer'))
+    assert set(BOTH) <= set(m.metrics_of(CELL, 'per_layer')) & set(m.metrics_of(GLM_CELL, 'per_layer'))
+    for name, r in swa_lm_readers.READERS.items():           # what their entries say
         entry = swa_lm_readers.entry(name, [CELL])
         assert entry == {'name': name, 'unit': r.unit, 'better': r.better, 'source': r.source, 'layer': r.layer,
                          'moves': 'train_img_per_s', 'workloads': [CELL]}
-        assert r.layer in ('step', 'attention', 'experts') and r.source in ('device_trace', 'program_counter')
-        if name in BOTH:
-            assert entry == dict(lm_readers.entry(name, [CELL]))
-    assert len(BOTH) == 6 and {n for n in NEW if n.endswith('mfu.train')} == {
-        'lm_step_mfu.train', 'attn_proj_mfu.train', 'attn_full_core_mfu.train', 'attn_window_core_mfu.train', 'moe_experts_mfu.train'}
+        assert dict(m.per_layer[name], workloads=[CELL]) == entry and CELL in m.per_layer[name]['workloads']
+        assert r.layer == 'attention' and r.source in ('device_trace', 'program_counter')
+        assert (r.unit, r.better) == (('ms', 'lower') if name.endswith('_ms.train') else ('%', 'higher'))
     cell, config = m.cell(CELL), m.config(CONFIG)
     assert cell['runner'] == 'swa_lm_train' and cell['chips'] == 1 and m.cells[CELL]['traffic'] == 'train_token_stream'
     stream = cell['traffic']['token_stream']
@@ -92,7 +86,7 @@ def test_the_manifest_gains_the_configuration_and_the_cell_and_nothing_else_move
     path = '/opt/skills/guides/model-configs/architectures.jsonl'
     for row in [json.loads(line) for line in open(path)] if os.path.exists(path) else []:
         if row['name'] == 'SmallThinker-21BA3B-Instruct':    # every published number under its key, but the three reduced
-            assert config['source'] == row['source_url'] == m.data['configs'][-1]['source']
+            assert config['source'] == row['source_url'] == m.data['configs'][3]['source']
             off = {k for k, v in row['config'].items() if config.get(k, 'missing') != v}
             assert off == set(config['reduced']) == set(config['published']) and all(
                 config['published'][k] == row['config'][k] for k in off)
@@ -160,9 +154,6 @@ def toy(tmp_path_factory):
                                                         'validation_tokens': 32 * 8}}}))
     man['configs'].append({'name': 'toy_swa', 'source': 'test', 'file': 'benchmarks/configs/toy_swa.json', 'reduced': [], 'why': 'test'})
     man['workloads'].append({'name': 'toy_swa_train', 'config': 'toy_swa', 'traffic': 'toy_tokens', 'chips': 1, 'why': 'test'})
-    for name, r in swa_lm_readers.READERS.items():           # an entry and a reader file each: nothing that is there is edited
-        man['per_layer'].append(swa_lm_readers.entry(name, [GLM_CELL, CELL] if name in BOTH else [CELL]))
-        (bench / 'layer_metrics' / f'{name}.py').write_text(READER_FILE.format(layer=r.layer, unit=r.unit, moves=swa_lm_readers.MOVES, name=name))
     for metric in man['end_to_end'] + man['per_layer']:
         if CELL in metric.get('workloads', ()):
             metric['workloads'].append('toy_swa_train')
@@ -211,15 +202,18 @@ def test_the_new_runner_runs_a_cell_added_by_files_and_prints_the_contracts_line
         'breakdown': {'device_ops': [], 'idle_gaps': []}})
     line = json.loads(json.dumps(bench_run.result_line(toy[0], 'toy_swa_train', traced, device, trace=True)))
     got = {k: v['value'] for k, v in line['metrics'].items()}
-    assert set(NEW) <= set(got) and not NOT_ITS & set(got)
+    assert set(METRICS) | {'step_mfu.train'} <= set(got) and not (NOT_ITS | set(COUNTED)) & set(got)
     assert {'step_device_ms.train', 'step_wall_ms.train', 'dispatch_host_ms.train', 'input_host_ms.train', 'step_call_ms.train',
             'device_idle_share.train', 'input_batch_wait_ms.train', 'loop_bookkeeping_ms.train', 'setup_compile_s'} <= set(got)
-    assert got['attn_device_share.train'] == pytest.approx(50.0) and got['moe_device_share.train'] == pytest.approx(14.0)
+    assert got['attn_device_ms.train'] == pytest.approx(50.0) and got['moe_device_ms.train'] == pytest.approx(14.0)
     assert got['moe_route_device_ms.train'] == pytest.approx(6.0)
+    assert record['needed_macs'] == swa_lm_flops.forward_macs(TOY_SIZES, 32, 8, sum(record['counters']['moe.local_slots']) / steps)
+    assert record['needed_step_flops'] == swa_lm_flops.train_flops(record['needed_macs']) and record['lm']['expert_layers'] == 4
+    # the same numbers as data, beside their limits: what the result line ends with
+    assert set(record['checks']) == compared and all(c['ok'] for c in record['checks'].values())
     slots = sum(record['counters']['moe.local_slots']) / steps
-    assert got['moe_slots_per_expert.train'] == pytest.approx(slots / (2 * 4)) and got['moe_load_max_over_mean.train'] >= 1.0
     macs = swa_lm_flops.forward_macs(TOY_SIZES, 32, 8, slots)
-    assert got['lm_step_mfu.train'] == pytest.approx(100 * swa_lm_flops.train_flops(macs) / 0.1 / 197e12)
+    assert got['step_mfu.train'] == pytest.approx(100 * swa_lm_flops.train_flops(macs) / 0.1 / 197e12)
     assert got['attn_full_core_mfu.train'] == pytest.approx(100 * 6 * macs['attn_core_full'] / 0.016 / 197e12)
     assert got['attn_window_core_mfu.train'] == pytest.approx(100 * 6 * macs['attn_core_window'] / 0.024 / 197e12)
     assert got['attn_proj_mfu.train'] == pytest.approx(100 * 6 * macs['attn_proj'] / 0.01 / 197e12)
@@ -227,30 +221,34 @@ def test_the_new_runner_runs_a_cell_added_by_files_and_prints_the_contracts_line
     # tiles of 8 x 8 (from the full cores' own count): 228 needed pairs a window layer in 7 tiles of 64
     assert swa_lm_readers.block_side(traced) == pytest.approx(8.0) and swa_lm_flops.window_pairs(32, 8) == 228
     assert got['attn_window_block_fill.train'] == pytest.approx(100 * 228 / (7 * 64))
-    assert any(l.startswith('device scopes cover 74.2 %') for l in swa_lm_readers.scope_table(traced))
-    # the same readings as the free text a traced run prints while `BENCHMARK.json` lacks the entries
-    said = {l.split()[1].rstrip(':'): l.split()[2] for l in swa_lm_readers.lines(traced)}
-    assert list(said) == NEW and all(float(said[n]) == pytest.approx(got[n], rel=1e-5) for n in NEW)
-    assert all('nothing to read' in l for l in swa_lm_readers.lines({}))
-    # a metric that lists both cells reads the GLM cell's record through the same file
+    table = device_scopes.scope_table(traced, swa_lm_readers.SCOPE_PARTS)
+    assert any(l.startswith('device scopes cover 74.2 %') for l in table)
+    assert any(l.startswith('device scope swa.attn.core_window: 24.00 ms a step, 24.0 % of busy, ') for l in table)
+    # the two readings that are no metric, as the free text a traced run prints
+    said = {l.split()[1].rstrip(':'): float(l.split()[2]) for l in lm_readers.lines(traced)}
+    assert list(said) == COUNTED and said['moe_load_max_over_mean.train'] >= 1.0
+    assert said['moe_slots_per_expert.train'] == pytest.approx(slots / (2 * 4), rel=1e-5)
+    assert all('nothing to read' in l for l in lm_readers.lines({}))
+    # a metric that lists both cells reads the GLM cell's record through the same file, and asks for no family
     glm = {n: toy[0].reader(n)(GLM_RECORD) for n in BOTH}
-    assert glm['moe_route_device_ms.train'] == pytest.approx(6.0) and glm['moe_slots_per_expert.train'] == pytest.approx(750 / 6)
-    assert all(v is not None for v in glm.values()) and glm == {n: lm_readers.READERS[n].read(GLM_RECORD) for n in BOTH}
+    assert glm['moe_route_device_ms.train'] == pytest.approx(6.0) and glm['moe_device_ms.train'] == pytest.approx(14.0)
+    assert glm['moe_experts_mfu.train'] == pytest.approx(100 * 6 * 1e9 / 0.008 / 197e12)
+    assert lm_readers.slots_per_expert(dict(GLM_RECORD, lm=dict(GLM_RECORD['lm'], expert_layers=3))) == pytest.approx(750 / 6)
     # and both definitions of the memory peak, until one is chosen
     assert any(l.startswith('memory_peak_bytes: ') and 'peaks.memory_peak_bytes' in l for l in lines)
     assert record['memory_peak_bytes'] <= record['memory_peak_bytes_summed']
 
 
-@pytest.mark.parametrize('name', NEW)
+@pytest.mark.parametrize('name', OWN + BOTH)
 def test_a_new_reader_returns_none_where_there_is_nothing_to_read(name):
-    """A parent without the scopes and counters, an image cell's run, the GLM cell's, an empty record: no value, no raise."""
-    read = swa_lm_readers.READERS[name].read
-    assert read({}) is None and read(GLM_RECORD) is None
+    """A parent without the scopes and counters, an image cell's run, an empty record: no value, no raise. The GLM
+    cell's record: nothing for this family's own five, a number for what both have."""
+    read = (swa_lm_readers.READERS if name in OWN else lm_readers.READERS)[name].read
+    assert read({}) is None and (read(GLM_RECORD) is None) == (name in OWN)
     assert read({'runner': 'train', 'steps': 3, 'sizes': {'embed_dim': 768}, 'device_kind': 'TPU v5 lite',
                  'trace': {'busy_s': 0.5, 'window_s': 1.0, 'idle_share': 0.5, 'work': 5}}) is None
     # this family's sizes and nothing measured: still nothing
     assert read({'runner': 'train', 'sizes': TOY_SIZES, 'device_kind': 'TPU v5 lite', 'lm': {'seq_len': 32, 'sequences': 8}}) is None
-    assert swa_lm_readers.read_any('not_a_reading', {}) is None
 
 
 def test_the_float8_control_is_not_correct(toy, sound):
@@ -274,8 +272,8 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(toy):
 
 def test_device_time_is_reduced_by_the_families_scopes_too():
     names = swa_lm_readers.declared_scopes()
-    assert names == device_scopes.declared_scopes() | {'swa.attn.proj', 'swa.attn.core_full', 'swa.attn.core_window'}
-    assert set(swa_lm_readers.SCOPE_PARTS) <= names and len(device_scopes.declared_scopes()) == 9      # the GLM reduction's set is as it was
+    assert names >= device_scopes.declared_scopes() | {'swa.attn.proj', 'swa.attn.core_full', 'swa.attn.core_window'}
+    assert set(swa_lm_readers.SCOPE_PARTS) <= names and set(device_scopes.SCOPE_PARTS) <= device_scopes.declared_scopes()
     of = lambda op: device_scopes.scope_of(op, names)  # noqa: E731
     assert of('jit(train_step)/transpose(jvp(checkpoint))/swa.attn.core_window/vmap(jit(_splash_attention))/pallas_call') == 'swa.attn.core_window'
     assert of('jit(train_step)/jvp(swa.attn.proj)/dot_general') == 'swa.attn.proj' and of('jit(train_step)/adamw/mul') is None

@@ -164,3 +164,76 @@ def test_the_kernels_block_map_skips_the_tiles_outside_the_window():
 
     trace(None), trace(4096), trace(16384)
     assert seen == {None: 136, 4096: 70, 16384: 136}
+
+
+# a router row a kind of token: kind 0 chooses experts 0 and 1 (both held by a share of experts 0-1), kind 1 experts
+# 0 and 2 (one held), kind 2 experts 2 and 3 (none held); a token shows its kind as a one-hot router input
+KINDS = jnp.asarray([[2.0, 1, 0, 0, 0, 0, 0, 0], [2.0, 0, 1, 0, 0, 0, 0, 0], [0, 0, 2.0, 1, 0, 0, 0, 0]])
+
+# (tokens of kind 0, of kind 1; the rest kind 2) -> local slots 2 * kind0 + kind1, of a buffer of 128 rows
+DISPATCH_CASES = {'even': None, 'exactly_full': (64, 0), 'one_over': (64, 1), 'every_slot_local': (128, 0), 'none_local': (0, 0)}
+
+
+@pytest.mark.parametrize('scoring', ['sigmoid_bias', 'softmax_topk'])
+@pytest.mark.parametrize('case', list(DISPATCH_CASES))
+def test_the_bounded_dispatch_is_the_worst_case_dispatch_result_and_every_gradient(case, scoring, monkeypatch):
+    """The buffer follows the share of the experts held (`dispatch_rows`: 128 of 256 rows here); a layer whose
+    local slots do not fit falls back to all rows, so result and gradients are the worst-case path's under any
+    routing, no slot is dropped, and `moe.fallback_layers` says which branch ran."""
+    from timm_tpu.layers import moe
+    T = 128
+    assert moe.dispatch_rows(T * 2, 2, 8) == 128
+    # a share of 2 of 8 experts: 256 slots a call of 128 tokens, a dispatch buffer of 128 rows
+    layer = SparseMoe(64, 32, 8, 2, experts_held=2, expert_offset=0, n_shared=0, routed_scaling_factor=1.8,
+                      scoring=scoring, activation='relu' if scoring == 'softmax_topk' else 'silu', rngs=nnx.Rngs(7))
+    x = jax.random.normal(jax.random.key(1), (T, 64))
+    if DISPATCH_CASES[case] is None:
+        layer.router[...] = jax.random.normal(jax.random.key(2), (64, 8))
+        a, local = jax.random.normal(jax.random.key(3), (T, 64)), None
+    else:
+        both, one = DISPATCH_CASES[case]
+        layer.router[...] = jnp.zeros((64, 8)).at[:3].set(KINDS)
+        kind = jnp.where(jnp.arange(T) < both, 0, jnp.where(jnp.arange(T) < both + one, 1, 2))
+        a, local = jax.nn.one_hot(jax.random.permutation(jax.random.key(4), kind), 64), 2 * both + one
+    graphdef, state = nnx.split(layer)
+    cot = jax.random.normal(jax.random.key(5), (T, 64))
+
+    def traced():
+        """Result, counters and gradients under remat, as a block runs the layer; traced anew at every call."""
+        def loss(state, x, a):
+            y, counters = jax.checkpoint(lambda s, x, a: nnx.merge(graphdef, s).routed(x, a))(state, x, a)
+            return (y * cot).sum(), (y, counters)
+        run = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+        return run(state, x, a), 'stablehlo.case' in run.lower(state, x, a).as_text()
+
+    ((_, (y, counters)), grads), branches = traced()
+    with monkeypatch.context() as m:
+        m.setattr(moe, 'dispatch_rows', lambda rows, held, num_experts: rows)      # the worst case: no conditional
+        ((_, (y_worst, counters_worst)), grads_worst), branches_worst = traced()
+    assert branches and not branches_worst
+    if local is not None:
+        assert int(counters['moe.local_slots']) == local
+    assert int(counters['moe.local_slots']) == int(counters_worst['moe.local_slots'])
+    assert int(counters['moe.dropped_slots']) == 0 and int(counters_worst['moe.dropped_slots']) == 0
+    assert int(counters['moe.fallback_layers']) == int(int(counters['moe.local_slots']) > 128)
+    assert int(counters['moe.fallback_layers']) == {'one_over': 1, 'every_slot_local': 1}.get(case, 0)
+    assert int(counters_worst['moe.fallback_layers']) == 0
+    assert float(jnp.abs(y - y_worst).max()) < 1e-5
+    leaves, leaves_worst = jax.tree.leaves_with_path(grads), jax.tree.leaves(grads_worst)
+    assert len(leaves) == 4 + 2 + (scoring == 'sigmoid_bias')       # router, three stacks, x, a (and the bias' zero)
+    for (path, g), g_worst in zip(leaves, leaves_worst):
+        assert float(jnp.abs(g - g_worst).max()) <= 1e-5 * max(1.0, float(jnp.abs(g_worst).max())), jax.tree_util.keystr(path)
+    if case != 'none_local':
+        assert float(jnp.abs(y).max()) > 0
+        assert all(float(jnp.abs(g).max()) > 0 for path, g in leaves if 'score_bias' not in jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize('held', [8, 2])
+def test_a_layer_that_holds_every_expert_builds_no_conditional(held):
+    """`experts_held == num_experts`: the buffer is all `T * top_k` rows and the lowered program has no branch."""
+    layer = SparseMoe(64, 32, 8, 2, experts_held=held, n_shared=0, rngs=nnx.Rngs(0))
+    step = jax.jit(jax.grad(lambda m, x: m(x)[0].sum()))
+    x = jnp.ones((2, 64, 64))          # 256 slots: a share of 2 of 8 gets a buffer of 128 rows
+    assert ('stablehlo.case' in step.lower(layer, x).as_text()) == (held < 8)
+    counters = layer(x)[1]
+    assert set(counters) == {'moe.local_slots', 'moe.load_max', 'moe.dropped_slots', 'moe.fallback_layers'}

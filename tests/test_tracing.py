@@ -246,7 +246,7 @@ def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_t
     after = tracing.snapshot()
     assert len(after['spans']) - len(before['spans']) <= 1 and after['counters'] == before['counters']   # the ring is not theirs
     kinds = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('glm.', 'moe.', 'lm.'))}
-    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 13
+    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 14
     # the window/full family's: three scopes of a kind of their own (the GLM reduction's set of nine is pinned by
     # `test_lm_harness.py`; `swa_lm_readers.declared_scopes` reads both kinds) and two tile counters
     swa = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('swa.', 'attn.'))}
@@ -271,3 +271,13 @@ def test_no_loader_worker_thread_opens_a_span():
         assert 'tracing.count(' in body or 'tracing.busy(' in body, fn
     assert 'tracing.span(' not in inspect.getsource(_DecodePool)
     assert 'tracing' not in inspect.getsource(decode_worker)
+
+
+@pytest.mark.parametrize('metrics,starts', [({'moe.fallback_layers': 1, 'loss': 0.0}, 'fallback 1 of 8 layers host ms/step: next'),
+                                            ({'loss': 0.0}, 'host ms/step: next'), (None, 'host ms/step: next')],
+                         ids=['expert_layers', 'image_model', 'no_metrics'])
+def test_the_log_line_names_the_expert_layers_that_fell_back_only_where_the_step_counts_them(metrics, starts):
+    """`moe.fallback_layers` is read by `train.py`'s log line, before the host breakdown: `fallback F of L layers`."""
+    import train
+    text, counters = train._host_line(tracing.now_ns(), {}, metrics, 8)
+    assert text.startswith(starts) and isinstance(counters, dict)

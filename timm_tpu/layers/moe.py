@@ -17,13 +17,23 @@ the exchange in `parallel/` would bring it; ROADMAP "Reach"). With
 `experts_held == num_experts` it is the whole layer.
 
 No token is dropped under any routing: the (token, choice) slots are sorted by
-held expert into a buffer of T * top_k rows — the worst case, every choice of
-every token on an expert held here — and the three gated-MLP products run as
-grouped products over the experts held (`jax.lax.ragged_dot`, group sizes =
-slots an expert). `moe.dropped_slots` counts local slots the buffer left out:
-0 by construction, and a counter so that a later bound has to prove it.
+held expert, the local ones first, and the first `C` of that order are gathered
+into a buffer of `C` rows, on which the three gated-MLP products run as grouped
+products over the experts held (`jax.lax.ragged_dot`, group sizes = slots an
+expert). `C` follows the share of the experts the layer holds, not the worst
+case: twice the mean load, `2 * T * top_k * experts_held / num_experts` rounded
+up to `TILE` rows and at most `T * top_k` (`dispatch_rows`). A layer whose local
+slots do not fit in `C` rows takes the same path over all `T * top_k` rows (the
+worst case: every choice of every token on an expert held here), chosen on the
+device by `lax.cond`, per layer and per step; `moe.fallback_layers` counts the
+layers that did. With `experts_held == num_experts` `C` is `T * top_k` and no
+conditional is built. `moe.dropped_slots` counts local slots the buffer of the
+branch taken left out: 0 by construction, because the bounded branch runs only
+when the local slots fit and the other holds every slot there is.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +43,16 @@ from ..utils import tracing
 from .mlp import SwiGLU
 from .weight_init import trunc_normal_
 
-__all__ = ['SparseMoe', 'route', 'merge_counters']
+__all__ = ['SparseMoe', 'route', 'merge_counters', 'dispatch_rows']
 
 ACTIVATIONS = {'silu': jax.nn.silu, 'relu': jax.nn.relu}
+TILE = 128      # rows: the dispatch buffer is whole tiles of the grouped products' row dimension
+
+
+def dispatch_rows(rows: int, experts_held: int, num_experts: int) -> int:
+    """Rows of the buffer that `rows` (token, choice) slots are dispatched into by a layer that holds
+    `experts_held` of `num_experts`: twice the slots an even routing brings, in whole tiles, at most all."""
+    return min(rows, -(-2 * rows * experts_held // (num_experts * TILE)) * TILE)
 
 
 def merge_counters(a: dict, b: dict) -> dict:
@@ -58,6 +75,32 @@ def route(scores_in, router_kernel, bias, top_k: int, scaling: float, scoring: s
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
+
+
+@functools.partial(jax.jit, static_argnames=('n', 'top_k', 'activation'))
+def _dispatch(x, order, group_sizes, slot_weight, w_gate, w_up, w_down, *, n: int, top_k: int, activation: str):
+    """The held experts' part of the result from the first `n` slots of `order` (local slots first, by expert),
+    and how many local slots those rows hold. A function of its own under `jax.jit`: layers of one shape share
+    one trace of it, of its derivative and of its lowering, which is most of what the second buffer size would
+    otherwise add to a program's set-up."""
+    with tracing.scope('glm.moe.route'):
+        slots = order[:n]
+        token = slots // top_k
+        covered = jnp.minimum(group_sizes.sum(), n)
+        # rows past the groups hold nothing defined, in a grouped product's result and in its cotangent
+        # alike (the TPU kernel skips their tiles): every operand and result is masked to the live rows,
+        # so that nothing undefined reaches a token, forward or backward
+        live = (jnp.arange(n) < covered)[:, None]
+        xs = jnp.where(live, x[token].astype(w_gate.dtype), 0)
+    with tracing.scope('glm.moe.experts'):
+        gate = jax.lax.ragged_dot(xs, w_gate, group_sizes)
+        up = jax.lax.ragged_dot(xs, w_up, group_sizes)
+        hidden = jnp.where(live, ACTIVATIONS[activation](gate) * up, 0)
+        ys = jax.lax.ragged_dot(hidden, w_down, group_sizes)
+    with tracing.scope('glm.moe.route'):
+        ys = jnp.where(live, ys * slot_weight[slots][:, None].astype(ys.dtype), 0)
+        y = jnp.zeros(x.shape, ys.dtype).at[token].add(ys)
+    return y, covered
 
 
 class SparseMoe(nnx.Module):
@@ -117,38 +160,40 @@ class SparseMoe(nnx.Module):
     def routed(self, x, router_in=None):
         """The held experts' part of the result for tokens x (T, dim), routed on `router_in` (T, dim; default x),
         and the counters."""
-        T, dim = x.shape
         held, k = self.experts_held, self.top_k
         dt = self.dtype or x.dtype
+        rows = x.shape[0] * k                                      # the worst case: every slot local
+        bound = dispatch_rows(rows, held, self.num_experts)
         with tracing.scope('glm.moe.route'):
             idx, weights = self._route(x if router_in is None else router_in)
             local = (idx >= self.expert_offset) & (idx < self.expert_offset + held)
             slot_expert = jnp.where(local, idx - self.expert_offset, held).reshape(-1)   # `held` = held elsewhere
             order = jnp.argsort(slot_expert, stable=True)          # local slots first, by expert
             group_sizes = jnp.bincount(slot_expert, length=held + 1)[:held].astype(jnp.int32)
-            rows = T * k                                           # the worst case: every slot local
-            covered = jnp.minimum(group_sizes.sum(), rows)
-            token = order // k
-            # rows past the groups hold nothing defined, in a grouped product's result and in its cotangent
-            # alike (the TPU kernel skips their tiles): every operand and result is masked to the live rows,
-            # so that nothing undefined reaches a token, forward or backward
-            live = (jnp.arange(rows) < covered)[:, None]
-            xs = jnp.where(live, x[token].astype(dt), 0)
-        with tracing.scope('glm.moe.experts'):
-            gate = jax.lax.ragged_dot(xs, self.w_gate[...].astype(dt), group_sizes)
-            up = jax.lax.ragged_dot(xs, self.w_up[...].astype(dt), group_sizes)
-            hidden = jnp.where(live, ACTIVATIONS[self.activation](gate) * up, 0)
-            ys = jax.lax.ragged_dot(hidden, self.w_down[...].astype(dt), group_sizes)
-        with tracing.scope('glm.moe.route'):
-            slot_weight = jnp.where(local, weights, 0.0).reshape(-1)[order]
-            ys = jnp.where(live, ys * slot_weight[:, None].astype(ys.dtype), 0)
-            y = jnp.zeros((T, dim), ys.dtype).at[token].add(ys)
+            slot_weight = jnp.where(local, weights, 0.0).reshape(-1)
             local_slots = local.sum().astype(jnp.int32)
-            counters = {
-                'moe.local_slots': tracing.device_counter('moe.local_slots', local_slots),
-                'moe.load_max': tracing.device_counter('moe.load_max', group_sizes.max()),
-                'moe.dropped_slots': tracing.device_counter('moe.dropped_slots', local_slots - covered),
-            }
+        with tracing.scope('glm.moe.experts'):
+            operands = (x, order, group_sizes, slot_weight,
+                        self.w_gate[...].astype(dt), self.w_up[...].astype(dt), self.w_down[...].astype(dt))
+        dispatch = functools.partial(_dispatch, top_k=k, activation=self.activation)
+        if bound == rows:
+            fallback = jnp.int32(0)
+            y, covered = dispatch(*operands, n=rows)
+        else:
+            # The conditional stands outside the scopes and each branch opens them inside: the instruction itself
+            # carries no scope, so a trace gives the branch's ops, not the branch, to the route and the products.
+            # A branch is rematerialised on its own: what its backward needs it computes again inside the
+            # conditional, so no buffer of either branch is kept across it for the other's sake.
+            over = local_slots > bound
+            fallback = over.astype(jnp.int32)
+            y, covered = jax.lax.cond(over, jax.checkpoint(functools.partial(dispatch, n=rows)),
+                                      jax.checkpoint(functools.partial(dispatch, n=bound)), *operands)
+        counters = {
+            'moe.local_slots': tracing.device_counter('moe.local_slots', local_slots),
+            'moe.load_max': tracing.device_counter('moe.load_max', group_sizes.max()),
+            'moe.dropped_slots': tracing.device_counter('moe.dropped_slots', local_slots - covered),
+            'moe.fallback_layers': tracing.device_counter('moe.fallback_layers', fallback),
+        }
         return y, counters
 
     def __call__(self, x, router_in=None):

@@ -178,8 +178,12 @@ class Glm4MoeLite(nnx.Module):
         """h (B, S, dim) from `forward_features`, next_ids (B, S) = the token after each position ->
         logits (B, S, vocab) for the token after that."""
         mtp = self.mtp
+        # the block runs outside the module's scope: its ops carry the scopes of the layers inside it, and the
+        # conditional of its expert layer, which a trace shows as one event over the branch taken, carries none
         with tracing.scope('glm.mtp'):
-            x, counters = self._run_block(mtp.block, self._mtp_input(h, next_ids), self._rope(h.shape[1]))
+            x = self._mtp_input(h, next_ids)
+        x, counters = self._run_block(mtp.block, x, self._rope(h.shape[1]))
+        with tracing.scope('glm.mtp'):
             x = mtp.norm(x)
             out = x if pre_logits else self.head(x)
         return (out, counters) if with_counters else out

@@ -69,7 +69,7 @@ SPANS = {
     'glm.moe.route': ('experts', 'device scope: router, top-k, sort, gather into expert order, weighted combine'),
     'glm.moe.experts': ('experts', 'device scope: the grouped products over the experts held'),
     'glm.moe.shared': ('experts', 'device scope: the shared expert'),
-    'glm.mtp': ('step', 'device scope: the multi-token-prediction module (its block carries the mla/moe scopes inside)'),
+    'glm.mtp': ('step', 'device scope: the multi-token-prediction module\'s own projection, norms and head (its block runs outside the scope, under the mla/moe scopes of its layers)'),
     'glm.head_loss': ('step', 'device scope: final norm, output head and cross-entropy, in chunks'),
     # the window/full attention family's scopes. Their kind reads 'swa device scope' and not 'device scope':
     # `benchmarks/harness/device_scopes.py` `declared_scopes()` takes the names whose kind starts with the
@@ -81,7 +81,8 @@ SPANS = {
     # step counters (`device_counter`): values computed inside the step program, returned in its metrics
     'moe.local_slots': ('experts', 'step counter: (token, expert) slots routed to experts held here, all expert layers'),
     'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),
-    'moe.dropped_slots': ('experts', 'step counter: local slots the dispatch left out; must read 0'),
+    'moe.dropped_slots': ('experts', 'step counter: local slots the dispatch buffer of the branch taken left out; 0 by construction (the bounded buffer of layers/moe.py dispatch_rows is taken only when the local slots fit, else all T * top_k rows): must read 0'),
+    'moe.fallback_layers': ('experts', 'step counter: expert layers whose local slots did not fit the bounded dispatch buffer and took the worst-case one'),
     'lm.tokens': ('step', 'step counter: tokens the step was given'),
     'attn.full_blocks': ('attention', 'step counter: (query block, key block) tiles with an unmasked pair that the full cores multiply in the forward pass, all layers and sequences'),
     'attn.window_blocks': ('attention', 'step counter: the same for the window cores, from the kernel\'s block map or the XLA path\'s slices'),

@@ -1102,11 +1102,12 @@ def _batch_to_device(arrays, mesh, shard_batch):
         return shard_batch({k: jnp.asarray(v) for k, v in arrays.items()}, mesh)
 
 
-def _host_line(since_ns, counters_before):
+def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
     """The log line's host breakdown since the previous line (README
     "Reading the host breakdown"): mean wall ms per update of each part of the
-    loop from the program's spans, and what the loader's threads did.
-    -> (text, the counters now)."""
+    loop from the program's spans, and what the loader's threads did; before
+    it, for a model with expert layers, how many of the step's `expert_layers`
+    took the worst-case dispatch buffer. -> (text, the counters now)."""
     from timm_tpu.utils import tracing
     snap = tracing.snapshot()
     rows = tracing.summary(since_ns, spans=snap['spans'])
@@ -1126,6 +1127,8 @@ def _host_line(since_ns, counters_before):
                  f"polls {did.get('task.sentinel_polls', 0)} binds {did.get('task.state_binds', 0)}")
     if procs:   # an image run: decode processes alive at the newest fetch, and those that ended unasked
         text += f" procs {procs[-1][1]} exits {did.get('loader.worker_exits', 0)}"
+    if metrics and 'moe.fallback_layers' in metrics:
+        text = f"fallback {int(metrics['moe.fallback_layers'])} of {expert_layers} layers " + text
     return text, snap['counters']
 
 
@@ -1143,10 +1146,15 @@ def _setup_line():
 def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
                     updates_per_epoch, saver=None, mixup_fn=None, shutdown=None,
                     skip_batches=0, start_updates=None, rollback_budget=None):
+    from flax import nnx
+
+    from timm_tpu.layers import SparseMoe
     from timm_tpu.resilience import TrainingPreempted, get_fault_injector
     from timm_tpu.utils import AverageMeter, tracing
     loss_m = AverageMeter()
     accum = args.grad_accum_steps
+    # expert-layer calls an update makes: the log line's `fallback .. of .. layers`
+    expert_layers = accum * sum(isinstance(m, SparseMoe) for _, m in nnx.iter_modules(task.model))
     num_updates = start_updates if start_updates is not None else epoch * updates_per_epoch
     lr = lr_scheduler.get_last_lr()[0] if lr_scheduler else args.lr
     injector = get_fault_injector()
@@ -1248,7 +1256,7 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
                 samples_since_log = 0
                 log_t0 = time.time()
                 nf = int(metrics['nonfinite_total']) if 'nonfinite_total' in metrics else 0
-                host, log_counters = _host_line(log_since_ns, log_counters)
+                host, log_counters = _host_line(log_since_ns, log_counters, metrics, expert_layers)
                 log_since_ns = tracing.now_ns()
                 if 'lm.tokens' in metrics:
                     # a sample is a sequence; the step's own count of its tokens gives tokens/s

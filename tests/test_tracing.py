@@ -212,6 +212,8 @@ def test_every_recorded_name_is_declared_and_every_declared_name_is_recorded_and
                 open(os.path.join(bench, 'harness', 'lm_train_runner.py')).read(),   # its `correct`: `moe.dropped_slots`
                 open(os.path.join(bench, 'harness', 'swa_lm_readers.py')).read(),    # the window/full cell's readings: `swa.attn.*`, `attn.*`
                 open(os.path.join(bench, 'harness', 'swa_lm_train_runner.py')).read(),
+                open(os.path.join(bench, 'harness', 'bd_lm_readers.py')).read(),     # the block-diffusion cell's: `swa.attn.core_bd`, `attn.bd_blocks`
+                open(os.path.join(bench, 'harness', 'bd_lm_train_runner.py')).read(),  # its `correct`: `lm.noised_masked`, `lm.masked_nll`
                 inspect.getsource(train._host_line), inspect.getsource(train._setup_line)]
     unread = [name for name in tracing.SPANS if not any(f"'{name}'" in text for text in readers)]
     assert not unread, unread
@@ -246,13 +248,14 @@ def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_t
     after = tracing.snapshot()
     assert len(after['spans']) - len(before['spans']) <= 1 and after['counters'] == before['counters']   # the ring is not theirs
     kinds = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('glm.', 'moe.', 'lm.'))}
-    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 14
+    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 16      # two of the block-diffusion task
     # the window/full family's: three scopes of a kind of their own (the GLM reduction's set of nine is pinned by
     # `test_lm_harness.py`; `swa_lm_readers.declared_scopes` reads both kinds) and two tile counters
     swa = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('swa.', 'attn.'))}
+    # the core under the block-diffusion mask came later, as a plain device scope: both reductions take it
     assert swa == {'swa.attn.proj': 'swa device scope', 'swa.attn.core_full': 'swa device scope',
-                   'swa.attn.core_window': 'swa device scope', 'attn.full_blocks': 'step counter',
-                   'attn.window_blocks': 'step counter'}
+                   'swa.attn.core_window': 'swa device scope', 'swa.attn.core_bd': 'device scope',
+                   'attn.full_blocks': 'step counter', 'attn.window_blocks': 'step counter', 'attn.bd_blocks': 'step counter'}
     assert all(tracing.SPANS[name][0] == 'attention' for name in swa)
 
 

@@ -42,14 +42,16 @@ _SEQ_LEN = 128     # tokens a sequence, for a model whose input is token ids
 
 
 def abstract_batch(model, size: int):
-    """(abstract input, output shape owed) for a model, by what the model says it is: a `task_kind` of
-    'causal_lm' takes token ids (B, S) and owes logits over `model.num_classes`; every other model is an image
-    classifier built with `_NUM_CLASSES`. No list of families: a new token family is swept as it registers."""
+    """(abstract inputs, output shape owed) for a model, by what the model says it is: a `task_kind` of
+    'causal_lm' takes token ids (B, S), one of 'block_diffusion_lm' the noised and the clean ids, and both owe
+    logits over `model.num_classes`; every other model is an image classifier built with `_NUM_CLASSES`. No list
+    of families: a new token family is swept as it registers."""
     import jax
     import jax.numpy as jnp
-    if getattr(model, 'task_kind', None) == 'causal_lm':
-        return jax.ShapeDtypeStruct((_BATCH, _SEQ_LEN), jnp.int32), (_BATCH, _SEQ_LEN, model.num_classes)
-    return jax.ShapeDtypeStruct((_BATCH, size, size, 3), jnp.float32), (_BATCH, _NUM_CLASSES)
+    id_tensors = {'causal_lm': 1, 'block_diffusion_lm': 2}.get(getattr(model, 'task_kind', None))
+    if id_tensors:
+        return (jax.ShapeDtypeStruct((_BATCH, _SEQ_LEN), jnp.int32),) * id_tensors, (_BATCH, _SEQ_LEN, model.num_classes)
+    return (jax.ShapeDtypeStruct((_BATCH, size, size, 3), jnp.float32),), (_BATCH, _NUM_CLASSES)
 
 
 def family_representative(module: str) -> Tuple[str, int]:
@@ -89,9 +91,9 @@ def sweep(families: Optional[Sequence[str]] = None,
             model.eval()
             graphdef, state = nnx.split(model)
             batch, owed = abstract_batch(model, size)
-            if batch.ndim == 2:
+            if batch[0].ndim == 2:
                 rec.update(img_size=None, seq_len=_SEQ_LEN)
-            out = jax.eval_shape(lambda s, x: nnx.merge(graphdef, s)(x), state, batch)
+            out = jax.eval_shape(lambda s, *x: nnx.merge(graphdef, s)(*x), state, *batch)
             rec['out_shape'] = tuple(out.shape)
             rec['ok'] = tuple(out.shape) == owed
             if not rec['ok']:

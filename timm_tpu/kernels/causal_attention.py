@@ -1,9 +1,10 @@
 """Causal flash attention on TPU for long token sequences: softmax(q k^T *
 scale + mask) v without the (heads, S, S) scores, forward and backward. The
 mask is causal, or causal AND within a window of the last `window` positions
-(key j is seen by query i when j <= i and i - j < window); the query heads may
-be a multiple of the key/value heads (grouped-query attention: query head g
-reads key/value head g // group).
+(key j is seen by query i when j <= i and i - j < window), or the
+block-diffusion mask over a noised copy beside the clean sequence
+(`block_diffusion_seen`); the query heads may be a multiple of the key/value
+heads (grouped-query attention: query head g reads key/value head g // group).
 
 The Pallas kernel is JAX's own splash attention
 (`jax.experimental.pallas.ops.tpu.splash_attention`: blocked online softmax in
@@ -35,61 +36,126 @@ BLOCK_COMPUTE = 512    # key/value columns a kernel step multiplies at once
 RESIDUALS = 'mla_core_out'   # checkpoint_name of the kernel's output and log-sum-exp, for a remat policy
 
 
-def causal_flash_supported(q, k, v, window=None) -> bool:
+def block_diffusion_seen(q_ids, kv_ids, length: int, block: int):
+    """The block-diffusion mask (BD3-LM arXiv:2503.09573) over 2 x `length` rows, `length` noised ones and then
+    the `length` clean ones; row r carries position r mod length, in block (r mod length) // block. A noised
+    query sees the noised keys of its own block and the clean keys of EARLIER blocks; a clean query sees the
+    clean keys of its own and earlier blocks and nothing noised. Written with operators alone: splash
+    attention calls it on NumPy index grids when it builds its block map and on the kernel's own index tiles."""
+    q_clean, k_clean = q_ids >= length, kv_ids >= length
+    bq, bk = (q_ids - q_clean * length) // block, (kv_ids - k_clean * length) // block
+    return (k_clean & ((bk < bq) | (q_clean & (bk == bq)))) | (~q_clean & ~k_clean & (bk == bq))
+
+
+def causal_flash_supported(q, k, v, window=None, block_diffusion=None) -> bool:
     """Shapes the kernel takes: q (B, H, S, D), k and v (B, H_kv, S, D) with one S and one D, H a multiple of
-    H_kv, D a multiple of the 128 lanes, S a multiple of its block; a window of at least one position."""
+    H_kv, D a multiple of the 128 lanes, S a multiple of its block; a window of at least one position. With
+    `block_diffusion` (the block length) k and v hold 2 L rows, L noised and L clean, and q all of them or the
+    L noised ones alone (a last layer's); L is then what the block has to divide."""
     if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape or (window is not None and window < 1):
         return False
     (B, H, S, D), H_kv = q.shape, k.shape[1]
-    if k.shape != (B, H_kv, S, D) or H % H_kv:
+    if block_diffusion is not None:
+        L = k.shape[2] // 2
+        if window is not None or block_diffusion < 1 or k.shape[2] != 2 * L or L % block_diffusion or S not in (L, 2 * L):
+            return False
+        S = L
+    elif k.shape[2] != S:
+        return False
+    if k.shape != (B, H_kv, k.shape[2], D) or H % H_kv:
         return False
     return D % 128 == 0 and S >= 256 and S % min(BLOCK, S) == 0 and min(BLOCK, S) % 128 == 0
 
 
-def _kernel(heads: int, seq: int, interpret: bool, window=None, grouped: bool = False):
+def _block_diffusion_seen_coded(code, kv_ids, length: int, block: int):
+    """`block_diffusion_seen` for query rows that come as a code instead of an index: a noised row's code is the
+    first position t of its block, a clean row's -(t + block). Then the row sees the noised keys [t, t + block)
+    (none for a clean row: code + block <= 0) and the clean keys [L, L + |code|). Two range tests in signed
+    compares: the kernel evaluates this on every index tile it visits, where the integer divisions of the
+    plain form cost more than the tile's products (v5e, one layer forward 18.9 ms with the divisions, 11.8 ms
+    with these compares: PERF.md section 6, PR 37), and an unsigned compare, one test a range, does not wrap in
+    the compiled kernel as it does in NumPy and under the interpreter (the chip's `correct` found that)."""
+    return ((kv_ids >= code) & (kv_ids < code + block)) | ((kv_ids >= length) & (kv_ids < length + abs(code)))
+
+
+@functools.lru_cache(maxsize=None)
+def _block_diffusion_mask(rows: int, length: int, block: int):
+    """`block_diffusion_seen` as splash attention's computable mask: `rows` queries (2 x length, or the
+    `length` noised ones alone) on 2 x length keys. The kernel hands the mask function a query row's entry of
+    `q_sequence`, which here holds the row's code (`_block_diffusion_seen_coded`), not its index."""
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
+
+    class BlockDiffusionMask(sm._ComputableMask):
+        def __init__(self):
+            super().__init__(shape=(rows, 2 * length),
+                             mask_function=lambda code, kv_ids: _block_diffusion_seen_coded(code, kv_ids, length, block))
+            row = np.arange(rows)
+            start = row % length // block * block
+            self.q_sequence = np.where(row < length, start, -(start + block)).astype(np.int32)
+
+        def __eq__(self, other):
+            return type(other) is type(self)        # one class a (rows, length, block): the cache above
+
+        def __hash__(self):
+            return hash((type(self), self.shape, block))
+
+    return BlockDiffusionMask()
+
+
+def _kernel(heads: int, seq: int, interpret: bool, window=None, grouped: bool = False, block_diffusion=None, rows=None):
     """The splash kernel over `heads` query heads: one key/value head a query head, or with `grouped` one
-    key/value head for all of them (the multi-query form)."""
+    key/value head for all of them (the multi-query form). With `block_diffusion` `seq` is L, the keys are
+    2 L and the queries `rows`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
     block, compute = min(BLOCK, seq), min(BLOCK_COMPUTE, seq)
     sizes = sk.BlockSizes(block_q=block, block_kv=block, block_kv_compute=compute,
                           block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=compute,
                           block_q_dq=block, block_kv_dq=block)
-    one = sm.CausalMask((seq, seq)) if window is None or window >= seq else sm.LocalMask((seq, seq), (window - 1, 0), 0)
+    if block_diffusion is not None:
+        one = _block_diffusion_mask(rows, seq, block_diffusion)
+    else:
+        one = sm.CausalMask((seq, seq)) if window is None or window >= seq else sm.LocalMask((seq, seq), (window - 1, 0), 0)
     make = sk.make_splash_mqa if grouped else sk.make_splash_mha
     return make(sm.MultiHeadMask([one] * heads), block_sizes=sizes, head_shards=1, q_seq_shards=1,
                 residual_checkpoint_name=RESIDUALS, interpret=interpret)
 
 
-def causal_flash_attention(q, k, v, scale: float, window=None, with_tiles: bool = False):
+def causal_flash_attention(q, k, v, scale: float, window=None, with_tiles: bool = False, block_diffusion=None):
     """q (B, H, S, D), k, v (B, H_kv, S, D) -> (B, H, S, D), causal over S and, with `window`, within the last
-    `window` positions; softmax in float32 inside the kernel. `with_tiles` also returns how many (query block,
-    key block) tiles of one sequence hold an unmasked pair, read from the kernel's own forward block map:
-    the tiles it multiplies, the rest it skips."""
-    if not causal_flash_supported(q, k, v, window):
-        raise ValueError(f'causal_flash_attention does not take q {q.shape} k {k.shape} v {v.shape} window {window}')
-    (B, H, S, D), H_kv = q.shape, k.shape[1]
+    `window` positions; or, with `block_diffusion` (the block length), q over 2 L rows or the L noised ones on
+    k, v (B, H_kv, 2 L, D) under `block_diffusion_seen`. Softmax in float32 inside the kernel. `with_tiles`
+    also returns how many (query block, key block) tiles of one sequence hold an unmasked pair, read from the
+    kernel's own forward block map: the tiles it multiplies, the rest it skips."""
+    if not causal_flash_supported(q, k, v, window, block_diffusion):
+        raise ValueError(f'causal_flash_attention does not take q {q.shape} k {k.shape} v {v.shape} window {window} '
+                         f'block_diffusion {block_diffusion}')
+    (B, H, S, D), (H_kv, S_kv) = q.shape, k.shape[1:3]
     interpret = jax.default_backend() != 'tpu'                                  # CPU tests run it interpreted
     q = q * jnp.asarray(scale, q.dtype)
+    mask = dict(window=window) if block_diffusion is None else dict(block_diffusion=block_diffusion, rows=S)
+    length = S if block_diffusion is None else S_kv // 2
     if H == H_kv:
-        kernel = _kernel(H, S, interpret, window)
+        kernel = _kernel(H, length, interpret, **mask)
         out = jax.vmap(kernel)(q, k, v)
     else:
         # one multi-query call a key/value head: K and V stay at H_kv heads in memory
-        kernel = _kernel(H // H_kv, S, interpret, window, grouped=True)
-        out = jax.vmap(kernel)(q.reshape(B * H_kv, H // H_kv, S, D), k.reshape(B * H_kv, S, D),
-                               v.reshape(B * H_kv, S, D)).reshape(B, H, S, D)
-    return (out, _tiles(S, window)) if with_tiles else out
+        kernel = _kernel(H // H_kv, length, interpret, grouped=True, **mask)
+        out = jax.vmap(kernel)(q.reshape(B * H_kv, H // H_kv, S, D), k.reshape(B * H_kv, S_kv, D),
+                               v.reshape(B * H_kv, S_kv, D)).reshape(B, H, S, D)
+    return (out, _tiles(length, **mask)) if with_tiles else out
 
 
 @functools.lru_cache(maxsize=None)
-def _tiles(seq: int, window) -> int:
+def _tiles(seq: int, window=None, block_diffusion=None, rows=None) -> int:
     """(query block, key block) tiles with an unmasked pair in the forward block map of the kernel `_kernel`
-    builds for this length and window (one head's: the heads' masks are alike). Built eagerly: inside a
+    builds for this length and mask (one head's: the heads' masks are alike). Built eagerly: inside a
     trace the kernel's own copy of the map is a traced constant."""
     import numpy as np
     with jax.ensure_compile_time_eval():
-        block_map = np.asarray(_kernel(1, seq, True, window).fwd_mask_info.block_mask)   # (1, query blocks, key blocks visited)
+        kernel = _kernel(1, seq, True, window, block_diffusion=block_diffusion, rows=rows)
+        block_map = np.asarray(kernel.fwd_mask_info.block_mask)     # (1, query blocks, key blocks visited)
     return int((block_map[0] != 0).sum())
 
 
@@ -97,16 +163,18 @@ def _tiles(seq: int, window) -> int:
 # registry entry
 
 
-def _registry_reference(q, k, v, window=None):
-    from ..layers.grouped_attention import grouped_causal_attention
+def _registry_reference(q, k, v, window=None, block_diffusion=None):
+    from ..layers.grouped_attention import grouped_block_diffusion_attention, grouped_causal_attention
     from ..layers.latent_attention import causal_attention
+    if block_diffusion is not None:
+        return grouped_block_diffusion_attention(q, k, v, q.shape[-1] ** -0.5, block_diffusion)
     if window is None and q.shape == k.shape:
         return causal_attention(q, k, v, q.shape[-1] ** -0.5)
     return grouped_causal_attention(q, k, v, q.shape[-1] ** -0.5, window)
 
 
-def _registry_kernel(q, k, v, window=None):
-    return causal_flash_attention(q, k, v, q.shape[-1] ** -0.5, window)
+def _registry_kernel(q, k, v, window=None, block_diffusion=None):
+    return causal_flash_attention(q, k, v, q.shape[-1] ** -0.5, window, block_diffusion=block_diffusion)
 
 
 def _registry_inputs(seed: int = 0, batch: int = 1, heads: int = 2, seq: int = 256, head_dim: int = 128,
@@ -154,6 +222,16 @@ def _register():
                 desc='SmallThinker-21BA3B window layer: the same heads, keys within 4096 positions; v5e, one layer: '
                      'forward 8.0 ms against 46.8 for the XLA path that slices keys to the window, forward and backward '
                      '31.1 against 123.2 (PR 31)',
+            ),
+            KernelCase(
+                name='gqa_block_diffusion4_s16384_d128',
+                dry=dict(batch=1, heads=4, kv_heads=2, seq=512, head_dim=128),
+                live=dict(batch=1, heads=32, kv_heads=4, seq=16384, head_dim=128, dtype='bfloat16'),
+                statics=dict(block_diffusion=4),
+                desc='SDAR-30B-A3B block-diffusion layer: 32 query heads on 4 key/value heads over 8192 noised rows '
+                     'beside 8192 clean ones, blocks of 4, 80 of 256 tiles visited; v5e, one layer: forward 11.8 ms '
+                     'against the XLA query-block path\'s 61.1, forward and backward 47.9 against 219.7; a last layer\'s '
+                     'noised queries alone (44 tiles) 6.6 / 26.5 against 34.1 / 120.0 (PR 37)',
             ),
         ),
         backends=('tpu',),

@@ -57,5 +57,5 @@ from .squeeze_excite import EffectiveSEModule, SEModule, SqueezeExcite
 from .weight_init import lecun_normal_, ones_, trunc_normal_, trunc_normal_tf_, variance_scaling_, zeros_
 from .hybrid_embed import HybridEmbed
 from .latent_attention import LatentAttention, causal_attention
-from .grouped_attention import GroupedQueryAttention, grouped_causal_attention
+from .grouped_attention import GroupedQueryAttention, grouped_block_diffusion_attention, grouped_causal_attention
 from .moe import SparseMoe

@@ -2,11 +2,21 @@
 and an optional rotary turn (SmallThinker arXiv:2507.20984: three layers of four
 turn their queries and keys and see the last `window` positions, the fourth
 turns nothing and sees everything before it, so it carries no position signal
-of its own).
+of its own), or under the block-diffusion mask over a noised copy beside the
+clean sequence, with an RMSNorm on every head's query and key before the turn
+(SDAR, whose trunk is Qwen3-MoE's).
 
 `num_heads` query heads read `num_kv_heads` key/value heads, query head g the
 key/value head g // (num_heads / num_kv_heads); every linear map is bias-free.
-Key j is seen by query i when j <= i and, with a window, i - j < window.
+Key j is seen by query i when j <= i and, with a window, i - j < window. With
+`block_diffusion` (a block length K) the input holds 2 L rows, L noised and
+then L clean ones, row r at position r mod L (the rotary table is read there),
+and the mask is `kernels.causal_attention.block_diffusion_seen`: a noised
+query sees the noised keys of its own block of K and the clean keys of earlier
+blocks, a clean query the clean keys of its own and earlier blocks. A layer
+whose output is read at its noised rows only (a model's last) is called with
+`queries=L`: queries, core and output projection run on those rows, and of the
+clean rows only K and V are made.
 Nothing is cached here: a decode cache that keeps a ring of `window` positions
 for the window layers beside a full one belongs to `serve/` (ROADMAP "Reach").
 
@@ -17,9 +27,13 @@ elsewhere (the CPU tests' toy sizes): XLA alone, queries in blocks of
 `block_q`, each rematerialised in the backward pass, the block at `i0` against
 the keys `[max(0, i0 - window + 1), i0 + block_q)` by a static slice, so what
 the mask excludes whole is never multiplied, and K and V are never repeated to
-the query heads (the group is an axis of the einsum). Both paths also say how
-many (query block, key block) tiles of a sequence they multiply, the step's
-`attn.full_blocks` / `attn.window_blocks` counters.
+the query heads (the group is an axis of the einsum). Under the block-diffusion
+mask the XLA path is `grouped_block_diffusion_attention`: a block of noised
+queries against its own blocks' noised keys and the clean keys before them
+under ONE softmax, a block of clean queries against the clean keys up to its
+last block. Both paths also say how many (query block, key block) tiles of a
+sequence they multiply, the step's `attn.full_blocks` / `attn.window_blocks` /
+`attn.bd_blocks` counters.
 """
 from __future__ import annotations
 
@@ -34,9 +48,10 @@ from jax.ad_checkpoint import checkpoint_name
 from ..utils import tracing
 from .attention import apply_rot_embed_cat
 from .latent_attention import CORE_OUT, SLOW_FROM, _warn_xla_core
+from .norm import RmsNorm
 from .weight_init import trunc_normal_
 
-__all__ = ['GroupedQueryAttention', 'grouped_causal_attention']
+__all__ = ['GroupedQueryAttention', 'grouped_causal_attention', 'grouped_block_diffusion_attention']
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6))
@@ -74,10 +89,57 @@ def grouped_causal_attention(q, k, v, scale: float, window: Optional[int] = None
     return (out, tiles) if with_tiles else out
 
 
+@functools.partial(jax.checkpoint, static_argnums=(3, 4, 5, 6, 7))
+def _block_diffusion_query_block(q, k, v, scale: float, start: int, spans: tuple, length: int, block: int):
+    """Query rows [start, start + bq) of every group, q (B, H_kv, G, bq, D), against the key rows of `spans`
+    ((first, last) ranges of the 2 x length rows, concatenated in k and v) under one softmax."""
+    from ..kernels.causal_attention import block_diffusion_seen
+    s = jnp.einsum('bhgqd,bhkd->bhgqk', q, k, preferred_element_type=jnp.float32) * scale
+    s = jax.lax.optimization_barrier(s)
+    qi = (start + jnp.arange(q.shape[3]))[:, None]
+    kj = jnp.concatenate([jnp.arange(a, b) for a, b in spans])[None, :]
+    p = jax.nn.softmax(jnp.where(block_diffusion_seen(qi, kj, length, block), s, -jnp.inf), axis=-1)
+    return jnp.einsum('bhgqk,bhkd->bhgqd', p.astype(v.dtype), v)
+
+
+def grouped_block_diffusion_attention(q, k, v, scale: float, block_diffusion: int, block_q: int = 1024, with_tiles: bool = False):
+    """softmax(q k^T * scale + mask) v under the block-diffusion mask of blocks of `block_diffusion`: k, v (B, H_kv, 2 L,
+    D) hold L noised rows and then L clean ones, q (B, H, R, D) their queries, all 2 L or the L noised ones.
+    Queries go in blocks of `block_q` rows, each rematerialised in the backward pass, a noised block at
+    positions [i0, i1) against the noised keys of the blocks it touches and the clean keys of the blocks
+    before its last, a clean block against the clean keys up to the end of its last block: the key tiles the
+    mask leaves empty are never multiplied. `with_tiles` also returns the (query block, key block) tiles of
+    one sequence those slices span, in blocks of `block_q` both ways."""
+    (B, H, R, D), (H_kv, rows), block = q.shape, k.shape[1:3], block_diffusion
+    L = rows // 2
+    if H % H_kv or rows != 2 * L or R not in (L, 2 * L) or L % block:
+        raise ValueError(f'q {q.shape} on k {k.shape} is no noised copy beside a clean sequence in blocks of {block}')
+    block_q = min(block_q, L)
+    if L % block_q:
+        raise ValueError(f'sequence length {L} is not a multiple of the query block {block_q}')
+    q = q.reshape(B, H_kv, H // H_kv, R, D)
+    out, tiles = [], 0
+    for start in range(0, R, block_q):
+        i0 = start % L
+        last = (i0 + block_q - 1) // block                     # the last block this query block touches
+        if start < L:
+            spans = ((i0 // block * block, min(L, (last + 1) * block)), (L, L + last * block))
+        else:
+            spans = ((L, L + min(L, (last + 1) * block)),)
+        spans = tuple((a, b) for a, b in spans if b > a)
+        ks, vs = (t[:, :, spans[0][0]:spans[0][1]] if len(spans) == 1 else
+                  jnp.concatenate([t[:, :, a:b] for a, b in spans], axis=2) for t in (k, v))
+        out.append(_block_diffusion_query_block(q[:, :, :, start:start + block_q], ks, vs, float(scale), start, spans, L, block))
+        tiles += sum(-(-b // block_q) - a // block_q for a, b in spans)
+    out = (out[0] if len(out) == 1 else jnp.concatenate(out, axis=3)).reshape(B, H, R, D)
+    return (out, tiles) if with_tiles else out
+
+
 class GroupedQueryAttention(nnx.Module):
     """x (B, S, dim) -> (y (B, S, dim), tiles): `rope` is the (S, 2 * head_dim) table of
     `build_rotary_pos_embed_1d`, read only where the layer was built with `rotary`; `tiles` is a Python int,
-    the (query block, key block) tiles the core multiplies for one sequence."""
+    the (query block, key block) tiles the core multiplies for one sequence. With `block_diffusion` S is 2 L,
+    the table has L rows, and `queries=L` gives y for the L noised rows alone."""
 
     def __init__(
             self,
@@ -88,6 +150,9 @@ class GroupedQueryAttention(nnx.Module):
             window: Optional[int] = None,
             rotary: bool = True,
             block_q: int = 1024,
+            qk_norm: bool = False,
+            block_diffusion: Optional[int] = None,
+            eps: float = 1e-6,
             *,
             dtype=None,
             param_dtype=jnp.float32,
@@ -96,7 +161,9 @@ class GroupedQueryAttention(nnx.Module):
         if num_heads % num_kv_heads:
             raise ValueError(f'{num_heads} query heads are not a multiple of {num_kv_heads} key/value heads')
         self.num_heads, self.num_kv_heads, self.head_dim = num_heads, num_kv_heads, head_dim
-        self.window, self.rotary, self.block_q = window, rotary, block_q
+        if window is not None and block_diffusion is not None:
+            raise ValueError('a window under the block-diffusion mask is not a mask this layer knows')
+        self.window, self.rotary, self.block_q, self.block_diffusion = window, rotary, block_q, block_diffusion
         self.scale = head_dim ** -0.5
         linear = functools.partial(nnx.Linear, use_bias=False, dtype=dtype, param_dtype=param_dtype,
                          kernel_init=trunc_normal_(std=0.02), rngs=rngs)
@@ -105,33 +172,46 @@ class GroupedQueryAttention(nnx.Module):
         self.k_proj = linear(dim, num_kv_heads * head_dim)
         self.v_proj = linear(dim, num_kv_heads * head_dim)
         self.proj = linear(num_heads * head_dim, dim)
+        # Qwen3's q_norm / k_norm: one learned scale of `head_dim`, every head alike, statistics in float32
+        norm = functools.partial(RmsNorm, head_dim, eps=eps, dtype=dtype, param_dtype=param_dtype, rngs=rngs)
+        self.q_norm, self.k_norm = (norm(), norm()) if qk_norm else (None, None)
 
-    def qkv(self, x, rope=None):
-        """-> q (B, H, S, D), k and v (B, H_kv, S, D), q and k turned where the layer turns."""
+    def qkv(self, x, rope=None, queries: Optional[int] = None):
+        """-> q (B, H, S, D), k and v (B, H_kv, S, D), q and k normalised where the layer has the norms and turned
+        where it turns; with `queries`, q of the first `queries` rows alone."""
         B, S, _ = x.shape
-        heads = lambda t, n: t.reshape(B, S, n, self.head_dim).transpose(0, 2, 1, 3)  # noqa: E731
-        q, k = heads(self.q_proj(x), self.num_heads), heads(self.k_proj(x), self.num_kv_heads)
-        v = heads(self.v_proj(x), self.num_kv_heads)
+        heads = lambda t, n: t.reshape(B, -1, n, self.head_dim).transpose(0, 2, 1, 3)  # noqa: E731
+        q = heads(self.q_proj(x if queries is None else x[:, :queries]), self.num_heads)
+        k, v = heads(self.k_proj(x), self.num_kv_heads), heads(self.v_proj(x), self.num_kv_heads)
+        if self.q_norm is not None:
+            q, k = self.q_norm(q), self.k_norm(k)
         if self.rotary:
             rope = rope.astype(jnp.float32)
-            q = apply_rot_embed_cat(q.astype(jnp.float32), rope, half=True).astype(q.dtype)
+            if self.block_diffusion is not None:
+                rope = jnp.concatenate([rope, rope])            # row r of the two halves carries position r mod L
+            q = apply_rot_embed_cat(q.astype(jnp.float32), rope if queries is None else rope[:queries], half=True).astype(q.dtype)
             k = apply_rot_embed_cat(k.astype(jnp.float32), rope, half=True).astype(k.dtype)
         return q, k, v
 
-    def __call__(self, x, rope=None):
+    def __call__(self, x, rope=None, queries: Optional[int] = None):
         B, S, _ = x.shape
         with tracing.scope('swa.attn.proj'):
-            q, k, v = self.qkv(x, rope)
-        window = self.window if self.window is not None and self.window < S else None   # a window over all of S masks nothing
-        core = tracing.scope('swa.attn.core_full') if self.window is None else tracing.scope('swa.attn.core_window')
+            q, k, v = self.qkv(x, rope, queries)
+        if self.block_diffusion is not None:
+            mask, core = dict(block_diffusion=self.block_diffusion), tracing.scope('swa.attn.core_bd')
+        else:
+            window = self.window if self.window is not None and self.window < S else None   # a window over all of S masks nothing
+            mask = dict(window=window)
+            core = tracing.scope('swa.attn.core_full') if self.window is None else tracing.scope('swa.attn.core_window')
         with core:
             from ..kernels import causal_flash_attention, causal_flash_supported
-            if causal_flash_supported(q, k, v, window=window):
-                out, tiles = causal_flash_attention(q, k, v, self.scale, window, with_tiles=True)
+            if causal_flash_supported(q, k, v, **mask):
+                out, tiles = causal_flash_attention(q, k, v, self.scale, with_tiles=True, **mask)
             else:
                 if S >= SLOW_FROM and jax.default_backend() == 'tpu':
                     _warn_xla_core(q.shape, v.shape)
-                out, tiles = grouped_causal_attention(q, k, v, self.scale, window, self.block_q, with_tiles=True)
+                xla = grouped_causal_attention if self.block_diffusion is None else grouped_block_diffusion_attention
+                out, tiles = xla(q, k, v, self.scale, block_q=self.block_q, with_tiles=True, **mask)
             out = checkpoint_name(out, CORE_OUT)
         with tracing.scope('swa.attn.proj'):
-            return self.proj(out.transpose(0, 2, 1, 3).reshape(B, S, self.num_heads * self.head_dim)), tiles
+            return self.proj(out.transpose(0, 2, 1, 3).reshape(B, -1, self.num_heads * self.head_dim)), tiles

@@ -65,4 +65,5 @@ from .vovnet import VovNet
 from .pit import PoolingVisionTransformer
 from .inception_v4 import InceptionV4
 from .glm4_moe_lite import Glm4MoeLite
+from .sdar_moe import SdarMoe
 from .smallthinker import SmallThinker

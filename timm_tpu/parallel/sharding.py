@@ -103,7 +103,8 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
       4. MLP fc2                          -> hidden over 'model' (row)
       5. other 2D+ matmul / conv kernels  -> shard largest divisible dim
       6. biases                           -> replicate
-      7. norm scales / LayerScale gammas  -> replicate
+      7. norm scales / LayerScale gammas  -> replicate (a per-head `attn.q_norm` / `attn.k_norm` scale among
+         them: one scale of head_dim, every head alike, so tensor-parallel heads each need all of it)
       8. tokens & position embeddings     -> replicate
       9. stacked expert kernels (E, in, out) -> 'fsdp' on the output dim
      10. a router's kernel                -> replicate (every chip scores alike)

@@ -27,14 +27,17 @@ __all__ = ['CausalLMTask', 'IGNORE']
 IGNORE = -1
 
 
-def _ce_sums(logits, target, topk: bool = False):
+def _ce_sums(logits, target, topk: bool = False, weight=None):
     """Summed cross-entropy over positions whose target is not IGNORE (float32),
-    and with `topk` the top-1 / top-5 hits there."""
+    with `topk` the top-1 / top-5 hits there, and with `weight` (a float a
+    position) the weighted sum beside the plain one."""
     valid = target != IGNORE
     safe = jnp.where(valid, target, 0)
     logits = logits.astype(jnp.float32)
     nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
     out = {'loss_sum': jnp.where(valid, nll, 0.0).sum()}
+    if weight is not None:
+        out['weighted_sum'] = jnp.where(valid, nll * weight, 0.0).sum()
     if topk:
         top = jax.lax.top_k(logits, 5)[1]
         out['top1'] = (valid & (top[..., 0] == safe)).sum()
@@ -50,12 +53,14 @@ class CausalLMTask(TrainingTask):
         self.mtp_loss_weight = mtp_loss_weight
         self.loss_chunk = loss_chunk
 
-    def _head_loss(self, model, h, target, head, topk: bool = False):
-        """Sums of `_ce_sums` over the sequence in chunks; `head(model, h_chunk)` gives a chunk's logits."""
+    def _head_loss(self, model, h, target, head, topk: bool = False, weight=None):
+        """Sums of `_ce_sums` over the sequence in chunks; `head(model, h_chunk)` gives a chunk's logits,
+        `weight` (B, S) a weight a position."""
         S = h.shape[1]
         chunk = min(self.loss_chunk, S)
-        one = nnx.remat(lambda m, hc, tc: _ce_sums(head(m, hc), tc, topk))
-        sums = [one(model, h[:, i:i + chunk], target[:, i:i + chunk]) for i in range(0, S, chunk)]
+        one = nnx.remat(lambda m, hc, tc, *wc: _ce_sums(head(m, hc), tc, topk, *wc))
+        rows = (h, target) if weight is None else (h, target, weight)
+        sums = [one(model, *(t[:, i:i + chunk] for t in rows)) for i in range(0, S, chunk)]
         return jax.tree.map(lambda *xs: sum(xs), *sums)
 
     def loss_forward(self, model: nnx.Module, batch: Dict[str, Any]):

@@ -78,6 +78,7 @@ SPANS = {
     'swa.attn.proj': ('attention', 'swa device scope: grouped-query q/k/v/o products, the norm before them, the rotary turn'),
     'swa.attn.core_full': ('attention', 'swa device scope: the causal core of a full (position-free) layer, forward and backward'),
     'swa.attn.core_window': ('attention', 'swa device scope: the causal core of a window layer, forward and backward'),
+    'swa.attn.core_bd': ('attention', 'device scope: the core under the block-diffusion mask (a noised copy beside the clean sequence), forward and backward'),
     # step counters (`device_counter`): values computed inside the step program, returned in its metrics
     'moe.local_slots': ('experts', 'step counter: (token, expert) slots routed to experts held here, all expert layers'),
     'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),
@@ -86,6 +87,9 @@ SPANS = {
     'lm.tokens': ('step', 'step counter: tokens the step was given'),
     'attn.full_blocks': ('attention', 'step counter: (query block, key block) tiles with an unmasked pair that the full cores multiply in the forward pass, all layers and sequences'),
     'attn.window_blocks': ('attention', 'step counter: the same for the window cores, from the kernel\'s block map or the XLA path\'s slices'),
+    'attn.bd_blocks': ('attention', 'step counter: the same for the cores under the block-diffusion mask'),
+    'lm.noised_masked': ('step', 'step counter: positions of the step\'s noised copies that hold the mask token'),
+    'lm.masked_nll': ('step', 'step counter: the cross-entropy summed over those positions, unweighted (over `lm.noised_masked`: the mean a masked position)'),
 }
 
 

@@ -25,6 +25,12 @@ import yaml
 
 _logger = logging.getLogger('train')
 
+# a model's `task_kind` -> (its task in `timm_tpu.task`, its feed): an image model has no `task_kind`
+TASK_KINDS = {None: ('ClassificationTask', 'images'),
+              'causal_lm': ('CausalLMTask', 'tokens'),
+              'block_diffusion_lm': ('BlockDiffusionLMTask', 'tokens')}
+TOKEN_KINDS = tuple(kind for kind, (_, feed) in TASK_KINDS.items() if feed == 'tokens')
+
 
 def make_parser():
     parser = argparse.ArgumentParser(description='TPU-native training')
@@ -386,7 +392,6 @@ def main(argv=None):
         create_mesh, init_distributed_device, is_primary, set_global_mesh, shard_batch,
     )
     from timm_tpu.scheduler import create_scheduler_v2, scheduler_kwargs
-    from timm_tpu.task import ClassificationTask
     from timm_tpu.utils import (
         AverageMeter, CheckpointSaver, accuracy, get_outdir, random_seed,
         setup_default_logging, tracing, update_summary,
@@ -493,11 +498,15 @@ def main(argv=None):
             model = _build_model()
     if args.num_classes is None:
         args.num_classes = model.num_classes
-    # the model's kind picks the task and the feed: a language model trains on --dataset tokens
-    causal_lm = getattr(model, 'task_kind', None) == 'causal_lm'
-    if causal_lm != (args.dataset == 'tokens'):
-        raise ValueError(f'--dataset tokens and a causal language model go together: '
-                         f'{args.model} is {"one" if causal_lm else "none"}, --dataset is {args.dataset!r}')
+    # the model's kind picks the task and the feed, in one place (`TASK_KINDS`)
+    task_kind = getattr(model, 'task_kind', None)
+    if task_kind not in TASK_KINDS:
+        raise ValueError(f'{args.model} is of kind {task_kind!r}; train.py knows {sorted(map(str, TASK_KINDS))}')
+    kind_task, kind_feed = TASK_KINDS[task_kind]
+    token_model = kind_feed == 'tokens'
+    if token_model != (args.dataset == 'tokens'):
+        raise ValueError(f'--dataset tokens and a language model ({", ".join(TOKEN_KINDS)}) go together: '
+                         f'{args.model} is of kind {task_kind!r}, --dataset is {args.dataset!r}')
     if args.grad_checkpointing:
         model.set_grad_checkpointing(True)
     if args.block_scan:
@@ -571,11 +580,9 @@ def main(argv=None):
             task_cls = NaFlexClassificationTask
             # NaFlex batches are normalized host-side by the loader
             norm_mean = norm_std = None
-        elif causal_lm:
-            from timm_tpu.task import CausalLMTask
-            task_cls = CausalLMTask
         else:
-            task_cls = ClassificationTask
+            from timm_tpu import task as tasks
+            task_cls = getattr(tasks, kind_task)
         if distill is not None:
             task_cls = (LogitDistillationTask if distill['kind'] == 'logit'
                         else FeatureDistillationTask)
@@ -693,7 +700,7 @@ def main(argv=None):
                 mean=data_config['mean'], std=data_config['std'],
                 interpolation=data_config['interpolation'], seed=args.seed)
             mixup_fn = None
-        elif causal_lm:
+        elif token_model:
             from timm_tpu.data import create_dataset
             from timm_tpu.data.loader import ThreadedLoader
             if not args.data_dir:
@@ -1326,7 +1333,7 @@ def validate(task, loader, args, mesh, shard_batch, use_ema=False):
         else:
             input_np, target_np = batch_data
             batch = shard_batch({'input': jnp.asarray(input_np), 'target': jnp.asarray(target_np)}, mesh)
-            if getattr(task.model, 'task_kind', None) == 'causal_lm':
+            if getattr(task.model, 'task_kind', None) in TOKEN_KINDS:
                 # a language-model task scores on the device: sums over the batch's valid positions
                 sums = {k: float(v) for k, v in task.eval_step(batch, use_ema=use_ema).items()}
                 n = max(sums['count'], 1.0)

@@ -411,6 +411,7 @@ def phase_sharded(model: str = MODEL, batch_size: int = BATCH_SIZE, devices=None
             'input': jnp.asarray(rng.rand(batch_size, size, size, 3), jnp.float32),
             'target': jnp.asarray(rng.randint(0, net.num_classes, batch_size))}, mesh)
         metrics = task.train_step(batch, lr=1e-3, step=1)
+        task.drain()  # the one step's non-finite counters: a step reads those of the step before it
         return task, float(metrics['loss']), float(metrics['grad_norm'])
 
     with collect_cache_events() as events:

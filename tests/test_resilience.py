@@ -154,6 +154,149 @@ def test_nonfinite_tolerance_aborts(mesh8, tiny_task):
     tiny_task.reset_nonfinite()
 
 
+# -- the loop around the lagged read (train.main in-process) ------------------
+
+@pytest.fixture
+def loop_journal(monkeypatch):
+    """What `train.main` did, in order: ('step', n) for a `train_step(step=n)`,
+    ('drain',) for a `drain()` that had a step to read, ('tripped', step of the
+    counters) where either raised, ('recovery', update) and ('checkpoint', epoch)
+    where the saver was asked to write."""
+    from timm_tpu.task import ClassificationTask
+    from timm_tpu.utils.checkpoint_saver import CheckpointSaver
+    journal = []
+
+    def watched(inner, entry):
+        def call(self, *args, **kwargs):
+            what = entry(self, *args, **kwargs)
+            if what:
+                journal.append(what)
+            try:
+                return inner(self, *args, **kwargs)
+            except NonFiniteError as e:
+                journal.append(('tripped', e.step, e.consecutive))
+                raise
+        return call
+
+    monkeypatch.setattr(ClassificationTask, 'train_step', watched(
+        ClassificationTask.train_step, lambda self, batch, lr, step=0: ('step', step)))
+    monkeypatch.setattr(ClassificationTask, 'drain', watched(
+        ClassificationTask.drain, lambda self: ('drain',) if self._unread is not None else None))
+    monkeypatch.setattr(CheckpointSaver, 'save_recovery', watched(
+        CheckpointSaver.save_recovery, lambda self, epoch, batch_idx=0, **kw: ('recovery', batch_idx)))
+    monkeypatch.setattr(CheckpointSaver, 'save_checkpoint', watched(
+        CheckpointSaver.save_checkpoint, lambda self, epoch, metric=None: ('checkpoint', epoch)))
+    yield journal
+    set_fault_injector('')
+
+
+def _main_argv(out_dir, *extra):
+    return ['--synthetic-data', '--model', 'test_vit', '--img-size', '32', '-b', '8', '--synthetic-len', '64',
+            '--opt', 'sgd', '--lr', '0.05', '--sched', 'cosine', '--warmup-epochs', '0', '--workers', '1',
+            '--log-interval', '50', '--output', str(out_dir), '--experiment', 'lag', '--nonfinite-tolerance', '3', *extra]
+
+
+@pytest.mark.parametrize('recovery_interval', [0, 1], ids=['in_the_next_call', 'in_the_drain_before_a_recovery_save'])
+def test_the_loop_aborts_one_enqueued_step_after_the_tripping_update_and_saves_nothing_between(
+        tmp_path, loop_journal, recovery_interval):
+    """Updates 2, 3, 4 are NaN at tolerance 3. The host reads one step behind:
+    the abort is for update 4 with 3 consecutive, out of the call that has
+    enqueued update 5 or, where a recovery file is due after every update, out of
+    the drain before update 4's. Nothing is written after update 4 ran."""
+    import train
+    with pytest.raises(SystemExit) as ei:
+        train.main(_main_argv(tmp_path, '--epochs', '1', '--fault-inject', 'nan_grads@2:3',
+                              '--recovery-interval', str(recovery_interval)))
+    assert ei.value.code == 3
+    steps = [e[1] for e in loop_journal if e[0] == 'step']
+    assert loop_journal[-1] == ('tripped', 4, 3)
+    if recovery_interval:
+        assert steps == [0, 1, 2, 3, 4] and loop_journal[-3:-1] == [('step', 4), ('drain',)]
+        assert [e[1] for e in loop_journal if e[0] == 'recovery'] == [0, 1, 2, 3]
+    else:
+        assert steps == [0, 1, 2, 3, 4, 5] and loop_journal[-2] == ('step', 5)
+    after = loop_journal[loop_journal.index(('step', 4)):]
+    assert not [e for e in after if e[0] in ('recovery', 'checkpoint')]
+    names = os.listdir(tmp_path / 'lag')
+    assert 'recovery-0-4.npz' not in names and not [n for n in names if n.startswith(('checkpoint-', 'last'))]
+    for name in names:
+        if name.endswith('.npz'):
+            assert verify_checkpoint(str(tmp_path / 'lag' / name))[0], name
+
+
+@pytest.mark.parametrize('recovery_interval', [0, 1], ids=['in_the_next_call', 'in_the_drain_before_a_recovery_save'])
+def test_the_loop_rolls_back_one_enqueued_step_after_the_tripping_update_and_goes_on(
+        tmp_path, loop_journal, caplog, recovery_interval):
+    """`--nonfinite-rollback`, two epochs of 8 updates, updates 10, 11, 12 NaN:
+    the rollback is for update 12, out of the call that enqueued update 13 (whose
+    batch is dropped with it: the next call is update 13 again) or out of the
+    drain before update 12's recovery file; it loads the newest file written
+    BEFORE the tripping step, and the run ends as a sound one does."""
+    import logging
+
+    import train
+    with caplog.at_level(logging.WARNING):
+        train.main(_main_argv(tmp_path, '--epochs', '2', '--fault-inject', 'nan_grads@10:3', '--nonfinite-rollback',
+                              '--recovery-interval', str(recovery_interval)))
+    rolled = [r.getMessage() for r in caplog.records if 'rolled back to' in r.getMessage()]
+    assert len(rolled) == 1 and 'at update 12:' in rolled[0]
+    # update 11's recovery file, or one of epoch 0's three names for the same state
+    assert any(n in rolled[0] for n in (('recovery-1-3.npz',) if recovery_interval
+                                        else ('checkpoint-0.npz', 'model_best.npz', 'last.npz'))), rolled
+    at = loop_journal.index(('tripped', 12, 3))
+    steps = [e[1] for e in loop_journal if e[0] == 'step']
+    if recovery_interval:
+        assert loop_journal[at - 2:at] == [('step', 12), ('drain',)] and loop_journal[at + 1] == ('recovery', 4)
+        assert steps == list(range(16))
+    else:
+        assert loop_journal[at - 1] == ('step', 13) and loop_journal[at + 1] == ('step', 13)
+        assert steps == list(range(14)) + [13, 14]                       # one batch went with the enqueued step
+    between = loop_journal[loop_journal.index(('step', 12)):at]
+    assert not [e for e in between if e[0] in ('recovery', 'checkpoint')]
+    assert loop_journal[-1] == ('checkpoint', 1) and ('drain',) in loop_journal[at:]   # the epoch's last step is read before it
+    assert 'checkpoint-1.npz' in os.listdir(tmp_path / 'lag')
+
+
+def test_a_preemption_reads_the_newest_step_before_its_recovery_file_and_nothing_after(tmp_path, loop_journal):
+    """SIGTERM after update 3: the drain comes before the recovery save, the
+    epoch then ends by `TrainingPreempted` and no further read is made."""
+    import train
+    with pytest.raises(SystemExit) as ei:
+        train.main(_main_argv(tmp_path, '--epochs', '1', '--fault-inject', 'sigterm@3'))
+    assert ei.value.code == 0
+    assert loop_journal[-3:] == [('step', 3), ('drain',), ('recovery', 3)]
+    assert 'recovery-0-3.npz' in os.listdir(tmp_path / 'lag')
+
+
+def test_the_sentinel_reads_every_step_it_is_handed_and_has_no_stride(monkeypatch):
+    """`observe` takes the state array or the pair of counters out of a step's
+    metrics, reads both every time (`check_every` and its environment variable
+    are gone: a read of a finished step is no sync to avoid), names the step it is
+    GIVEN, and `reset()` starts both counts again."""
+    import inspect
+
+    import jax.numpy as jnp
+
+    from timm_tpu.resilience import NonFiniteSentinel
+    from timm_tpu.utils import tracing
+    monkeypatch.setenv('TIMM_TPU_NONFINITE_CHECK_EVERY', '4')
+    assert list(inspect.signature(NonFiniteSentinel).parameters) == ['tolerance']
+    sentinel = NonFiniteSentinel(2)
+    polls = tracing.snapshot()['counters'].get('task.sentinel_polls', 0)
+    assert sentinel.observe(jnp.asarray([0, 0], jnp.int32), step=5) is False
+    assert sentinel.observe((jnp.asarray(1, jnp.int32), jnp.asarray(1, jnp.int32)), step=6) is True
+    with pytest.raises(NonFiniteError) as ei:
+        sentinel.observe([jnp.asarray(2, jnp.int32), jnp.asarray(2, jnp.int32)], step=7)
+    assert (ei.value.consecutive, ei.value.total, ei.value.step) == (2, 2, 7) and 'at update 7' in str(ei.value)
+    assert tracing.snapshot()['counters']['task.sentinel_polls'] - polls == 3
+    sentinel.reset()
+    assert (sentinel.consecutive, sentinel.total) == (0, 0)
+    assert sentinel.observe(jnp.asarray([1, 1], jnp.int32), step=8) is True       # fresh device counters warn again
+    for path in ('train.py', 'README.md', 'timm_tpu/resilience/sentinel.py', 'timm_tpu/resilience/__init__.py',
+                 'timm_tpu/task/task.py'):
+        assert 'CHECK_EVERY' not in open(os.path.join(REPO_ROOT, path)).read(), path
+
+
 # -- retry / skip policy -----------------------------------------------------
 
 def test_retry_io_backoff_then_success():

@@ -1,6 +1,10 @@
 """The host's work around a step: the sentinel's counters are outputs of the
-step program (no eager slice after the call), a bad step commits nothing, and
-`train.main` freezes the collector's generations for the run and thaws them."""
+step program (no eager slice after the call), the host reads them one step
+behind the dispatch (call N reads step N-1's, `drain()` the last), a bad step
+commits nothing, and `train.main` freezes the collector's generations for the
+run and thaws them."""
+import logging
+
 import jax
 import numpy as np
 import pytest
@@ -9,16 +13,19 @@ from flax import nnx
 pytestmark = pytest.mark.resilience
 
 
-@pytest.fixture(scope='module')
-def task(mesh8):
+def _task(mesh, opt='adamw', **kwargs):
     import timm_tpu
     from timm_tpu.loss import LabelSmoothingCrossEntropy
     from timm_tpu.optim import create_optimizer_v2
     from timm_tpu.task import ClassificationTask
     model = timm_tpu.create_model('test_vit', num_classes=10, img_size=32)
-    task = ClassificationTask(
-        model, optimizer=create_optimizer_v2(model, opt='adamw', lr=1e-3), mesh=mesh8,
-        train_loss_fn=LabelSmoothingCrossEntropy(0.1), nonfinite_tolerance=3)
+    return ClassificationTask(model, optimizer=create_optimizer_v2(model, opt=opt, lr=1e-3), mesh=mesh,
+                              train_loss_fn=LabelSmoothingCrossEntropy(0.1), **kwargs)
+
+
+@pytest.fixture(scope='module')
+def task(mesh8):
+    task = _task(mesh8, nonfinite_tolerance=3)
     task.setup_ema(decay=0.9)
     return task
 
@@ -33,8 +40,10 @@ def _batch(mesh, nan=False, seed=0):
 
 def test_the_counters_in_metrics_are_outputs_of_the_program(mesh8, task, monkeypatch):
     """No eager op between the jitted call and the return: what `metrics` holds
-    ARE the arrays the program returned, all of them and no other, and the
-    sentinel is handed the program's own state array."""
+    ARE the arrays the program returned, all of them and no other; the state the
+    task keeps is the program's own array, and what the sentinel is handed are the
+    PREVIOUS call's two counters out of its metrics (the state array itself is
+    donated to the next call): the first call polls nothing."""
     task.train_step(_batch(mesh8), lr=1e-3, step=0)      # builds and compiles the step
     task.reset_nonfinite()
     real, outs, polled = task._train_step, [], []
@@ -52,8 +61,108 @@ def test_the_counters_in_metrics_are_outputs_of_the_program(mesh8, task, monkeyp
     assert set(metrics) == set(returned) == {'loss', 'grad_norm', 'nonfinite', 'nonfinite_count', 'nonfinite_total'}
     assert all(metrics[k] is returned[k] and isinstance(metrics[k], jax.Array) for k in metrics)
     assert int(metrics['nonfinite_count']) == 1 and int(metrics['nonfinite_total']) == 1 and bool(metrics['nonfinite'])
-    assert task._sentinel_state is out[4] and polled == [(out[4], 1)]
+    assert task._sentinel_state is out[4] and polled == []
+    later = task.train_step(_batch(mesh8), lr=1e-3, step=2)
+    assert task._sentinel_state is outs[1][0][4]
+    (state, step), = polled
+    assert step == 1 and len(state) == 2
+    assert state[0] is metrics['nonfinite_count'] and state[1] is metrics['nonfinite_total']
+    assert state[0] is not later['nonfinite_count'] and int(later['nonfinite_count']) == 0
     task.reset_nonfinite()
+
+
+def _counters():
+    from timm_tpu.utils import tracing
+    c = tracing.snapshot()['counters']
+    return c.get('task.sentinel_polls', 0), c.get('task.polls_host_ahead', 0)
+
+
+def test_call_n_observes_step_n_minus_1_and_drain_the_last_once(mesh8, task, monkeypatch):
+    task.reset_nonfinite()
+    polled, observe = [], task.sentinel.observe
+    monkeypatch.setattr(task.sentinel, 'observe', lambda state, step=0: (
+        polled.append((step, [int(x) for x in state])), observe(state, step=step))[1])
+    polls, ahead = _counters()
+    for step, nan in enumerate([False, True, False, True]):
+        task.train_step(_batch(mesh8, nan=nan), lr=1e-3, step=10 + step)
+        assert [s for s, _ in polled] == list(range(10, 10 + step))        # every step before this one, in order, once
+    assert polled == [(10, [0, 0]), (11, [1, 1]), (12, [0, 1])]
+    assert task.sentinel.consecutive == 0 and task.sentinel.total == 1     # as of step 12: step 13 is still unread
+    task.drain()
+    assert polled[3:] == [(13, [1, 2])] and (task.sentinel.consecutive, task.sentinel.total) == (1, 2)
+    task.drain()                                                           # nothing is read twice
+    assert len(polled) == 4
+    polls_now, ahead_now = _counters()
+    assert polls_now - polls == 4 and 0 <= ahead_now - ahead <= 4           # both count, in the program's ring
+    task.reset_nonfinite()
+
+
+@pytest.mark.parametrize('how', ['next_call', 'drain'])
+def test_tolerance_consecutive_bad_steps_raise_one_call_later_with_the_bad_steps_number(mesh8, task, how):
+    from timm_tpu.resilience import NonFiniteError
+    task.reset_nonfinite()
+    task.train_step(_batch(mesh8), lr=1e-3, step=0)
+    for step in (1, 2, 3):                                                 # the third bad step's own call reads the second
+        task.train_step(_batch(mesh8, nan=True), lr=1e-3, step=step)
+    assert task.sentinel.consecutive == 2
+    with pytest.raises(NonFiniteError) as ei:
+        if how == 'drain':
+            task.drain()
+        else:
+            task.train_step(_batch(mesh8, seed=1), lr=1e-3, step=4)
+    assert (ei.value.consecutive, ei.value.total, ei.value.step, ei.value.tolerance) == (3, 3, 3, 3)
+    task.reset_nonfinite()
+    task.drain()                                                           # the rollback's reset forgot step 4
+    assert task.sentinel.consecutive == 0
+
+
+@pytest.mark.parametrize('forget', ['reset_nonfinite', 'load_checkpoint_state'])
+def test_a_rollback_forgets_the_unread_step(mesh8, task, monkeypatch, forget):
+    task.reset_nonfinite()
+    state = task.get_checkpoint_state()
+    task.train_step(_batch(mesh8, nan=True), lr=1e-3, step=0)
+    assert task._unread is not None and task._unread[0] == 0
+    polled = []
+    monkeypatch.setattr(task.sentinel, 'observe', lambda state, step=0: polled.append(step))
+    if forget == 'reset_nonfinite':
+        task.reset_nonfinite()
+    else:
+        task.load_checkpoint_state(state, strict=False)
+    assert task._unread is None
+    task.drain()
+    task.train_step(_batch(mesh8), lr=1e-3, step=1)
+    assert polled == []                                                    # step 0 went with the state it belonged to
+    monkeypatch.undo()
+    task.reset_nonfinite()
+
+
+@pytest.mark.parametrize('rebuild', ['set_grad_accum', 'set_block_scan', 'setup_ema'])
+def test_what_drops_the_step_binding_reads_the_unread_step_first(mesh8, rebuild):
+    task = _task(mesh8, opt='sgd', nonfinite_tolerance=3)
+    task.train_step(_batch(mesh8, nan=True), lr=1e-3, step=7)
+    assert task._unread[0] == 7 and task.sentinel.total == 0
+    {'set_grad_accum': lambda: task.set_grad_accum(2), 'set_block_scan': lambda: task.set_block_scan(True),
+     'setup_ema': lambda: task.setup_ema(decay=0.9)}[rebuild]()
+    assert task._unread is None and task._train_step is None and (task.sentinel.consecutive, task.sentinel.total) == (1, 1)
+
+
+def test_a_bad_step_followed_by_a_good_one_logs_the_warning_once_with_its_own_step(mesh8, task, caplog):
+    task.reset_nonfinite()
+    with caplog.at_level(logging.WARNING, logger='timm_tpu.resilience.sentinel'):
+        for step, nan in ((20, False), (21, True), (22, False), (23, False)):
+            task.train_step(_batch(mesh8, nan=nan), lr=1e-3, step=step)
+        task.drain()
+    lines = [r.getMessage() for r in caplog.records if 'Non-finite' in r.getMessage()]
+    assert len(lines) == 1 and 'at update 21:' in lines[0] and '(1 consecutive, 1 total)' in lines[0]
+    task.reset_nonfinite()
+
+
+def test_without_the_guard_nothing_is_kept_or_polled(mesh8):
+    task = _task(mesh8, opt='sgd', nonfinite_guard=False)
+    polls = _counters()[0]
+    metrics = task.train_step(_batch(mesh8), lr=1e-3, step=0)
+    task.drain()
+    assert 'nonfinite_count' not in metrics and task._unread is None and _counters()[0] == polls
 
 
 def test_a_bad_step_commits_nothing_of_parameters_optimizer_state_or_ema(mesh8, task):

@@ -2,9 +2,14 @@
 preemption-aware shutdown, reader retry policy, and a fault-injection harness.
 
 See README "Fault tolerance" for the knobs:
-  TIMM_TPU_NONFINITE_TOLERANCE / _GUARD / _CHECK_EVERY, TIMM_TPU_POISON_BUDGET,
+  TIMM_TPU_NONFINITE_TOLERANCE / _GUARD, TIMM_TPU_POISON_BUDGET,
   TIMM_TPU_PREEMPTION_POLL, TIMM_TPU_FAULT_INJECT, train.py --resume auto /
   --fault-inject / --nonfinite-rollback.
+
+The sentinel's host read lags the dispatch by one step (sentinel.py): a
+NonFiniteError arrives one `train_step` call after the step that trips it, or
+from `TrainingTask.drain()`, which the loop calls before anything is saved or
+evaluated.
 """
 from .durable import (
     SCHEMA_VERSION, CorruptCheckpointError, atomic_copy, atomic_write_bytes,

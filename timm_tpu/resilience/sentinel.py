@@ -6,12 +6,17 @@ device-resident `[consecutive, total]` int32 counter pair and selects between
 the updated and previous (params, opt_state, EMA) with `jnp.where`, so a bad
 step costs its compute but commits nothing — no retrace, no host round-trip.
 
-Host side: `NonFiniteSentinel` polls the counter (every
-TIMM_TPU_NONFINITE_CHECK_EVERY steps; 1 = precise, larger values avoid a
-per-step device sync on TPU — correct either way because the consecutive
-counter only resets on a GOOD step, so a run long enough to abort is still
-standing at the next poll) and raises `NonFiniteError` after K consecutive
-bad steps (K = TIMM_TPU_NONFINITE_TOLERANCE, default 3).
+Host side: `NonFiniteSentinel.observe` reads one step's counters and raises
+`NonFiniteError` after K consecutive bad steps (K =
+TIMM_TPU_NONFINITE_TOLERANCE, default 3). Every step's counters are read,
+once, in order — but one call late: `TrainingTask.train_step` N hands it the
+counters step N-1 returned (its own `metrics['nonfinite_count']` /
+`['nonfinite_total']`, outputs of the program and a snapshot of that step), so
+the read waits for a step that has a successor queued behind it and the host's
+work between two steps runs behind the device. The abort therefore arrives one
+call after the step that trips it; `TrainingTask.drain()` reads the step still
+unread wherever the old ordering matters (before a checkpoint, a recovery file,
+an evaluation).
 
 Because loss and grads are computed from the globally-sharded batch with
 replicated params, the all-finite flag is identical on every host of a pod —
@@ -83,28 +88,23 @@ def update_sentinel_state(state: jax.Array, ok: jax.Array) -> jax.Array:
 
 
 class NonFiniteSentinel:
-    def __init__(self, tolerance: Optional[int] = None, check_every: Optional[int] = None):
+    def __init__(self, tolerance: Optional[int] = None):
         if tolerance is None:
             tolerance = int(os.environ.get('TIMM_TPU_NONFINITE_TOLERANCE', DEFAULT_TOLERANCE))
-        if check_every is None:
-            check_every = int(os.environ.get('TIMM_TPU_NONFINITE_CHECK_EVERY', 1))
         assert tolerance >= 1, 'nonfinite tolerance must be >= 1'
         self.tolerance = tolerance
-        self.check_every = max(1, check_every)
         self.consecutive = 0   # as of the last poll
         self.total = 0
-        self._calls = 0
 
     def reset(self):
-        self.consecutive = 0
-        self._calls = 0
+        """With fresh device counters (`TrainingTask.reset_nonfinite`): both start at 0 again."""
+        self.consecutive = self.total = 0
 
     def observe(self, sentinel_state, step: int = 0) -> bool:
-        """Poll the device counters; True if the LAST step was skipped.
-        Raises NonFiniteError once `tolerance` consecutive steps went bad."""
-        self._calls += 1
-        if self._calls % self.check_every != 0:
-            return False
+        """Read one step's `[consecutive, total]` counters (the state array or
+        the pair of scalars the step returns in its metrics); `step` is the
+        update they belong to. True if that step was skipped. Raises
+        NonFiniteError once `tolerance` consecutive steps went bad."""
         tracing.count('task.sentinel_polls')
         counts = jax.device_get(sentinel_state)
         consecutive, total = int(counts[0]), int(counts[1])

@@ -103,6 +103,9 @@ class TrainingTask:
         self._nonfinite_guard = guard_enabled(nonfinite_guard)
         self.sentinel = NonFiniteSentinel(nonfinite_tolerance) if self._nonfinite_guard else None
         self._sentinel_state = self._new_sentinel_state() if self._nonfinite_guard else None
+        # (step, nonfinite_count, nonfinite_total) of the newest step, until the
+        # next train_step or drain() reads them: the host's read lags the dispatch
+        self._unread = None
         # on-device input normalization, fused into the jitted step (the
         # reference normalizes on-GPU in PrefetchLoader, loader.py:124-159)
         if mean is not None:
@@ -207,6 +210,7 @@ class TrainingTask:
         """(reference task.py:110). The EMA tree is a deep COPY placed like the
         params (donation aliases param and EMA buffers independently; sharing
         storage with the live params would alias one buffer twice)."""
+        self.drain()
         self.ema = ModelEmaV3(decay=decay, use_warmup=warmup, **kwargs)
         self.ema_params = jax.device_put(
             jax.tree.map(lambda p: jnp.array(p, copy=True), nnx.state(self.model, nnx.Param)),
@@ -221,6 +225,7 @@ class TrainingTask:
         flax versions whose jit cache ignores attr-only graphdef changes."""
         if not hasattr(self.model, 'set_block_scan'):
             return False
+        self.drain()
         self.model.set_block_scan(enable)
         self._train_step = self._step_vars = None
         self._eval_step = None
@@ -235,6 +240,7 @@ class TrainingTask:
         steps = max(1, int(steps))
         if steps == self.grad_accum_steps:
             return False
+        self.drain()
         self.grad_accum_steps = steps
         self._train_step = self._step_vars = None
         return True
@@ -442,7 +448,12 @@ class TrainingTask:
     def train_step(self, batch: Dict[str, Any], lr: float, step: int = 0):
         """One optimization step; `batch['input']` is NHWC, batch dim sharded
         over the mesh (use parallel.shard_batch). The spans split the call's
-        host time by part (utils/tracing.py; PERF.md section 3)."""
+        host time by part (utils/tracing.py; PERF.md section 3).
+
+        The non-finite counters the host reads here are those of the call
+        BEFORE this one: this step is enqueued behind the one still running, and
+        a `NonFiniteError` is raised one call after the step that trips it (or by
+        `drain()`, which whoever saves or evaluates the state calls first)."""
         with tracing.span('task.train_step'):
             if self._train_step is None:
                 self._train_step = self._build_train_step()
@@ -465,12 +476,31 @@ class TrainingTask:
                 self._ema_leaves = ema_out
                 if self._sentinel_state is not None:
                     self._sentinel_state = sent_out
-            if self._sentinel_state is not None and self.sentinel is not None:
-                # polls the device counters (every TIMM_TPU_NONFINITE_CHECK_EVERY
-                # steps) and raises NonFiniteError after K consecutive bad steps
-                with tracing.span('task.sentinel_poll'):
-                    self.sentinel.observe(sent_out, step=step)
+            if self.sentinel is not None:
+                # the step's own counters: outputs of the program, not donated, a snapshot of
+                # this step (`sent_out` goes into the next call and cannot be read after it)
+                unread, self._unread = self._unread, (step, metrics['nonfinite_count'], metrics['nonfinite_total'])
+                self._observe(unread)
         return metrics
+
+    def _observe(self, unread):
+        """Read one step's counters (none before a run's first step: the span is
+        there all the same); raises NonFiniteError after K consecutive bad steps."""
+        with tracing.span('task.sentinel_poll'):
+            if unread is None:
+                return
+            step, *counts = unread
+            if not counts[0].is_ready():  # both are outputs of one execution
+                tracing.count('task.polls_host_ahead')  # the device is still busy: it did not wait for the host
+            self.sentinel.observe(counts, step=step)
+
+    def drain(self):
+        """Read the counters of the step still unread, if any: the old ordering,
+        for whoever is about to save, evaluate or rebuild the step. A second call
+        reads nothing."""
+        if self._unread is not None:
+            unread, self._unread = self._unread, None
+            self._observe(unread)
 
     def _train_step_args(self, batch: Dict[str, Any], lr: float, step: int):
         """The jitted step, built if need be, and the arguments `train_step`
@@ -507,7 +537,9 @@ class TrainingTask:
         return jax.device_put(new_sentinel_state(), replicate_sharding(self.mesh))
 
     def reset_nonfinite(self):
-        """Clear the consecutive-bad-step counters (after a rollback)."""
+        """Clear the consecutive-bad-step counters (after a rollback); the step
+        still unread is forgotten with the state it belonged to."""
+        self._unread = None
         if self._sentinel_state is not None:
             self._sentinel_state = self._new_sentinel_state()
         if self.sentinel is not None:
@@ -562,7 +594,8 @@ class TrainingTask:
         """Restore from a flat checkpoint dict; loaded leaves are re-placed
         under THIS task's shardings, so a checkpoint saved on any mesh shape
         (single-device, data-only, data×fsdp, multi-process sharded) loads on
-        any other."""
+        any other. A step still unread (`drain`) is forgotten: its state goes."""
+        self._unread = None
         params = unflatten_into(nnx.state(self.model, nnx.Param), state, 'state_dict', strict=strict)
         nnx.update(self.model, self._place(params, self._param_shardings))
         if self.ema_params is not None and any(k.startswith('state_dict_ema.') for k in state):

@@ -58,8 +58,9 @@ SPANS = {
     'task.scalars_put': ('step', 'the two jnp.asarray scalar transfers (lr, ema decay)'),
     'task.step_call': ('step', 'the jitted call on flat tuples: dispatch, the drop of the donated optimizer arrays; first call also trace + compile'),
     'task.state_update': ('step', 'the returned arrays written into the same Variables (dropping the donated ones) + EMA / sentinel leaves kept'),
-    'task.sentinel_poll': ('step', "sentinel.observe(): the device_get of the step's counters"),
+    'task.sentinel_poll': ('step', "sentinel.observe(): the device_get of the counters of the step BEFORE the one just enqueued (drain(): of the last)"),
     'task.sentinel_polls': ('step', 'counter: observe() calls that read the device'),
+    'task.polls_host_ahead': ('step', "counter: polls that found the observed step's counters not ready yet: the device still had work queued and did not wait for the host"),
     'task.state_binds': ('step', "counter: times the step was (re)built and bound to the model's Variables; 1 a run"),
     # device scopes (`scope`): jax.named_scope names on the program's ops, read from a trace's XLA Ops line
     'glm.embed': ('step', 'device scope: token embedding lookup'),

@@ -17,6 +17,7 @@ import sys
 import time
 from collections import OrderedDict
 from datetime import datetime
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -1061,14 +1062,17 @@ def _thaw():
         _frozen = False
 
 
-def _resilient_train_step(task, batch, lr, step, args, saver, rollback_budget):
-    """task.train_step with optional rollback-to-last-checkpoint when the
-    non-finite tolerance trips. Returns metrics, or None when the step was
-    dropped by a rollback (caller skips the batch and continues)."""
+def _or_rollback(task, call, saver, rollback_budget):
+    """`call()` (a `task.train_step` or a `task.drain`: what reads a step's
+    non-finite counters) with optional rollback-to-last-checkpoint when the
+    non-finite tolerance trips in it. Returns its result, or None after a
+    rollback: the caller of a step skips the batch and continues. The counters
+    read are those of the step BEFORE the one a `train_step` enqueues: the
+    rollback discards that one too."""
     from timm_tpu.resilience import NonFiniteError, load_with_fallback, resolve_auto_resume
     try:
-        return task.train_step(batch, lr=lr, step=step)
-    except NonFiniteError:
+        return call()
+    except NonFiniteError as e:
         if not rollback_budget or rollback_budget[0] <= 0 or saver is None:
             raise
         rb = resolve_auto_resume(saver.checkpoint_dir)
@@ -1079,7 +1083,7 @@ def _resilient_train_step(task, batch, lr, step, args, saver, rollback_budget):
         task.reset_nonfinite()
         rollback_budget[0] -= 1
         _logger.warning(
-            f'Non-finite tolerance hit at update {step}: rolled back to {used} '
+            f'Non-finite tolerance hit at update {e.step}: rolled back to {used} '
             f'({rollback_budget[0]} rollback(s) left); continuing')
         return None
 
@@ -1123,6 +1127,7 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
     did = {k: v - counters_before.get(k, 0) for k, v in snap['counters'].items()}
     depths = [v for t, v in snap['gauges'].get('loader.batch_q_depth', ()) if t >= since_ns]
     procs = snap['gauges'].get('loader.decode_procs')
+    polls = did.get('task.sentinel_polls', 0)
     text = (f"host ms/step: next {ms('train.loader_next'):.1f} split {ms('task.state_split'):.1f} "
             f"put {ms('task.scalars_put', 'train.batch_to_device'):.1f} call {ms('task.step_call'):.1f} "
             f"update {ms('task.state_update'):.1f} poll {ms('task.sentinel_poll'):.1f} "
@@ -1131,7 +1136,8 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
         text += (f" | loader q {sum(depths) / max(len(depths), 1):.1f} "
                  f"decode {did['loader.decode_busy_ns'] / did['loader.samples'] / 1e6:.1f} ms/img "
                  f"{did['loader.decode_busy_ns'] / did['loader.batches'] / 1e6:.0f} ms/batch "
-                 f"polls {did.get('task.sentinel_polls', 0)} binds {did.get('task.state_binds', 0)}")
+                 f"polls {polls} ahead {did.get('task.polls_host_ahead', 0)}/{polls} "
+                 f"binds {did.get('task.state_binds', 0)}")
     if procs:   # an image run: decode processes alive at the newest fetch, and those that ended unasked
         text += f" procs {procs[-1][1]} exits {did.get('loader.worker_exits', 0)}"
     if metrics and 'moe.fallback_layers' in metrics:
@@ -1166,6 +1172,12 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
     lr = lr_scheduler.get_last_lr()[0] if lr_scheduler else args.lr
     injector = get_fault_injector()
 
+    def drain():
+        """The newest step's non-finite counters, read before anything is saved,
+        evaluated or killed: `train_step` itself reads one step behind. Aborts
+        or rolls back as a step does."""
+        _or_rollback(task, task.drain, saver, rollback_budget)
+
     def poll_faults_and_shutdown(batch_idx, update_idx):
         """After each committed update: deliver injected SIGKILL/SIGTERM, then
         write a step-granular recovery checkpoint and stop if shutdown was
@@ -1176,6 +1188,7 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
             # next named consensus times out on it and resolves to stop.
             # Drain the dispatched step first (its collective sends must land
             # so survivors can materialize the post-step state on their own).
+            drain()
             jax.block_until_ready((metrics, task.opt_state))
             _logger.warning(f'[fault-inject] kill_host at update {num_updates - 1}: SIGKILL')
             os.kill(os.getpid(), __import__('signal').SIGKILL)
@@ -1191,6 +1204,7 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
         if shutdown is not None and shutdown.should_stop(update_idx):
             path = ''
             if saver is not None:
+                drain()
                 path = saver.save_recovery(
                     epoch, update_idx,
                     extra_state=_recovery_extras(batch_idx + 1, num_updates, args))
@@ -1240,7 +1254,8 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
                 arrays = {'input': input_all, 'target': target_all}
                 seq = ''
             batch = _batch_to_device(arrays, mesh, shard_batch)
-            metrics = _resilient_train_step(task, batch, lr, num_updates, args, saver, rollback_budget)
+            metrics = _or_rollback(
+                task, partial(task.train_step, batch, lr=lr, step=num_updates), saver, rollback_budget)
             if metrics is None:
                 update_idx += 1
                 continue
@@ -1274,6 +1289,7 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
                     f'{seq}{ips:.1f} img/s' + (f' NaN-skipped: {nf}' if nf else '') + f' {host}')
             with tracing.span('train.bookkeeping'):
                 if saver is not None and args.recovery_interval and (update_idx + 1) % args.recovery_interval == 0:
+                    drain()
                     saver.save_recovery(epoch, update_idx,
                                         extra_state=_recovery_extras(batch_idx + 1, num_updates, args))
                 poll_faults_and_shutdown(batch_idx, update_idx)
@@ -1291,11 +1307,14 @@ def train_one_epoch(epoch, task, loader, args, lr_scheduler, mesh, shard_batch,
             input_all = np.concatenate([input_all] + [input_all] * reps, axis=0)[:accum * micro_inputs[0].shape[0]]
             target_all = np.concatenate([target_all] + [target_all] * reps, axis=0)[:accum * micro_inputs[0].shape[0]]
         batch = _batch_to_device({'input': input_all, 'target': target_all}, mesh, shard_batch)
-        metrics = _resilient_train_step(task, batch, lr, num_updates, args, saver, rollback_budget)
+        metrics = _or_rollback(task, partial(task.train_step, batch, lr=lr, step=num_updates), saver, rollback_budget)
         if metrics is not None:
             num_updates += 1
             if lr_scheduler is not None:
                 lr = lr_scheduler.step_update(num_updates)[0]
+    # the epoch's last step, before evaluation and the epoch's checkpoint. Not in a `finally`: an epoch that
+    # another exception ends (preemption after its recovery save, a closed window) reads nothing more
+    drain()
     out = OrderedDict([('loss', loss_m.avg if loss_m.count else float((metrics or {}).get('loss', 0.0))), ('lr', lr)])
     if metrics and 'nonfinite_total' in metrics:
         out['nonfinite_steps'] = int(metrics['nonfinite_total'])

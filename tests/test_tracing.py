@@ -214,6 +214,7 @@ def test_every_recorded_name_is_declared_and_every_declared_name_is_recorded_and
                 open(os.path.join(bench, 'harness', 'swa_lm_train_runner.py')).read(),
                 open(os.path.join(bench, 'harness', 'bd_lm_readers.py')).read(),     # the block-diffusion cell's: `swa.attn.core_bd`, `attn.bd_blocks`
                 open(os.path.join(bench, 'harness', 'bd_lm_train_runner.py')).read(),  # its `correct`: `lm.noised_masked`, `lm.masked_nll`
+                open(os.path.join(bench, 'harness', 'step_scopes.py')).read(),       # every cell's `step.*`, the image cells' `img.*`: `img.block`
                 inspect.getsource(train._host_line), inspect.getsource(train._setup_line)]
     unread = [name for name in tracing.SPANS if not any(f"'{name}'" in text for text in readers)]
     assert not unread, unread
@@ -249,14 +250,22 @@ def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_t
     assert len(after['spans']) - len(before['spans']) <= 1 and after['counters'] == before['counters']   # the ring is not theirs
     kinds = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('glm.', 'moe.', 'lm.'))}
     assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 16      # two of the block-diffusion task
-    # the window/full family's: three scopes of a kind of their own (the GLM reduction's set of nine is pinned by
-    # `test_lm_harness.py`; `swa_lm_readers.declared_scopes` reads both kinds) and two tile counters
+    # ONE kind of device scope: the window/full family's three were 'swa device scope' while `tracing.py` could not be
+    # edited by the PRs that met `test_lm_harness.py`'s pin of the GLM reduction's nine (a superset since PR 35)
     swa = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('swa.', 'attn.'))}
-    # the core under the block-diffusion mask came later, as a plain device scope: both reductions take it
-    assert swa == {'swa.attn.proj': 'swa device scope', 'swa.attn.core_full': 'swa device scope',
-                   'swa.attn.core_window': 'swa device scope', 'swa.attn.core_bd': 'device scope',
+    assert swa == {'swa.attn.proj': 'device scope', 'swa.attn.core_full': 'device scope',
+                   'swa.attn.core_window': 'device scope', 'swa.attn.core_bd': 'device scope',
                    'attn.full_blocks': 'step counter', 'attn.window_blocks': 'step counter', 'attn.bd_blocks': 'step counter'}
+    assert {what.split(':')[0] for _, what in tracing.SPANS.values() if 'scope' in what.split(':')[0]} == {'device scope'}
     assert all(tracing.SPANS[name][0] == 'attention' for name in swa)
+    # the image models' and the step's own: seventeen, family-neutral, each under the layer its metric names
+    ours = {name: layer for name, (layer, what) in tracing.SPANS.items() if name.startswith(('img.', 'step.'))}
+    assert len(ours) == 17 and all(tracing.SPANS[name][1].startswith('device scope: ') for name in ours)
+    assert {name for name, layer in ours.items() if layer == 'attention'} == {'img.attn.qkv', 'img.attn.core', 'img.attn.proj'}
+    assert {layer for layer in ours.values()} == {'step', 'attention'}
+    from benchmarks.harness import device_scopes
+    assert all(device_scopes.SCOPE_TOKEN.fullmatch(name) for name in ours)               # the reduction's token: no digit, letters before the first dot
+    assert set(ours) <= device_scopes.declared_scopes()                                  # all three families' reductions take them
 
 
 def test_no_loader_worker_thread_opens_a_span():

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from flax import nnx
 
+from ..utils import tracing
 from .config import softmax_with_policy, use_fused_attn
 from .drop import Dropout, dropout_rng_key
 from .weight_init import trunc_normal_, zeros_
@@ -164,18 +165,21 @@ class Attention(nnx.Module):
     def __call__(self, x, attn_mask=None):
         from ..parallel import shard_activation
         B, N, C = x.shape
-        q, k, v = self._qkv(x)
-        dropout_p = 0.0 if self.attn_drop.deterministic else self.attn_drop_rate
-        dropout_key = dropout_rng_key(self.attn_drop) if dropout_p > 0.0 else None
-        x = scaled_dot_product_attention(
-            q, k, v, attn_mask=attn_mask, dropout_p=dropout_p, dropout_key=dropout_key, scale=self.scale,
-            softmax_dtype=self.softmax_dtype,
-        )
-        x = shard_activation(x.transpose(0, 2, 1, 3).reshape(B, N, C), 'hidden')
-        if self.norm is not None:
-            x = self.norm(x)
-        x = self.proj(x)
-        x = self.proj_drop(x)
+        with tracing.scope('img.attn.qkv'):
+            q, k, v = self._qkv(x)
+        with tracing.scope('img.attn.core'):
+            dropout_p = 0.0 if self.attn_drop.deterministic else self.attn_drop_rate
+            dropout_key = dropout_rng_key(self.attn_drop) if dropout_p > 0.0 else None
+            x = scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask, dropout_p=dropout_p, dropout_key=dropout_key, scale=self.scale,
+                softmax_dtype=self.softmax_dtype,
+            )
+        with tracing.scope('img.attn.proj'):
+            x = shard_activation(x.transpose(0, 2, 1, 3).reshape(B, N, C), 'hidden')
+            if self.norm is not None:
+                x = self.norm(x)
+            x = self.proj(x)
+            x = self.proj_drop(x)
         return x
 
 

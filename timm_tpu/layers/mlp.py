@@ -13,6 +13,7 @@ from typing import Callable, Optional, Union
 import jax.numpy as jnp
 from flax import nnx
 
+from ..utils import tracing
 from .create_act import get_act_fn
 from .drop import Dropout
 from .helpers import to_2tuple
@@ -69,13 +70,14 @@ class Mlp(nnx.Module):
         self.drop2 = Dropout(drop_probs[1], rngs=rngs)
 
     def __call__(self, x):
-        x = _shard_hidden(self.fc1(x))
-        x = self.act(x)
-        x = self.drop1(x)
-        if self.norm is not None:
-            x = self.norm(x)
-        x = self.fc2(x)
-        x = self.drop2(x)
+        with tracing.scope('img.mlp'):
+            x = _shard_hidden(self.fc1(x))
+            x = self.act(x)
+            x = self.drop1(x)
+            if self.norm is not None:
+                x = self.norm(x)
+            x = self.fc2(x)
+            x = self.drop2(x)
         return x
 
 

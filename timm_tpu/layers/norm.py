@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from flax import nnx
 
+from ..utils import tracing
 from .config import norm_internal_dtype, resolve_dtype_arg
 
 __all__ = [
@@ -100,9 +101,10 @@ class LayerNorm(nnx.LayerNorm):
 
     def __call__(self, x):
         dt = _resolve_internal(getattr(self, 'internal_dtype', None))
-        if dt is None:
-            return super().__call__(x)
-        return _layernorm_fast(x, _param_value(self.scale), _param_value(self.bias), self.epsilon, dt)
+        with tracing.scope('img.norm'):
+            if dt is None:
+                return super().__call__(x)
+            return _layernorm_fast(x, _param_value(self.scale), _param_value(self.bias), self.epsilon, dt)
 
 
 # NHWC: channels are already last, identical computation.

@@ -11,6 +11,7 @@ from typing import Callable, Optional, Tuple, Union
 import jax.numpy as jnp
 from flax import nnx
 
+from ..utils import tracing
 from .helpers import to_2tuple
 from .weight_init import lecun_normal_, zeros_
 
@@ -84,11 +85,12 @@ class PatchEmbed(nnx.Module):
             pad_w = (pw - W % pw) % pw
             if pad_h or pad_w:
                 x = jnp.pad(x, ((0, 0), (0, pad_h), (0, pad_w), (0, 0)))
-        x = self.proj(x)
-        if self.norm is not None:
-            x = self.norm(x)
-        if self.flatten:
-            x = x.reshape(x.shape[0], -1, x.shape[-1])  # (B, H*W, C)
+        with tracing.scope('img.patch_embed'):
+            x = self.proj(x)
+            if self.norm is not None:
+                x = self.norm(x)
+            if self.flatten:
+                x = x.reshape(x.shape[0], -1, x.shape[-1])  # (B, H*W, C)
         return x
 
 

@@ -21,6 +21,7 @@ from ..layers import (
     NormMlpClassifierHead, calculate_drop_path_rates, create_conv2d, get_act_fn,
     get_norm_layer, make_divisible, trunc_normal_,
 )
+from ..utils import tracing
 from ._builder import build_model_with_cfg
 from ._features import feature_take_indices
 from ._manipulate import (
@@ -44,7 +45,8 @@ class Downsample(nnx.Module):
     def __call__(self, x):
         if self.conv is None:
             return x
-        return self.conv(x)
+        with tracing.scope('img.downsample'):
+            return self.conv(x)
 
 
 class ConvNeXtBlock(nnx.Module):
@@ -89,16 +91,18 @@ class ConvNeXtBlock(nnx.Module):
             dtype=dtype, param_dtype=param_dtype, rngs=rngs)
 
     def __call__(self, x):
-        shortcut = x
-        x = self.conv_dw(x)
-        x = self.norm(x)
-        x = self.mlp(x)
-        if self.ls is not None:
-            x = self.ls(x)
-        x = self.drop_path(x)
-        if self.shortcut is not None:
-            shortcut = self.shortcut(shortcut)
-        return x + shortcut
+        with tracing.scope('img.block'):
+            shortcut = x
+            with tracing.scope('img.conv_dw'):
+                x = self.conv_dw(x)
+            x = self.norm(x)
+            x = self.mlp(x)
+            if self.ls is not None:
+                x = self.ls(x)
+            x = self.drop_path(x)
+            if self.shortcut is not None:
+                shortcut = self.shortcut(shortcut)
+            return x + shortcut
 
 
 class ConvNeXtStage(nnx.Module):
@@ -157,8 +161,9 @@ class ConvNeXtStage(nnx.Module):
 
     def __call__(self, x):
         if self.downsample_norm is not None:
-            x = self.downsample_norm(x)
-            x = self.downsample_conv(x)
+            with tracing.scope('img.downsample'):
+                x = self.downsample_norm(x)
+                x = self.downsample_conv(x)
         if self.stage_scan:
             try:
                 return scan_stage_stack(self.blocks, x, remat=self.grad_checkpointing)
@@ -333,12 +338,13 @@ class ConvNeXt(nnx.Module):
 
     # -- forward -------------------------------------------------------------
     def _stem(self, x):
-        x = self.stem_conv(x)
-        if self.stem_conv2 is not None:
-            if getattr(self, 'stem_act', None) is not None:
-                x = self.stem_act(x)
-            x = self.stem_conv2(x)
-        return self.stem_norm(x)
+        with tracing.scope('img.stem'):
+            x = self.stem_conv(x)
+            if self.stem_conv2 is not None:
+                if getattr(self, 'stem_act', None) is not None:
+                    x = self.stem_act(x)
+                x = self.stem_conv2(x)
+            return self.stem_norm(x)
 
     def forward_features(self, x):
         x = self._stem(x)
@@ -349,7 +355,8 @@ class ConvNeXt(nnx.Module):
         return x
 
     def forward_head(self, x, pre_logits: bool = False):
-        return self.head(x, pre_logits=pre_logits)
+        with tracing.scope('img.head'):
+            return self.head(x, pre_logits=pre_logits)
 
     def __call__(self, x):
         return self.forward_head(self.forward_features(x))

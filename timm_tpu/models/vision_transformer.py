@@ -27,6 +27,7 @@ from ..layers import (
     resample_abs_pos_embed, scaled_dot_product_attention, trunc_normal_, zeros_,
 )
 from ..layers.drop import apply_drop_path
+from ..utils import tracing
 from ._builder import build_model_with_cfg
 from ._features import feature_take_indices
 from ._manipulate import (
@@ -99,14 +100,15 @@ class Block(nnx.Module):
         self.drop_path2 = DropPath(drop_path, rngs=rngs)
 
     def __call__(self, x, attn_mask=None, drop_path_override=None):
-        y = self.attn(self.norm1(x), attn_mask=attn_mask)
-        if self.ls1 is not None:
-            y = self.ls1(y)
-        x = x + apply_drop_path(y, self.drop_path1, drop_path_override, 0)
-        y = self.mlp(self.norm2(x))
-        if self.ls2 is not None:
-            y = self.ls2(y)
-        x = x + apply_drop_path(y, self.drop_path2, drop_path_override, 1)
+        with tracing.scope('img.block'):
+            y = self.attn(self.norm1(x), attn_mask=attn_mask)
+            if self.ls1 is not None:
+                y = self.ls1(y)
+            x = x + apply_drop_path(y, self.drop_path1, drop_path_override, 0)
+            y = self.mlp(self.norm2(x))
+            if self.ls2 is not None:
+                y = self.ls2(y)
+            x = x + apply_drop_path(y, self.drop_path2, drop_path_override, 1)
         return x
 
 
@@ -160,10 +162,11 @@ class ResPostBlock(nnx.Module):
             self.norm2.scale[...] = self.norm2.scale[...] * init_values
 
     def __call__(self, x, attn_mask=None, drop_path_override=None):
-        x = x + apply_drop_path(
-            self.norm1(self.attn(x, attn_mask=attn_mask)), self.drop_path1, drop_path_override, 0)
-        x = x + apply_drop_path(
-            self.norm2(self.mlp(x)), self.drop_path2, drop_path_override, 1)
+        with tracing.scope('img.block'):
+            x = x + apply_drop_path(
+                self.norm1(self.attn(x, attn_mask=attn_mask)), self.drop_path1, drop_path_override, 0)
+            x = x + apply_drop_path(
+                self.norm2(self.mlp(x)), self.drop_path2, drop_path_override, 1)
         return x
 
 
@@ -809,21 +812,23 @@ class VisionTransformer(nnx.Module):
         if self.dynamic_img_size:
             grid_size = self.patch_embed.dynamic_feat_size(x.shape[1:3])
         x = self.patch_embed(x)
-        # an externally supplied attn_mask is sized for the UNPADDED sequence,
-        # so the alignment pad is skipped for that call
-        x, pad_mask, orig_len = self._pos_embed(
-            x, grid_size=grid_size, pad_tokens_to=0 if attn_mask is not None else None)
-        if pad_mask is not None:
-            attn_mask = pad_mask
-        if self.patch_drop is not None:
-            x = self.patch_drop(x)
-        if self.norm_pre is not None:
-            x = self.norm_pre(x)
+        with tracing.scope('img.patch_embed'):
+            # an externally supplied attn_mask is sized for the UNPADDED sequence,
+            # so the alignment pad is skipped for that call
+            x, pad_mask, orig_len = self._pos_embed(
+                x, grid_size=grid_size, pad_tokens_to=0 if attn_mask is not None else None)
+            if pad_mask is not None:
+                attn_mask = pad_mask
+            if self.patch_drop is not None:
+                x = self.patch_drop(x)
+            if self.norm_pre is not None:
+                x = self.norm_pre(x)
         x = self._forward_block_stack(x, attn_mask=attn_mask)
-        if self.norm is not None:
-            x = self.norm(x)
-        if x.shape[1] != orig_len:
-            x = x[:, :orig_len]  # strip the alignment pad before the head
+        with tracing.scope('img.head'):
+            if self.norm is not None:
+                x = self.norm(x)
+            if x.shape[1] != orig_len:
+                x = x[:, :orig_len]  # strip the alignment pad before the head
         return x
 
     def _forward_block_stack(self, x, attn_mask=None, collect=False, blocks=None):
@@ -877,13 +882,14 @@ class VisionTransformer(nnx.Module):
         return global_pool_nlc(x, pool_type=pool_type, num_prefix_tokens=self.num_prefix_tokens, mask=mask)
 
     def forward_head(self, x, pre_logits: bool = False):
-        x = self.pool(x)
-        if self.fc_norm is not None:
-            x = self.fc_norm(x)
-        x = self.head_drop(x)
-        if pre_logits or self.head is None:
-            return x
-        return self.head(x)
+        with tracing.scope('img.head'):
+            x = self.pool(x)
+            if self.fc_norm is not None:
+                x = self.fc_norm(x)
+            x = self.head_drop(x)
+            if pre_logits or self.head is None:
+                return x
+            return self.head(x)
 
     def __call__(self, x, attn_mask=None):
         x = self.forward_features(x, attn_mask=attn_mask)

@@ -6,6 +6,7 @@ from typing import Any, Callable, Dict, Optional
 from flax import nnx
 
 from ..loss import LabelSmoothingCrossEntropy
+from ..utils import tracing
 from .task import TrainingTask
 
 __all__ = ['ClassificationTask']
@@ -26,7 +27,8 @@ class ClassificationTask(TrainingTask):
 
     def loss_forward(self, model: nnx.Module, batch: Dict[str, Any]):
         output = model(batch['input'])
-        loss = self.train_loss_fn(output, batch['target'])
+        with tracing.scope('step.loss'):
+            loss = self.train_loss_fn(output, batch['target'])
         return loss, output
 
 

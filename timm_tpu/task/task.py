@@ -322,7 +322,8 @@ class TrainingTask:
         def train_step(param_leaves, rest_leaves, opt_leaves, ema_leaves, sentinel_state, batch, lr, ema_decay):
             params, rest, opt_state, ema_params = map(
                 jax.tree.unflatten, defs, (param_leaves, rest_leaves, opt_leaves, ema_leaves))
-            batch = normalize_input(batch)
+            with tracing.scope('step.input'):
+                batch = normalize_input(batch)
 
             if accum > 1 and accum_scan:
                 # ONE lax.scan over stacked microbatches: trace/compile cost
@@ -368,38 +369,43 @@ class TrainingTask:
             else:
                 (loss, (new_rest, counters)), grads = grad_fn(params, rest, batch)
 
-            grad_norm = global_grad_norm(grads)
-            if clip_grad is not None:
-                params_for_clip = params if clip_mode == 'agc' else None
-                grads, _ = dispatch_clip_grad(grads, clip_grad, mode=clip_mode, params=params_for_clip)
+            with tracing.scope('step.clip'):
+                grad_norm = global_grad_norm(grads)
+                if clip_grad is not None:
+                    params_for_clip = params if clip_mode == 'agc' else None
+                    grads, _ = dispatch_clip_grad(grads, clip_grad, mode=clip_mode, params=params_for_clip)
 
-            if fused_cfg is not None:
-                # one-pass fused AdamW+EMA kernel: replaces update + apply
-                # (+ the EMA pass below); opt_state structure is preserved so
-                # the shardings/donation annotations hold unchanged
-                new_params, new_opt_state, fused_ema = fused_adamw_step(
-                    params, grads, opt_state, ema_params if has_ema else None,
-                    lr=lr, ema_decay=ema_decay, **fused_cfg)
-            else:
-                updates, new_opt_state = optimizer.update(grads, opt_state, params, lr=lr)
-                new_params = optax.apply_updates(params, updates)
-                fused_ema = None
+            with tracing.scope('step.update'):
+                if fused_cfg is not None:
+                    # one-pass fused AdamW+EMA kernel: replaces update + apply
+                    # (+ the EMA pass below); opt_state structure is preserved so
+                    # the shardings/donation annotations hold unchanged
+                    new_params, new_opt_state, fused_ema = fused_adamw_step(
+                        params, grads, opt_state, ema_params if has_ema else None,
+                        lr=lr, ema_decay=ema_decay, **fused_cfg)
+                else:
+                    updates, new_opt_state = optimizer.update(grads, opt_state, params, lr=lr)
+                    new_params = optax.apply_updates(params, updates)
+                    fused_ema = None
             if guard:
                 # all-finite reduction over loss + raw grads; a bad step keeps
                 # params/opt_state/EMA bit-identical to the previous step
-                ok = tree_all_finite(loss, grads)
-                select = lambda new, old: jnp.where(ok, new, old)  # noqa: E731
-                new_params = jax.tree.map(select, new_params, params)
-                new_opt_state = jax.tree.map(select, new_opt_state, opt_state)
-                sentinel_state = update_sentinel_state(sentinel_state, ok)
+                with tracing.scope('step.guard'):
+                    ok = tree_all_finite(loss, grads)
+                    select = lambda new, old: jnp.where(ok, new, old)  # noqa: E731
+                    new_params = jax.tree.map(select, new_params, params)
+                    new_opt_state = jax.tree.map(select, new_opt_state, opt_state)
+                    sentinel_state = update_sentinel_state(sentinel_state, ok)
 
             if has_ema:
                 # decay==0 naturally syncs EMA to model (reference ModelEmaV3
                 # lerp weight 1.0 during the update_after_step window).
-                new_ema = fused_ema if fused_ema is not None else \
-                    ema_update(ema_params, new_params, ema_decay)
+                with tracing.scope('step.ema'):
+                    new_ema = fused_ema if fused_ema is not None else \
+                        ema_update(ema_params, new_params, ema_decay)
                 if guard:
-                    new_ema = jax.tree.map(select, new_ema, ema_params)
+                    with tracing.scope('step.guard'):
+                        new_ema = jax.tree.map(select, new_ema, ema_params)
                 ema_params = new_ema
             metrics = {'loss': loss, 'grad_norm': grad_norm, **counters}
             if guard:
@@ -526,9 +532,13 @@ class TrainingTask:
         reads `cost_analysis()` (FLOPs / bytes accessed) and the HLO
         `input_output_alias` header (donation legality) off it, and the
         compile goes through the persistent cache so repeated probes are
-        disk-bound."""
+        disk-bound. Its text is kept for whoever reads the step's device
+        scopes out of a trace (`tracing.program_text('task.step_call')`):
+        on this path only, never by `train_step`."""
         step_fn, args = self._train_step_args(batch, lr, step)
-        return step_fn.lower(*args).compile()
+        compiled = step_fn.lower(*args).compile()
+        tracing.keep_program('task.step_call', compiled)
+        return compiled
 
     def _new_sentinel_state(self):
         """Fresh counters placed like the step's output: an unplaced array

@@ -15,8 +15,14 @@ free on the host, and read from a profiler trace / the step's metrics.
 
 Nothing is written out and nothing switches it off: readers (`train.py`'s log
 line, `benchmarks/harness/program_spans.py`, tests) take `snapshot()` or
-`summary()`. Every name is declared in `SPANS`; `PERF.md` section 3 says which
-metric reads which.
+`summary()`. A device scope is in a trace only through the compiled program's
+text (an op event carries its instruction, not its metadata), so whoever
+compiles a program ahead of time hands it over (`keep_program(name, compiled)`,
+under the span that dispatches the program: `TrainingTask.lower_train_step`
+keeps the step's under `task.step_call`) and `program_text(name)` gives that
+text to any reader in the process, or None: one string a name, replaced by the
+next, never kept on the training path. Every name is declared in `SPANS`;
+`PERF.md` section 3 says which metric reads which.
 """
 from __future__ import annotations
 
@@ -72,14 +78,29 @@ SPANS = {
     'glm.moe.shared': ('experts', 'device scope: the shared expert'),
     'glm.mtp': ('step', 'device scope: the multi-token-prediction module\'s own projection, norms and head (its block runs outside the scope, under the mla/moe scopes of its layers)'),
     'glm.head_loss': ('step', 'device scope: final norm, output head and cross-entropy, in chunks'),
-    # the window/full attention family's scopes. Their kind reads 'swa device scope' and not 'device scope':
-    # `benchmarks/harness/device_scopes.py` `declared_scopes()` takes the names whose kind starts with the
-    # latter, and `tests/benchmark_harness/test_lm_harness.py` holds that set equal to the nine above
-    # (PERF.md section 7); `benchmarks/harness/swa_lm_readers.py` reads both kinds
-    'swa.attn.proj': ('attention', 'swa device scope: grouped-query q/k/v/o products, the norm before them, the rotary turn'),
-    'swa.attn.core_full': ('attention', 'swa device scope: the causal core of a full (position-free) layer, forward and backward'),
-    'swa.attn.core_window': ('attention', 'swa device scope: the causal core of a window layer, forward and backward'),
+    'swa.attn.proj': ('attention', 'device scope: grouped-query q/k/v/o products, the norm before them, the rotary turn'),
+    'swa.attn.core_full': ('attention', 'device scope: the causal core of a full (position-free) layer, forward and backward'),
+    'swa.attn.core_window': ('attention', 'device scope: the causal core of a window layer, forward and backward'),
     'swa.attn.core_bd': ('attention', 'device scope: the core under the block-diffusion mask (a noised copy beside the clean sequence), forward and backward'),
+    # the image models' scopes, on the shared layers (every model built from them has them), and the step's own,
+    # which every task runs. The innermost scope of an op counts: `img.block` holds what no inner scope takes
+    'img.patch_embed': ('step', 'device scope: the patch convolution, class / register tokens, position embedding, the norm before the blocks'),
+    'img.stem': ('step', 'device scope: a convolutional stem (its norm is `img.norm`)'),
+    'img.downsample': ('step', 'device scope: the convolution between two stages, or on a block\'s shortcut'),
+    'img.block': ('step', 'device scope: a whole block; what is left to it are LayerScale, stochastic depth, the residual adds and layout changes between its inner scopes'),
+    'img.norm': ('step', 'device scope: every LayerNorm (= LayerNorm2d), forward and backward, wherever it is called from'),
+    'img.attn.qkv': ('attention', 'device scope: the fused qkv product, bias, split and head transpose'),
+    'img.attn.core': ('attention', 'device scope: scores, mask, softmax, attention dropout, P V'),
+    'img.attn.proj': ('attention', 'device scope: the (B, H, N, D) -> (B, N, C) transpose, the output product, its dropout'),
+    'img.mlp': ('step', 'device scope: fc1, activation, fc2 of `Mlp`: a token MLP or a channels-last pointwise pair'),
+    'img.conv_dw': ('step', 'device scope: a block\'s depthwise convolution: forward, input gradient, weight gradient'),
+    'img.head': ('step', 'device scope: pool, final norm (as `img.norm`), classifier'),
+    'step.input': ('step', 'device scope: the cast / normalisation of the batch inside the step'),
+    'step.loss': ('step', 'device scope: the classification loss after the model call, and where its backward pass starts'),
+    'step.clip': ('step', 'device scope: the global gradient norm and the scaling of every gradient leaf'),
+    'step.update': ('step', 'device scope: the optimizer\'s moments and the parameter write'),
+    'step.guard': ('step', 'device scope: the all-finite reduction and the selects over parameters, moments and EMA'),
+    'step.ema': ('step', 'device scope: the EMA\'s lerp'),
     # step counters (`device_counter`): values computed inside the step program, returned in its metrics
     'moe.local_slots': ('experts', 'step counter: (token, expert) slots routed to experts held here, all expert layers'),
     'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),
@@ -201,6 +222,20 @@ def scope(name: str):
     host nothing at run time (it names the ops traced inside it); a profiler
     trace carries the name on every device op, forward and backward."""
     return jax.named_scope(_known(name))
+
+
+_programs: dict = {}     # declared span -> the compiled text of the program it dispatches (`keep_program`)
+
+
+def keep_program(name: str, compiled) -> None:
+    """Keep the text of a program compiled ahead of time (a `jax.stages.Compiled`) under the declared span that
+    dispatches it: what a reader of device scopes needs beside the trace. The text, not the executable."""
+    _programs[_known(name)] = compiled.as_text()
+
+
+def program_text(name: str) -> Optional[str]:
+    """The compiled text last kept under `name`; None where nobody compiled that program ahead of time."""
+    return _programs.get(_known(name))
 
 
 def device_counter(name: str, value):

@@ -106,8 +106,8 @@ def test_unsteered_script_fails_without_a_tpu():
 # ---- (b) every registered kernel compiles for the described chip -------------
 
 @pytest.fixture(scope='module')
-def v5e_chip():
-    """One device of a described (not attached) v5e:2x2 topology, with the
+def v5e_devices():
+    """The four devices of a described (not attached) v5e:2x2 topology, with the
     persistent compile cache off: such compiles are written to it but cannot
     be read back without a chip."""
     os.environ.setdefault('TPU_LOG_DIR', 'disabled')
@@ -120,9 +120,14 @@ def v5e_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update('jax_enable_compilation_cache', False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update('jax_enable_compilation_cache', enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def v5e_chip(v5e_devices):
+    return jax.sharding.SingleDeviceSharding(v5e_devices[0])
 
 
 @pytest.mark.kernels
@@ -132,12 +137,31 @@ def test_kernel_live_case_compiles_for_v5e(spec, case, v5e_chip, monkeypatch):
     """Interpret mode cannot see what the TPU compiler refuses (block shapes,
     casts, memory spaces). The kernels pick interpret mode from
     jax.default_backend(); the test, not the program, says 'tpu' here."""
+    from timm_tpu.parallel import mesh as mesh_mod
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    monkeypatch.setattr(mesh_mod, '_GLOBAL_MESH', None)   # one described chip: not the 8 CPU devices an earlier file's mesh may span
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
         jax.eval_shape(lambda: spec.make_inputs(seed=0, **case.live)))
     compiled = harness._jit_arm(spec.kernel_fn, case.statics).lower(shapes).compile()
     assert 'tpu_custom_call' in compiled.as_text()
+
+
+@pytest.mark.kernels
+def test_attention_pair_compiles_for_four_v5e_chips_under_a_data_mesh_without_a_collective(v5e_devices, monkeypatch):
+    """ViT-B's shape, batch 128 over a ('data',) mesh of the 2x2 host: forward and backward kernels under
+    `shard_map`, a device its 32 images, and nothing gathered or reduced between the devices."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from timm_tpu.kernels import packed_attention
+    from timm_tpu.parallel import mesh as mesh_mod
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    mesh = Mesh(np.array(v5e_devices).reshape(4), ('data',))
+    monkeypatch.setattr(mesh_mod, '_GLOBAL_MESH', mesh)
+    qkv = jax.ShapeDtypeStruct((128, 197, 3 * 12 * 64), jnp.bfloat16, sharding=NamedSharding(mesh, PartitionSpec('data')))
+    grad = jax.grad(lambda x: packed_attention(x, 12).astype(jnp.float32).sum())
+    text = jax.jit(grad).lower(qkv).compile().as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert not any(op in text for op in ('all-gather(', 'all-reduce(', 'all-to-all(', 'collective-permute('))
 
 
 # ---- (c) where the compile cache lives ---------------------------------------

@@ -293,3 +293,15 @@ def test_the_log_line_names_the_expert_layers_that_fell_back_only_where_the_step
     import train
     text, counters = train._host_line(tracing.now_ns(), {}, metrics, 8)
     assert text.startswith(starts) and isinstance(counters, dict)
+
+
+def test_the_log_line_names_the_attention_calls_traced_since_the_previous_line_and_the_core_each_took():
+    """`attention.fused_calls` / `attention.plain_calls` are read by `train.py`'s log line, after `loop`: on a run's
+    first line the step's `Attention` calls, on a later line nothing (nothing was traced since)."""
+    import train
+    before = tracing.snapshot()['counters']
+    for name, n in (('attention.fused_calls', 2), ('attention.plain_calls', 1)):
+        tracing.count(name, n)
+    text, now = train._host_line(tracing.now_ns(), before)
+    assert ' loop 0.0 attn fused 2 plain 1' in text and text.startswith('host ms/step: next')
+    assert 'attn' not in train._host_line(tracing.now_ns(), now)[0]

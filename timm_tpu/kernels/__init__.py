@@ -10,8 +10,9 @@ the chip has been made yet: ROADMAP S6); an
 unregistered kernel module fails the lint in tests/test_kernels.py.
 
 Portfolio:
-- `flash_attention` — fused attention behind `use_fused_attn()` dispatch
-  (layers/attention.py); gate: win at masked N>=576 or delete.
+- `flash_attention` — self-attention at image-model lengths as one forward and
+  one backward kernel on the qkv product's own layout; the default core of
+  `layers/attention.py` wherever `flash_attention_supported` holds.
 - `fused_adamw` — one-HBM-pass AdamW+EMA update, the opt-in
   `TrainingTask(fused_update=True)` path; optax stays default + oracle.
 - `augment_epilogue` — one-pass uint8->erase->mix->normalize epilogue for
@@ -20,14 +21,14 @@ Portfolio:
   Pallas splash-attention kernel, wrapped); the default core of
   `layers/latent_attention.py` wherever its shapes apply.
 """
-from .flash_attention import flash_attention, flash_attention_supported
+from .flash_attention import flash_attention, flash_attention_supported, packed_attention
 from .fused_adamw import fused_adamw_apply, fused_adamw_step
 from .augment_epilogue import augment_epilogue_supported, augment_image_batch_fused
 from .causal_attention import causal_flash_attention, causal_flash_supported
 from .registry import KernelCase, KernelSpec, all_specs, ensure_registered
 
 __all__ = [
-    'flash_attention', 'flash_attention_supported',
+    'flash_attention', 'flash_attention_supported', 'packed_attention',
     'fused_adamw_apply', 'fused_adamw_step',
     'augment_epilogue_supported', 'augment_image_batch_fused',
     'causal_flash_attention', 'causal_flash_supported',

@@ -1,11 +1,15 @@
 """Multi-head attention (reference: timm/layers/attention.py:1-293).
 
-TPU-first design: tokens are (B, N, C); the fused path dispatches to
-`jax.nn.dot_product_attention` (XLA flash lowering) or the local Pallas
-flash kernel (timm_tpu/kernels/flash_attention.py) when shapes allow; the
-manual path is plain einsum+softmax which XLA also fuses well. Selection is
-trace-time via `use_fused_attn()` — the reference's SDPA-vs-manual switch at
-attention.py:123-129.
+TPU-first design: tokens are (B, N, C). Where `flash_attention_supported`
+holds (a TPU backend, N <= 1024, no mask or a key-padding mask, no attention
+dropout, the float32 softmax policy, whole 128-lane head columns, one device)
+the core is the Pallas kernel pair of timm_tpu/kernels/flash_attention.py:
+`Attention` hands it the qkv product's output as it is and gives its output
+to the output product as it is, and `scaled_dot_product_attention` packs
+(B, H, N, D) operands onto that layout. Everything else takes the plain
+einsum + softmax path (`_sdpa`) or, above N = 1024, XLA's
+`jax.nn.dot_product_attention`. Selection is at trace time, by what the
+call can see — the reference's SDPA-vs-manual switch at attention.py:123-129.
 """
 from __future__ import annotations
 
@@ -87,12 +91,13 @@ def scaled_dot_product_attention(
     fused = use_fused_attn() if fused is None else fused
     if fused and dropout_p == 0.0:
         from ..kernels import flash_attention_supported, flash_attention
-        if flash_attention_supported(q, k, v, attn_mask):
-            return flash_attention(q, k, v, mask=attn_mask, scale=scale)
-        # At image-model sequence lengths the plain einsum+softmax graph beats
-        # jax.nn.dot_product_attention on v5e (measured ViT-B/16 @224 train:
-        # 867 vs 786 img/s/chip) — the N^2 score matrix is small enough that
-        # XLA's fusion of it wins over the generic attention lowering.
+        if q.ndim == 4 and q.shape == k.shape == v.shape and q.dtype == k.dtype == v.dtype:
+            B, H, N, D = q.shape
+            if flash_attention_supported(B, N, H, D, attn_mask, softmax_dtype=softmax_dtype, itemsize=q.dtype.itemsize):
+                return flash_attention(q, k, v, mask=attn_mask, scale=scale)
+        # What the kernel pair does not take at image-model lengths (additive or
+        # per-query masks, a softmax policy, heads that do not fill 128 lanes,
+        # a mesh over several devices) stays on the plain einsum + softmax graph.
         if q.shape[-2] <= 1024:
             return _sdpa(q, k, v, attn_mask, 0.0, None, scale, softmax_dtype)
         # XLA's fused path: expects (B, N, H, D)
@@ -148,10 +153,11 @@ class Attention(nnx.Module):
         self.proj = linear(dim, dim, use_bias=proj_bias)
         self.proj_drop = Dropout(proj_drop, rngs=rngs)
 
-    def _qkv(self, x):
+    def _heads(self, qkv):
+        """(B, N, 3 * H * D) -> q, k, v as (B, H, N, D), q / k normed: the plain path's operands."""
         from ..parallel import shard_activation
-        B, N, C = x.shape
-        qkv = self.qkv(x).reshape(B, N, 3, self.num_heads, self.head_dim)
+        B, N, _ = qkv.shape
+        qkv = qkv.reshape(B, N, 3, self.num_heads, self.head_dim)
         qkv = qkv.transpose(2, 0, 3, 1, 4)  # (3, B, H, N, D)
         # heads over 'model' matches the column-parallel qkv kernel split, so
         # scores/softmax/values never leave the owning tp shard
@@ -162,20 +168,47 @@ class Attention(nnx.Module):
             k = self.k_norm(k)
         return q, k, v
 
+    def _packed(self, qkv):
+        """The qkv product's output as the kernel pair takes it, (B, N, 3 * H * D): as it is, or with the
+        q / k norms applied on its (B, N, H, D) views (they act on the last axis)."""
+        if self.q_norm is None and self.k_norm is None:
+            return qkv
+        B, N, _ = qkv.shape
+        q, k, v = (qkv.reshape(B, N, 3, self.num_heads, self.head_dim)[:, :, i] for i in range(3))
+        q = q if self.q_norm is None else self.q_norm(q)
+        k = k if self.k_norm is None else self.k_norm(k)
+        return jnp.stack([q.astype(v.dtype), k.astype(v.dtype), v], axis=2).reshape(qkv.shape)
+
     def __call__(self, x, attn_mask=None):
+        from ..kernels import flash_attention_supported, packed_attention
         from ..parallel import shard_activation
         B, N, C = x.shape
+        dropout_p = 0.0 if self.attn_drop.deterministic else self.attn_drop_rate
         with tracing.scope('img.attn.qkv'):
-            q, k, v = self._qkv(x)
+            qkv = self.qkv(x)
+            # the kernel pair (kernels/flash_attention.py) where the call allows it: no head transpose before or
+            # after the core and no scores in HBM; the two counters say which core a traced call took
+            packed = flash_attention_supported(B, N, self.num_heads, self.head_dim, attn_mask, dropout_p=dropout_p,
+                                               softmax_dtype=self.softmax_dtype, itemsize=qkv.dtype.itemsize)
+            if packed:
+                tracing.count('attention.fused_calls')
+                qkv = self._packed(qkv)
+            else:
+                tracing.count('attention.plain_calls')
+                q, k, v = self._heads(qkv)
         with tracing.scope('img.attn.core'):
-            dropout_p = 0.0 if self.attn_drop.deterministic else self.attn_drop_rate
-            dropout_key = dropout_rng_key(self.attn_drop) if dropout_p > 0.0 else None
-            x = scaled_dot_product_attention(
-                q, k, v, attn_mask=attn_mask, dropout_p=dropout_p, dropout_key=dropout_key, scale=self.scale,
-                softmax_dtype=self.softmax_dtype,
-            )
+            if packed:
+                x = packed_attention(qkv, self.num_heads, attn_mask, self.scale)
+            else:
+                dropout_key = dropout_rng_key(self.attn_drop) if dropout_p > 0.0 else None
+                x = scaled_dot_product_attention(
+                    q, k, v, attn_mask=attn_mask, dropout_p=dropout_p, dropout_key=dropout_key, scale=self.scale,
+                    softmax_dtype=self.softmax_dtype,
+                )
         with tracing.scope('img.attn.proj'):
-            x = shard_activation(x.transpose(0, 2, 1, 3).reshape(B, N, C), 'hidden')
+            if not packed:
+                x = x.transpose(0, 2, 1, 3).reshape(B, N, C)
+            x = shard_activation(x, 'hidden')
             if self.norm is not None:
                 x = self.norm(x)
             x = self.proj(x)

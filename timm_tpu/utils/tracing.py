@@ -101,6 +101,8 @@ SPANS = {
     'step.update': ('step', 'device scope: the optimizer\'s moments and the parameter write'),
     'step.guard': ('step', 'device scope: the all-finite reduction and the selects over parameters, moments and EMA'),
     'step.ema': ('step', 'device scope: the EMA\'s lerp'),
+    'attention.fused_calls': ('attention', 'counter: `Attention` calls traced (or run eagerly) onto the kernel pair of kernels/flash_attention.py'),
+    'attention.plain_calls': ('attention', 'counter: `Attention` calls traced (or run eagerly) onto `scaled_dot_product_attention`: `_sdpa` or XLA\'s attention'),
     # step counters (`device_counter`): values computed inside the step program, returned in its metrics
     'moe.local_slots': ('experts', 'step counter: (token, expert) slots routed to experts held here, all expert layers'),
     'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),

@@ -1118,7 +1118,8 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
     "Reading the host breakdown"): mean wall ms per update of each part of the
     loop from the program's spans, and what the loader's threads did; before
     it, for a model with expert layers, how many of the step's `expert_layers`
-    took the worst-case dispatch buffer. -> (text, the counters now)."""
+    took the worst-case dispatch buffer; after `loop`, on a line since whose predecessor `Attention` calls were
+    traced, how many took the kernel pair (`fused`) and how many `_sdpa` (`plain`). -> (text, the counters now)."""
     from timm_tpu.utils import tracing
     snap = tracing.snapshot()
     rows = tracing.summary(since_ns, spans=snap['spans'])
@@ -1132,6 +1133,9 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
             f"put {ms('task.scalars_put', 'train.batch_to_device'):.1f} call {ms('task.step_call'):.1f} "
             f"update {ms('task.state_update'):.1f} poll {ms('task.sentinel_poll'):.1f} "
             f"loop {ms('train.bookkeeping', 'train.log_sync'):.1f}")
+    if did.get('attention.fused_calls') or did.get('attention.plain_calls'):
+        # `Attention` calls traced since the previous line (a run's first line: the step's) and the core each took
+        text += f" attn fused {did.get('attention.fused_calls', 0)} plain {did.get('attention.plain_calls', 0)}"
     if did.get('loader.samples') and did.get('loader.batches'):
         text += (f" | loader q {sum(depths) / max(len(depths), 1):.1f} "
                  f"decode {did['loader.decode_busy_ns'] / did['loader.samples'] / 1e6:.1f} ms/img "

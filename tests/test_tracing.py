@@ -214,12 +214,15 @@ def test_every_recorded_name_is_declared_and_every_declared_name_is_recorded_and
                 open(os.path.join(bench, 'harness', 'swa_lm_train_runner.py')).read(),
                 open(os.path.join(bench, 'harness', 'bd_lm_readers.py')).read(),     # the block-diffusion cell's: `swa.attn.core_bd`, `attn.bd_blocks`
                 open(os.path.join(bench, 'harness', 'bd_lm_train_runner.py')).read(),  # its `correct`: `lm.noised_masked`, `lm.masked_nll`
+                open(os.path.join(bench, 'harness', 'cla_lm_readers.py')).read(),    # the chunk-pooled cell's: `evabyte.*`, `attn.eva_*`
+                open(os.path.join(bench, 'harness', 'cla_lm_train_runner.py')).read(),  # its `correct`: `lm.head_nll`
                 open(os.path.join(bench, 'harness', 'step_scopes.py')).read(),       # every cell's `step.*`, the image cells' `img.*`: `img.block`
                 inspect.getsource(train._host_line), inspect.getsource(train._setup_line)]
     unread = [name for name in tracing.SPANS if not any(f"'{name}'" in text for text in readers)]
     assert not unread, unread
     # the layers are the ones PERF.md section 3 and BENCHMARK.json name
-    assert {layer for layer, _ in tracing.SPANS.values()} == {'entry and compile cache', 'input', 'step', 'attention', 'experts'}
+    assert {layer for layer, _ in tracing.SPANS.values()} == {'entry and compile cache', 'input', 'step', 'attention', 'experts',
+                                                             'feed-forward'}
 
 
 @pytest.mark.parametrize('record', [lambda n: tracing.scope(n), lambda n: tracing.device_counter(n, 1)], ids=['scope', 'device_counter'])
@@ -249,13 +252,16 @@ def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_t
     after = tracing.snapshot()
     assert len(after['spans']) - len(before['spans']) <= 1 and after['counters'] == before['counters']   # the ring is not theirs
     kinds = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('glm.', 'moe.', 'lm.'))}
-    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 16      # two of the block-diffusion task
+    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 17      # two of the block-diffusion task, `lm.head_nll`
     # ONE kind of device scope: the window/full family's three were 'swa device scope' while `tracing.py` could not be
     # edited by the PRs that met `test_lm_harness.py`'s pin of the GLM reduction's nine (a superset since PR 35)
     swa = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('swa.', 'attn.'))}
     assert swa == {'swa.attn.proj': 'device scope', 'swa.attn.core_full': 'device scope',
                    'swa.attn.core_window': 'device scope', 'swa.attn.core_bd': 'device scope',
-                   'attn.full_blocks': 'step counter', 'attn.window_blocks': 'step counter', 'attn.bd_blocks': 'step counter'}
+                   'attn.full_blocks': 'step counter', 'attn.window_blocks': 'step counter', 'attn.bd_blocks': 'step counter',
+                   'attn.eva_blocks': 'step counter', 'attn.eva_pairs': 'step counter'}
+    eva = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith('evabyte.')}
+    assert eva == dict.fromkeys(('evabyte.attn.proj', 'evabyte.attn.summary', 'evabyte.attn.core', 'evabyte.ffn'), 'device scope')
     assert {what.split(':')[0] for _, what in tracing.SPANS.values() if 'scope' in what.split(':')[0]} == {'device scope'}
     assert all(tracing.SPANS[name][0] == 'attention' for name in swa)
     # the image models' and the step's own: seventeen, family-neutral, each under the layer its metric names

@@ -3,8 +3,11 @@ scale + mask) v without the (heads, S, S) scores, forward and backward. The
 mask is causal, or causal AND within a window of the last `window` positions
 (key j is seen by query i when j <= i and i - j < window), or the
 block-diffusion mask over a noised copy beside the clean sequence
-(`block_diffusion_seen`); the query heads may be a multiple of the key/value
-heads (grouped-query attention: query head g reads key/value head g // group).
+(`block_diffusion_seen`), or the chunk-window mask of a chunk-pooled linear
+attention (`chunk_window_seen`: S / chunk summary keys before the S single
+ones; a query sees the single keys of its own window causally and the
+summaries of every earlier window); the query heads may be a multiple of the
+key/value heads (grouped-query attention: query head g reads key/value head g // group).
 
 The Pallas kernel is JAX's own splash attention
 (`jax.experimental.pallas.ops.tpu.splash_attention`: blocked online softmax in
@@ -36,6 +39,12 @@ BLOCK_COMPUTE = 512    # key/value columns a kernel step multiplies at once
 RESIDUALS = 'mla_core_out'   # checkpoint_name of the kernel's output and log-sum-exp, for a remat policy
 
 
+def _block(seq: int, chunk_window=None) -> int:
+    """The kernel's query and key/value block for `seq` positions: `BLOCK`, a shorter sequence as one block;
+    under the chunk-window mask also no longer than the seq // chunk summaries, which then fill whole blocks."""
+    return min(BLOCK, seq if chunk_window is None else seq // chunk_window[1])
+
+
 def block_diffusion_seen(q_ids, kv_ids, length: int, block: int):
     """The block-diffusion mask (BD3-LM arXiv:2503.09573) over 2 x `length` rows, `length` noised ones and then
     the `length` clean ones; row r carries position r mod length, in block (r mod length) // block. A noised
@@ -47,14 +56,42 @@ def block_diffusion_seen(q_ids, kv_ids, length: int, block: int):
     return (k_clean & ((bk < bq) | (q_clean & (bk == bq)))) | (~q_clean & ~k_clean & (bk == bq))
 
 
-def causal_flash_supported(q, k, v, window=None, block_diffusion=None) -> bool:
+def chunk_window_seen(q_ids, kv_ids, length: int, window: int, chunk: int):
+    """The chunk-window mask of a chunk-pooled linear attention (EVA, arXiv:2302.04542, in the deterministic form
+    with one softmax over both kinds of key): `length` queries on `length // chunk` summary keys, chunk j's at
+    index j, and then the `length` single keys, position t's at index length // chunk + t. Query i, in window
+    i // window, sees the single keys of its OWN window up to itself and the summaries of every chunk of every
+    EARLIER window, none of its own. Written with operators alone (splash attention calls it on NumPy index grids
+    when it builds its block map and on the kernel's own index tiles), and with the query's window taken by a bit
+    mask and a shift where `window` and `chunk` are powers of two: the kernel evaluates this on every index tile it
+    visits, and a division there costs more than the tile's products (PERF.md section 6, PR 37)."""
+    summaries = length // chunk
+    if window & (window - 1) == 0 and chunk & (chunk - 1) == 0:
+        first = q_ids & -window                                     # the first position of the query's window
+        before = first >> (chunk.bit_length() - 1)                  # summaries of the windows before it
+    else:
+        first = q_ids // window * window
+        before = first // chunk
+    t = kv_ids - summaries
+    return ((t >= first) & (t <= q_ids)) | (kv_ids < before)
+
+
+def causal_flash_supported(q, k, v, window=None, block_diffusion=None, chunk_window=None) -> bool:
     """Shapes the kernel takes: q (B, H, S, D), k and v (B, H_kv, S, D) with one S and one D, H a multiple of
     H_kv, D a multiple of the 128 lanes, S a multiple of its block; a window of at least one position. With
     `block_diffusion` (the block length) k and v hold 2 L rows, L noised and L clean, and q all of them or the
-    L noised ones alone (a last layer's); L is then what the block has to divide."""
+    L noised ones alone (a last layer's); L is then what the block has to divide. With `chunk_window` (a window
+    and a chunk length) k and v hold S // chunk summaries and then the S single keys; the window divides S, the
+    chunk the window, and the block (`_block`: the summaries' count where that is under `BLOCK`) both kinds."""
     if q.ndim != 4 or k.ndim != 4 or k.shape != v.shape or (window is not None and window < 1):
         return False
     (B, H, S, D), H_kv = q.shape, k.shape[1]
+    if chunk_window is not None:
+        (win, chunk), block = chunk_window, _block(S, chunk_window)
+        if window is not None or block_diffusion is not None or win < 1 or chunk < 1 or S % win or win % chunk \
+                or k.shape != (B, H_kv, S // chunk + S, D) or H % H_kv:
+            return False
+        return D % 128 == 0 and block % 128 == 0 and (S // chunk) % block == 0 and S % block == 0
     if block_diffusion is not None:
         L = k.shape[2] // 2
         if window is not None or block_diffusion < 1 or k.shape[2] != 2 * L or L % block_diffusion or S not in (L, 2 * L):
@@ -103,18 +140,42 @@ def _block_diffusion_mask(rows: int, length: int, block: int):
     return BlockDiffusionMask()
 
 
-def _kernel(heads: int, seq: int, interpret: bool, window=None, grouped: bool = False, block_diffusion=None, rows=None):
+@functools.lru_cache(maxsize=None)
+def _chunk_window_mask(length: int, window: int, chunk: int):
+    """`chunk_window_seen` as splash attention's computable mask: `length` queries on length // chunk + length
+    keys; a query row's entry of `q_sequence` is its index."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
+
+    class ChunkWindowMask(sm._ComputableMask):
+        def __init__(self):
+            super().__init__(shape=(length, length // chunk + length),
+                             mask_function=lambda q_ids, kv_ids: chunk_window_seen(q_ids, kv_ids, length, window, chunk))
+
+        def __eq__(self, other):
+            return type(other) is type(self)        # one class a (length, window, chunk): the cache above
+
+        def __hash__(self):
+            return hash((type(self), self.shape, window, chunk))
+
+    return ChunkWindowMask()
+
+
+def _kernel(heads: int, seq: int, interpret: bool, window=None, grouped: bool = False, block_diffusion=None, rows=None,
+            chunk_window=None):
     """The splash kernel over `heads` query heads: one key/value head a query head, or with `grouped` one
     key/value head for all of them (the multi-query form). With `block_diffusion` `seq` is L, the keys are
-    2 L and the queries `rows`."""
+    2 L and the queries `rows`; with `chunk_window` the keys are seq // chunk summaries and then the seq single ones."""
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
     from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as sm
-    block, compute = min(BLOCK, seq), min(BLOCK_COMPUTE, seq)
+    block = _block(seq, chunk_window)
+    compute = min(BLOCK_COMPUTE, block)
     sizes = sk.BlockSizes(block_q=block, block_kv=block, block_kv_compute=compute,
                           block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=compute,
                           block_q_dq=block, block_kv_dq=block)
     if block_diffusion is not None:
         one = _block_diffusion_mask(rows, seq, block_diffusion)
+    elif chunk_window is not None:
+        one = _chunk_window_mask(seq, *chunk_window)
     else:
         one = sm.CausalMask((seq, seq)) if window is None or window >= seq else sm.LocalMask((seq, seq), (window - 1, 0), 0)
     make = sk.make_splash_mqa if grouped else sk.make_splash_mha
@@ -122,19 +183,25 @@ def _kernel(heads: int, seq: int, interpret: bool, window=None, grouped: bool = 
                 residual_checkpoint_name=RESIDUALS, interpret=interpret)
 
 
-def causal_flash_attention(q, k, v, scale: float, window=None, with_tiles: bool = False, block_diffusion=None):
+def causal_flash_attention(q, k, v, scale: float, window=None, with_tiles: bool = False, block_diffusion=None,
+                           chunk_window=None):
     """q (B, H, S, D), k, v (B, H_kv, S, D) -> (B, H, S, D), causal over S and, with `window`, within the last
     `window` positions; or, with `block_diffusion` (the block length), q over 2 L rows or the L noised ones on
-    k, v (B, H_kv, 2 L, D) under `block_diffusion_seen`. Softmax in float32 inside the kernel. `with_tiles`
+    k, v (B, H_kv, 2 L, D) under `block_diffusion_seen`; or, with `chunk_window` (a window and a chunk length), q
+    on k, v (B, H_kv, S // chunk + S, D), summaries first, under `chunk_window_seen`: ONE softmax over both kinds
+    of key, and the gradient reaches the summaries as it reaches any key. Softmax in float32 inside the kernel. `with_tiles`
     also returns how many (query block, key block) tiles of one sequence hold an unmasked pair, read from the
     kernel's own forward block map: the tiles it multiplies, the rest it skips."""
-    if not causal_flash_supported(q, k, v, window, block_diffusion):
+    if not causal_flash_supported(q, k, v, window, block_diffusion, chunk_window):
         raise ValueError(f'causal_flash_attention does not take q {q.shape} k {k.shape} v {v.shape} window {window} '
-                         f'block_diffusion {block_diffusion}')
+                         f'block_diffusion {block_diffusion} chunk_window {chunk_window}')
     (B, H, S, D), (H_kv, S_kv) = q.shape, k.shape[1:3]
     interpret = jax.default_backend() != 'tpu'                                  # CPU tests run it interpreted
     q = q * jnp.asarray(scale, q.dtype)
-    mask = dict(window=window) if block_diffusion is None else dict(block_diffusion=block_diffusion, rows=S)
+    if chunk_window is not None:
+        mask = dict(chunk_window=tuple(chunk_window))
+    else:
+        mask = dict(window=window) if block_diffusion is None else dict(block_diffusion=block_diffusion, rows=S)
     length = S if block_diffusion is None else S_kv // 2
     if H == H_kv:
         kernel = _kernel(H, length, interpret, **mask)
@@ -148,13 +215,13 @@ def causal_flash_attention(q, k, v, scale: float, window=None, with_tiles: bool 
 
 
 @functools.lru_cache(maxsize=None)
-def _tiles(seq: int, window=None, block_diffusion=None, rows=None) -> int:
+def _tiles(seq: int, window=None, block_diffusion=None, rows=None, chunk_window=None) -> int:
     """(query block, key block) tiles with an unmasked pair in the forward block map of the kernel `_kernel`
     builds for this length and mask (one head's: the heads' masks are alike). Built eagerly: inside a
     trace the kernel's own copy of the map is a traced constant."""
     import numpy as np
     with jax.ensure_compile_time_eval():
-        kernel = _kernel(1, seq, True, window, block_diffusion=block_diffusion, rows=rows)
+        kernel = _kernel(1, seq, True, window, block_diffusion=block_diffusion, rows=rows, chunk_window=chunk_window)
         block_map = np.asarray(kernel.fwd_mask_info.block_mask)     # (1, query blocks, key blocks visited)
     return int((block_map[0] != 0).sum())
 
@@ -163,9 +230,12 @@ def _tiles(seq: int, window=None, block_diffusion=None, rows=None) -> int:
 # registry entry
 
 
-def _registry_reference(q, k, v, window=None, block_diffusion=None):
+def _registry_reference(q, k, v, window=None, block_diffusion=None, chunk_window=None):
+    from ..layers.chunked_linear_attention import chunk_window_attention
     from ..layers.grouped_attention import grouped_block_diffusion_attention, grouped_causal_attention
     from ..layers.latent_attention import causal_attention
+    if chunk_window is not None:
+        return chunk_window_attention(q, k, v, q.shape[-1] ** -0.5, *chunk_window)
     if block_diffusion is not None:
         return grouped_block_diffusion_attention(q, k, v, q.shape[-1] ** -0.5, block_diffusion)
     if window is None and q.shape == k.shape:
@@ -173,16 +243,18 @@ def _registry_reference(q, k, v, window=None, block_diffusion=None):
     return grouped_causal_attention(q, k, v, q.shape[-1] ** -0.5, window)
 
 
-def _registry_kernel(q, k, v, window=None, block_diffusion=None):
-    return causal_flash_attention(q, k, v, q.shape[-1] ** -0.5, window, block_diffusion=block_diffusion)
+def _registry_kernel(q, k, v, window=None, block_diffusion=None, chunk_window=None):
+    return causal_flash_attention(q, k, v, q.shape[-1] ** -0.5, window, block_diffusion=block_diffusion,
+                                  chunk_window=chunk_window)
 
 
 def _registry_inputs(seed: int = 0, batch: int = 1, heads: int = 2, seq: int = 256, head_dim: int = 128,
-                     dtype: str = 'float32', kv_heads: int = None):
+                     dtype: str = 'float32', kv_heads: int = None, summaries: int = 0):
+    """`summaries` more key/value rows than queries (the chunk-window mask's summary keys, before the single ones)."""
     import numpy as np
     rng = np.random.default_rng(seed)
-    q, k, v = (jnp.asarray(rng.standard_normal((batch, h, seq, head_dim)) * 0.5, dtype)
-               for h in (heads, kv_heads or heads, kv_heads or heads))
+    q, k, v = (jnp.asarray(rng.standard_normal((batch, h, rows, head_dim)) * 0.5, dtype)
+               for h, rows in ((heads, seq), (kv_heads or heads, summaries + seq), (kv_heads or heads, summaries + seq)))
     return dict(q=q, k=k, v=v)
 
 
@@ -232,6 +304,16 @@ def _register():
                      'beside 8192 clean ones, blocks of 4, 80 of 256 tiles visited; v5e, one layer: forward 11.8 ms '
                      'against the XLA query-block path\'s 61.1, forward and backward 47.9 against 219.7; a last layer\'s '
                      'noised queries alone (44 tiles) 6.6 / 26.5 against 34.1 / 120.0 (PR 37)',
+            ),
+            KernelCase(
+                name='chunk_window2048_16_s16384_d128',
+                dry=dict(batch=1, heads=1, seq=4096, summaries=256, head_dim=128),
+                live=dict(batch=1, heads=16, seq=16384, summaries=1024, head_dim=128, dtype='bfloat16'),
+                statics=dict(chunk_window=(2048, 16)),
+                desc='EvaByte chunk-pooled layer, one chip\'s 16 of 32 heads: 16384 queries on 1024 chunk summaries and '
+                     'then the 16384 single keys, windows of 2048, 38 of 16 x 17 tiles visited (the dry case runs two '
+                     'windows in blocks of 256); v5e, one layer: forward 2.50 ms against the XLA query-block path\'s 9.93, forward and '
+                     'backward 11.00 against 30.34 (PR 41)',
             ),
         ),
         backends=('tpu',),

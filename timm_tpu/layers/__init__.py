@@ -58,4 +58,5 @@ from .weight_init import lecun_normal_, ones_, trunc_normal_, trunc_normal_tf_, 
 from .hybrid_embed import HybridEmbed
 from .latent_attention import LatentAttention, causal_attention
 from .grouped_attention import GroupedQueryAttention, grouped_block_diffusion_attention, grouped_causal_attention
+from .chunked_linear_attention import ChunkedLinearAttention, chunk_summaries, chunk_window_attention, chunk_window_pairs
 from .moe import SparseMoe

@@ -64,6 +64,7 @@ from .repghost import RepGhostNet
 from .vovnet import VovNet
 from .pit import PoolingVisionTransformer
 from .inception_v4 import InceptionV4
+from .evabyte import EvaByte
 from .glm4_moe_lite import Glm4MoeLite
 from .sdar_moe import SdarMoe
 from .smallthinker import SmallThinker

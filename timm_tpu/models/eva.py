@@ -3,6 +3,9 @@
 ViT with rotary position embeddings (shared per-model ROPE table, applied to
 non-prefix tokens), optional SwiGLU MLP with inner norm, and pre/post-norm
 block options. Covers the eva02 family (the reference zoo's top-1 leader).
+
+Not `models/evabyte.py` (EvaByte, a byte-level language model whose mixer is the
+linear attention EVA, arXiv:2302.04542): the two share a name and nothing else.
 """
 from __future__ import annotations
 

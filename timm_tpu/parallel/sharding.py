@@ -108,7 +108,10 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
       8. tokens & position embeddings     -> replicate
       9. stacked expert kernels (E, in, out) -> 'fsdp' on the output dim
      10. a router's kernel                -> replicate (every chip scores alike)
-     11. everything else                  -> replicate (catch-all)
+     11. a chunk-pooled attention's two learned vectors a head (`attn.phi`, `attn.mu`, (heads, head_dim))
+                                          -> replicate (2 x heads x head_dim numbers; the compiler slices
+                                             them to the heads a chip's q/k/v columns hold)
+     12. everything else                  -> replicate (catch-all)
 
     Rules 1-4 fall back to 'fsdp_largest' placement when the mesh has no
     'model' axis, so tp=1 reproduces the 2-axis table exactly.
@@ -135,6 +138,7 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
         # `layers/moe.py` holds its experts as three bare stacks, not as Linears, and its router as a bare kernel
         PartitionRule(r'\.mlp\.(?:w_gate|w_up|w_down)$', 'fsdp_largest', name='expert-stack'),
         PartitionRule(r'\.mlp\.router$', 'replicate', name='router'),
+        PartitionRule(r'\.attn\.(?:phi|mu)$', 'replicate', name='head-vector'),
         PartitionRule(r'.*', 'replicate', name='catch-all'),
     )
 

@@ -9,6 +9,13 @@ MTP module at position i sees the trunk's output and the embedding of
 valid positions, in float32; the output head and the cross-entropy run in
 chunks of the sequence, each rematerialised in the backward pass, so the
 (tokens, vocabulary) logits of a whole batch never exist.
+
+A model with `num_pred_heads` P > 1 (EvaByte: P linear heads from ONE product,
+the logits head-major) is trained on P targets a position: head p's is the id
+p + 1 positions on, `target[i + p]`, and IGNORE where that runs past the window
+the feed gave (the last p + 1 positions). The loss is the equal-weight mean of
+the P heads' means over their own valid positions; `loss_main` is head 0's, the
+next-token loss, and the step counter `lm.head_nll` carries all P.
 """
 from __future__ import annotations
 
@@ -27,15 +34,26 @@ __all__ = ['CausalLMTask', 'IGNORE']
 IGNORE = -1
 
 
+def head_targets(target, heads: int):
+    """target (B, S), `target[i]` the id after position i -> (B, S, heads): head p's target at i is the id
+    p + 1 positions on, IGNORE where the window ends before it."""
+    return jnp.stack([jnp.pad(target[:, p:], ((0, 0), (0, p)), constant_values=IGNORE) for p in range(heads)], axis=-1)
+
+
 def _ce_sums(logits, target, topk: bool = False, weight=None):
     """Summed cross-entropy over positions whose target is not IGNORE (float32),
     with `topk` the top-1 / top-5 hits there, and with `weight` (a float a
-    position) the weighted sum beside the plain one."""
+    position) the weighted sum beside the plain one. A target (B, S, P) of P
+    prediction heads takes logits (B, S, P * V), head-major, and gives each
+    sum a head, (P,)."""
+    over = None
+    if target.ndim == 3:
+        logits, over = logits.reshape(*target.shape, -1), (0, 1)
     valid = target != IGNORE
     safe = jnp.where(valid, target, 0)
     logits = logits.astype(jnp.float32)
     nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-    out = {'loss_sum': jnp.where(valid, nll, 0.0).sum()}
+    out = {'loss_sum': jnp.where(valid, nll, 0.0).sum(over)}
     if weight is not None:
         out['weighted_sum'] = jnp.where(valid, nll * weight, 0.0).sum()
     if topk:
@@ -66,10 +84,18 @@ class CausalLMTask(TrainingTask):
     def loss_forward(self, model: nnx.Module, batch: Dict[str, Any]):
         ids, target = batch['input'], batch['target']
         h, counters = model.forward_features(ids, with_counters=True)
+        heads = getattr(model, 'num_pred_heads', 1)
         with tracing.scope('glm.head_loss'):
-            main = self._head_loss(model, h, target, lambda m, hc: m.forward_head(hc))
-            loss = main['loss_sum'] / jnp.maximum((target != IGNORE).sum(), 1)
-        output = {'loss_main': loss}
+            if heads > 1:
+                targets = head_targets(target, heads)
+                main = self._head_loss(model, h, targets, lambda m, hc: m.forward_head(hc))
+                each = main['loss_sum'] / jnp.maximum((targets != IGNORE).sum((0, 1)), 1)
+                loss, first = each.mean(), each[0]
+                counters = dict(counters, **{'lm.head_nll': tracing.device_counter('lm.head_nll', each)})
+            else:
+                main = self._head_loss(model, h, target, lambda m, hc: m.forward_head(hc))
+                loss = first = main['loss_sum'] / jnp.maximum((target != IGNORE).sum(), 1)
+        output = {'loss_main': first}
         if getattr(model, 'mtp', None) is not None and self.mtp_loss_weight:
             # the module's input at i is the embedding of target[i]; it predicts target[i + 1]
             next_ids = jnp.where(target == IGNORE, 0, target)
@@ -91,5 +117,6 @@ class CausalLMTask(TrainingTask):
     def eval_forward(self, model: nnx.Module, batch: Dict[str, Any]):
         """Sums over the batch's valid positions: next-token loss, top-1 and top-5 hits, and their count."""
         h = model.forward_features(batch['input'])
-        sums = self._head_loss(model, h, batch['target'], lambda m, hc: m.forward_head(hc), topk=True)
+        vocab = model.vocab_held        # of several prediction heads the first, the next-token one, is scored
+        sums = self._head_loss(model, h, batch['target'], lambda m, hc: m.forward_head(hc)[..., :vocab], topk=True)
         return dict(sums, count=(batch['target'] != IGNORE).sum())

@@ -82,6 +82,10 @@ SPANS = {
     'swa.attn.core_full': ('attention', 'device scope: the causal core of a full (position-free) layer, forward and backward'),
     'swa.attn.core_window': ('attention', 'device scope: the causal core of a window layer, forward and backward'),
     'swa.attn.core_bd': ('attention', 'device scope: the core under the block-diffusion mask (a noised copy beside the clean sequence), forward and backward'),
+    'evabyte.attn.proj': ('attention', 'device scope: the q/k/v/o products of the heads held, the norm before them, the rotary turn, the float32 residual add'),
+    'evabyte.attn.summary': ('attention', 'device scope: the chunk softmax against a head\'s learned vector and the two pooled sums (summary keys and values), forward and backward'),
+    'evabyte.attn.core': ('attention', 'device scope: the joint softmax over a window\'s single keys and the earlier windows\' summaries, forward and backward'),
+    'evabyte.ffn': ('feed-forward', 'device scope: the dense SwiGLU of every layer with the norm before it and its float32 residual add'),
     # the image models' scopes, on the shared layers (every model built from them has them), and the step's own,
     # which every task runs. The innermost scope of an op counts: `img.block` holds what no inner scope takes
     'img.patch_embed': ('step', 'device scope: the patch convolution, class / register tokens, position embedding, the norm before the blocks'),
@@ -112,6 +116,9 @@ SPANS = {
     'attn.full_blocks': ('attention', 'step counter: (query block, key block) tiles with an unmasked pair that the full cores multiply in the forward pass, all layers and sequences'),
     'attn.window_blocks': ('attention', 'step counter: the same for the window cores, from the kernel\'s block map or the XLA path\'s slices'),
     'attn.bd_blocks': ('attention', 'step counter: the same for the cores under the block-diffusion mask'),
+    'attn.eva_blocks': ('attention', 'step counter: the same for the cores under the chunk-window mask (queries on summaries and single keys)'),
+    'attn.eva_pairs': ('attention', 'step counter: (query, key) pairs the chunk-window mask leaves, single keys and summaries, all heads held, layers and sequences (float32: from the shapes alone)'),
+    'lm.head_nll': ('step', 'step counter: the mean cross-entropy of each of a model\'s `num_pred_heads` prediction heads over its own valid positions, a vector; over micro-batches the means add'),
     'lm.noised_masked': ('step', 'step counter: positions of the step\'s noised copies that hold the mask token'),
     'lm.masked_nll': ('step', 'step counter: the cross-entropy summed over those positions, unweighted (over `lm.noised_masked`: the mean a masked position)'),
 }

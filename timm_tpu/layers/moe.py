@@ -20,7 +20,16 @@ No token is dropped under any routing: the (token, choice) slots are sorted by
 held expert, the local ones first, and the first `C` of that order are gathered
 into a buffer of `C` rows, on which the three gated-MLP products run as grouped
 products over the experts held (`jax.lax.ragged_dot`, group sizes = slots an
-expert). `C` follows the share of the experts the layer holds, not the worst
+expert, counted by comparing the slots' keys with the held ids). No row is
+scattered, forward or backward: `order` is a permutation of the slots, its
+inverse `argsort(order)` says at which buffer row a slot's result lies, and a
+token's result is the sum, at float32, of the rows its `top_k` slots point at
+(a row gather a choice, masked to the live rows). The gather into the buffer
+and the gather-sum out of it are each other's transpose, so the two are a
+`jax.custom_vjp` pair (`_to_buffer`, `_to_tokens`) in which each is the
+other's derivative: two functions serve the four passes.
+
+`C` follows the share of the experts the layer holds, not the worst
 case: twice the mean load, `2 * T * top_k * experts_held / num_experts` rounded
 up to `TILE` rows and at most `T * top_k` (`dispatch_rows`). A layer whose local
 slots do not fit in `C` rows takes the same path over all `T * top_k` rows (the
@@ -47,6 +56,12 @@ __all__ = ['SparseMoe', 'route', 'merge_counters', 'dispatch_rows']
 
 ACTIVATIONS = {'silu': jax.nn.silu, 'relu': jax.nn.relu}
 TILE = 128      # rows: the dispatch buffer is whole tiles of the grouped products' row dimension
+LANES = 128     # columns of one lane tile
+# A row gather runs some five times faster from a source the compiler holds in the chip's fast memory than from
+# one in HBM (PERF.md section 6, PR 42; a v5e has 128 MiB of it, which a source shares with its neighbours): one
+# of up to half of it the compiler puts there whole, a larger one only in pieces, and none that exceeds it
+FAST_BYTES = 128 << 20
+PIECE_BYTES = 48 << 20
 
 
 def dispatch_rows(rows: int, experts_held: int, num_experts: int) -> int:
@@ -77,12 +92,64 @@ def route(scores_in, router_kernel, bias, top_k: int, scaling: float, scoring: s
     return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
 
 
+def _group_sizes(slot_expert, held: int):
+    """Slots an expert held here: a comparison count of the `T * k` keys against the `held` ids, int32 (the
+    key `held` says held elsewhere and is counted nowhere)."""
+    return (slot_expert[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+
+
+def _column_pieces(n: int, dim: int, itemsize: int) -> list:
+    """Column bounds of the pieces a gather-sum reads its (n, dim) source in: one piece, but for a source between
+    half and all of `FAST_BYTES`, which is read in pieces of at most `PIECE_BYTES`, whole lane columns each."""
+    size = n * dim * itemsize
+    pieces = -(-size // PIECE_BYTES) if FAST_BYTES // 2 < size <= FAST_BYTES and dim % LANES == 0 else 1
+    return [dim // LANES * c // pieces * LANES for c in range(pieces)] + [dim]
+
+
+# The two moves between token order and buffer order, over one set of integer operands: `token[r]` is the token
+# whose slot lies at buffer row `r`, `pos[t, j]` the buffer row of token t's j-th slot (the same permutation read
+# both ways), and rows from `covered` on are dead.
+def _take_rows(x, token, pos, covered):
+    """x (T, dim) -> (n, dim): row r is x[token[r]] for r < covered, zero past it."""
+    with tracing.scope('glm.moe.route'):
+        return jnp.where((jnp.arange(token.shape[0]) < covered)[:, None], x[token], 0)
+
+
+def _sum_rows(rows, token, pos, covered):
+    """rows (n, dim) -> (T, dim): token t's row is the sum over its `k` slots j of rows[pos[t, j]], of the slots
+    that lie below row `covered` (`covered <= n`); added choice by choice at float32, cast once."""
+    with tracing.scope('glm.moe.route'):
+        n, dim = rows.shape
+        bounds = _column_pieces(n, dim, rows.dtype.itemsize)
+        dead = (pos >= covered).T[:, :, None]
+        at = jnp.minimum(pos, n - 1).T
+
+        def summed(piece):
+            return sum(jnp.where(off, 0, piece[p]).astype(jnp.float32) for p, off in zip(at, dead)).astype(rows.dtype)
+
+        pieces = [summed(rows[:, lo:hi]) for lo, hi in zip(bounds, bounds[1:])]
+        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
+
+
+def _transposes(move, back):
+    """`move` as a function whose derivative is `back`: each scatters what the other gathers, so neither pass
+    scatters a row. The integer operands take no cotangent."""
+    f = jax.custom_vjp(move)
+    f.defvjp(lambda x, *at: (move(x, *at), at), lambda at, d: (back(d, *at), None, None, None))
+    return f
+
+
+_to_buffer, _to_tokens = _transposes(_take_rows, _sum_rows), _transposes(_sum_rows, _take_rows)
+
+
 @functools.partial(jax.jit, static_argnames=('n', 'top_k', 'activation'))
-def _dispatch(x, order, group_sizes, slot_weight, w_gate, w_up, w_down, *, n: int, top_k: int, activation: str):
+def _dispatch(x, order, pos, group_sizes, slot_weight, w_gate, w_up, w_down, *, n: int, top_k: int, activation: str):
     """The held experts' part of the result from the first `n` slots of `order` (local slots first, by expert),
-    and how many local slots those rows hold. A function of its own under `jax.jit`: layers of one shape share
-    one trace of it, of its derivative and of its lowering, which is most of what the second buffer size would
-    otherwise add to a program's set-up."""
+    and how many local slots those rows hold. Rows go into the buffer by a gather through `order` and come back
+    by a gather through its inverse `pos = argsort(order)` (`_to_buffer`, `_to_tokens`: a `custom_vjp` pair, each
+    the other's derivative). A function of its own under `jax.jit`: layers of one shape share one trace of it,
+    of its derivative and of its lowering, which is most of what the second buffer size would otherwise add to a
+    program's set-up."""
     with tracing.scope('glm.moe.route'):
         slots = order[:n]
         token = slots // top_k
@@ -91,7 +158,7 @@ def _dispatch(x, order, group_sizes, slot_weight, w_gate, w_up, w_down, *, n: in
         # alike (the TPU kernel skips their tiles): every operand and result is masked to the live rows,
         # so that nothing undefined reaches a token, forward or backward
         live = (jnp.arange(n) < covered)[:, None]
-        xs = jnp.where(live, x[token].astype(w_gate.dtype), 0)
+        xs = _to_buffer(x, token, pos, covered).astype(w_gate.dtype)
     with tracing.scope('glm.moe.experts'):
         gate = jax.lax.ragged_dot(xs, w_gate, group_sizes)
         up = jax.lax.ragged_dot(xs, w_up, group_sizes)
@@ -99,7 +166,7 @@ def _dispatch(x, order, group_sizes, slot_weight, w_gate, w_up, w_down, *, n: in
         ys = jax.lax.ragged_dot(hidden, w_down, group_sizes)
     with tracing.scope('glm.moe.route'):
         ys = jnp.where(live, ys * slot_weight[slots][:, None].astype(ys.dtype), 0)
-        y = jnp.zeros(x.shape, ys.dtype).at[token].add(ys)
+        y = _to_tokens(ys, token, pos, covered)
     return y, covered
 
 
@@ -169,11 +236,12 @@ class SparseMoe(nnx.Module):
             local = (idx >= self.expert_offset) & (idx < self.expert_offset + held)
             slot_expert = jnp.where(local, idx - self.expert_offset, held).reshape(-1)   # `held` = held elsewhere
             order = jnp.argsort(slot_expert, stable=True)          # local slots first, by expert
-            group_sizes = jnp.bincount(slot_expert, length=held + 1)[:held].astype(jnp.int32)
+            pos = jnp.argsort(order).reshape(-1, k)                # its inverse: the buffer row of a token's slots
+            group_sizes = _group_sizes(slot_expert, held)
             slot_weight = jnp.where(local, weights, 0.0).reshape(-1)
-            local_slots = local.sum().astype(jnp.int32)
+            local_slots = group_sizes.sum()
         with tracing.scope('glm.moe.experts'):
-            operands = (x, order, group_sizes, slot_weight,
+            operands = (x, order, pos, group_sizes, slot_weight,
                         self.w_gate[...].astype(dt), self.w_up[...].astype(dt), self.w_down[...].astype(dt))
         dispatch = functools.partial(_dispatch, top_k=k, activation=self.activation)
         if bound == rows:

@@ -100,7 +100,8 @@ def analysis_programs():
 
 # ten traced steps' busy seconds on the v5e of the cells `test_step_mfu.py`'s `BUSY_S` does not know: that file gives
 # an unknown cell one second in ten steps and asks for a share in (0, 100); this cell's step needs 69.8 TFLOP
-LATER_CELLS_BUSY_S = {'evabyte_6b5_hp2_train_16k': 7.178}      # busy_s of ten traced steps (my chip run, PR 41, call A)
+LATER_CELLS_BUSY_S = {'evabyte_6b5_hp2_train_16k': 7.178,      # busy_s of ten traced steps (my chip run, PR 41, call A)
+                      'lfm2_8b_a1b_ep4_train_8k': 6.707}       # busy_s of ten traced steps (my chip run, PR 43, call R1); this cell's step needs 42.5-43.5 TFLOP
 
 
 @pytest.fixture(autouse=True)

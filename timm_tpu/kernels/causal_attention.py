@@ -78,7 +78,9 @@ def chunk_window_seen(q_ids, kv_ids, length: int, window: int, chunk: int):
 
 def causal_flash_supported(q, k, v, window=None, block_diffusion=None, chunk_window=None) -> bool:
     """Shapes the kernel takes: q (B, H, S, D), k and v (B, H_kv, S, D) with one S and one D, H a multiple of
-    H_kv, D a multiple of the 128 lanes, S a multiple of its block; a window of at least one position. With
+    H_kv, D a multiple of the 128 lanes or, under the plain causal mask alone, half a lane tile (64: LFM2's heads;
+    the kernel's blocks then span the whole of D, and its softmax state stays 128 lanes wide), S a multiple of its
+    block; a window of at least one position. With
     `block_diffusion` (the block length) k and v hold 2 L rows, L noised and L clean, and q all of them or the
     L noised ones alone (a last layer's); L is then what the block has to divide. With `chunk_window` (a window
     and a chunk length) k and v hold S // chunk summaries and then the S single keys; the window divides S, the
@@ -101,7 +103,8 @@ def causal_flash_supported(q, k, v, window=None, block_diffusion=None, chunk_win
         return False
     if k.shape != (B, H_kv, k.shape[2], D) or H % H_kv:
         return False
-    return D % 128 == 0 and S >= 256 and S % min(BLOCK, S) == 0 and min(BLOCK, S) % 128 == 0
+    lanes = D % 128 == 0 or (D == 64 and window is None and block_diffusion is None)
+    return lanes and S >= 256 and S % min(BLOCK, S) == 0 and min(BLOCK, S) % 128 == 0
 
 
 def _block_diffusion_seen_coded(code, kv_ids, length: int, block: int):
@@ -285,6 +288,15 @@ def _register():
                 desc='SmallThinker-21BA3B full (position-free) layer: 28 query heads on 4 key/value heads, 16384 '
                      'positions; v5e, one layer: forward 15.5 ms against the XLA query-block path\'s 88.1, forward and '
                      'backward 60.3 against 230.3 (PR 31)',
+            ),
+            KernelCase(
+                name='gqa_full_s8192_d64',
+                dry=dict(batch=1, heads=8, kv_heads=2, seq=256, head_dim=64),
+                live=dict(batch=4, heads=32, kv_heads=8, seq=8192, head_dim=64, dtype='bfloat16'),
+                desc='LFM2-8B-A1B attention layer: 32 query heads on 8 key/value heads of width 64 (half a lane tile), '
+                     '4 sequences of 8192; v5e, one layer: forward 19.7 ms against the XLA query-block path\'s 777.6, forward and '
+                     'backward 76.2 against 949.5; with q, k and v padded to 128 inside a wrapper 20.5 / 76.9: the native '
+                     'width costs what the padded one does in the products and moves half the bytes (PR 43)',
             ),
             KernelCase(
                 name='gqa_window4096_s16384_d128',

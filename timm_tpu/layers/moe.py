@@ -6,7 +6,10 @@ GLM-4.7-Flash `glm4_moe_lite` configures it: sigmoid scores over ALL
 `num_experts`, the `top_k` largest of score + bias chosen, weights = the chosen
 scores normalised over all `top_k` chosen and scaled. 'softmax_topk' is
 SmallThinker's (arXiv:2507.20984): the `top_k` largest LOGITS chosen, weights =
-a softmax over the chosen logits; no bias, no scaling. The router may read
+a softmax over the chosen logits; no bias, no scaling. A third model,
+LFM2-8B-A1B (`models/lfm2_moe.py`), uses 'sigmoid_bias' at its own numbers: 4 of
+32, no shared expert, scaling 1, and its published code's normaliser epsilon
+(`norm_eps` 1e-6 where GLM's is 1e-20). The router may read
 another tensor than the experts (`router_in`: SmallThinker routes on the
 attention's input, so a layer's routing does not wait for its attention), and
 the experts' gate activation is SiLU (SwiGLU) or ReLU (ReGLU). The layer holds experts
@@ -71,16 +74,20 @@ def dispatch_rows(rows: int, experts_held: int, num_experts: int) -> int:
 
 
 def merge_counters(a: dict, b: dict) -> dict:
-    """Counters of two layers as one: slots and blocks add, the largest load is the larger."""
-    if not a or not b:
-        return a or b
-    return {k: jnp.maximum(a[k], b[k]) if k.endswith('_max') else a[k] + b[k] for k in a}
+    """Counters of two layers as one: slots and blocks add, the largest load is the larger; a counter only one of
+    them has (layers of different kinds in one model) is kept as it is."""
+    out = dict(a)
+    for k, v in b.items():
+        out[k] = v if k not in out else jnp.maximum(out[k], v) if k.endswith('_max') else out[k] + v
+    return out
 
 
-def route(scores_in, router_kernel, bias, top_k: int, scaling: float, scoring: str = 'sigmoid_bias'):
+def route(scores_in, router_kernel, bias, top_k: int, scaling: float, scoring: str = 'sigmoid_bias',
+          norm_eps: float = 1e-20):
     """x (T, d) -> chosen expert ids (T, k) and weights (T, k), float32.
     The scores, their choice and the weights are float32 at full precision:
-    a top-k choice flips on the last bits."""
+    a top-k choice flips on the last bits. `norm_eps` is what 'sigmoid_bias' adds to the chosen scores' sum
+    before it divides by it (a model's published code fixes it)."""
     logits = jnp.matmul(scores_in.astype(jnp.float32), router_kernel.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     if scoring == 'softmax_topk':
@@ -89,7 +96,7 @@ def route(scores_in, router_kernel, bias, top_k: int, scaling: float, scoring: s
     s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    return idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * scaling
+    return idx, chosen / (chosen.sum(-1, keepdims=True) + norm_eps) * scaling
 
 
 def _group_sizes(slot_expert, held: int):
@@ -188,6 +195,7 @@ class SparseMoe(nnx.Module):
             routed_scaling_factor: float = 1.0,
             scoring: str = 'sigmoid_bias',
             activation: str = 'silu',
+            norm_eps: float = 1e-20,
             *,
             dtype=None,
             param_dtype=jnp.float32,
@@ -201,14 +209,15 @@ class SparseMoe(nnx.Module):
         self.num_experts, self.top_k = num_experts, top_k
         self.scoring, self.activation = scoring, activation
         self.experts_held, self.expert_offset = held, expert_offset
-        self.scaling = routed_scaling_factor
+        self.scaling, self.norm_eps = routed_scaling_factor, norm_eps
         self.dtype = dtype
         init = trunc_normal_(std=0.02)
         key = rngs.params
         self.router = nnx.Param(init(key(), (dim, num_experts), param_dtype))
         # `e_score_correction_bias`: steers the choice, not the weights; a buffer without gradient. Its
         # update from the experts' load is not part of the step (the rate is not in the public config).
-        # The softmax rule has none.
+        # Zero until somebody places one: a checkpoint's learned values, or a benchmark runner's seeded
+        # vector (`benchmarks/harness/sconv_lm_train_runner.py`). The softmax rule has none.
         self.score_bias = nnx.Variable(jnp.zeros((num_experts,), jnp.float32)) if scoring == 'sigmoid_bias' else None
         self.w_gate = nnx.Param(init(key(), (held, dim, hidden), param_dtype))
         self.w_up = nnx.Param(init(key(), (held, dim, hidden), param_dtype))
@@ -218,7 +227,7 @@ class SparseMoe(nnx.Module):
 
     def _route(self, router_in):
         bias = None if self.score_bias is None else jax.lax.stop_gradient(self.score_bias[...])
-        return route(router_in, self.router[...], bias, self.top_k, self.scaling, self.scoring)
+        return route(router_in, self.router[...], bias, self.top_k, self.scaling, self.scoring, self.norm_eps)
 
     def choose(self, router_in):
         """Chosen expert ids (..., top_k) of tokens whose router input is (..., dim), of all `num_experts`."""

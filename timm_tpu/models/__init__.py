@@ -66,5 +66,6 @@ from .pit import PoolingVisionTransformer
 from .inception_v4 import InceptionV4
 from .evabyte import EvaByte
 from .glm4_moe_lite import Glm4MoeLite
+from .lfm2_moe import Lfm2Moe
 from .sdar_moe import SdarMoe
 from .smallthinker import SmallThinker

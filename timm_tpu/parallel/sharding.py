@@ -111,7 +111,10 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
      11. a chunk-pooled attention's two learned vectors a head (`attn.phi`, `attn.mu`, (heads, head_dim))
                                           -> replicate (2 x heads x head_dim numbers; the compiler slices
                                              them to the heads a chip's q/k/v columns hold)
-     12. everything else                  -> replicate (catch-all)
+     12. a gated short convolution's taps (`conv.taps`, (dim, 3): 6144 numbers a layer)
+                                          -> replicate (its two products, `conv.in_proj` / `conv.out_proj`, are
+                                             plain kernels under rule 5; a tied embedding is rule 8's, once)
+     13. everything else                  -> replicate (catch-all)
 
     Rules 1-4 fall back to 'fsdp_largest' placement when the mesh has no
     'model' axis, so tp=1 reproduces the 2-axis table exactly.
@@ -139,6 +142,7 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
         PartitionRule(r'\.mlp\.(?:w_gate|w_up|w_down)$', 'fsdp_largest', name='expert-stack'),
         PartitionRule(r'\.mlp\.router$', 'replicate', name='router'),
         PartitionRule(r'\.attn\.(?:phi|mu)$', 'replicate', name='head-vector'),
+        PartitionRule(r'\.conv\.taps$', 'replicate', name='conv-taps'),
         PartitionRule(r'.*', 'replicate', name='catch-all'),
     )
 

@@ -86,6 +86,9 @@ SPANS = {
     'evabyte.attn.summary': ('attention', 'device scope: the chunk softmax against a head\'s learned vector and the two pooled sums (summary keys and values), forward and backward'),
     'evabyte.attn.core': ('attention', 'device scope: the joint softmax over a window\'s single keys and the earlier windows\' summaries, forward and backward'),
     'evabyte.ffn': ('feed-forward', 'device scope: the dense SwiGLU of every layer with the norm before it and its float32 residual add'),
+    # digit-free names: the benchmark's reduction finds a scope by `[a-z]+(?:\.[a-z_]+)+` (`harness/device_scopes.py`)
+    'sconv.proj': ('short convolution', 'device scope: a gated short convolution\'s two products (`in_proj` to three gates\' worth of channels, `out_proj` back) and the norm before them'),
+    'sconv.mix': ('short convolution', 'device scope: the two elementwise gates and the causal depthwise taps between the products, forward and backward (the taps\' gradient among it)'),
     # the image models' scopes, on the shared layers (every model built from them has them), and the step's own,
     # which every task runs. The innermost scope of an op counts: `img.block` holds what no inner scope takes
     'img.patch_embed': ('step', 'device scope: the patch convolution, class / register tokens, position embedding, the norm before the blocks'),
@@ -118,6 +121,7 @@ SPANS = {
     'attn.bd_blocks': ('attention', 'step counter: the same for the cores under the block-diffusion mask'),
     'attn.eva_blocks': ('attention', 'step counter: the same for the cores under the chunk-window mask (queries on summaries and single keys)'),
     'attn.eva_pairs': ('attention', 'step counter: (query, key) pairs the chunk-window mask leaves, single keys and summaries, all heads held, layers and sequences (float32: from the shapes alone)'),
+    'sconv.rows': ('short convolution', 'step counter: positions x gated short-convolution layers of the step (from the shapes): the rows its memory-bound middle moves'),
     'lm.head_nll': ('step', 'step counter: the mean cross-entropy of each of a model\'s `num_pred_heads` prediction heads over its own valid positions, a vector; over micro-batches the means add'),
     'lm.noised_masked': ('step', 'step counter: positions of the step\'s noised copies that hold the mask token'),
     'lm.masked_nll': ('step', 'step counter: the cross-entropy summed over those positions, unweighted (over `lm.noised_masked`: the mean a masked position)'),

@@ -297,3 +297,106 @@ def test_every_parameter_of_the_family_has_one_partition_rule_and_an_expert_stac
     assert tuple(spec_for_param('blocks.1.mlp.w_gate', (8, 2048, 1536), mesh)) == (None, None, 'fsdp')
     assert tuple(spec_for_param('blocks.1.mlp.w_down', (8, 1536, 2048), mesh)) == (None, None, 'fsdp')
     assert tuple(spec_for_param('blocks.1.mlp.router', (2048, 64), mesh)) == ()
+
+
+def _written_out(attn, x):
+    """The layer as an uncompressed multi-head attention from the same kernels, as
+    `test_latent_attention_prefill_is_an_uncompressed_multi_head_attention_from_the_same_w_kvb` writes it: per-head
+    key and value matrices split from W_kvb, one S x S causal softmax, float32."""
+    B, S_, _ = x.shape
+    H, nope, rd, vd, rank = attn.num_heads, attn.nope, attn.rope, attn.v_dim, attn.kv_lora_rank
+    q = (attn.q_norm(x @ attn.q_a.kernel[...]) @ attn.q_b.kernel[...]).reshape(B, S_, H, nope + rd)
+    kv_a = x @ attn.kv_a.kernel[...]
+    c_kv, k_rope = attn.kv_norm(kv_a[..., :rank]), kv_a[..., rank:]
+    w_kvb = attn.kv_b.kernel[...].reshape(rank, H, nope + vd)
+    k_nope, v = jnp.einsum('bsr,rhd->bshd', c_kv, w_kvb[..., :nope]), jnp.einsum('bsr,rhd->bshd', c_kv, w_kvb[..., nope:])
+    turn = lambda t: ref.rope(jnp.moveaxis(t, 1, -2), 1e6)  # noqa: E731  (.., S, D)
+    q = jnp.concatenate([jnp.moveaxis(q[..., :nope], 1, 2), turn(q[..., nope:])], axis=-1)          # (B, H, S, D)
+    k = jnp.concatenate([jnp.moveaxis(k_nope, 1, 2), jnp.broadcast_to(turn(k_rope[:, :, None]), (B, 1, S_, rd)).repeat(H, 1)], -1)
+    scores = jnp.einsum('bhqd,bhkd->bhqk', q, k) / math.sqrt(nope + rd)
+    scores = jnp.where(jnp.tril(jnp.ones((S_, S_), bool)), scores, -jnp.inf)
+    return jnp.einsum('bhqk,bkhd->bqhd', jax.nn.softmax(scores, -1), v).reshape(B, S_, H * vd) @ attn.o.kernel[...]
+
+
+@pytest.mark.parametrize('sizes,rows,seq,dtype,kernel,tol', [
+    ((64, 4, 24, 16, 12, 8, 16), 2, S, None, False, 1e-5),                # the toy size on the XLA query-block core
+    ((64, 2, 24, 16, 64, 64, 128), 1, 256, jnp.bfloat16, True, 2e-2),     # a kernel-eligible size, the kernel interpreted
+], ids=['float32-xla-core', 'bfloat16-interpreted-kernel'])
+def test_latent_attention_output_and_every_parameter_gradient_are_the_written_out_attentions(sizes, rows, seq, dtype, kernel, tol):
+    """The head-major products (column blocks of `q_b`, `kv_b` and the (H, D, dim) view of `o`) against the written-out
+    attention, which multiplies the whole kernels and transposes: the output and the gradient of every parameter,
+    each within `tol` of the reference's largest entry. float32 on float32 differs by summation order (1e-7 was
+    seen); the bfloat16 layer read 4e-3 to 8e-3 against the float32 reference (1e-4 / 1e-3 absolute, the float32
+    kernel test's limits, are below bfloat16's own rounding here), and a wrong column block or head order reads 1."""
+    from timm_tpu.kernels import causal_flash_supported
+    attn = LatentAttention(*sizes, block_q=16, dtype=dtype, rngs=nnx.Rngs(3))
+    plain = LatentAttention(*sizes, rngs=nnx.Rngs(3))                        # the same parameters, float32 throughout
+    x = jax.random.normal(jax.random.key(0), (rows, seq, sizes[0]))
+    rope = build_rotary_pos_embed_1d(seq, sizes[5], 1e6)
+    assert causal_flash_supported(*attn.qkv(x, rope)) == kernel
+    layer = lambda a, x: a(x, rope).astype(jnp.float32)  # noqa: E731
+    got, want = nnx.jit(layer)(attn, x), nnx.jit(_written_out)(plain, x)
+    assert float(jnp.abs(got - want).max()) < tol * float(jnp.abs(want).max())
+    grads = nnx.jit(nnx.grad(lambda a, x: (layer(a, x) ** 2).sum()))(attn, x)
+    want_grads = nnx.jit(nnx.grad(lambda a, x: (_written_out(a, x) ** 2).sum()))(plain, x)
+    gaps = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max() / jnp.abs(b).max()), nnx.state(grads), nnx.state(want_grads))
+    assert len(jax.tree.leaves(gaps)) == 7 and max(jax.tree.leaves(gaps)) < tol, gaps
+
+
+@pytest.fixture(scope='module')
+def v5e_chip():
+    """One device of a described (not attached) v5e:2x2 topology, as `tests/test_chip_smoke.py` `v5e_devices` describes
+    it, with the persistent compile cache off (such compiles are written to it but cannot be read back without a chip)."""
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform='tpu', topology_name='v5e:2x2')
+    except Exception as e:  # no libtpu on this box: nothing to compile for
+        pytest.skip(f'cannot describe a v5e topology here: {e!r}')
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update('jax_enable_compilation_cache', enabled)
+    compilation_cache.reset_cache()
+
+
+def test_the_cells_block_compiled_for_a_v5e_moves_no_activation_between_mla_products_and_the_core(v5e_chip, monkeypatch):
+    """One block of the GLM cell (RMSNorm + `LatentAttention(2048, 20, 768, 512, 192, 64, 256)`, bfloat16, 2 x 8192,
+    rematerialised with `CORE_OUT` saved), loss and gradient, compiled for the described chip: under `glm.mla.proj`
+    the entry computation holds no `copy`, `slice` or `transpose` of 100 MB or more. The reshape-and-transpose
+    spelling had thirteen (nine head transposes of 168 / 294 MB and four slices of a transposed kv, PERF.md section
+    6, PR 44): q, k_nope and v leave their products as (B, H, S, D) and the core's output enters `o`'s as it is."""
+    import re
+    from timm_tpu.layers import RmsNorm
+    from timm_tpu.layers.latent_attention import CORE_OUT
+    from timm_tpu.parallel import mesh as mesh_mod
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')       # the kernel path; the test, not the program, says so
+    monkeypatch.setattr(mesh_mod, '_GLOBAL_MESH', None)
+
+    class Block(nnx.Module):
+        def __init__(self, rngs):
+            self.norm1 = RmsNorm(2048, eps=1e-5, dtype=jnp.bfloat16, rngs=rngs)
+            self.attn = LatentAttention(2048, 20, 768, 512, 192, 64, 256, dtype=jnp.bfloat16, rngs=rngs)
+
+        def __call__(self, x, rope):
+            return x + self.attn(self.norm1(x), rope)
+
+    graphdef, state = nnx.split(nnx.eval_shape(lambda: Block(nnx.Rngs(0))))
+
+    def loss(state, x):
+        policy = jax.checkpoint_policies.save_only_these_names(CORE_OUT)
+        block = nnx.remat(lambda b, x, rope: b(x, rope), policy=policy)
+        return (block(nnx.merge(graphdef, state), x, build_rotary_pos_embed_1d(8192, 64, 1e6)).astype(jnp.float32) ** 2).mean()
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), state)
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=v5e_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(shapes, x).compile().as_text()
+    assert text.count('tpu_custom_call') >= 3                         # the kernel's forward, dq and dkv: the cell's path
+    entry = text[text.index('\nENTRY '):]
+    width = {'bf16': 2, 'f32': 4}
+    moved = [(name, op, f'{dtype}[{dims}]') for name, dtype, dims, op in
+             re.findall(r'^\s*(?:ROOT )?%?([\w.\-]+) = (bf16|f32)\[([\d,]+)\]\S* (copy|slice|transpose)\([^\n]*op_name="[^"]*glm\.mla\.proj', entry, re.M)
+             if width[dtype] * math.prod(int(d) for d in dims.split(',')) >= 100e6]
+    assert not moved, moved
+    assert 'glm.mla.proj' in entry and re.search(r'= bf16\[2,20,8192,256\]\S* fusion\(', entry)    # the scope and a head-major result are there to be read

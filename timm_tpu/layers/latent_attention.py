@@ -8,6 +8,10 @@ rotary part, the key's rotary part being ONE vector a position shared by all
 heads. Nothing is cached here: a decode cache for the latent belongs to
 `serve/` (ROADMAP "Reach").
 
+q, k and v leave their products as (B, H, S, D), the layout the core reads, and the core's output enters `o`'s
+product as it is: every (B, S, H, D) transpose of an activation was a copy of 168-294 MB in the GLM cell's step, nine
+of them and four slices a layer between the products and the kernel (PERF.md section 6, PR 44).
+
 The core, `causal_attention`, never builds the (heads, S, S) scores: queries
 go in blocks of `block_q`, block i sees keys [0, (i+1) * block_q) by a static
 slice, so the causally dead blocks are never computed, and each block is
@@ -46,6 +50,7 @@ SLOW_FROM = 2048            # positions from which the XLA core on a TPU is wort
 
 _logger = logging.getLogger(__name__)
 _WARNED_SHAPES = set()
+HEAD_MAJOR = 'bsr,rhd->bhsd'   # x (B, S, rank) by a (rank, H, D) view of a kernel, written as the core reads it
 
 
 def _warn_xla_core(q_shape, v_shape):
@@ -78,6 +83,13 @@ def causal_attention(q, k, v, scale: float, block_q: int = 1024):
     out = [_query_block(q[:, :, i:i + block_q], k[:, :, :i + block_q], v[:, :, :i + block_q], float(scale), i)
            for i in range(0, S, block_q)]
     return out[0] if len(out) == 1 else jnp.concatenate(out, axis=2)
+
+
+def _per_head(linear, x, num_heads: int):
+    """`linear(x)`'s operands as `nnx.Linear` promotes them, the (rank, H x D) kernel seen as (rank, H, D): what is
+    reshaped and cut into column blocks is the kernel (megabytes), never the (B, S, H x D) product of it."""
+    x, kernel = linear.promote_dtype((x, linear.kernel[...]), dtype=linear.dtype)
+    return x, kernel.reshape(kernel.shape[0], num_heads, -1)
 
 
 class LatentAttention(nnx.Module):
@@ -117,23 +129,26 @@ class LatentAttention(nnx.Module):
         self.o = linear(num_heads * v_head_dim, dim)
 
     def qkv(self, x, rope):
-        """-> q, k (B, H, S, nope + rope) and v (B, H, S, v_dim)."""
-        B, S, _ = x.shape
+        """-> q, k (B, H, S, nope + rope) and v (B, H, S, v_dim), each written in that layout by the product that makes it."""
         H = self.num_heads
-        q = self.q_b(self.q_norm(self.q_a(x))).reshape(B, S, H, self.nope + self.rope).transpose(0, 2, 1, 3)
+        c_q, w_q = _per_head(self.q_b, self.q_norm(self.q_a(x)), H)
+        q = jnp.einsum(HEAD_MAJOR, c_q, w_q)       # a head's whole width in one product: its rotary columns alone are half a lane tile in GLM
         q_nope, q_rope = q[..., :self.nope], q[..., self.nope:]
         kv = self.kv_a(x)
         c_kv, k_rope = kv[..., :self.kv_lora_rank], kv[..., self.kv_lora_rank:]
-        kv = self.kv_b(self.kv_norm(c_kv)).reshape(B, S, H, self.nope + self.v_dim).transpose(0, 2, 1, 3)
-        k_nope, v = kv[..., :self.nope], kv[..., self.nope:]
+        c_kv, w_kv = _per_head(self.kv_b, self.kv_norm(c_kv), H)
+        v = jnp.einsum(HEAD_MAJOR, c_kv, w_kv[..., self.nope:])
         rope = rope.astype(jnp.float32)
         q_rope = apply_rot_embed_cat(q_rope.astype(jnp.float32), rope, half=True).astype(q.dtype)
         k_rope = apply_rot_embed_cat(k_rope.astype(jnp.float32), rope, half=True).astype(q.dtype)   # (B, S, rope)
-        k_rope = jnp.broadcast_to(k_rope[:, None], (B, H, S, self.rope))
-        return jnp.concatenate([q_nope, q_rope], -1), jnp.concatenate([k_nope, k_rope], -1), v
+        # k leaves its product whole: zero columns of the kernel where the rotary key, one vector a position for all
+        # heads, is added in the product's own output fusion (no concatenate, no broadcast to H heads in memory)
+        w_k = jnp.pad(w_kv[..., :self.nope], ((0, 0), (0, 0), (0, self.rope)))
+        k = jnp.einsum(HEAD_MAJOR, c_kv, w_k) + jnp.pad(k_rope, ((0, 0), (0, 0), (self.nope, 0)))[:, None]
+        return jnp.concatenate([q_nope, q_rope], -1), k, v
 
     def __call__(self, x, rope):
-        B, S, _ = x.shape
+        S = x.shape[1]
         with tracing.scope('glm.mla.proj'):
             q, k, v = self.qkv(x, rope)
         with tracing.scope('glm.mla.core'):
@@ -146,4 +161,5 @@ class LatentAttention(nnx.Module):
                 out = causal_attention(q, k, v, self.scale, self.block_q)
             out = checkpoint_name(out, CORE_OUT)
         with tracing.scope('glm.mla.proj'):
-            return self.o(out.transpose(0, 2, 1, 3).reshape(B, S, self.num_heads * self.v_dim))
+            out, w_o = self.o.promote_dtype((out, self.o.kernel[...]), dtype=self.o.dtype)
+            return jnp.einsum('bhsd,hdm->bsm', out, w_o.reshape(self.num_heads, self.v_dim, -1))

@@ -105,30 +105,7 @@ def test_unsteered_script_fails_without_a_tpu():
 
 # ---- (b) every registered kernel compiles for the described chip -------------
 
-@pytest.fixture(scope='module')
-def v5e_devices():
-    """The four devices of a described (not attached) v5e:2x2 topology, with the
-    persistent compile cache off: such compiles are written to it but cannot
-    be read back without a chip."""
-    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    try:
-        topo = topologies.get_topology_desc(platform='tpu', topology_name='v5e:2x2')
-    except Exception as e:  # no libtpu on this box: nothing to compile for
-        pytest.skip(f'cannot describe a v5e topology here: {e!r}')
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update('jax_enable_compilation_cache', False)
-    compilation_cache.reset_cache()
-    yield topo.devices
-    jax.config.update('jax_enable_compilation_cache', enabled)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope='module')
-def v5e_chip(v5e_devices):
-    return jax.sharding.SingleDeviceSharding(v5e_devices[0])
-
+# `v5e_devices` / `v5e_chip`: tests/conftest.py (a described, not attached, v5e:2x2)
 
 @pytest.mark.kernels
 @pytest.mark.parametrize('spec,case', harness.parity_cases(),

@@ -343,25 +343,6 @@ def test_latent_attention_output_and_every_parameter_gradient_are_the_written_ou
     assert len(jax.tree.leaves(gaps)) == 7 and max(jax.tree.leaves(gaps)) < tol, gaps
 
 
-@pytest.fixture(scope='module')
-def v5e_chip():
-    """One device of a described (not attached) v5e:2x2 topology, as `tests/test_chip_smoke.py` `v5e_devices` describes
-    it, with the persistent compile cache off (such compiles are written to it but cannot be read back without a chip)."""
-    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    try:
-        topo = topologies.get_topology_desc(platform='tpu', topology_name='v5e:2x2')
-    except Exception as e:  # no libtpu on this box: nothing to compile for
-        pytest.skip(f'cannot describe a v5e topology here: {e!r}')
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update('jax_enable_compilation_cache', False)
-    compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
-    jax.config.update('jax_enable_compilation_cache', enabled)
-    compilation_cache.reset_cache()
-
-
 def test_the_cells_block_compiled_for_a_v5e_moves_no_activation_between_mla_products_and_the_core(v5e_chip, monkeypatch):
     """One block of the GLM cell (RMSNorm + `LatentAttention(2048, 20, 768, 512, 192, 64, 256)`, bfloat16, 2 x 8192,
     rematerialised with `CORE_OUT` saved), loss and gradient, compiled for the described chip: under `glm.mla.proj`

@@ -2,7 +2,10 @@
 through `order` and come back by a gather through its inverse, a `custom_vjp` pair in which each move is the other's
 derivative, and the group sizes are a comparison count. Held here against a plain formulation written with
 `.at[].add`, as a transpose pair on random cotangents, against `jnp.bincount`, and in the text of the three
-families' lowered training steps, which may hold no scatter of rows in an expert layer."""
+families' lowered training steps, which may hold no scatter of rows in an expert layer. Both moves read a large
+source in column pieces (`_column_pieces`, by its bytes): the bounds at the cells' shapes, the results bitwise those
+of one piece, and, in the text of the LFM2 cell's layer compiled for a described v5e, the gathers' sources in the
+chip's fast memory (`tracing.scope_gathers`, which the step's two gauges and `train.py`'s log line read too)."""
 import os
 import re
 import sys
@@ -20,6 +23,7 @@ import timm_tpu  # noqa: E402
 from timm_tpu.layers import SparseMoe, moe  # noqa: E402
 from timm_tpu.optim import create_optimizer_v2  # noqa: E402
 from timm_tpu.task import BlockDiffusionLMTask, CausalLMTask  # noqa: E402
+from timm_tpu.utils import tracing  # noqa: E402
 
 T, DIM, HIDDEN, EXPERTS, K = 128, 64, 32, 8, 2
 # a router row a kind of token: kind 0 chooses experts 0 and 1, kind 1 experts 0 and 2, kind 2 experts 2 and 3; a
@@ -115,20 +119,28 @@ ROUTED = {
 }
 
 
+@pytest.mark.parametrize('pieces', [1, 2], ids=['whole', 'both_moves_in_two_pieces'])
 @pytest.mark.parametrize('n', [128, 256], ids=['bounded', 'all_rows'])
 @pytest.mark.parametrize('case', list(ROUTED))
-def test_the_two_moves_are_each_others_transpose(case, n):
+def test_the_two_moves_are_each_others_transpose(case, n, pieces, monkeypatch):
     """`jax.vjp` of the gather into the buffer is the gather-sum back, and the other way round, to the bit; both
     agree with what autodiff makes of the plain masked gather (a scatter-add); and <to_buffer(x), u> = <x,
-    to_tokens(u)>. Rows past `covered` are NaN in what comes from the buffer: nothing of them reaches a token."""
+    to_tokens(u)>. Rows past `covered` are NaN in what comes from the buffer: nothing of them reaches a token. With
+    both moves' sources read in column pieces every result is, to the bit, the one read whole."""
     token, pos, covered = moves(ROUTED[case](), 2, n)
+    dim = 2 * moe.LANES
     assert int(covered) == {'dead_rows': int(covered), 'no_local_slot': 0, 'every_token_on_one_expert': T,
                             'every_slot_local': n}[case]
-    x, dy = jax.random.normal(jax.random.key(1), (2, T, DIM))
-    u = jax.random.normal(jax.random.key(2), (n, DIM))
+    x, dy = jax.random.normal(jax.random.key(1), (2, T, dim))
+    u = jax.random.normal(jax.random.key(2), (n, dim))
     u_nan = jnp.where((jnp.arange(n) < covered)[:, None], u, jnp.nan)
+    whole = moe._to_buffer(x, token, pos, covered), moe._to_tokens(u_nan, token, pos, covered)
+    if pieces > 1:
+        in_pieces(monkeypatch, min(T, n) * dim * 4, pieces)
+        assert len(moe._column_pieces(T, dim, 4, moe.TAKE_WHOLE_BYTES)) == len(moe._column_pieces(n, dim, 4, moe.FAST_BYTES // 2)) == pieces + 1
     xs, back = jax.vjp(lambda x: moe._to_buffer(x, token, pos, covered), x)
     y, there = jax.vjp(lambda rows: moe._to_tokens(rows, token, pos, covered), u_nan)
+    assert bool((xs == whole[0]).all()) and bool((y == whole[1]).all())
     assert bool(jnp.isfinite(y).all()) and bool((back(u_nan)[0] == y).all())
     assert bool((there(dy)[0] == moe._to_buffer(dy, token, pos, covered)).all())
     live = (jnp.arange(n) < covered)[:, None]
@@ -139,25 +151,56 @@ def test_the_two_moves_are_each_others_transpose(case, n):
         assert float(jnp.abs(xs).max()) == 0.0 and float(jnp.abs(y).max()) == 0.0
 
 
-def test_the_pieces_of_a_source_are_whole_lane_columns_by_its_size():
-    """The cells' bounded buffers (bfloat16: 120 / 128 / 64 MiB) in 3 / 3 / 1 pieces, a fall-back's whole, a
-    width that is no multiple of 128 whole."""
-    assert moe._column_pieces(24576, 2560, 2) == [0, 768, 1664, 2560]
-    assert moe._column_pieces(32768, 2048, 2) == [0, 640, 1280, 2048]
-    assert moe._column_pieces(16384, 2048, 2) == [0, 2048] and moe._column_pieces(98304, 2560, 2) == [0, 2560]
-    assert moe._column_pieces(32768, 2000, 2) == [0, 2000]
+SUM_WHOLE = moe.FAST_BYTES // 2     # what `_sum_rows` reads whole; `_take_rows`: `TAKE_WHOLE_BYTES`
+PIECES = {
+    # the gather-sum's rule (one piece up to half the fast memory): the cells' bounded buffers, bfloat16
+    'smallthinker_bounded_120MiB': ((24576, 2560, 2), SUM_WHOLE, [0, 768, 1664, 2560]),
+    'sdar_bounded_128MiB': ((32768, 2048, 2), SUM_WHOLE, [0, 640, 1280, 2048]),
+    'glm_bounded_64MiB': ((16384, 2048, 2), SUM_WHOLE, [0, 2048]),
+    'a_width_that_is_no_multiple_of_128': ((32768, 2000, 2), SUM_WHOLE, [0, 2000]),
+    'lfm2_bounded_256MiB': ((65536, 2048, 2), SUM_WHOLE, [0, 256, 640, 1024, 1280, 1664, 2048]),
+    # the fall-backs (`T * top_k` rows, 480 / 512 MiB: over `PIECED_BYTES`) whole, as the parent read them
+    'smallthinker_fallback_480MiB': ((98304, 2560, 2), SUM_WHOLE, [0, 2560]),
+    'sdar_lfm2_fallback_512MiB': ((131072, 2048, 2), SUM_WHOLE, [0, 2048]),
+    'the_largest_pieced_256MiB_at_two_lane_columns': ((1 << 19, 256, 2), SUM_WHOLE, [0, 128, 256]),
+    # the gather into the buffer's rule (one piece up to what the compiler places whole): the cells' token blocks
+    'take_glm_sdar_x_64MiB': ((16384, 2048, 2), moe.TAKE_WHOLE_BYTES, [0, 2048]),
+    'take_smallthinker_x_80MiB': ((16384, 2560, 2), moe.TAKE_WHOLE_BYTES, [0, 2560]),
+    'take_lfm2_x_128MiB': ((32768, 2048, 2), moe.TAKE_WHOLE_BYTES, [0, 640, 1280, 2048]),
+}
 
 
+@pytest.mark.parametrize('case', list(PIECES))
+def test_the_pieces_of_a_source_are_whole_lane_columns_by_its_size(case):
+    """By the source's bytes alone: up to `FAST_BYTES` the bounds PR 42 shipped, digit for digit; pieces up to
+    `PIECED_BYTES`, whole above; a piece is at most `PIECE_BYTES` and whole lane columns; a width that is no
+    multiple of 128 whole."""
+    (n, dim, itemsize), whole, bounds = PIECES[case]
+    got = moe._column_pieces(n, dim, itemsize, whole)
+    assert got == bounds
+    if len(bounds) > 2 and not case.startswith('the_largest'):      # one lane column is the smallest piece there is
+        assert all(lo % 128 == 0 and lo < hi and n * (hi - lo) * itemsize <= moe.PIECE_BYTES for lo, hi in zip(bounds, bounds[1:]))
+
+
+def in_pieces(monkeypatch, n_bytes: int, pieces: int):
+    """Both moves read a source of `n_bytes` in `pieces` column pieces."""
+    monkeypatch.setattr(moe, 'FAST_BYTES', n_bytes)
+    monkeypatch.setattr(moe, 'TAKE_WHOLE_BYTES', n_bytes // 2)
+    monkeypatch.setattr(moe, 'PIECE_BYTES', -(-n_bytes // pieces))
+
+
+@pytest.mark.parametrize('move', ['sum_rows', 'take_rows'])
 @pytest.mark.parametrize('pieces', [2, 3])
-def test_a_source_read_in_pieces_gives_the_sum_of_one_read_whole(pieces, monkeypatch):
-    n, dim = 128, 384
+def test_a_source_read_in_pieces_gives_the_sum_of_one_read_whole(pieces, move, monkeypatch):
+    """To the bit: a column split changes no element's arithmetic."""
+    n, dim = 128, 768
     token, pos, covered = moves(ROUTED['dead_rows'](), 2, n)
-    rows = jax.random.normal(jax.random.key(3), (n, dim))
-    whole = moe._sum_rows(rows, token, pos, covered)
-    monkeypatch.setattr(moe, 'FAST_BYTES', n * dim * 4)
-    monkeypatch.setattr(moe, 'PIECE_BYTES', -(-n * dim * 4 // pieces))
-    assert len(moe._column_pieces(n, dim, 4)) == pieces + 1
-    assert bool((moe._sum_rows(rows, token, pos, covered) == whole).all())
+    source = jax.random.normal(jax.random.key(3), (n, dim))
+    f = getattr(moe, '_' + move)
+    whole = f(source, token, pos, covered)
+    in_pieces(monkeypatch, n * dim * 4, pieces)
+    assert len(moe._column_pieces(n, dim, 4, moe.TAKE_WHOLE_BYTES if move == 'take_rows' else moe.FAST_BYTES // 2)) == pieces + 1
+    assert bool((f(source, token, pos, covered) == whole).all())
 
 
 @pytest.mark.parametrize('held', [2, 8])
@@ -237,3 +280,60 @@ def test_the_witness_sees_a_scatter_formulation(monkeypatch):
         found = row_scatters(step(), dim=DIM)
     jax.clear_caches()
     assert {(f[0], f[1]) for f in found} >= {('f32', (T, DIM)), ('s32', (3,))}, found
+
+
+def parent_rule(n, dim, itemsize, whole):
+    """`_column_pieces` as PR 42 shipped it: pieces only for a gather-sum's source between half and all of the fast
+    memory; a larger one, and every source of the gather into the buffer, whole."""
+    size = n * dim * itemsize
+    pieced = whole == moe.FAST_BYTES // 2 < size <= moe.FAST_BYTES and dim % moe.LANES == 0
+    pieces = -(-size // moe.PIECE_BYTES) if pieced else 1
+    return [dim // moe.LANES * c // pieces * moe.LANES for c in range(pieces)] + [dim]
+
+
+def test_the_cells_layer_compiled_for_a_v5e_gathers_its_rows_from_fast_memory(v5e_chip, monkeypatch):
+    """The engagement witness of the size rule. One expert layer of the LFM2 cell (8 of 32 experts at top-4 over
+    32768 tokens, bfloat16: a 256 MiB dispatch buffer, a 128 MiB token block, a 512 MiB fall-back buffer), loss and
+    gradient, compiled for the described chip: of the gather fusions under `glm.moe.route`, both branches of the
+    conditional, forward and backward, `tracing.scope_gathers` finds four in five on a source in the chip's fast
+    memory (64 of 79: the fall-back's buffer is read whole from HBM by the rule, 8 gathers, and the compiler leaves
+    a few reads of a piece there: PERF.md section 6, PR 45). Under the rule PR 42 shipped the same reader sees the
+    row gathers' sources in HBM: only the scalar gathers are fast."""
+    graphdef, state = nnx.split(nnx.eval_shape(lambda: SparseMoe(
+        2048, 1792, 32, 4, experts_held=8, n_shared=0, scoring='sigmoid_bias', dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, rngs=nnx.Rngs(0))))
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip), state)
+    x = jax.ShapeDtypeStruct((32768, 2048), jnp.bfloat16, sharding=v5e_chip)
+
+    def compiled():
+        jax.clear_caches()                                              # `_dispatch` is traced once a shape
+        loss = lambda state, x: (nnx.merge(graphdef, state).routed(x)[0].astype(jnp.float32) ** 2).mean()  # noqa: E731
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(shapes, x).compile().as_text()
+
+    gathers, fast = tracing.scope_gathers(compiled(), 'glm.moe.route')
+    # bounded: 3 takes + 4 x 6 sums forward, 3 + 4 x 6 + 4 x 3 backward; the fall-back 3 takes + 4 whole and so on
+    assert gathers >= 70 and fast >= 0.75 * gathers, (gathers, fast)
+    with monkeypatch.context() as m:
+        m.setattr(moe, '_column_pieces', parent_rule)
+        whole, whole_fast = tracing.scope_gathers(compiled(), 'glm.moe.route')
+    jax.clear_caches()
+    assert 20 <= whole < gathers and whole_fast <= 6, (whole, whole_fast)   # the chosen scores' and weights' gathers
+
+
+def test_the_kept_step_program_sets_the_routes_two_gauges_and_the_log_line_prints_them():
+    """Where the step program is compiled ahead of time and kept, the gather fusions under `glm.moe.route` in its
+    text are counted once into two gauges, which `train.py`'s log line prints; on the CPU no source is in a fast
+    memory. A program without an expert layer sets none (its reading is (0, 0))."""
+    import train
+    model = timm_tpu.create_model('glm4_moe_lite_toy', seed=0)
+    task = CausalLMTask(model, optimizer=create_optimizer_v2(model, opt='adamw', lr=1e-3, weight_decay=0.1),
+                        clip_grad=1.0, loss_chunk=32)
+    ids = jnp.zeros((8, 64), jnp.int32)
+    before = len(tracing.snapshot()['gauges'].get('moe.route_gathers', ()))
+    text = task.lower_train_step({'input': ids, 'target': ids}, 1e-3, 0).as_text()
+    gauges = tracing.snapshot()['gauges']
+    assert len(gauges['moe.route_gathers']) == len(gauges['moe.route_gathers_fast']) == before + 1
+    gathers, fast = gauges['moe.route_gathers'][-1][1], gauges['moe.route_gathers_fast'][-1][1]
+    assert (gathers, fast) == tracing.scope_gathers(text, 'glm.moe.route') and gathers >= 2 * (1 + K) and fast == 0
+    assert train._host_line(tracing.now_ns(), {})[0].endswith(f' route gathers {gathers} fast 0')
+    assert tracing.scope_gathers(jax.jit(lambda x: x[::2] * 2).lower(ids).compile().as_text(), 'glm.moe.route') == (0, 0)

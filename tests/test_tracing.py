@@ -254,7 +254,7 @@ def test_a_device_scope_names_the_ops_traced_in_it_and_a_step_counter_rides_in_t
     after = tracing.snapshot()
     assert len(after['spans']) - len(before['spans']) <= 1 and after['counters'] == before['counters']   # the ring is not theirs
     kinds = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('glm.', 'moe.', 'lm.'))}
-    assert set(kinds.values()) == {'device scope', 'step counter'} and len(kinds) == 17      # two of the block-diffusion task, `lm.head_nll`
+    assert set(kinds.values()) == {'device scope', 'step counter', 'gauge'} and len(kinds) == 19   # two of the block-diffusion task, `lm.head_nll`, the route's two gauges
     # ONE kind of device scope: the window/full family's three were 'swa device scope' while `tracing.py` could not be
     # edited by the PRs that met `test_lm_harness.py`'s pin of the GLM reduction's nine (a superset since PR 35)
     swa = {name: what.split(':')[0] for name, (_, what) in tracing.SPANS.items() if name.startswith(('swa.', 'attn.'))}
@@ -313,3 +313,61 @@ def test_the_log_line_names_the_attention_calls_traced_since_the_previous_line_a
     text, now = train._host_line(tracing.now_ns(), before)
     assert ' loop 0.0 attn fused 2 plain 1' in text and text.startswith('host ms/step: next')
     assert 'attn' not in train._host_line(tracing.now_ns(), now)[0]
+
+
+PROGRAM = """HloModule jit_step
+
+%fused_computation (param_0.2: bf16[65536,384], param_1.13: s32[32768]) -> bf16[32768,384] {
+  %param_0.2 = bf16[65536,384]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %param_1.13 = s32[32768]{0:T(1024)S(1)} parameter(1)
+  ROOT %gather.1 = bf16[32768,384]{1,0:T(8,128)(2,1)} gather(%param_0.2, %param_1.13), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,384}, metadata={op_name="jit(step)/glm.moe.route/gather"}
+}
+
+%fused_computation.1 (param_0.3: bf16[65536,384], param_1.14: s32[32768]) -> bf16[32768,384] {
+  %param_0.3 = bf16[65536,384]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.14 = s32[32768]{0:T(1024)S(1)} parameter(1)
+  %gather.2 = bf16[32768,384]{1,0:T(8,128)(2,1)} gather(%param_0.3, %param_1.14), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,384}, metadata={op_name="jit(step)/glm.moe.route/gather"}
+  ROOT %select.1 = bf16[32768,384]{1,0:T(8,128)(2,1)} select(%gather.2, %gather.2, %gather.2), metadata={op_name="jit(step)/glm.moe.route/select_n"}
+}
+
+%fused_computation.2 (param_0.4: s32[32768]) -> s32[32768] {
+  %param_0.4 = s32[32768]{0:T(1024)} parameter(0)
+  ROOT %clamp.1 = s32[32768]{0:T(1024)S(1)} clamp(%param_0.4, %param_0.4, %param_0.4), metadata={op_name="jit(step)/glm.moe.route/gather"}
+}
+
+%fused_computation.3 (param_0.5: bf16[65536,64], param_1.15: s32[32768]) -> bf16[32768,64] {
+  %param_0.5 = bf16[65536,64]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %param_1.15 = s32[32768]{0:T(1024)S(1)} parameter(1)
+  ROOT %gather.3 = bf16[32768,64]{1,0:T(8,128)(2,1)} gather(%param_0.5, %param_1.15), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,64}, metadata={op_name="jit(step)/glm.embed/gather"}
+}
+
+%branch_1 (arg: (bf16[65536,384], s32[32768])) -> bf16[32768,384] {
+  %arg = (bf16[65536,384]{1,0:T(8,128)(2,1)}, s32[32768]{0:T(1024)}) parameter(0)
+  %get-tuple-element.1 = bf16[65536,384]{1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=0
+  %get-tuple-element.2 = s32[32768]{0:T(1024)} get-tuple-element(%arg), index=1
+  %copy-done.3 = bf16[65536,384]{1,0:T(8,128)(2,1)S(1)} copy-done(%get-tuple-element.1)
+  %broadcast_clamp_fusion = s32[32768]{0:T(1024)S(1)} fusion(%get-tuple-element.2), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(step)/glm.moe.route/gather"}
+  ROOT %fusion.7 = bf16[32768,384]{1,0:T(8,128)(2,1)} fusion(%copy-done.3, %broadcast_clamp_fusion), kind=kCustom, calls=%fused_computation, metadata={op_name="jit(step)/transpose(jvp(glm.moe.route))/gather"}
+}
+
+ENTRY %main (rows: bf16[65536,384], at: s32[32768], table: bf16[65536,64]) -> bf16[32768,384] {
+  %rows = bf16[65536,384]{1,0:T(8,128)(2,1)} parameter(0)
+  %at = s32[32768]{0:T(1024)} parameter(1)
+  %table = bf16[65536,64]{1,0:T(8,128)(2,1)S(1)} parameter(2)
+  %fusion.8 = bf16[32768,384]{1,0:T(8,128)(2,1)} fusion(%rows, %at), kind=kCustom, calls=%fused_computation.1, metadata={op_name="jit(step)/glm.moe.route/select_n"}
+  %fusion.9 = bf16[32768,64]{1,0:T(8,128)(2,1)} fusion(%table, %at), kind=kCustom, calls=%fused_computation.3, metadata={op_name="jit(step)/glm.embed/gather"}
+  ROOT %gather.4 = bf16[32768,384]{1,0:T(8,128)(2,1)} gather(%copy-done.3, %at), offset_dims={1}, collapsed_slice_dims={0}, start_index_map={0}, index_vector_dim=1, slice_sizes={1,384}, metadata={op_name="jit(step)/glm.moe.route/gather"}
+}
+"""
+
+
+def test_the_reader_of_a_scopes_gathers_counts_fusions_that_gather_and_those_whose_source_is_in_fast_memory():
+    """`scope_gathers` on a few lines of a compiled program's text: under `glm.moe.route` a gather fusion inside a
+    conditional's branch whose source is an `S(1)` copy (fast), one in the entry computation whose source is a
+    parameter in HBM and whose root is a select (its `op_name` holds no 'gather': the fused computation does), and
+    a gather that stands in no fusion (fast): 3 and 2. Not counted: the index fusion, which carries the gather's
+    `op_name` and gathers nothing, the gathers inside the fused computations (their fusions are), and the
+    embedding's gather under another scope, which is that scope's one."""
+    assert tracing.scope_gathers(PROGRAM, 'glm.moe.route') == (3, 2)
+    assert tracing.scope_gathers(PROGRAM, 'glm.embed') == (1, 1)
+    assert tracing.scope_gathers(PROGRAM, 'glm.mla.proj') == (0, 0) == tracing.scope_gathers('', 'glm.moe.route')

@@ -30,7 +30,11 @@ token's result is the sum, at float32, of the rows its `top_k` slots point at
 (a row gather a choice, masked to the live rows). The gather into the buffer
 and the gather-sum out of it are each other's transpose, so the two are a
 `jax.custom_vjp` pair (`_to_buffer`, `_to_tokens`) in which each is the
-other's derivative: two functions serve the four passes.
+other's derivative: two functions serve the four passes. Both read their
+source in column pieces where it is too large for the compiler to hold it in
+the chip's fast memory whole and small enough for pieces to pay
+(`_column_pieces`: by the source's bytes alone; a column split changes no
+element's arithmetic).
 
 `C` follows the share of the experts the layer holds, not the worst
 case: twice the mean load, `2 * T * top_k * experts_held / num_experts` rounded
@@ -60,11 +64,17 @@ __all__ = ['SparseMoe', 'route', 'merge_counters', 'dispatch_rows']
 ACTIVATIONS = {'silu': jax.nn.silu, 'relu': jax.nn.relu}
 TILE = 128      # rows: the dispatch buffer is whole tiles of the grouped products' row dimension
 LANES = 128     # columns of one lane tile
-# A row gather runs some five times faster from a source the compiler holds in the chip's fast memory than from
-# one in HBM (PERF.md section 6, PR 42; a v5e has 128 MiB of it, which a source shares with its neighbours): one
-# of up to half of it the compiler puts there whole, a larger one only in pieces, and none that exceeds it
+# A row gather runs faster from a source the compiler holds in the chip's fast memory than from one in HBM (PERF.md
+# section 6, PR 42 and PR 45; a v5e has 128 MiB of it, which a source shares with its neighbours). What the
+# compiler puts there whole depends on the move: the source of a gather-sum up to half of the fast memory, the
+# source of the one gather into the buffer up to `TAKE_WHOLE_BYTES`. A larger source is read in column pieces of
+# at most `PIECE_BYTES`, each of which the compiler copies there, up to `PIECED_BYTES`; one over that (every
+# fall-back buffer: 480-512 MiB, each row read once) is read whole from HBM: in pieces a layer on its fall-back
+# branch read 165.4 ms against 156.0 (LFM2's shape) and 77.8 against 74.0 (SmallThinker's), my chip run, PR 45
 FAST_BYTES = 128 << 20
 PIECE_BYTES = 48 << 20
+TAKE_WHOLE_BYTES = 112 << 20
+PIECED_BYTES = 2 * FAST_BYTES
 
 
 def dispatch_rows(rows: int, experts_held: int, num_experts: int) -> int:
@@ -105,12 +115,22 @@ def _group_sizes(slot_expert, held: int):
     return (slot_expert[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
 
 
-def _column_pieces(n: int, dim: int, itemsize: int) -> list:
-    """Column bounds of the pieces a gather-sum reads its (n, dim) source in: one piece, but for a source between
-    half and all of `FAST_BYTES`, which is read in pieces of at most `PIECE_BYTES`, whole lane columns each."""
-    size = n * dim * itemsize
-    pieces = -(-size // PIECE_BYTES) if FAST_BYTES // 2 < size <= FAST_BYTES and dim % LANES == 0 else 1
-    return [dim // LANES * c // pieces * LANES for c in range(pieces)] + [dim]
+def _column_pieces(n: int, dim: int, itemsize: int, whole: int) -> list:
+    """Column bounds of the pieces a row move reads its (n, dim) source in: one piece up to `whole` bytes and over
+    `PIECED_BYTES`, between them pieces of at most `PIECE_BYTES`, whole lane columns each."""
+    lanes = dim // LANES
+    pieces = 1
+    if whole < n * dim * itemsize <= PIECED_BYTES and dim % LANES == 0:
+        pieces = -(-lanes // max(1, PIECE_BYTES // (n * LANES * itemsize)))
+    return [lanes * c // pieces * LANES for c in range(pieces)] + [dim]
+
+
+def _by_pieces(source, whole: int, move):
+    """`move` (columns (n, w) -> (m, w)) of `source`, column piece by column piece (`_column_pieces`)."""
+    bounds = _column_pieces(*source.shape, source.dtype.itemsize, whole)
+    if len(bounds) == 2:
+        return move(source)
+    return jnp.concatenate([move(source[:, lo:hi]) for lo, hi in zip(bounds, bounds[1:])], axis=1)
 
 
 # The two moves between token order and buffer order, over one set of integer operands: `token[r]` is the token
@@ -119,23 +139,21 @@ def _column_pieces(n: int, dim: int, itemsize: int) -> list:
 def _take_rows(x, token, pos, covered):
     """x (T, dim) -> (n, dim): row r is x[token[r]] for r < covered, zero past it."""
     with tracing.scope('glm.moe.route'):
-        return jnp.where((jnp.arange(token.shape[0]) < covered)[:, None], x[token], 0)
+        live = (jnp.arange(token.shape[0]) < covered)[:, None]
+        return _by_pieces(x, TAKE_WHOLE_BYTES, lambda piece: jnp.where(live, piece[token], 0))
 
 
 def _sum_rows(rows, token, pos, covered):
     """rows (n, dim) -> (T, dim): token t's row is the sum over its `k` slots j of rows[pos[t, j]], of the slots
     that lie below row `covered` (`covered <= n`); added choice by choice at float32, cast once."""
     with tracing.scope('glm.moe.route'):
-        n, dim = rows.shape
-        bounds = _column_pieces(n, dim, rows.dtype.itemsize)
         dead = (pos >= covered).T[:, :, None]
-        at = jnp.minimum(pos, n - 1).T
+        at = jnp.minimum(pos, rows.shape[0] - 1).T
 
         def summed(piece):
             return sum(jnp.where(off, 0, piece[p]).astype(jnp.float32) for p, off in zip(at, dead)).astype(rows.dtype)
 
-        pieces = [summed(rows[:, lo:hi]) for lo, hi in zip(bounds, bounds[1:])]
-        return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
+        return _by_pieces(rows, FAST_BYTES // 2, summed)
 
 
 def _transposes(move, back):

@@ -28,6 +28,8 @@ from __future__ import annotations
 
 import collections
 import itertools
+import math
+import re
 import statistics
 import threading
 import time
@@ -115,6 +117,8 @@ SPANS = {
     'moe.load_max': ('experts', 'step counter: largest number of slots on one held expert in one layer'),
     'moe.dropped_slots': ('experts', 'step counter: local slots the dispatch buffer of the branch taken left out; 0 by construction (the bounded buffer of layers/moe.py dispatch_rows is taken only when the local slots fit, else all T * top_k rows): must read 0'),
     'moe.fallback_layers': ('experts', 'step counter: expert layers whose local slots did not fit the bounded dispatch buffer and took the worst-case one'),
+    'moe.route_gathers': ('experts', 'gauge: gather fusions under `glm.moe.route` in the step program\'s compiled text, every computation (both branches of every layer\'s conditional, forward, rematerialised and backward); set where the program is kept (`TrainingTask.lower_train_step`), by `scope_gathers`'),
+    'moe.route_gathers_fast': ('experts', 'gauge: those of them whose source (the largest operand) the compiler placed in the chip\'s fast memory (`S(1)` in its layout): equal to `moe.route_gathers` when the size rule of layers/moe.py `_column_pieces` engaged on every row gather'),
     'lm.tokens': ('step', 'step counter: tokens the step was given'),
     'attn.full_blocks': ('attention', 'step counter: (query block, key block) tiles with an unmasked pair that the full cores multiply in the forward pass, all layers and sequences'),
     'attn.window_blocks': ('attention', 'step counter: the same for the window cores, from the kernel\'s block map or the XLA path\'s slices'),
@@ -249,6 +253,45 @@ def keep_program(name: str, compiled) -> None:
 def program_text(name: str) -> Optional[str]:
     """The compiled text last kept under `name`; None where nobody compiled that program ahead of time."""
     return _programs.get(_known(name))
+
+
+_INSTRUCTION = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\w+\[[\d,]*\]\S*) ([\w\-]+)\((.*)$')
+_CALLS = re.compile(r'calls=%?([\w.\-]+)')
+
+
+def _elements(text: str) -> int:
+    """Elements of an array type as a compiled program writes it (`bf16[65536,384]{1,0:T(8,128)(2,1)S(1)}`); 0 for a tuple."""
+    m = re.match(r'\w+\[([\d,]*)\]', text)
+    return math.prod(int(d) for d in m.group(1).split(',') if d) if m else 0
+
+
+def scope_gathers(text: str, scope: str) -> tuple:
+    """(gathers, fast) of a compiled program's text, over ALL its computations (a conditional's branches are
+    computations of their own): the fusions whose `op_name` lies under device scope `scope` and which hold a
+    `gather` (and the gathers under it that stand in no fusion), and how many of them read their largest operand,
+    the source, from memory space `S(1)`, the chip's fast memory, as its layout says. What a trace cannot say: an
+    op event carries no operand's placement."""
+    types, rows, fused, gathering, computation = {}, [], set(), set(), None
+    for line in text.splitlines():
+        if line.endswith('{') and not line.startswith(' '):
+            computation = re.match(r'(?:ENTRY )?%?([\w.\-]+)', line).group(1)
+        m = _INSTRUCTION.match(line)
+        if m:
+            name, result, op, rest = m.groups()
+            types[name] = result
+            if op == 'gather':
+                gathering.add(computation)
+            elif op == 'fusion':
+                fused.add(_CALLS.search(rest).group(1))
+            if op in ('gather', 'fusion') and scope in rest.partition('op_name="')[2].partition('"')[0]:
+                rows.append((computation, op, rest))
+    gathers = fast = 0
+    for computation, op, rest in rows:
+        if _CALLS.search(rest).group(1) in gathering if op == 'fusion' else computation not in fused:
+            operands = re.findall(r'[\w.\-]+', rest.partition('), ')[0])
+            source = max((types.get(o, '') for o in operands), key=_elements)
+            gathers, fast = gathers + 1, fast + ('S(1)' in source)
+    return gathers, fast
 
 
 def device_counter(name: str, value):

@@ -1146,6 +1146,9 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
         text += f" procs {procs[-1][1]} exits {did.get('loader.worker_exits', 0)}"
     if metrics and 'moe.fallback_layers' in metrics:
         text = f"fallback {int(metrics['moe.fallback_layers'])} of {expert_layers} layers " + text
+    if 'moe.route_gathers' in snap['gauges']:   # set where the step program was compiled ahead of time and kept
+        text += (f" route gathers {snap['gauges']['moe.route_gathers'][-1][1]} "
+                 f"fast {snap['gauges']['moe.route_gathers_fast'][-1][1]}")
     return text, snap['counters']
 
 

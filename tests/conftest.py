@@ -43,9 +43,9 @@ def pytest_configure(config):
         'quantized serve parity, distill smoke (runs in tier-1)')
     config.addinivalue_line(
         'markers',
-        'kernels: Pallas kernel portfolio — registry lint, auto-generated '
-        'parity, fused AdamW/EMA drift, augment-epilogue oracle parity, '
-        'win-or-delete verdicts (runs in tier-1)')
+        'kernels: the Pallas kernels a step runs — registry, auto-generated '
+        'parity against the XLA reference, which calls take a kernel, and that '
+        'nothing a user sets selects one (runs in tier-1)')
     config.addinivalue_line(
         'markers',
         'elastic: elastic pod-scale training — resize-the-mesh resume drills '

@@ -87,7 +87,7 @@ def test_serve_phase_fails_when_scan_falls_back():
 def test_rehearse_kernels_phase(capsys):
     chip_smoke.phase_kernels(live=False)
     seen = _phase_line(capsys, 'kernels')['checked']
-    assert sorted(seen['kernels']) == ['augment_epilogue', 'causal_flash_attention', 'flash_attention', 'fused_adamw']
+    assert sorted(seen['kernels']) == ['causal_flash_attention', 'flash_attention']
     assert not any(k['tpu_custom_call'] for k in seen['kernels'].values())  # interpreted here
 
 

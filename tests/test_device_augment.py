@@ -108,11 +108,8 @@ def test_mixup_disabled_emits_identity_values():
 
 # ---- 2. the fused device program vs its numpy oracle ------------------------
 
-@pytest.mark.parametrize('re_mode', ['const', 'rand', 'pixel'])
-def test_augment_image_batch_matches_np_oracle(re_mode):
-    """Full fused program (uint8 -> erase -> mixup -> normalize -> soft
-    targets) against the eager numpy twin; 'pixel' exercises the on-device
-    threaded-key noise, which the oracle reproduces via the same key."""
+def _sampled_batch(re_mode):
+    """Parameters as the loader's samplers draw them: 8 x 16 x 16, up to two erase boxes a row, batch-mode mix."""
     rng = np.random.RandomState(4)
     mix = Mixup(mixup_alpha=0.8, cutmix_alpha=1.0, mode='batch',
                 num_classes=NC, seed=21)
@@ -125,6 +122,48 @@ def test_augment_image_batch_matches_np_oracle(re_mode):
     if re_mode == 'pixel':
         batch['noise_epoch'] = np.uint32(3)
         batch['noise_step'] = np.uint32(7)
+    return batch
+
+
+def _hand_batch(seed=3, batch=8, size=32, erase_k=1, with_mix=True):
+    """Hand-built parameters at another batch and size: `erase_k` boxes in EVERY row, a mix per row or none at all
+    (no `lam`: the program returns the integer targets as they came)."""
+    rng = np.random.default_rng(seed)
+    b, h = batch, size
+    out = {'image': rng.integers(0, 256, (b, h, h, 3)).astype(np.uint8),
+           'target': rng.integers(0, NC, (b,)).astype(np.int32)}
+    boxes = np.zeros((b, erase_k, 4), np.int32)
+    for i in range(b):
+        for kk in range(erase_k):
+            eh, ew = rng.integers(4, h // 2, 2)
+            boxes[i, kk] = (rng.integers(0, h - eh), rng.integers(0, h - ew), eh, ew)
+    out['erase_box'] = boxes
+    if with_mix:
+        yl = rng.integers(0, h // 2, (b,))
+        xl = rng.integers(0, h // 2, (b,))
+        out['lam'] = rng.uniform(0.2, 1.0, (b,)).astype(np.float32)
+        out['use_cutmix'] = rng.integers(0, 2, (b,)).astype(bool)
+        out['bbox'] = np.stack([yl, yl + h // 4, xl, xl + h // 4], 1).astype(np.int32)
+    return out
+
+
+_ORACLE_CASES = {
+    'const': ('const', lambda: _sampled_batch('const')),
+    'rand': ('rand', lambda: _sampled_batch('rand')),
+    'pixel': ('pixel', lambda: _sampled_batch('pixel')),
+    'no_mix': ('const', lambda: _hand_batch(with_mix=False)),
+    'two_boxes_b6_s24': ('const', lambda: _hand_batch(erase_k=2, batch=6, size=24)),
+}
+
+
+@pytest.mark.parametrize('case', list(_ORACLE_CASES))
+def test_augment_image_batch_matches_np_oracle(case):
+    """Full fused program (uint8 -> erase -> mixup -> normalize -> soft
+    targets) against the eager numpy twin; 'pixel' exercises the on-device
+    threaded-key noise, which the oracle reproduces via the same key;
+    'no_mix' is the eval-style path (erase + normalize, hard targets out)."""
+    re_mode, make_batch = _ORACLE_CASES[case]
+    batch = make_batch()
     kw = dict(mean=(0.48, 0.45, 0.41), std=(0.22, 0.22, 0.22), re_mode=re_mode,
               re_mean=(0.1, 0.1, 0.1), re_std=(0.4, 0.4, 0.4), noise_seed=9,
               num_classes=NC, smoothing=0.1)
@@ -132,6 +171,7 @@ def test_augment_image_batch_matches_np_oracle(re_mode):
     x_dev, y_dev = jax.jit(
         lambda bt: augment_image_batch(bt, **kw))(
             {k: jnp.asarray(v) for k, v in batch.items()})
+    assert (y_dev.ndim == 1 and y_dev.dtype == jnp.int32) == (case == 'no_mix')
     np.testing.assert_allclose(np.asarray(x_dev), x_np, atol=1e-5)
     np.testing.assert_allclose(np.asarray(y_dev), y_np, atol=1e-6)
 

@@ -226,15 +226,24 @@ def test_the_loop_aborts_one_enqueued_step_after_the_tripping_update_and_saves_n
 
 @pytest.mark.parametrize('recovery_interval', [0, 1], ids=['in_the_next_call', 'in_the_drain_before_a_recovery_save'])
 def test_the_loop_rolls_back_one_enqueued_step_after_the_tripping_update_and_goes_on(
-        tmp_path, loop_journal, caplog, recovery_interval):
+        tmp_path, loop_journal, caplog, monkeypatch, recovery_interval):
     """`--nonfinite-rollback`, two epochs of 8 updates, updates 10, 11, 12 NaN:
     the rollback is for update 12, out of the call that enqueued update 13 (whose
     batch is dropped with it: the next call is update 13 again) or out of the
     drain before update 12's recovery file; it loads the newest file written
-    BEFORE the tripping step, and the run ends as a sound one does."""
+    BEFORE the tripping step, once the writer thread has written it, and the run
+    ends as a sound one does."""
     import logging
 
     import train
+    from timm_tpu.resilience import AsyncCheckpointWriter
+    waited, writer_drain = [], AsyncCheckpointWriter.drain
+
+    def drain(self, *args, **kwargs):       # where in the journal the writer was waited for
+        waited.append(len(loop_journal))
+        return writer_drain(self, *args, **kwargs)
+
+    monkeypatch.setattr(AsyncCheckpointWriter, 'drain', drain)
     with caplog.at_level(logging.WARNING):
         train.main(_main_argv(tmp_path, '--epochs', '2', '--fault-inject', 'nan_grads@10:3', '--nonfinite-rollback',
                               '--recovery-interval', str(recovery_interval)))
@@ -244,6 +253,7 @@ def test_the_loop_rolls_back_one_enqueued_step_after_the_tripping_update_and_goe
     assert any(n in rolled[0] for n in (('recovery-1-3.npz',) if recovery_interval
                                         else ('checkpoint-0.npz', 'model_best.npz', 'last.npz'))), rolled
     at = loop_journal.index(('tripped', 12, 3))
+    assert at + 1 in waited     # the rollback waits for the writer: update 11's file may still be with it
     steps = [e[1] for e in loop_journal if e[0] == 'step']
     if recovery_interval:
         assert loop_journal[at - 2:at] == [('step', 12), ('drain',)] and loop_journal[at + 1] == ('recovery', 4)

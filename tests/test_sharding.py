@@ -357,6 +357,9 @@ def _run_drill(mode, workdir, devices):
         XLA_FLAGS=f'--xla_force_host_platform_device_count={devices}',
         TIMM_TPU_DRILL_DEVICES=str(devices),
         TF_CPP_MIN_LOG_LEVEL='3',
+        # a cache of the child's own: an 8-device step READ from a cache another process filled dies in
+        # XLA:CPU's collective rendezvous (rc -6 after 40 s); one the child compiles does not
+        JAX_COMPILATION_CACHE_DIR=os.path.join(str(workdir), f'jax_cache_{mode}'),
     )
     r = subprocess.run([sys.executable, _DRILL, mode, str(workdir)],
                        capture_output=True, text=True, env=env, cwd=REPO_ROOT, timeout=300)

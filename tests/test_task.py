@@ -140,3 +140,58 @@ def test_ema_decay_zero_syncs_to_model(mesh8):
     params = jax.tree.leaves(nnx.state(task.model, nnx.Param))
     ema = jax.tree.leaves(task.ema_params)
     assert all(np.allclose(np.asarray(p), np.asarray(e)) for p, e in zip(params, ema))
+
+
+class _TinyNet(nnx.Module):
+    def __init__(self, rngs):
+        self.fc1 = nnx.Linear(24, 48, rngs=rngs)
+        self.fc2 = nnx.Linear(48, 10, rngs=rngs)
+        self.num_classes = 10
+
+    def __call__(self, x):
+        return self.fc2(nnx.relu(self.fc1(x.reshape(x.shape[0], -1))))
+
+
+@pytest.mark.parametrize('mu_dtype', ['float32', 'bfloat16'], ids=['fp32', 'mu_bf16'])
+def test_step_update_follows_hand_written_adamw_and_ema(mesh8, mu_dtype):
+    """Five donated steps of the task's `step.update` / `step.ema` against AdamW and EMA written out in plain
+    jax.numpy and fed the step's own gradients: bias-corrected moments, decoupled decay on the matrices only (the
+    factory's mask), the first moment kept in `mu_dtype` between steps, ema = d * ema + (1 - d) * p at the
+    controller's d."""
+    lr, wd, b1, b2, eps = 0.01, 0.05, 0.9, 0.999, 1e-8
+    model = _TinyNet(nnx.Rngs(0))
+    opt = create_optimizer_v2(model, opt='adamw', lr=lr, weight_decay=wd, mu_dtype=mu_dtype)
+    grads_seen, chain_update = [], opt.update
+
+    def update(grads, *args, **kwargs):  # the gradients as the chain gets them, out of the jitted step
+        jax.debug.callback(lambda g: grads_seen.append(jax.tree.map(jnp.array, g)), grads)
+        return chain_update(grads, *args, **kwargs)
+
+    opt.update = update
+    task = ClassificationTask(model, optimizer=opt)
+    task.setup_ema(decay=0.99)
+
+    p = ema = jax.tree.map(jnp.asarray, jax.device_get(nnx.state(model, nnx.Param)))
+    m = jax.tree.map(lambda a: jnp.zeros_like(a, dtype=mu_dtype), p)
+    v = jax.tree.map(jnp.zeros_like, p)
+    # optax writes `b1 * mu` with a python b1, so the product is taken in mu's dtype: a bfloat16 moment decays by
+    # bfloat16(0.9) = 0.8984375 (the compiled step keeps the product itself in float32)
+    b1_kept = float(jnp.asarray(b1, mu_dtype))
+    rng = np.random.RandomState(0)
+    for t in range(1, 6):
+        batch = {'input': jnp.asarray(rng.rand(8, 2, 2, 6), jnp.float32), 'target': jnp.asarray(rng.randint(0, 10, 8))}
+        task.train_step(batch, lr=lr, step=t)
+        jax.effects_barrier()
+        g = grads_seen[-1]
+        m = jax.tree.map(lambda m_, g_: b1_kept * m_.astype(jnp.float32) + (1 - b1) * g_, m, g)
+        v = jax.tree.map(lambda v_, g_: b2 * v_ + (1 - b2) * g_ * g_, v, g)
+        p = jax.tree.map(
+            lambda p_, m_, v_: p_ - lr * (m_ / (1 - b1 ** t) / (jnp.sqrt(v_ / (1 - b2 ** t)) + eps)
+                                          + wd * p_ * (p_.ndim > 1)), p, m, v)
+        m = jax.tree.map(lambda m_: m_.astype(mu_dtype), m)
+        d = task.ema.get_decay(t)  # the controller's schedule: 0 (a copy) at step 1, then 0.99
+        ema = jax.tree.map(lambda e_, p_: d * e_ + (1 - d) * p_, ema, p)
+    assert len(grads_seen) == 5
+    for got, want in ((nnx.state(task.model, nnx.Param), p), (task.ema_params, ema)):
+        for a, b in zip(jax.tree.leaves(jax.device_get(got)), jax.tree.leaves(want), strict=True):
+            assert float(np.abs(a - b).max()) <= 1e-6

@@ -170,6 +170,6 @@ def audit_softmax_policy(fn=None, args=None,
 @rule('dtype-promotion', 'B',
       'under a declared-bf16 softmax policy the exp/div pipeline stays '
       'bf16 (the f32 max-subtraction is the one allowed upcast) — a stray '
-      'upcast means TIMM_TPU_SOFTMAX_DTYPE silently disconnected')
+      'upcast means set_softmax_dtype silently disconnected')
 def dtype_promotion(ctx: AnalysisContext) -> List[Finding]:
     return audit_softmax_policy()

@@ -61,7 +61,7 @@ def silent_except(ctx: AnalysisContext) -> List[Finding]:
 @rule('fp32-softmax', 'A',
       'layers must route softmax dtype through config.softmax_with_policy; '
       'a hard-coded fp32 upcast next to a softmax bypasses '
-      'TIMM_TPU_SOFTMAX_DTYPE (config.py is the one allowed location)')
+      'set_softmax_dtype (config.py is the one allowed location)')
 def fp32_softmax(ctx: AnalysisContext) -> List[Finding]:
     findings = []
     for path in ctx.source_files(_PACKAGE, 'layers'):

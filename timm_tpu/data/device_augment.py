@@ -250,28 +250,13 @@ class DeviceAugment:
     (bucketed loaders hit a small fixed program set, zero recompiles after
     warmup). On accelerators the batch is donated, freeing the staged
     uint8/param buffers as soon as the program runs (see
-    batch_donate_argnums for why CPU is excluded).
-
-    `fused_epilogue` (default: TIMM_TPU_PALLAS_AUGMENT=1) routes the image
-    epilogue through the one-pass Pallas kernel
-    (kernels/augment_epilogue.py, registered win-or-delete); it only covers
-    'const' erase mode, and the kernel wrapper itself falls back to this XLA
-    program for out-of-regime batches, so the switch is always safe."""
+    batch_donate_argnums for why CPU is excluded)."""
 
     def __init__(self, mean, std, re_mode='const', re_mean=None, re_std=None,
                  num_classes=0, smoothing=0.0, noise_seed=42,
-                 out_dtype=jnp.float32, fused_epilogue=None):
-        if fused_epilogue is None:
-            import os
-            fused_epilogue = os.environ.get('TIMM_TPU_PALLAS_AUGMENT', '0') == '1'
-        if fused_epilogue:
-            from timm_tpu.kernels.augment_epilogue import augment_image_batch_fused
-            augment_fn = augment_image_batch_fused
-        else:
-            augment_fn = augment_image_batch
-        self.fused_epilogue = bool(fused_epilogue)
+                 out_dtype=jnp.float32):
         self.fn = jax.jit(functools.partial(
-            augment_fn,
+            augment_image_batch,
             mean=tuple(mean), std=tuple(std), re_mode=re_mode,
             re_mean=tuple(re_mean if re_mean is not None else (0.0,) * len(mean)),
             re_std=tuple(re_std if re_std is not None else (1.0,) * len(std)),

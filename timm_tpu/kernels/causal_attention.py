@@ -266,10 +266,6 @@ def _register():
     register(KernelSpec(
         name='causal_flash_attention',
         module=__name__,
-        regime='causal self-attention over thousands of positions with wide heads (latent attention at '
-               'S = 8192, D = 256): the XLA path materialises (heads, block, S) float32 scores per query block',
-        gate='beat the XLA query-block path at S >= 2048 on TPU or be deleted (v5e, one MLA layer forward and '
-             'backward at 2 x 20 x 8192 x 256: 64.5 ms against 578 ms, PR 26)',
         parity_tol=2e-2,
         kernel_fn=_registry_kernel,
         reference_fn=_registry_reference,
@@ -328,7 +324,6 @@ def _register():
                      'backward 11.00 against 30.34 (PR 41)',
             ),
         ),
-        backends=('tpu',),
     ))
 
 

@@ -344,11 +344,6 @@ def _register():
     register(KernelSpec(
         name='flash_attention',
         module=__name__,
-        regime='self-attention at image-model lengths (N <= 1024) on the qkv product\'s own (B, N, 3 * H * D) '
-               'layout, unmasked or under a key-padding mask: the XLA path writes (B, H, N, N) float32 scores '
-               'and probabilities to HBM in both passes and transposes heads around them; the pair does neither',
-        gate='the ViT-B/16 train step on a v5e is faster with the pair than with `_sdpa` (PERF.md section 6, PR 40) '
-             'or the pair is deleted',
         parity_tol=2e-2,
         kernel_fn=_registry_kernel,
         reference_fn=_registry_reference,
@@ -361,7 +356,6 @@ def _register():
                 desc='ViT-B/16 at 224: the benchmark cell\'s shape, no mask'),
             masked(576, 2), masked(784, 1), masked(1024, 1),
         ),
-        backends=('tpu',),
     ))
 
 

@@ -8,8 +8,7 @@ has to beat), (b) a **declared regime** — the concrete shapes/dtypes/mask
 pattern where it claims to win, split into a `dry` arm (tiny, CPU-interpret,
 tier-1-smoked) and a `live` arm (the claimed shapes, decided on hardware) —
 and (c) a **parity tolerance**. harness.py consumes these specs to
-auto-generate the per-kernel parity test, the perfbudget `kernels` probe
-metrics, and `harness.run_kernel_ab`'s keep/delete verdict lines; an
+auto-generate the per-kernel parity test; an
 unregistered kernel module cannot land (tests/test_kernels.py lint).
 
 Kernel modules register themselves at import time; `ensure_registered()`
@@ -20,13 +19,12 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Tuple
 
-__all__ = ['KernelCase', 'KernelSpec', 'register', 'unregister', 'get',
-           'all_specs', 'kernel_names', 'ensure_registered', 'default_io_bytes']
+__all__ = ['KernelCase', 'KernelSpec', 'register', 'get', 'all_specs', 'kernel_names', 'ensure_registered']
 
 # modules whose import populates the registry (the portfolio)
-_PORTFOLIO = ('flash_attention', 'fused_adamw', 'augment_epilogue', 'causal_attention')
+_PORTFOLIO = ('flash_attention', 'causal_attention')
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,19 +47,14 @@ class KernelSpec:
     `kernel_fn` / `reference_fn` share one signature: ``fn(**inputs,
     **case.statics)`` where `inputs = make_inputs(seed=..., **case.dry)`
     (or `.live`). Outputs may be a single array or a pytree; parity compares
-    them leaf-for-leaf. `backends` scopes where the win claim is decidable —
-    off those backends the harness emits a `pending` verdict (parity still
-    measured, via `pallas_call(interpret=True)`)."""
+    them leaf-for-leaf."""
     name: str
     module: str                      # python module the lint checks off
-    regime: str                      # prose: where the kernel claims to win
-    gate: str                        # the win-or-delete sentence
     parity_tol: float
     kernel_fn: Callable
     reference_fn: Callable
     make_inputs: Callable            # (seed=0, **case_kwargs) -> {name: array}
     cases: Tuple[KernelCase, ...]
-    backends: Tuple[str, ...] = ('tpu',)
 
     def __post_init__(self):
         if not self.cases:
@@ -79,10 +72,6 @@ def register(spec: KernelSpec) -> KernelSpec:
         raise ValueError(f'kernel {spec.name!r} already registered')
     _REGISTRY[spec.name] = spec
     return spec
-
-
-def unregister(name: str) -> None:
-    _REGISTRY.pop(name, None)
 
 
 def ensure_registered() -> None:
@@ -108,21 +97,3 @@ def all_specs() -> Tuple[KernelSpec, ...]:
 def kernel_names() -> Tuple[str, ...]:
     ensure_registered()
     return tuple(sorted(_REGISTRY))
-
-
-def default_io_bytes(spec: KernelSpec, case: KernelCase,
-                     inputs: Optional[Dict] = None, seed: int = 0) -> int:
-    """Analytic one-pass HBM bytes of a kernel invocation: every input
-    operand read once + every output written once. For a Pallas kernel this
-    IS the HBM traffic contract (each grid block is DMA'd HBM->VMEM exactly
-    once; intermediates live in VMEM) — the number the XLA arm's pre-fusion
-    ``cost_analysis()['bytes accessed']`` is compared against in the
-    perfbudget `kernels` probe."""
-    import jax
-
-    if inputs is None:
-        inputs = spec.make_inputs(seed=seed, **case.dry)
-    total = sum(int(leaf.nbytes) for leaf in jax.tree.leaves(inputs))
-    out = jax.eval_shape(lambda kw: spec.reference_fn(**kw, **case.statics), inputs)
-    total += sum(int(leaf.size) * leaf.dtype.itemsize for leaf in jax.tree.leaves(out))
-    return total

@@ -18,9 +18,8 @@ Compute-precision policy (mirrors the reference's `fast_norm` global):
   ``None`` keeps the framework fp32-stats path bit-for-bit; ``bfloat16``
   computes mean/var in bf16 (PERF.md: ~25 LayerNorms upcast per ViT step).
 
-Both are seeded from ``TIMM_TPU_SOFTMAX_DTYPE`` / ``TIMM_TPU_NORM_DTYPE``
-(values: ``float32`` | ``bfloat16`` | empty = default) so each lever can be
-A/B'd in a fresh process, and both are overridable per call/instance.
+The norm policy is seeded from ``TIMM_TPU_NORM_DTYPE`` (values: ``float32`` |
+``bfloat16`` | empty = default), and both are overridable per call/instance.
 Every knob ships OFF by default with an exact-parity guarantee when disabled.
 """
 from __future__ import annotations
@@ -61,7 +60,7 @@ def resolve_dtype_arg(value, allow_none: bool = True):
     return jnp.dtype(value)
 
 
-_SOFTMAX_DTYPE = resolve_dtype_arg(os.environ.get('TIMM_TPU_SOFTMAX_DTYPE', ''))
+_SOFTMAX_DTYPE = None
 _NORM_DTYPE = resolve_dtype_arg(os.environ.get('TIMM_TPU_NORM_DTYPE', ''))
 
 

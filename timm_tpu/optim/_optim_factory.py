@@ -113,11 +113,6 @@ class Optimizer:
         params = optax.apply_updates(params, updates)
     """
 
-    # set by create_optimizer_v2 iff the chain is plain adamw — the exact
-    # recipe (b1/b2/eps/wd/mu_dtype/mask) the fused one-pass kernel mirrors;
-    # None means TrainingTask(fused_update=True) must refuse this optimizer
-    fused_adamw_args: Optional[Dict[str, Any]] = None
-
     def __init__(
             self,
             tx_factory: Callable[..., optax.GradientTransformation],
@@ -397,8 +392,7 @@ def create_optimizer_v2(
     Adam-family optimizers (adam/adamw/nadamw/lamb/...) reduced, halving its
     HBM read+write traffic per step (~0.7 GB/step of ViT-B's 2.08 GB
     optimizer traffic, PERF.md §2 item 3); v stays fp32. Default None keeps
-    fp32 state bit-for-bit. Seeded from TIMM_TPU_MU_DTYPE when unset so
-    it can be A/B'd per process.
+    fp32 state bit-for-bit.
     """
     is_model = isinstance(model_or_params, nnx.Module)
     lr_scales = None
@@ -431,9 +425,6 @@ def create_optimizer_v2(
         opt_args['eps'] = eps
     if info.has_momentum:
         opt_args['momentum'] = momentum
-    if mu_dtype is None:
-        import os
-        mu_dtype = os.environ.get('TIMM_TPU_MU_DTYPE') or None
     if mu_dtype is not None:
         from ..layers.config import resolve_dtype_arg
         opt_args['mu_dtype'] = resolve_dtype_arg(mu_dtype)
@@ -502,20 +493,4 @@ def create_optimizer_v2(
         caution=caution,
         defaults={'opt': opt, 'weight_decay': weight_decay},
     )
-    # The one-pass fused AdamW+EMA kernel (kernels/fused_adamw.py) mirrors
-    # exactly the plain adamw chain: inject_hyperparams(adamw)(lr, ...). Any
-    # wrapper that changes the update math (lookahead, caution, layer-decay
-    # lr scales, coupled-L2 rebinding) is out of regime, so the recipe is
-    # attached only when none apply; TrainingTask(fused_update=True) requires
-    # it and refuses optimizers without it.
-    if (opt_name == 'adamw' and not use_lookahead and not caution
-            and lr_scales is None and tx_factory is optax.adamw):
-        optimizer.fused_adamw_args = {
-            'b1': float(opt_args.get('b1', 0.9)),
-            'b2': float(opt_args.get('b2', 0.999)),
-            'eps': float(opt_args.get('eps', 1e-8)),
-            'weight_decay': float(opt_args.get('weight_decay', 0.0)),
-            'mu_dtype': opt_args.get('mu_dtype'),
-            'wd_mask': opt_args.get('mask'),
-        }
     return optimizer

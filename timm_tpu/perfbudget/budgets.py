@@ -81,14 +81,6 @@ TOLERANCES: Dict[str, tuple] = {
     'quant_halves_hbm': ('bool', 0.0),         # both ratios <= 0.55x fp32
     'quant_sharding_ok': ('bool', 0.0),
     'quant_scales_sharded': ('lower', 0.10),
-    # kernel portfolio (the `kernels` probe, kernels/harness.py): per kernel,
-    # jaxpr eqn counts of both arms band-pinned; `<k>_io_bytes` is an exact
-    # shape/dtype sum (tight band) while `<k>_ref_bytes_accessed` is the
-    # XLA:CPU cost-model estimate (loose band, like bytes_accessed above);
-    # `<k>_wins_bytes` is the one-pass-beats-reference bool the win-or-delete
-    # verdict rests on. `kernels_registered` pins the portfolio size (band
-    # with zero tolerance = exact count) so a dropped registration cannot
-    # pass silently.
     # elastic resize probe (resilience/elastic.py): pure legality — the
     # re-placed-after-resize state must land sharded on the new mesh and the
     # rescale solver must hold the global batch; device counts pinned exactly
@@ -96,27 +88,6 @@ TOLERANCES: Dict[str, tuple] = {
     'elastic_global_batch_ok': ('bool', 0.0),
     'elastic_devices_from': ('band', 0.0),
     'elastic_devices_to': ('band', 0.0),
-    'kernels_registered': ('band', 0.0),
-    'fused_adamw_eqns': ('band', 0.10),
-    'fused_adamw_ref_eqns': ('band', 0.10),
-    'fused_adamw_io_bytes': ('band', 0.02),
-    'fused_adamw_ref_bytes_accessed': ('band', 0.50),
-    'fused_adamw_wins_bytes': ('bool', 0.0),
-    'flash_attention_eqns': ('band', 0.10),
-    'flash_attention_ref_eqns': ('band', 0.10),
-    'flash_attention_io_bytes': ('band', 0.02),
-    'flash_attention_ref_bytes_accessed': ('band', 0.50),
-    'flash_attention_wins_bytes': ('bool', 0.0),
-    'augment_epilogue_eqns': ('band', 0.10),
-    'augment_epilogue_ref_eqns': ('band', 0.10),
-    'augment_epilogue_io_bytes': ('band', 0.02),
-    'augment_epilogue_ref_bytes_accessed': ('band', 0.50),
-    'augment_epilogue_wins_bytes': ('bool', 0.0),
-    'causal_flash_attention_eqns': ('band', 0.10),
-    'causal_flash_attention_ref_eqns': ('band', 0.10),
-    'causal_flash_attention_io_bytes': ('band', 0.02),
-    'causal_flash_attention_ref_bytes_accessed': ('band', 0.50),
-    'causal_flash_attention_wins_bytes': ('bool', 0.0),
 }
 _DEFAULT_TOL = ('band', 0.10)
 

@@ -29,12 +29,7 @@ check — same program test_sharding compiles, so the persistent cache is
 shared), ``serve`` drives the engine prewarm path, ``augment`` compiles the
 on-device data-path programs (fused image augment + donated naflex augment),
 ``naflex`` compiles the packed variable-resolution train step at one bucket
-shape, ``kernels`` lowers every registered Pallas kernel against its XLA
-reference at the declared dry regime shapes (kernels/harness.py) and budgets
-jaxpr eqns + the bytes story per kernel: analytic one-pass ``*_io_bytes``
-for the kernel arm (interpret-mode cost_analysis is emulation noise) vs the
-compiled reference's ``*_ref_bytes_accessed``, plus the ``*_wins_bytes``
-bool the win-or-delete verdict machinery keys on.
+shape.
 """
 from __future__ import annotations
 
@@ -61,10 +56,9 @@ class ProbeConfig:
     block_scan: Optional[bool] = None     # None = model default
     grad_accum: int = 1
     opt: str = 'adamw'
-    collect: str = 'full'   # 'trace' | 'full' | 'fwd' | 'serve' | 'quant' | 'augment' | 'naflex' | 'kernels' | 'elastic'
+    collect: str = 'full'   # 'trace' | 'full' | 'fwd' | 'serve' | 'quant' | 'augment' | 'naflex' | 'elastic'
     buckets: Tuple[int, ...] = (2, 4)     # serve only
     seq_len: int = 25                     # naflex packed probe only
-    fused_update: bool = False            # route the step through fused_adamw
     # batch spatial size when it is NOT a ctor kwarg (conv models size from
     # the data; their ctors reject img_size) — falls back to model_kwargs
     img_size: Optional[int] = None
@@ -127,16 +121,6 @@ DEFAULT_MATRIX: Tuple[ProbeConfig, ...] = (
     ProbeConfig(name='naflex_packed', model='test_naflexvit',
                 model_kwargs=(('num_classes', 10),),
                 batch_size=8, collect='naflex', seq_len=25),
-    # kernel portfolio: per registered Pallas kernel, jaxpr eqns of both arms
-    # + analytic one-pass io bytes vs the compiled XLA reference's bytes-
-    # accessed at the declared dry regime shapes (kernels/harness.py)
-    ProbeConfig(name='kernels', collect='kernels'),
-    # the fused AdamW+EMA train step: same test_vit step as 'base' but routed
-    # through the one-pass kernel — donation must survive (donation_ok) and
-    # the step must still lower/compile with the opt_state shardings intact
-    ProbeConfig(name='fused_update', model='test_vit',
-                model_kwargs=(('num_classes', 10), ('img_size', 32)),
-                batch_size=8, collect='full', fused_update=True),
     # elastic resize: state saved on an 8-device (2,4) mesh re-places on the
     # 4-device post-resize mesh (fsdp clamped by resolve_elastic_axes), the
     # rescale solver holds the global batch, and the RE-PLACED train step
@@ -324,8 +308,7 @@ def _probe_train(cfg: ProbeConfig) -> Dict:
         return ClassificationTask(model,
                                   optimizer=create_optimizer_v2(model, opt=cfg.opt, lr=0.1),
                                   mesh=mesh, grad_accum_steps=cfg.grad_accum,
-                                  train_loss_fn=LabelSmoothingCrossEntropy(0.1),
-                                  fused_update=cfg.fused_update)
+                                  train_loss_fn=LabelSmoothingCrossEntropy(0.1))
 
     task = build_task()
     batch = shard_batch(batch, mesh)
@@ -766,20 +749,6 @@ def _probe_elastic(cfg: ProbeConfig) -> Dict:
     return metrics
 
 
-def _probe_kernels(cfg: ProbeConfig) -> Dict:
-    """Per-kernel lowering A/B over the registry (kernels/harness.py): one
-    budget anchor per kernel (its first declared regime case, dry arm).
-    ``<k>_io_bytes`` is the kernel's analytic one-pass HBM contract and
-    ``<k>_ref_bytes_accessed`` the compiled XLA reference's cost-model bytes;
-    fused_adamw's reference IS the unfused optax update+EMA chain, so its
-    ``fused_adamw_wins_bytes`` bool is exactly the ISSUE-12 one-pass-
-    reduction acceptance gate. ``kernels_registered`` pins the portfolio
-    size so a silently dropped registration fails the budget diff."""
-    from ..kernels.harness import kernel_metrics
-
-    return dict(kernel_metrics())
-
-
 def probe_config(cfg: ProbeConfig) -> Dict:
     """Probe one config; global mesh is saved/restored so probes compose with
     whatever mesh the calling process (tests, a CLI) had active."""
@@ -795,8 +764,6 @@ def probe_config(cfg: ProbeConfig) -> Dict:
             return _probe_augment(cfg)
         if cfg.collect == 'naflex':
             return _probe_naflex(cfg)
-        if cfg.collect == 'kernels':
-            return _probe_kernels(cfg)
         if cfg.collect == 'elastic':
             return _probe_elastic(cfg)
         return _probe_train(cfg)

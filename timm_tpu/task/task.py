@@ -49,7 +49,6 @@ import numpy as np
 import optax
 from flax import nnx
 
-from ..kernels.fused_adamw import fused_adamw_step, validate_fused_opt_state
 from ..optim import Optimizer
 from ..parallel import (
     build_opt_shardings, build_param_shardings, get_global_mesh, replicate_sharding,
@@ -83,7 +82,6 @@ class TrainingTask:
             nonfinite_guard: Optional[bool] = None,
             nonfinite_tolerance: Optional[int] = None,
             partition_rules=None,
-            fused_update: bool = False,
     ):
         self.model = model
         self.optimizer = optimizer
@@ -93,9 +91,6 @@ class TrainingTask:
         self.clip_grad = clip_grad
         self.clip_mode = clip_mode
         self.partition_rules = partition_rules
-        # opt-in one-pass fused AdamW+EMA update (kernels/fused_adamw.py);
-        # the optax path stays the default and the parity oracle
-        self.fused_update = bool(fused_update)
         # non-finite sentinel (resilience/sentinel.py): an all-finite reduction
         # over loss+grads fused into the jitted step; bad steps commit nothing
         # and K consecutive bad steps abort via NonFiniteError. Default on
@@ -142,17 +137,6 @@ class TrainingTask:
         else:
             self.opt_state = None
             self._opt_shardings = None
-
-        if self.fused_update and self.optimizer is not None:
-            # fail at construction, not first step: the fused kernel mirrors
-            # the plain adamw chain only (create_optimizer_v2 attaches
-            # fused_adamw_args exactly when that chain was built)
-            if getattr(self.optimizer, 'fused_adamw_args', None) is None:
-                raise ValueError(
-                    'fused_update=True requires a plain adamw optimizer from '
-                    'create_optimizer_v2 (no lookahead/caution/layer-decay '
-                    'wrappers) — this optimizer carries no fused_adamw_args')
-            validate_fused_opt_state(self.opt_state)
 
         self.ema: Optional[ModelEmaV3] = None
         self.ema_params = None
@@ -264,10 +248,6 @@ class TrainingTask:
         clip_grad, clip_mode = self.clip_grad, self.clip_mode
         has_ema = self.ema_params is not None
         guard = self._nonfinite_guard
-        fused_cfg = getattr(optimizer, 'fused_adamw_args', None) if self.fused_update else None
-        if self.fused_update and fused_cfg is None:
-            raise ValueError('fused_update=True but the optimizer carries no '
-                             'fused_adamw_args (plain adamw chain required)')
         loss_forward = self.loss_forward
         step_counters = self.step_counters
         normalize_input = self.normalize_input
@@ -376,17 +356,8 @@ class TrainingTask:
                     grads, _ = dispatch_clip_grad(grads, clip_grad, mode=clip_mode, params=params_for_clip)
 
             with tracing.scope('step.update'):
-                if fused_cfg is not None:
-                    # one-pass fused AdamW+EMA kernel: replaces update + apply
-                    # (+ the EMA pass below); opt_state structure is preserved so
-                    # the shardings/donation annotations hold unchanged
-                    new_params, new_opt_state, fused_ema = fused_adamw_step(
-                        params, grads, opt_state, ema_params if has_ema else None,
-                        lr=lr, ema_decay=ema_decay, **fused_cfg)
-                else:
-                    updates, new_opt_state = optimizer.update(grads, opt_state, params, lr=lr)
-                    new_params = optax.apply_updates(params, updates)
-                    fused_ema = None
+                updates, new_opt_state = optimizer.update(grads, opt_state, params, lr=lr)
+                new_params = optax.apply_updates(params, updates)
             if guard:
                 # all-finite reduction over loss + raw grads; a bad step keeps
                 # params/opt_state/EMA bit-identical to the previous step
@@ -401,8 +372,7 @@ class TrainingTask:
                 # decay==0 naturally syncs EMA to model (reference ModelEmaV3
                 # lerp weight 1.0 during the update_after_step window).
                 with tracing.scope('step.ema'):
-                    new_ema = fused_ema if fused_ema is not None else \
-                        ema_update(ema_params, new_params, ema_decay)
+                    new_ema = ema_update(ema_params, new_params, ema_decay)
                 if guard:
                     with tracing.scope('step.guard'):
                         new_ema = jax.tree.map(select, new_ema, ema_params)

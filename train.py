@@ -72,11 +72,6 @@ def make_parser():
     group.add_argument('--block-scan', action='store_true', default=False,
                        help='run homogeneous transformer block stacks as one lax.scan '
                             'over stacked per-layer params (O(1)-in-depth trace/compile)')
-    group.add_argument('--fused-update', action='store_true', default=False,
-                       help='route the optimizer update through the one-HBM-pass fused '
-                            'AdamW+EMA Pallas kernel (timm_tpu/kernels/fused_adamw.py). '
-                            'Requires a plain adamw --opt chain; optax stays the default '
-                            'and the parity oracle')
     group.add_argument('--distill', default='', type=str, metavar='SPEC',
                        help="knowledge-distillation spec "
                             "'teacher=NAME[,kind=logit|feature][,alpha=F][,temperature=F]"
@@ -623,7 +618,6 @@ def main(argv=None):
             std=norm_std,
             nonfinite_guard=False if args.no_nonfinite_guard else None,
             nonfinite_tolerance=args.nonfinite_tolerance,
-            fused_update=args.fused_update,
             **task_kwargs,
         )
 
@@ -1075,6 +1069,8 @@ def _or_rollback(task, call, saver, rollback_budget):
     except NonFiniteError as e:
         if not rollback_budget or rollback_budget[0] <= 0 or saver is None:
             raise
+        if saver.async_writer is not None:
+            saver.async_writer.drain()  # the newest file may still be with the writer thread
         rb = resolve_auto_resume(saver.checkpoint_dir)
         if rb is None:
             raise

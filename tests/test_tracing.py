@@ -218,13 +218,14 @@ def test_every_recorded_name_is_declared_and_every_declared_name_is_recorded_and
                 open(os.path.join(bench, 'harness', 'cla_lm_train_runner.py')).read(),  # its `correct`: `lm.head_nll`
                 open(os.path.join(bench, 'harness', 'sconv_lm_readers.py')).read(),  # the short-convolution cell's: `sconv.proj, sconv.mix`
                 open(os.path.join(bench, 'harness', 'sconv_lm_train_runner.py')).read(),  # its `correct`: `sconv.rows`
+                open(os.path.join(bench, 'harness', 'kda_lm_readers.py')).read(),    # the delta-rule cell's: `kda.proj`, `kda.mix`, `kda.core`, `kda.rows`, `kda.chunks`
                 open(os.path.join(bench, 'harness', 'step_scopes.py')).read(),       # every cell's `step.*`, the image cells' `img.*`: `img.block`
                 inspect.getsource(train._host_line), inspect.getsource(train._setup_line)]
     unread = [name for name in tracing.SPANS if not any(f"'{name}'" in text for text in readers)]
     assert not unread, unread
     # the layers are the ones PERF.md section 3 and BENCHMARK.json name
     assert {layer for layer, _ in tracing.SPANS.values()} == {'entry and compile cache', 'input', 'step', 'attention', 'experts',
-                                                             'feed-forward', 'short convolution'}
+                                                             'feed-forward', 'short convolution', 'delta attention'}
 
 
 @pytest.mark.parametrize('record', [lambda n: tracing.scope(n), lambda n: tracing.device_counter(n, 1)], ids=['scope', 'device_counter'])

@@ -295,6 +295,13 @@ def _register():
                      'width costs what the padded one does in the products and moves half the bytes (PR 43)',
             ),
             KernelCase(
+                name='mqa_full_s8192_d128',
+                dry=dict(batch=1, heads=8, kv_heads=1, seq=256, head_dim=128),
+                live=dict(batch=1, heads=8, kv_heads=1, seq=8192, head_dim=128, dtype='bfloat16'),
+                desc='Solar-Open2-250B gated attention layer, one chip\'s 8 of 64 query heads on 1 of 8 key/value heads: '
+                     'the multi-query form once, the plain causal mask, no positions (PR 47)',
+            ),
+            KernelCase(
                 name='gqa_window4096_s16384_d128',
                 dry=dict(batch=1, heads=2, kv_heads=1, seq=5120, head_dim=128),
                 live=dict(batch=1, heads=28, kv_heads=4, seq=16384, head_dim=128, dtype='bfloat16'),

@@ -60,4 +60,5 @@ from .latent_attention import LatentAttention, causal_attention
 from .grouped_attention import GroupedQueryAttention, grouped_block_diffusion_attention, grouped_causal_attention
 from .chunked_linear_attention import ChunkedLinearAttention, chunk_summaries, chunk_window_attention, chunk_window_pairs
 from .short_conv import ShortConv, gated_short_conv
+from .delta_attention import KimiDeltaAttention, chunked_delta_rule
 from .moe import SparseMoe

@@ -53,6 +53,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils import tracing
 from .attention import apply_rot_embed_cat
+from .helpers import head_slice
 from .latent_attention import CORE_OUT, SLOW_FROM, _warn_xla_core
 from .weight_init import trunc_normal_
 
@@ -158,10 +159,9 @@ class ChunkedLinearAttention(nnx.Module):
     def take_heads(self, name: str, whole):
         """This share's slice of a leaf of the whole `num_heads`-head layer, by the leaf's name in this module
         (`q_proj.kernel`, `proj.kernel`, `phi`, ..): what a loader of published weights hands a share."""
-        first, last = self.head_offset * self.head_dim, (self.head_offset + self.heads_held) * self.head_dim
         if name in ('phi', 'mu'):
-            return whole[self.head_offset:self.head_offset + self.heads_held]
-        return whole[first:last] if name == 'proj.kernel' else whole[:, first:last]
+            return head_slice(whole, 0, self.head_offset, self.heads_held, 1)
+        return head_slice(whole, 0 if name == 'proj.kernel' else 1, self.head_offset, self.heads_held, self.head_dim)
 
     def qkv(self, x, rope):
         """-> q, k, v (B, H, N, D) of the heads held, q and k turned."""

@@ -1,0 +1,249 @@
+"""A gated delta-rule linear attention over token sequences with a per-channel
+decay (KDA, Kimi Delta Attention: Kimi Linear, arXiv:2510.26692; the mixer of
+three layers of four in Solar-Open2-250B, beside a softmax attention on the
+fourth): no softmax, no positions, a carried state of (head_dim x head_dim)
+float32 numbers a head.
+
+For the normalised input a (B, S, dim), every linear map bias-free, H heads of width D:
+  q~, k~, v = SiLU(conv(a W_q)), SiLU(conv(a W_k)), SiLU(conv(a W_v))     conv: causal, depthwise, `conv_size` taps a
+                                                                          channel, the LAST tap on the current position
+  q = q~ / ||q~|| * D^-1/2,  k = k~ / ||k~||                              per head and position
+  g = -exp(A_log) * softplus(a W_f_down W_f_up + dt_bias)                 log of the decay, per CHANNEL, <= 0; A_log a head
+  beta = 2 * sigmoid(a W_beta)                                            one a head, in (0, 2): eigenvalues in (-1, 1)
+  S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T   S_0 = 0 at every sequence's start
+  o_t = S_t^T q_t
+  y = [RMSNorm_head(o) * sigmoid(a W_g_down W_g_up)] W_o                  one learned scale of D for all heads
+
+The recurrence is computed in CHUNKS of C positions (`chunked_delta_rule`). With
+G the running sum of g inside a chunk (inclusive), S the state at the chunk's
+start and u_t = beta_t (v_t - (Diag(exp g_t) S_{t-1})^T k_t) the value the rule
+writes at t, S_t = Diag(e^{G_t}) S + sum_{j<=t} Diag(e^{G_t - G_j}) k_j u_j^T, so
+the chunk's rows satisfy the unit-lower-triangular system
+
+  (I + Diag(beta) A) U = Diag(beta) (V - (K e^G) S),   A[t, j] = sum_d k_t k_j e^{G_t - G_j}  (j < t)
+
+whose inverse T gives W = T Diag(beta) (K e^G) and U~ = T Diag(beta) V once a
+chunk, for every chunk at once; then, chunk after chunk (`lax.scan`, carrying S):
+
+  U = U~ - W S;   O = (Q e^G) S + P U,   P[t, j] = sum_d q_t k_j e^{G_t - G_j}  (j <= t);
+  S' = Diag(e^{G_C}) S + (K e^{G_C - G})^T U.
+
+No exponent above zero is ever taken, whatever the decays: A and P are built in
+sub-blocks of `SUB` rows, a row block against the EARLIER columns through the
+decay to its own first row (two factors, each <= 1, so a dense product), and
+against its own columns pair by pair. T is the exact block inverse: rows by
+substitution inside a sub-block, sub-blocks merged two by two. g, its sums,
+both matrices, T and the state are float32 and every product of the core runs
+at `Precision.HIGHEST`: a bfloat16 pass in the solve or a bfloat16 state is not
+a rounding here but another model (`tests/test_delta_attention.py`). The
+backward pass is autodiff's: the scan reversed, its residuals the chunk-boundary
+states and U (B x H x S / C x D x D x 4 bytes: 67 MB a layer at 8 heads x 8192),
+never a state a position.
+
+The layer is TOLD WHICH HEADS IT HOLDS (`heads_held`, `head_offset`), as
+`ChunkedLinearAttention` is: it projects to those heads only, carries their
+taps, `A_log` and `dt_bias`, and returns their part of the output product. The
+two low-rank gates' down-products and the norm's scale are whole on every chip.
+Nothing is cached here: the (H, D, D) state and `conv_size - 1` rows a decode
+step carries belong to `serve/` (ROADMAP "Reach").
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from flax import nnx
+
+from ..utils import tracing
+from .helpers import head_slice
+from .norm import RmsNorm
+from .short_conv import _shift
+from .weight_init import trunc_normal_
+
+__all__ = ['KimiDeltaAttention', 'chunked_delta_rule']
+
+SUB = 16            # rows of a sub-block: pairwise inside it, dense products against the columns before it
+L2_EPS = 1e-6
+HIGHEST = jax.lax.Precision.HIGHEST
+_written = jax.lax.optimization_barrier     # as `short_conv.py`: the middle stays an operation of its own
+
+
+def _decayed_pairs(rows, k, G, sub: int):
+    """For each x of `rows` (N, C, D): M[t, j] = sum_d x_t k_j e^{G_t - G_j} for j <= t, 0 above the diagonal; k and
+    G (N, C, D), G non-increasing along C. A row sub-block meets the columns of EARLIER sub-blocks through its own
+    first row r: (x_t e^{G_t - r}) . (k_j e^{r - G_j}), both exponents <= 0; its own columns pair by pair."""
+    N, C, D = G.shape
+    ns = C // sub
+    Gs, ks = G.reshape(N, ns, sub, D), k.reshape(N, ns, sub, D)
+    first = Gs[:, :, :1]
+    seen = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+    own_decay = jnp.exp(jnp.where(seen[:, :, None], Gs[:, :, :, None] - Gs[:, :, None, :], -jnp.inf))   # (N, ns, sub, sub, D)
+    if ns > 1:
+        earlier = (jnp.arange(C) // sub)[None, :] < jnp.arange(ns)[:, None]                              # (ns, C)
+        k_before = k[:, None] * jnp.exp(jnp.where(earlier[None, :, :, None], first - G[:, None], -jnp.inf))
+        to_first = jnp.exp(Gs - first)
+        place = jnp.eye(ns, dtype=G.dtype)[None, :, None, :, None]
+    out = []
+    for x in rows:
+        xs = x.reshape(N, ns, sub, D)
+        own = (xs[:, :, :, None] * ks[:, :, None] * own_decay).sum(-1)                                   # (N, ns, sub, sub)
+        if ns == 1:
+            out.append(own.reshape(N, C, C))
+            continue
+        before = jnp.einsum('nasd,nacd->nasc', xs * to_first, k_before, precision=HIGHEST)
+        out.append((before.reshape(N, ns, sub, ns, sub) + own[:, :, :, None] * place).reshape(N, C, C))
+    return out
+
+
+def _unit_lower_inverse(L, sub: int):
+    """The inverse of unit-lower-triangular L (N, C, C), C = sub x a power of two: inside a diagonal block of `sub` rows by
+    substitution (row t of the inverse is e_t - L[t, :t] @ the rows before it), two blocks merged as
+    [[T1, 0], [-T2 L21 T1, T2]]. What lies above L's diagonal is not read."""
+    C = L.shape[-1]
+    if C <= sub:
+        eye = jnp.eye(C, dtype=L.dtype)
+        rows = [jnp.broadcast_to(eye[0], L.shape[:-2] + (C,))]
+        for t in range(1, C):
+            rows.append(eye[t] - jnp.einsum('...j,...jc->...c', L[..., t, :t], jnp.stack(rows, axis=-2), precision=HIGHEST))
+        return jnp.stack(rows, axis=-2)
+    h = C // 2
+    T = _unit_lower_inverse(jnp.stack([L[..., :h, :h], L[..., h:, h:]]), sub)
+    T1, T2 = T[0], T[1]
+    T21 = -jnp.matmul(jnp.matmul(T2, L[..., h:, :h], precision=HIGHEST), T1, precision=HIGHEST)
+    return jnp.concatenate([jnp.concatenate([T1, jnp.zeros_like(T21)], axis=-1), jnp.concatenate([T21, T2], axis=-1)], axis=-2)
+
+
+def _chunk_terms(q, k, v, g, beta, sub: int):
+    """What a chunk needs that does not depend on its incoming state, for N chunks at once: q, k, g (N, C, D), v (N, C,
+    Dv), beta (N, C) -> W, U~, Q e^G, P, K e^{G_C - G}, e^{G_C}."""
+    G = jnp.cumsum(g, axis=1)
+    decay = jnp.exp(G)
+    A, P = _decayed_pairs((k, q), k, G, sub)
+    C = G.shape[1]
+    strictly = jnp.arange(C)[:, None] > jnp.arange(C)[None, :]
+    T = _unit_lower_inverse(jnp.eye(C, dtype=G.dtype) + jnp.where(strictly, beta[:, :, None] * A, 0.0), sub)
+    both = jnp.matmul(T, beta[:, :, None] * jnp.concatenate([k * decay, v], axis=-1), precision=HIGHEST)
+    D = k.shape[-1]
+    return both[..., :D], both[..., D:], q * decay, P, k * jnp.exp(G[:, -1:] - G), decay[:, -1]
+
+
+def chunked_delta_rule(q, k, v, g, beta, chunk: int = 64, state_dtype=jnp.float32):
+    """The gated delta rule with a per-channel decay, S_0 = 0 for every sequence: q, k, g (B, H, S, D), v (B, H, S, Dv),
+    beta (B, H, S); g the log of the decay (<= 0) -> o (B, H, S, Dv) float32, o_t = S_t^T q_t. S a multiple of the
+    chunk (a shorter sequence is one chunk), the chunk of `SUB` x a power of two or under `SUB`. `state_dtype` is the carried
+    state's: float32; the tests read what bfloat16 costs."""
+    B, H, S, D = q.shape
+    C = min(chunk, S)
+    sub = min(SUB, C)
+    if S % C or C % sub or (C // sub) & (C // sub - 1):
+        raise ValueError(f'{S} positions in chunks of {C} and sub-blocks of {sub}: the chunk has to divide the sequence, and '
+                         f'be {sub} times a power of two')
+    f32, n = jnp.float32, S // C
+    chunks = lambda t: t.astype(f32).reshape((B * H * n, C) + t.shape[3:])  # noqa: E731
+    terms = jax.checkpoint(functools.partial(_chunk_terms, sub=sub))(*(chunks(t) for t in (q, k, v, g, beta)))
+    # (B H n, ..) -> (n, B H, ..): the scan runs over a sequence's chunks, every head of every sequence at once
+    W, Ut, Qg, P, Kd, end = (t.reshape((B * H, n) + t.shape[1:]).swapaxes(0, 1) for t in terms)
+
+    def step(state, xs):
+        W, Ut, Qg, P, Kd, end = xs
+        s = state.astype(f32)
+        U = Ut - jnp.matmul(W, s, precision=HIGHEST)
+        out = jnp.matmul(Qg, s, precision=HIGHEST) + jnp.matmul(P, U, precision=HIGHEST)
+        s = end[:, :, None] * s + jnp.einsum('ncd,nce->nde', Kd, U, precision=HIGHEST)
+        return s.astype(state_dtype), out
+
+    _, out = jax.lax.scan(step, jnp.zeros((B * H, D, v.shape[-1]), state_dtype), (W, Ut, Qg, P, Kd, end))
+    return out.swapaxes(0, 1).reshape(B, H, S, v.shape[-1])
+
+
+def _causal_taps(x, w):
+    """x (B, S, ch), w (ch, K) -> y_t = sum_j w[:, j] x_{t - (K - 1 - j)}, zero before the sequence, at float32."""
+    K, f32 = w.shape[1], jnp.float32
+    return sum(w[:, j].astype(f32) * _shift(x, K - 1 - j).astype(f32) for j in range(K))
+
+
+class KimiDeltaAttention(nnx.Module):
+    """a (B, S, dim) -> this share's part of the output product (B, S, dim)."""
+
+    def __init__(
+            self,
+            dim: int,
+            num_heads: int,
+            head_dim: int = 128,
+            conv_size: int = 4,
+            gate_rank: Optional[int] = None,
+            heads_held: Optional[int] = None,
+            head_offset: int = 0,
+            chunk: int = 64,
+            eps: float = 1e-5,
+            *,
+            dtype=None,
+            param_dtype=jnp.float32,
+            rngs: nnx.Rngs,
+    ):
+        held = heads_held or num_heads
+        if head_offset < 0 or head_offset + held > num_heads:
+            raise ValueError(f'heads {head_offset} .. {head_offset + held} are not among {num_heads}')
+        self.num_heads, self.heads_held, self.head_offset, self.head_dim = num_heads, held, head_offset, head_dim
+        self.chunk = chunk
+        rank, wide = gate_rank or head_dim, held * head_dim
+        init = trunc_normal_(std=0.02)
+        linear = functools.partial(nnx.Linear, use_bias=False, dtype=dtype, param_dtype=param_dtype, kernel_init=init, rngs=rngs)
+        self.q_proj, self.k_proj, self.v_proj = linear(dim, wide), linear(dim, wide), linear(dim, wide)
+        taps = lambda: nnx.Param(init(rngs.params(), (wide, conv_size), param_dtype))  # noqa: E731
+        self.q_taps, self.k_taps, self.v_taps = taps(), taps(), taps()
+        self.f_down, self.f_up = linear(dim, rank), linear(rank, wide)
+        self.beta_proj = linear(dim, held)
+        # Kimi Linear's: A = exp(A_log) uniform in (1, 16) a head; dt_bias the inverse softplus of a step in (1e-3, 0.1)
+        key = rngs.params
+        self.A_log = nnx.Param(jnp.log(jax.random.uniform(key(), (held,), param_dtype, 1.0, 16.0)))
+        dt = jnp.exp(jax.random.uniform(key(), (wide,), param_dtype, jnp.log(1e-3), jnp.log(0.1)))
+        self.dt_bias = nnx.Param(dt + jnp.log(-jnp.expm1(-dt)))
+        self.g_down, self.g_up = linear(dim, rank), linear(rank, wide)
+        self.o_norm = RmsNorm(head_dim, eps=eps, dtype=dtype, param_dtype=param_dtype, rngs=rngs)
+        self.o_proj = linear(wide, dim)
+
+    def take_heads(self, name: str, whole):
+        """This share's slice of a leaf of the whole `num_heads`-head layer, by the leaf's name in this module."""
+        cut = functools.partial(head_slice, whole, offset=self.head_offset, held=self.heads_held)
+        if name in ('f_down.kernel', 'g_down.kernel', 'o_norm.scale'):
+            return whole
+        if name in ('A_log', 'beta_proj.kernel'):
+            return cut(axis=whole.ndim - 1, width=1)
+        return cut(axis=0 if name in ('o_proj.kernel', 'q_taps', 'k_taps', 'v_taps', 'dt_bias') else 1, width=self.head_dim)
+
+    def mix_in(self, qkv, f, b):
+        """The elementwise middle before the core: taps, SiLU and the L2 norms of q and k; the decay's log and beta at
+        float32. qkv three of (B, S, H D), f (B, S, H D), b (B, S, H) -> q, k, v, g (B, H, S, D), beta (B, H, S)."""
+        B, S, _ = f.shape
+        H, D, f32 = self.heads_held, self.head_dim, jnp.float32
+        heads = lambda t: t.reshape(B, S, H, D).transpose(0, 2, 1, 3)  # noqa: E731
+        q, k, v = (heads(jax.nn.silu(_causal_taps(t, w[...]))) for t, w in zip(qkv, (self.q_taps, self.k_taps, self.v_taps)))
+        q = q * jax.lax.rsqrt(jnp.square(q).sum(-1, keepdims=True) + L2_EPS) * D ** -0.5
+        k = k * jax.lax.rsqrt(jnp.square(k).sum(-1, keepdims=True) + L2_EPS)
+        rate = jnp.exp(self.A_log[...].astype(f32))[None, :, None, None]
+        g = -rate * heads(jax.nn.softplus(f.astype(f32) + self.dt_bias[...].astype(f32)))
+        beta = 2.0 * jax.nn.sigmoid(b.astype(f32)).transpose(0, 2, 1)
+        store = qkv[0].dtype
+        return q.astype(store), k.astype(store), v.astype(store), g, beta
+
+    def mix_out(self, o, gate):
+        """o (B, H, S, D), gate (B, S, H D) -> the gated, per-head normalised output (B, S, H D) in the gate's dtype."""
+        B, H, S, D = o.shape
+        o = self.o_norm(o).transpose(0, 2, 1, 3).reshape(B, S, H * D)
+        return (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(gate.dtype)
+
+    def __call__(self, a):
+        with tracing.scope('kda.proj'):
+            qkv = (self.q_proj(a), self.k_proj(a), self.v_proj(a))
+            f, gate, b = self.f_up(self.f_down(a)), self.g_up(self.g_down(a)), self.beta_proj(a)
+        with tracing.scope('kda.mix'):
+            q, k, v, g, beta = _written(self.mix_in(*_written((qkv, f, b))))
+        with tracing.scope('kda.core'):
+            o = chunked_delta_rule(q, k, v, g, beta, self.chunk).astype(v.dtype)
+        with tracing.scope('kda.mix'):
+            y = _written(self.mix_out(*_written((o, gate))))
+        with tracing.scope('kda.proj'):
+            return self.o_proj(y)

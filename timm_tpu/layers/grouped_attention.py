@@ -8,6 +8,16 @@ clean sequence, with an RMSNorm on every head's query and key before the turn
 
 `num_heads` query heads read `num_kv_heads` key/value heads, query head g the
 key/value head g // (num_heads / num_kv_heads); every linear map is bias-free.
+With `gate` the core's output is multiplied elementwise by the sigmoid of one
+more product of the layer's input before the output product (Solar-Open2's
+`use_gqa_gate`; its attention layers turn nothing and see everything, as
+SmallThinker's full layers). SmallThinker, SDAR and LFM2 hold ALL heads on
+every chip; Solar-Open2's share is TOLD WHICH HEADS IT HOLDS (`heads_held`,
+`head_offset`: whole key/value heads with their query groups, as
+`ChunkedLinearAttention` and `KimiDeltaAttention` are told theirs): it projects
+to those heads only and returns their PART of the output product, a sum over
+heads, so the parts of all shares add up to the whole layer's output; on one
+chip nothing stands in for the absent heads or their sum.
 Key j is seen by query i when j <= i and, with a window, i - j < window. With
 `block_diffusion` (a block length K) the input holds 2 L rows, L noised and
 then L clean ones, row r at position r mod L (the rotary table is read there),
@@ -47,6 +57,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..utils import tracing
 from .attention import apply_rot_embed_cat
+from .helpers import head_slice
 from .latent_attention import CORE_OUT, SLOW_FROM, _warn_xla_core
 from .norm import RmsNorm
 from .weight_init import trunc_normal_
@@ -153,6 +164,9 @@ class GroupedQueryAttention(nnx.Module):
             qk_norm: bool = False,
             block_diffusion: Optional[int] = None,
             eps: float = 1e-6,
+            heads_held: Optional[int] = None,
+            head_offset: int = 0,
+            gate: bool = False,
             *,
             dtype=None,
             param_dtype=jnp.float32,
@@ -160,6 +174,13 @@ class GroupedQueryAttention(nnx.Module):
     ):
         if num_heads % num_kv_heads:
             raise ValueError(f'{num_heads} query heads are not a multiple of {num_kv_heads} key/value heads')
+        group, held = num_heads // num_kv_heads, heads_held or num_heads
+        if head_offset < 0 or head_offset + held > num_heads or held % group or head_offset % group:
+            raise ValueError(f'query heads {head_offset} .. {head_offset + held} of {num_heads} are no whole key/value '
+                             f'heads with their groups of {group}')
+        # the share: `num_heads` / `num_kv_heads` are the heads HELD from here on, `head_offset` the first query head
+        self.head_offset = head_offset
+        num_heads, num_kv_heads = held, held // group
         self.num_heads, self.num_kv_heads, self.head_dim = num_heads, num_kv_heads, head_dim
         if window is not None and block_diffusion is not None:
             raise ValueError('a window under the block-diffusion mask is not a mask this layer knows')
@@ -172,9 +193,20 @@ class GroupedQueryAttention(nnx.Module):
         self.k_proj = linear(dim, num_kv_heads * head_dim)
         self.v_proj = linear(dim, num_kv_heads * head_dim)
         self.proj = linear(num_heads * head_dim, dim)
+        # an output gate (Solar-Open2's `use_gqa_gate`): sigmoid of one more product of the layer's input, on the core's output
+        self.gate_proj = linear(dim, num_heads * head_dim) if gate else None
         # Qwen3's q_norm / k_norm: one learned scale of `head_dim`, every head alike, statistics in float32
         norm = functools.partial(RmsNorm, head_dim, eps=eps, dtype=dtype, param_dtype=param_dtype, rngs=rngs)
         self.q_norm, self.k_norm = (norm(), norm()) if qk_norm else (None, None)
+
+    def take_heads(self, name: str, whole):
+        """This share's slice of a leaf of the whole layer, by the leaf's name in this module (`q_proj.kernel`, ..): query
+        heads `head_offset` .. + `num_heads` and the key/value heads they read."""
+        if name in ('q_norm.scale', 'k_norm.scale'):
+            return whole
+        group = self.num_heads // self.num_kv_heads
+        offset, held = (self.head_offset // group, self.num_kv_heads) if name[0] in 'kv' else (self.head_offset, self.num_heads)
+        return head_slice(whole, 0 if name == 'proj.kernel' else 1, offset, held, self.head_dim)
 
     def qkv(self, x, rope=None, queries: Optional[int] = None):
         """-> q (B, H, S, D), k and v (B, H_kv, S, D), q and k normalised where the layer has the norms and turned
@@ -214,4 +246,8 @@ class GroupedQueryAttention(nnx.Module):
                 out, tiles = xla(q, k, v, self.scale, block_q=self.block_q, with_tiles=True, **mask)
             out = checkpoint_name(out, CORE_OUT)
         with tracing.scope('swa.attn.proj'):
-            return self.proj(out.transpose(0, 2, 1, 3).reshape(B, -1, self.num_heads * self.head_dim)), tiles
+            out = out.transpose(0, 2, 1, 3).reshape(B, -1, self.num_heads * self.head_dim)
+            if self.gate_proj is not None:
+                gate = self.gate_proj(x if queries is None else x[:, :queries])
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
+            return self.proj(out), tiles

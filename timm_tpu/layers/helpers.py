@@ -39,3 +39,11 @@ def extend_tuple(x, n):
     if pad_n <= 0:
         return x[:n]
     return x + (x[-1],) * pad_n
+
+
+def head_slice(whole, axis: int, offset: int, held: int, width: int):
+    """Heads `offset` .. `offset + held` of an array that holds its heads side by side along `axis`, `width` entries a
+    head: what a share of a head-parallel layer takes of a leaf of the whole layer."""
+    index = [slice(None)] * whole.ndim
+    index[axis] = slice(offset * width, (offset + held) * width)
+    return whole[tuple(index)]

@@ -9,7 +9,11 @@ SmallThinker's (arXiv:2507.20984): the `top_k` largest LOGITS chosen, weights =
 a softmax over the chosen logits; no bias, no scaling. A third model,
 LFM2-8B-A1B (`models/lfm2_moe.py`), uses 'sigmoid_bias' at its own numbers: 4 of
 32, no shared expert, scaling 1, and its published code's normaliser epsilon
-(`norm_eps` 1e-6 where GLM's is 1e-20). The router may read
+(`norm_eps` 1e-6 where GLM's is 1e-20), and a fourth,
+Solar-Open2-250B (`models/solar_open2.py`), at GLM's own rule (one shared
+expert, scaling 1, epsilon 1e-20) and the largest numbers the layer has met:
+8 of 320, a router of 320 outputs (2.5 lane tiles), a share of 1/40 (8
+experts held: `dispatch_rows` 3328 of 65536 slots at 8192 tokens). The router may read
 another tensor than the experts (`router_in`: SmallThinker routes on the
 attention's input, so a layer's routing does not wait for its attention), and
 the experts' gate activation is SiLU (SwiGLU) or ReLU (ReGLU). The layer holds experts

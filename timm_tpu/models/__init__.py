@@ -69,3 +69,4 @@ from .glm4_moe_lite import Glm4MoeLite
 from .lfm2_moe import Lfm2Moe
 from .sdar_moe import SdarMoe
 from .smallthinker import SmallThinker
+from .solar_open2 import SolarOpen2

@@ -84,7 +84,7 @@ class PartitionRule:
 # conv (NHWC Linear) matches the same suffixes — its kernel is rank 2, so the
 # megatron specs apply unchanged. Convnext's NHWC MLP fc1/fc2 Linears already
 # match the mlp rules.
-_TP_ATTN_QKV = r'\.(?:attn|token_mixer)\.(?:qkv|q_proj|k_proj|v_proj|q|kv)\.kernel$'
+_TP_ATTN_QKV = r'\.(?:attn|token_mixer)\.(?:qkv|q_proj|k_proj|v_proj|gate_proj|q|kv)\.kernel$'
 _TP_ATTN_OUT = r'\.(?:attn|token_mixer)\.proj\.kernel$'
 _TP_MLP_IN = r'\.mlp\.(?:fc1|fc1_g|fc1_x)\.kernel$'
 _TP_MLP_OUT = r'\.mlp\.fc2\.kernel$'
@@ -114,7 +114,10 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
      12. a gated short convolution's taps (`conv.taps`, (dim, 3): 6144 numbers a layer)
                                           -> replicate (its two products, `conv.in_proj` / `conv.out_proj`, are
                                              plain kernels under rule 5; a tied embedding is rule 8's, once)
-     13. everything else                  -> replicate (catch-all)
+         a gated delta-rule mixer's taps (`kda.q_taps` / `k_taps` / `v_taps`, (heads x head_dim, 4)) likewise; its
+         `dt_bias` is rule 6's, its head norm's scale rule 7's, its nine products plain kernels under rule 5
+     13. a gated delta-rule mixer's decay rate a head (`kda.A_log`, (heads,)) -> replicate
+     14. everything else                  -> replicate (catch-all)
 
     Rules 1-4 fall back to 'fsdp_largest' placement when the mesh has no
     'model' axis, so tp=1 reproduces the 2-axis table exactly.
@@ -142,7 +145,8 @@ def default_partition_rules() -> Tuple[PartitionRule, ...]:
         PartitionRule(r'\.mlp\.(?:w_gate|w_up|w_down)$', 'fsdp_largest', name='expert-stack'),
         PartitionRule(r'\.mlp\.router$', 'replicate', name='router'),
         PartitionRule(r'\.attn\.(?:phi|mu)$', 'replicate', name='head-vector'),
-        PartitionRule(r'\.conv\.taps$', 'replicate', name='conv-taps'),
+        PartitionRule(r'\.(?:conv\.taps|kda\.[qkv]_taps)$', 'replicate', name='conv-taps'),
+        PartitionRule(r'\.kda\.A_log$', 'replicate', name='decay-rate'),
         PartitionRule(r'.*', 'replicate', name='catch-all'),
     )
 

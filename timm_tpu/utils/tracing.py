@@ -91,6 +91,9 @@ SPANS = {
     # digit-free names: the benchmark's reduction finds a scope by `[a-z]+(?:\.[a-z_]+)+` (`harness/device_scopes.py`)
     'sconv.proj': ('short convolution', 'device scope: a gated short convolution\'s two products (`in_proj` to three gates\' worth of channels, `out_proj` back) and the norm before them'),
     'sconv.mix': ('short convolution', 'device scope: the two elementwise gates and the causal depthwise taps between the products, forward and backward (the taps\' gradient among it)'),
+    'kda.proj': ('delta attention', 'device scope: a gated delta-rule mixer\'s products (q, k, v, the two low-rank gates, beta, the output product) and the norm before them'),
+    'kda.mix': ('delta attention', 'device scope: the elementwise middle of a gated delta-rule mixer, behind barriers: taps, SiLU, the L2 norms, the decay\'s log and beta before the core, the gated per-head norm after it, forward and backward'),
+    'kda.core': ('delta attention', 'device scope: the chunked delta-rule recurrence (the decayed pair sums, the triangular inverse, the scan over chunks that carries the state), forward, rematerialised and backward'),
     # the image models' scopes, on the shared layers (every model built from them has them), and the step's own,
     # which every task runs. The innermost scope of an op counts: `img.block` holds what no inner scope takes
     'img.patch_embed': ('step', 'device scope: the patch convolution, class / register tokens, position embedding, the norm before the blocks'),
@@ -126,6 +129,8 @@ SPANS = {
     'attn.eva_blocks': ('attention', 'step counter: the same for the cores under the chunk-window mask (queries on summaries and single keys)'),
     'attn.eva_pairs': ('attention', 'step counter: (query, key) pairs the chunk-window mask leaves, single keys and summaries, all heads held, layers and sequences (float32: from the shapes alone)'),
     'sconv.rows': ('short convolution', 'step counter: positions x gated short-convolution layers of the step (from the shapes): the rows its memory-bound middle moves'),
+    'kda.rows': ('delta attention', 'step counter: positions x gated delta-rule layers of the step (from the shapes): the rows its elementwise middle moves and its recurrence visits'),
+    'kda.chunks': ('delta attention', 'step counter: chunks x heads held x gated delta-rule layers of the step (from the shapes): the triangular systems solved and the scan\'s steps x heads'),
     'lm.head_nll': ('step', 'step counter: the mean cross-entropy of each of a model\'s `num_pred_heads` prediction heads over its own valid positions, a vector; over micro-batches the means add'),
     'lm.noised_masked': ('step', 'step counter: positions of the step\'s noised copies that hold the mask token'),
     'lm.masked_nll': ('step', 'step counter: the cross-entropy summed over those positions, unweighted (over `lm.noised_masked`: the mean a masked position)'),

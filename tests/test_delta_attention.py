@@ -17,6 +17,8 @@ if ROOT not in sys.path:
 from benchmarks.reference.solar_open2 import delta_rule  # noqa: E402
 from timm_tpu.layers import KimiDeltaAttention, chunked_delta_rule  # noqa: E402
 from timm_tpu.layers.delta_attention import _decayed_pairs, _unit_lower_inverse  # noqa: E402
+from timm_tpu.layers.latent_attention import CORE_OUT  # noqa: E402
+from timm_tpu.utils import tracing  # noqa: E402
 
 B, H, S, D = 2, 2, 128, 32
 TOL = 2e-5      # float32 on both sides: summation order alone (the gradients of q reach 18)
@@ -43,13 +45,15 @@ def inputs(kind: str, seed: int = 0, S: int = S):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize('chunk', [16, 64])
-@pytest.mark.parametrize('kind', ['mixed', 'forget', 'keep'])
-def test_the_chunked_core_is_the_recurrence_in_outputs_and_every_gradient(kind, chunk):
-    args = inputs(kind)
-    if kind != 'mixed':
+@pytest.mark.parametrize('kind,chunk,seq', [(kind, chunk, S) for kind in ('mixed', 'forget', 'keep', 'gather') for chunk in (16, 64)]
+                         + [('mixed', 64, 32)], ids=str)
+def test_the_chunked_core_is_the_recurrence_in_outputs_and_every_gradient(kind, chunk, seq):
+    """`seq` 32 under a chunk of 64 is the one-chunk path: a sequence shorter than the chunk is its own chunk, and both
+    scans make one step."""
+    args = inputs(kind, S=seq)
+    if kind in ('forget', 'keep'):
         assert float(args[4].max()) > 1.999 and float(args[4].mean()) > 1.98
-    w = jax.random.normal(jax.random.key(9), (B, H, S, D))
+    w = jax.random.normal(jax.random.key(9), (B, H, seq, D))
     got, got_grads = jax.jit(jax.value_and_grad(lambda *a: (chunked_delta_rule(*a, chunk=chunk) * w).sum(), argnums=range(5)))(*args)
     want, want_grads = jax.jit(jax.value_and_grad(lambda *a: (recurrence(*a) * w).sum(), argnums=range(5)))(*args)
     out, ref = jax.jit(lambda *a: chunked_delta_rule(*a, chunk=chunk))(*args), jax.jit(recurrence)(*args)
@@ -59,6 +63,30 @@ def test_the_chunked_core_is_the_recurrence_in_outputs_and_every_gradient(kind, 
         assert bool(jnp.isfinite(a).all()) and float(jnp.abs(b).max()) > 1e-4, name
         assert float(jnp.abs(a - b).max()) < TOL * max(1.0, float(jnp.abs(b).max())), (name, float(jnp.abs(a - b).max()))
     assert abs(float(got) - float(want)) < TOL * max(1.0, abs(float(want)))
+
+
+def test_a_checkpoint_that_keeps_the_cores_name_keeps_the_states_and_the_gradients_are_the_unwrapped_ones():
+    """Under `save_only_these_names(CORE_OUT)`, a block's policy, the backward pass finds the chunk-boundary states and
+    runs the reversed scan alone: two loops in the program, as without the checkpoint; under a policy that keeps
+    nothing the forward scan is run again to make them: three. The gradients are the same to `TOL` either way, and the
+    operands' dtypes are the cotangents' (a block hands the core bfloat16 q, k and v)."""
+    args = inputs('mixed', seed=2)
+    w = jax.random.normal(jax.random.key(9), (B, H, S, D))
+    loss = lambda *a: (chunked_delta_rule(*a, chunk=16) * w).sum()  # noqa: E731
+    grad = lambda f: jax.jit(jax.value_and_grad(f, argnums=range(5)))  # noqa: E731   the value too: its pass is then live
+    plain = grad(loss)
+    kept = grad(jax.checkpoint(loss, policy=jax.checkpoint_policies.save_only_these_names(CORE_OUT)))
+    again = grad(jax.checkpoint(loss, policy=jax.checkpoint_policies.nothing_saveable))
+    loops = lambda f: tracing.scope_loops(f.lower(*args).compile().as_text(), '')  # noqa: E731
+    assert (loops(plain), loops(kept), loops(again)) == (2, 2, 3)
+    want = plain(*args)[1]
+    for got in (kept(*args)[1], again(*args)[1]):
+        for name, a, b in zip('q k v g beta'.split(), got, want):
+            assert float(jnp.abs(b).max()) > 1e-4 and float(jnp.abs(a - b).max()) < TOL * max(1.0, float(jnp.abs(b).max())), name
+    bf = lambda x: x.astype(jnp.bfloat16)  # noqa: E731
+    stored = kept(bf(args[0]), bf(args[1]), bf(args[2]), args[3], args[4])[1]
+    assert [x.dtype for x in stored] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    assert all(float(jnp.abs(a.astype(jnp.float32) - b).max()) < 0.02 * float(jnp.abs(b).max()) for a, b in zip(stored, want))
 
 
 def test_no_exponent_above_zero_is_taken_and_the_block_inverse_is_exact():

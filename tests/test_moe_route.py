@@ -336,4 +336,5 @@ def test_the_kept_step_program_sets_the_routes_two_gauges_and_the_log_line_print
     gathers, fast = gauges['moe.route_gathers'][-1][1], gauges['moe.route_gathers_fast'][-1][1]
     assert (gathers, fast) == tracing.scope_gathers(text, 'glm.moe.route') and gathers >= 2 * (1 + K) and fast == 0
     assert train._host_line(tracing.now_ns(), {})[0].endswith(f' route gathers {gathers} fast 0')
+    assert tracing.scope_loops(text, 'kda.core') == 0        # no delta-rule layer: `kda.core_scans` is not set by this program
     assert tracing.scope_gathers(jax.jit(lambda x: x[::2] * 2).lower(ids).compile().as_text(), 'glm.moe.route') == (0, 0)

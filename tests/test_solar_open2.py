@@ -246,6 +246,27 @@ def test_causal_lm_task_two_steps_follow_the_reference(toy):
     assert not mask['blocks.1.kda.A_log'] and not mask['blocks.1.kda.dt_bias'] and not mask['blocks.1.kda.o_norm.scale'] and not mask['norm.scale']
 
 
+def test_the_kept_step_program_holds_two_scans_a_delta_rule_layer_and_the_gauge_and_the_log_line_say_so():
+    """The step as the cell trains it (blocks rematerialised under `save_only_these_names(CORE_OUT)`), four chunks a
+    sequence, compiled ahead of time and kept: under `kda.core` one loop forward and one backward a KDA layer. The block's
+    second forward pass finds the chunk-boundary states kept and holds no scan (before PR 48: three a layer).
+    `tracing.scope_loops` is what the gauge `kda.core_scans` reads where a step program is kept, and `train.py`'s log
+    line prints it."""
+    import train
+    from timm_tpu.utils import tracing
+    assert tracing.SPANS['kda.core_scans'][0] == 'delta attention' and tracing.SPANS['kda.core_scans'][1].startswith('gauge: ')
+    ids = jnp.zeros((8, 4 * 16), jnp.int32)
+    model = timm_tpu.create_model('solar_open2_toy', seed=0)
+    model.set_grad_checkpointing(True)
+    task = CausalLMTask(model, optimizer=create_optimizer_v2(model, opt='adamw', lr=1e-3, weight_decay=0.1), clip_grad=1.0, loss_chunk=16)
+    before = len(tracing.snapshot()['gauges'].get('kda.core_scans', ()))
+    text = task.lower_train_step({'input': ids, 'target': ids}, 1e-3, 0).as_text()
+    series = tracing.snapshot()['gauges']['kda.core_scans']
+    assert len(series) == before + 1 and series[-1][1] == tracing.scope_loops(text, 'kda.core') == 2 * sum(b.kda is not None for b in model.blocks) == 6
+    assert tracing.scope_loops(text, 'swa.attn') == 0 < tracing.scope_loops(text, '')
+    assert ' kda scans 6' in train._host_line(tracing.now_ns(), {})[0]
+
+
 def test_the_model_trains_through_train_main_on_the_token_feed_and_its_loss_falls(tmp_path):
     """A stream a model can learn (every id is the one before it plus 7): the loss of the last steps lies well under the
     first's ln 256."""

@@ -23,10 +23,14 @@ the chunk's rows satisfy the unit-lower-triangular system
   (I + Diag(beta) A) U = Diag(beta) (V - (K e^G) S),   A[t, j] = sum_d k_t k_j e^{G_t - G_j}  (j < t)
 
 whose inverse T gives W = T Diag(beta) (K e^G) and U~ = T Diag(beta) V once a
-chunk, for every chunk at once; then, chunk after chunk (`lax.scan`, carrying S):
+chunk, for every chunk at once; then, chunk after chunk (`lax.scan`, carrying S
+and nothing else, two products a step):
 
-  U = U~ - W S;   O = (Q e^G) S + P U,   P[t, j] = sum_d q_t k_j e^{G_t - G_j}  (j <= t);
-  S' = Diag(e^{G_C}) S + (K e^{G_C - G})^T U.
+  U = U~ - W S;   S' = Diag(e^{G_C}) S + (K e^{G_C - G})^T U;
+
+and from the stack of the chunks' INCOMING states and of U, every chunk at once,
+
+  O = (Q e^G) S + P U,   P[t, j] = sum_d q_t k_j e^{G_t - G_j}  (j <= t).
 
 No exponent above zero is ever taken, whatever the decays: A and P are built in
 sub-blocks of `SUB` rows, a row block against the EARLIER columns through the
@@ -35,10 +39,27 @@ against its own columns pair by pair. T is the exact block inverse: rows by
 substitution inside a sub-block, sub-blocks merged two by two. g, its sums,
 both matrices, T and the state are float32 and every product of the core runs
 at `Precision.HIGHEST`: a bfloat16 pass in the solve or a bfloat16 state is not
-a rounding here but another model (`tests/test_delta_attention.py`). The
-backward pass is autodiff's: the scan reversed, its residuals the chunk-boundary
-states and U (B x H x S / C x D x D x 4 bytes: 67 MB a layer at 8 heads x 8192),
-never a state a position.
+a rounding here but another model (`tests/test_delta_attention.py`).
+
+The chunk-boundary states are first-class values of the core (a
+`jax.custom_vjp`, `_core`). They are all the backward pass keeps beside the
+core's operands (B x H x S / C x D x D x 4 bytes: 67 MB a layer at 8 heads x
+8192; never a state a position), and they carry `checkpoint_name(.., CORE_OUT)`,
+as the core's output does where the layer calls it: a block rematerialised
+under `save_only_these_names(CORE_OUT)` keeps both, so its second forward pass
+makes q, k, v, g, beta again (the cheap middle) and runs no scan. The backward
+pass is written by hand, the same products transposed at the same precision:
+the chunk terms and U again from the kept states, batched; one reversed scan
+that carries the state's cotangent alone,
+
+  dU = P^T dO + (K e^{G_C - G}) dS';   dS = Diag(e^{G_C}) dS' + (Q e^G)^T dO - W^T dU,
+
+P^T dO and (Q e^G)^T dO made for every chunk beforehand, so two products a
+step again; then dW = -dU S^T, dU~ = dU, d(Q e^G) = dO S^T, dP = dO U^T,
+d(K e^{G_C - G}) = U dS'^T and d e^{G_C} = sum_e dS' * S for every chunk at
+once, and autodiff's way (`jax.vjp` of `_chunk_terms`) from the terms back to
+q, k, v, g and beta. One scan forward and one backward a layer, each bound by
+the latency of S / C dependent steps, is what is left in sequence.
 
 The layer is TOLD WHICH HEADS IT HOLDS (`heads_held`, `head_offset`), as
 `ChunkedLinearAttention` is: it projects to those heads only, carries their
@@ -55,9 +76,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from flax import nnx
+from jax.ad_checkpoint import checkpoint_name
 
 from ..utils import tracing
 from .helpers import head_slice
+from .latent_attention import CORE_OUT
 from .norm import RmsNorm
 from .short_conv import _shift
 from .weight_init import trunc_normal_
@@ -84,7 +107,6 @@ def _decayed_pairs(rows, k, G, sub: int):
         earlier = (jnp.arange(C) // sub)[None, :] < jnp.arange(ns)[:, None]                              # (ns, C)
         k_before = k[:, None] * jnp.exp(jnp.where(earlier[None, :, :, None], first - G[:, None], -jnp.inf))
         to_first = jnp.exp(Gs - first)
-        place = jnp.eye(ns, dtype=G.dtype)[None, :, None, :, None]
     out = []
     for x in rows:
         xs = x.reshape(N, ns, sub, D)
@@ -93,7 +115,10 @@ def _decayed_pairs(rows, k, G, sub: int):
             out.append(own.reshape(N, C, C))
             continue
         before = jnp.einsum('nasd,nacd->nasc', xs * to_first, k_before, precision=HIGHEST)
-        out.append((before.reshape(N, ns, sub, ns, sub) + own[:, :, :, None] * place).reshape(N, C, C))
+        # a row block's own columns padded into place: an identity over blocks would be an (N, ns, sub, ns, sub) tensor
+        # with a minor axis of `sub`, an eighth of a lane row, written out forward and again backward
+        placed = [jnp.pad(own[:, a], ((0, 0), (0, 0), (a * sub, C - (a + 1) * sub))) for a in range(ns)]
+        out.append((before + jnp.stack(placed, axis=1)).reshape(N, C, C))
     return out
 
 
@@ -129,33 +154,94 @@ def _chunk_terms(q, k, v, g, beta, sub: int):
     return both[..., :D], both[..., D:], q * decay, P, k * jnp.exp(G[:, -1:] - G), decay[:, -1]
 
 
+def _chunk_major(t, C: int):
+    """(B, H, S, ..) -> (S / C x B H, C, ..) float32, a sequence's chunks outermost: the scans run over them, every head of
+    every sequence at once, and what is batched over chunks needs no other layout."""
+    B, H, S = t.shape[:3]
+    return t.astype(jnp.float32).reshape((B * H, S // C, C) + t.shape[3:]).swapaxes(0, 1).reshape((S // C * B * H, C) + t.shape[3:])
+
+
+def _terms(q, k, v, g, beta, C: int):
+    """`_chunk_terms` of (B, H, S, ..) tensors, each term (S / C, B H, ..)."""
+    terms = _chunk_terms(*(_chunk_major(t, C) for t in (q, k, v, g, beta)), sub=min(SUB, C))
+    return tuple(t.reshape((q.shape[2] // C, q.shape[0] * q.shape[1]) + t.shape[1:]) for t in terms)
+
+
+def _incoming_states(W, Ut, Kd, end, state_dtype):
+    """The only sequential part of the forward pass: each chunk's INCOMING state (n, N, D, Dv) in `state_dtype` and what
+    the rule writes in it, U (n, N, C, Dv), from terms (n, N, ..). Two products a step."""
+    def step(state, xs):
+        W, Ut, Kd, end = xs
+        s = state.astype(jnp.float32)
+        U = Ut - jnp.matmul(W, s, precision=HIGHEST)
+        s = end[:, :, None] * s + jnp.einsum('ncd,nce->nde', Kd, U, precision=HIGHEST)
+        return s.astype(state_dtype), (state, U)
+
+    _, stacks = jax.lax.scan(step, jnp.zeros((W.shape[1], W.shape[3], Ut.shape[3]), state_dtype), (W, Ut, Kd, end))
+    return stacks
+
+
+def _state_cotangents(W, Kd, end, from_P, from_Q, state_dtype):
+    """The only sequential part of the backward pass, the scan reversed: the cotangent dS' of each chunk's OUTGOING state
+    (n, N, D, Dv) and dU (n, N, C, Dv), given P^T dO and (Q e^G)^T dO of every chunk. dU = P^T dO + Kd dS' and
+    dS = e^{G_C} dS' + (Q e^G)^T dO - W^T dU: two products a step."""
+    def step(d_next, xs):
+        W, Kd, end, from_P, from_Q = xs
+        d = d_next.astype(jnp.float32)
+        dU = from_P + jnp.matmul(Kd, d, precision=HIGHEST)
+        dS = end[:, :, None] * d + from_Q - jnp.einsum('ncd,nce->nde', W, dU, precision=HIGHEST)
+        return dS.astype(state_dtype), (d_next, dU)
+
+    _, stacks = jax.lax.scan(step, jnp.zeros(from_Q.shape[1:], state_dtype), (W, Kd, end, from_P, from_Q), reverse=True)
+    return stacks
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _core(q, k, v, g, beta, C: int, state_dtype):
+    return _core_fwd(q, k, v, g, beta, C, state_dtype)[0]
+
+
+def _core_fwd(q, k, v, g, beta, C: int, state_dtype):
+    B, H, S, _ = q.shape
+    W, Ut, Qg, P, Kd, end = _terms(q, k, v, g, beta, C)
+    states, U = _incoming_states(W, Ut, Kd, end, state_dtype)
+    states = checkpoint_name(states, CORE_OUT)      # a block's rematerialisation keeps them: its second pass holds no scan
+    out = jnp.matmul(Qg, states.astype(jnp.float32), precision=HIGHEST) + jnp.matmul(P, U, precision=HIGHEST)
+    return out.swapaxes(0, 1).reshape(B, H, S, v.shape[-1]), (q, k, v, g, beta, states)
+
+
+def _core_bwd(C: int, state_dtype, kept, d_out):
+    """Every product is the transpose of one of the forward pass's, float32 at `HIGHEST`, batched over chunks but for
+    the two that hold the state's cotangent; the way back from the terms to q, k, v, g, beta is autodiff's."""
+    q, k, v, g, beta, states = kept
+    (W, Ut, Qg, P, Kd, end), back = jax.vjp(functools.partial(_terms, C=C), q, k, v, g, beta)
+    S = states.astype(jnp.float32)
+    U = Ut - jnp.matmul(W, S, precision=HIGHEST)
+    dO = _chunk_major(d_out, C).reshape(U.shape)
+    from_P = jnp.einsum('...cj,...ce->...je', P, dO, precision=HIGHEST)
+    from_Q = jnp.einsum('...cd,...ce->...de', Qg, dO, precision=HIGHEST)
+    d_next, dU = _state_cotangents(W, Kd, end, from_P, from_Q, state_dtype)
+    d_next = d_next.astype(jnp.float32)
+    by_state = lambda rows, state: jnp.einsum('...ce,...de->...cd', rows, state, precision=HIGHEST)  # noqa: E731
+    return back((-by_state(dU, S), dU, by_state(dO, S), jnp.einsum('...ce,...je->...cj', dO, U, precision=HIGHEST),
+                 by_state(U, d_next), (d_next * S).sum(-1)))
+
+
+_core.defvjp(_core_fwd, _core_bwd)
+
+
 def chunked_delta_rule(q, k, v, g, beta, chunk: int = 64, state_dtype=jnp.float32):
     """The gated delta rule with a per-channel decay, S_0 = 0 for every sequence: q, k, g (B, H, S, D), v (B, H, S, Dv),
     beta (B, H, S); g the log of the decay (<= 0) -> o (B, H, S, Dv) float32, o_t = S_t^T q_t. S a multiple of the
     chunk (a shorter sequence is one chunk), the chunk of `SUB` x a power of two or under `SUB`. `state_dtype` is the carried
     state's: float32; the tests read what bfloat16 costs."""
-    B, H, S, D = q.shape
+    S = q.shape[2]
     C = min(chunk, S)
     sub = min(SUB, C)
     if S % C or C % sub or (C // sub) & (C // sub - 1):
         raise ValueError(f'{S} positions in chunks of {C} and sub-blocks of {sub}: the chunk has to divide the sequence, and '
                          f'be {sub} times a power of two')
-    f32, n = jnp.float32, S // C
-    chunks = lambda t: t.astype(f32).reshape((B * H * n, C) + t.shape[3:])  # noqa: E731
-    terms = jax.checkpoint(functools.partial(_chunk_terms, sub=sub))(*(chunks(t) for t in (q, k, v, g, beta)))
-    # (B H n, ..) -> (n, B H, ..): the scan runs over a sequence's chunks, every head of every sequence at once
-    W, Ut, Qg, P, Kd, end = (t.reshape((B * H, n) + t.shape[1:]).swapaxes(0, 1) for t in terms)
-
-    def step(state, xs):
-        W, Ut, Qg, P, Kd, end = xs
-        s = state.astype(f32)
-        U = Ut - jnp.matmul(W, s, precision=HIGHEST)
-        out = jnp.matmul(Qg, s, precision=HIGHEST) + jnp.matmul(P, U, precision=HIGHEST)
-        s = end[:, :, None] * s + jnp.einsum('ncd,nce->nde', Kd, U, precision=HIGHEST)
-        return s.astype(state_dtype), out
-
-    _, out = jax.lax.scan(step, jnp.zeros((B * H, D, v.shape[-1]), state_dtype), (W, Ut, Qg, P, Kd, end))
-    return out.swapaxes(0, 1).reshape(B, H, S, v.shape[-1])
+    return _core(q, k, v, g, beta, C, state_dtype)
 
 
 def _causal_taps(x, w):
@@ -242,7 +328,8 @@ class KimiDeltaAttention(nnx.Module):
         with tracing.scope('kda.mix'):
             q, k, v, g, beta = _written(self.mix_in(*_written((qkv, f, b))))
         with tracing.scope('kda.core'):
-            o = chunked_delta_rule(q, k, v, g, beta, self.chunk).astype(v.dtype)
+            # named as an attention core's output is, in the dtype it is stored in: half the float32 core's bytes to keep
+            o = checkpoint_name(chunked_delta_rule(q, k, v, g, beta, self.chunk).astype(v.dtype), CORE_OUT)
         with tracing.scope('kda.mix'):
             y = _written(self.mix_out(*_written((o, gate))))
         with tracing.scope('kda.proj'):

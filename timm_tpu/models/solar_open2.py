@@ -169,7 +169,8 @@ class SolarOpen2(nnx.Module):
         if not self.grad_checkpointing:
             return blk(x)
         # as `Glm4MoeLite._run_block`: a block is recomputed in the backward pass, but for an attention core's output
-        # and log-sum-exp; a KDA block keeps nothing, its chunk-boundary states are made again with it
+        # and log-sum-exp; a KDA block keeps its core's output and chunk-boundary states (`layers/delta_attention.py`
+        # names them `CORE_OUT` too: 67 + 17 MB a layer at the cell's size), so the scan over chunks is not run again
         policy = jax.checkpoint_policies.save_only_these_names(CORE_OUT)
         return nnx.remat(lambda b, x: b(x), policy=policy)(blk, x)
 

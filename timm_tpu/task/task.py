@@ -508,10 +508,14 @@ class TrainingTask:
         step_fn, args = self._train_step_args(batch, lr, step)
         compiled = step_fn.lower(*args).compile()
         tracing.keep_program('task.step_call', compiled)
-        gathers, fast = tracing.scope_gathers(tracing.program_text('task.step_call'), 'glm.moe.route')
+        text = tracing.program_text('task.step_call')
+        gathers, fast = tracing.scope_gathers(text, 'glm.moe.route')
         if gathers:     # a model with expert layers: how many of the route's gathers read a source in fast memory
             tracing.gauge('moe.route_gathers', gathers)
             tracing.gauge('moe.route_gathers_fast', fast)
+        scans = tracing.scope_loops(text, 'kda.core')
+        if scans:       # a model with gated delta-rule layers: two a layer once the chunk-boundary states are kept
+            tracing.gauge('kda.core_scans', scans)
         return compiled
 
     def _new_sentinel_state(self):

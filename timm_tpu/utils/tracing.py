@@ -93,7 +93,7 @@ SPANS = {
     'sconv.mix': ('short convolution', 'device scope: the two elementwise gates and the causal depthwise taps between the products, forward and backward (the taps\' gradient among it)'),
     'kda.proj': ('delta attention', 'device scope: a gated delta-rule mixer\'s products (q, k, v, the two low-rank gates, beta, the output product) and the norm before them'),
     'kda.mix': ('delta attention', 'device scope: the elementwise middle of a gated delta-rule mixer, behind barriers: taps, SiLU, the L2 norms, the decay\'s log and beta before the core, the gated per-head norm after it, forward and backward'),
-    'kda.core': ('delta attention', 'device scope: the chunked delta-rule recurrence (the decayed pair sums, the triangular inverse, the scan over chunks that carries the state), forward, rematerialised and backward'),
+    'kda.core': ('delta attention', 'device scope: the chunked delta-rule recurrence (the decayed pair sums, the triangular inverse, the scan over chunks that carries the state, whose chunk-boundary states a block keeps), forward and backward'),
     # the image models' scopes, on the shared layers (every model built from them has them), and the step's own,
     # which every task runs. The innermost scope of an op counts: `img.block` holds what no inner scope takes
     'img.patch_embed': ('step', 'device scope: the patch convolution, class / register tokens, position embedding, the norm before the blocks'),
@@ -129,6 +129,7 @@ SPANS = {
     'attn.eva_blocks': ('attention', 'step counter: the same for the cores under the chunk-window mask (queries on summaries and single keys)'),
     'attn.eva_pairs': ('attention', 'step counter: (query, key) pairs the chunk-window mask leaves, single keys and summaries, all heads held, layers and sequences (float32: from the shapes alone)'),
     'sconv.rows': ('short convolution', 'step counter: positions x gated short-convolution layers of the step (from the shapes): the rows its memory-bound middle moves'),
+    'kda.core_scans': ('delta attention', 'gauge: `while` instructions under `kda.core` in the step program\'s compiled text, by `scope_loops`: one scan over chunks forward and one backward a gated delta-rule layer (the block\'s second forward pass finds the chunk-boundary states kept); set where the program is kept (`TrainingTask.lower_train_step`)'),
     'kda.rows': ('delta attention', 'step counter: positions x gated delta-rule layers of the step (from the shapes): the rows its elementwise middle moves and its recurrence visits'),
     'kda.chunks': ('delta attention', 'step counter: chunks x heads held x gated delta-rule layers of the step (from the shapes): the triangular systems solved and the scan\'s steps x heads'),
     'lm.head_nll': ('step', 'step counter: the mean cross-entropy of each of a model\'s `num_pred_heads` prediction heads over its own valid positions, a vector; over micro-batches the means add'),
@@ -264,6 +265,11 @@ _INSTRUCTION = re.compile(r'^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\w+\[[\d,]*\]\
 _CALLS = re.compile(r'calls=%?([\w.\-]+)')
 
 
+def _op_name(rest: str) -> str:
+    """An instruction's `op_name` (the scopes it was traced under) from what follows its opening bracket; '' if none."""
+    return rest.partition('op_name="')[2].partition('"')[0]
+
+
 def _elements(text: str) -> int:
     """Elements of an array type as a compiled program writes it (`bf16[65536,384]{1,0:T(8,128)(2,1)S(1)}`); 0 for a tuple."""
     m = re.match(r'\w+\[([\d,]*)\]', text)
@@ -288,7 +294,7 @@ def scope_gathers(text: str, scope: str) -> tuple:
                 gathering.add(computation)
             elif op == 'fusion':
                 fused.add(_CALLS.search(rest).group(1))
-            if op in ('gather', 'fusion') and scope in rest.partition('op_name="')[2].partition('"')[0]:
+            if op in ('gather', 'fusion') and scope in _op_name(rest):
                 rows.append((computation, op, rest))
     gathers = fast = 0
     for computation, op, rest in rows:
@@ -297,6 +303,13 @@ def scope_gathers(text: str, scope: str) -> tuple:
             source = max((types.get(o, '') for o in operands), key=_elements)
             gathers, fast = gathers + 1, fast + ('S(1)' in source)
     return gathers, fast
+
+
+def scope_loops(text: str, scope: str) -> int:
+    """The `while` instructions of a program's text, over all its computations, whose `op_name` lies under device
+    scope `scope`: a `lax.scan` each, as long as the compiler leaves it a loop."""
+    matches = map(_INSTRUCTION.match, (line for line in text.splitlines() if ' while(' in line))
+    return sum(m.group(3) == 'while' and scope in _op_name(m.group(4)) for m in matches if m)
 
 
 def device_counter(name: str, value):

@@ -1115,7 +1115,8 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
     loop from the program's spans, and what the loader's threads did; before
     it, for a model with expert layers, how many of the step's `expert_layers`
     took the worst-case dispatch buffer; after `loop`, on a line since whose predecessor `Attention` calls were
-    traced, how many took the kernel pair (`fused`) and how many `_sdpa` (`plain`). -> (text, the counters now)."""
+    traced, how many took the kernel pair (`fused`) and how many `_sdpa` (`plain`); at the end, once the step program
+    was compiled ahead of time and kept, what its text says: `kda scans` and `route gathers`. -> (text, the counters now)."""
     from timm_tpu.utils import tracing
     snap = tracing.snapshot()
     rows = tracing.summary(since_ns, spans=snap['spans'])
@@ -1142,6 +1143,8 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
         text += f" procs {procs[-1][1]} exits {did.get('loader.worker_exits', 0)}"
     if metrics and 'moe.fallback_layers' in metrics:
         text = f"fallback {int(metrics['moe.fallback_layers'])} of {expert_layers} layers " + text
+    if 'kda.core_scans' in snap['gauges']:      # as the route's gathers below: read from the kept step program's text
+        text += f" kda scans {snap['gauges']['kda.core_scans'][-1][1]}"
     if 'moe.route_gathers' in snap['gauges']:   # set where the step program was compiled ahead of time and kept
         text += (f" route gathers {snap['gauges']['moe.route_gathers'][-1][1]} "
                  f"fast {snap['gauges']['moe.route_gathers_fast'][-1][1]}")

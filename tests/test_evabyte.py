@@ -30,6 +30,9 @@ from benchmarks.reference import evabyte as ref  # noqa: E402
 from timm_tpu.task import CausalLMTask  # noqa: E402
 
 from evabyte_common import N, SIZES, TOL, W, batch as _batch  # noqa: E402
+from remat_common import block_grad_dots  # noqa: E402
+from timm_tpu.layers.latent_attention import CORE_OUT  # noqa: E402
+from timm_tpu.layers.mlp import FFN_UP  # noqa: E402
 
 
 @pytest.fixture(scope='module')
@@ -104,6 +107,51 @@ def test_model_matches_the_reference_logits_loss_head_losses_and_every_gradient_
     assert int(counters['attn.eva_blocks']) == 2 * 2 * (4 * 3 + 2 * (0 + 1 + 1 + 2)) and int(counters['lm.tokens']) == 2 * N
     assert float(counters['attn.eva_pairs']) == 2 * 2 * 4 * (4 * 32 * 33 // 2 + 32 * 8 * (0 + 1 + 2 + 3))
     assert not [k for k in counters if k.startswith('moe.')]
+
+
+@pytest.mark.parametrize('names', [(CORE_OUT,), (CORE_OUT, FFN_UP)], ids=['core_out', 'core_out+ffn_up'])
+def test_what_a_block_keeps_moves_no_loss_or_gradient_bit_and_the_two_up_products_are_not_formed_again(toy, names, monkeypatch):
+    """The toy's loss and every gradient leaf with each block rematerialised under `save_only_these_names(*names)`
+    against the model's own `_run_block`: EXACTLY equal, under the policy PR 49 left (the core's output alone) and the
+    one it set (the feed-forward block's two up-products beside it): the kept values are the bits the second forward
+    pass would form again, and on the CPU the compiler rounds them no differently. And the products of one block's
+    gradient: 95 `dot_general` equations with the core's output alone kept, two fewer with `FFN_UP` listed, which is
+    what the model's own block holds (`fc2`'s second forward is dead code before the jaxpr is written)."""
+    model, _ = toy
+    ids, target = _batch()
+    task = CausalLMTask(model, loss_chunk=32)
+    monkeypatch.setattr(model, 'grad_checkpointing', True)      # as the cell trains
+    graphdef, state, rest = nnx.split(model, nnx.Param, ...)
+    loss_fn = lambda st: task.loss_forward(nnx.merge(graphdef, st, rest, copy=True), {'input': ids, 'target': target})[0]  # noqa: E731
+    own_loss, own_grads = jax.jit(jax.value_and_grad(loss_fn))(state)
+    assert block_grad_dots(model, 0, N, names) == 95 - 2 * (FFN_UP in names) and block_grad_dots(model, 0, N) == 93
+    policy = jax.checkpoint_policies.save_only_these_names(*names)
+    monkeypatch.setattr(type(model), '_run_block',
+                        lambda self, blk, x, rope: nnx.remat(lambda b, x, rope: b(x, rope), policy=policy)(blk, x, rope))
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(state)
+    assert float(loss) == float(own_loss)
+    got, want = program.named_leaves(grads), program.named_leaves(own_grads)
+    assert set(got) == set(want) and all(bool((got[k] == want[k]).all()) for k in want)
+
+
+def test_the_kept_step_program_holds_nine_products_a_feed_forward_block_and_the_gauge_and_the_log_line_say_so():
+    """The step as the cell trains it (blocks rematerialised under `_run_block`'s policy), compiled ahead of time and
+    kept: under `evabyte.ffn` three products forward and six backward a layer, none under `rematted_computation` (the
+    block's second forward pass finds the two up-products kept; eleven a layer before PR 49). `tracing.scope_products`
+    is what the gauge `ffn.products` reads where a step program is kept, and `train.py`'s log line prints it."""
+    import train
+    from timm_tpu.optim import create_optimizer_v2
+    from timm_tpu.utils import tracing
+    ids = jnp.zeros((8, N), jnp.int32)
+    model = timm_tpu.create_model('evabyte_toy', seed=0)
+    model.set_grad_checkpointing(True)
+    task = CausalLMTask(model, optimizer=create_optimizer_v2(model, opt='adamw', lr=1e-3, weight_decay=0.1), clip_grad=1.0, loss_chunk=32)
+    before = len(tracing.snapshot()['gauges'].get('ffn.products', ()))
+    text = task.lower_train_step({'input': ids, 'target': ids}, 1e-3, 0).as_text()
+    series = tracing.snapshot()['gauges']['ffn.products']
+    assert len(series) == before + 1 and series[-1][1] == tracing.scope_products(text, 'evabyte.ffn') == 9 * len(model.blocks) == 18
+    assert tracing.scope_products(text, 'rematted_computation/evabyte.ffn') == 0 < tracing.scope_products(text, 'rematted_computation/evabyte.attn.proj')
+    assert tracing.scope_products(text, 'glm.dense_ffn') == 0 and ' ffn products 18' in train._host_line(tracing.now_ns(), {})[0]
 
 
 def test_no_position_sees_a_later_id_through_its_own_chunk_window_or_a_summary(toy):

@@ -426,5 +426,24 @@ def test_split_attn_groups():
     assert m(x).shape == (2, 8, 8, 16)
 
 
+@pytest.mark.parametrize('name,index,products', [
+    ('glm4_moe_lite_toy', 0, 42), ('glm4_moe_lite_toy', 1, 46), ('lfm2_moe_toy', 0, 27), ('solar_open2_toy', 0, 63),
+    ('solar_open2_toy', 1, 127)], ids=['glm-dense', 'glm-shared-expert', 'lfm2-dense', 'solar-gqa-shared', 'solar-kda-shared'])
+def test_the_name_on_swiglus_up_products_is_inert_under_a_block_policy_that_does_not_list_it(name, index, products):
+    """`SwiGLU` names its two up-products `FFN_UP` (PR 49) and only EvaByte's block policy lists the name. The blocks
+    of the three other families that hold a `SwiGLU` (a leading dense layer, a shared expert) run under
+    `save_only_these_names(CORE_OUT)`: the `dot_general` equations in one block's gradient, through the model's own
+    `_run_block`, are the numbers read on PR 49's parent, before the name existed. Listing the name would take two
+    products out of the second forward pass and hold the pair through the whole backward pass, which for a LEADING
+    layer is the step's peak (PERF.md section 7): an edit that widens a shared policy shows here, on the CPU."""
+    import timm_tpu
+    from remat_common import block_grad_dots
+    from timm_tpu.layers.latent_attention import CORE_OUT
+    from timm_tpu.layers.mlp import FFN_UP
+    model = timm_tpu.create_model(name, seed=0)
+    assert block_grad_dots(model, index, 32) == products == block_grad_dots(model, index, 32, (CORE_OUT,))
+    assert block_grad_dots(model, index, 32, (CORE_OUT, FFN_UP)) == products - 2
+
+
 # The hard-coded-fp32-softmax lint is now the analysis rule `fp32-softmax`
 # (timm_tpu/analysis/source_rules.py), enforced by tests/test_analysis.py.

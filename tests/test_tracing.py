@@ -372,3 +372,52 @@ def test_the_reader_of_a_scopes_gathers_counts_fusions_that_gather_and_those_who
     assert tracing.scope_gathers(PROGRAM, 'glm.moe.route') == (3, 2)
     assert tracing.scope_gathers(PROGRAM, 'glm.embed') == (1, 1)
     assert tracing.scope_gathers(PROGRAM, 'glm.mla.proj') == (0, 0) == tracing.scope_gathers('', 'glm.moe.route')
+
+
+PRODUCTS = """HloModule jit_step
+
+%fused_computation.5 (param_0.9: bf16[128,64], param_1.9: bf16[64,160]) -> bf16[128,160] {
+  %param_0.9 = bf16[128,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %param_1.9 = bf16[64,160]{1,0:T(8,128)(2,1)} parameter(1)
+  %convolution.7 = bf16[128,160]{1,0:T(8,128)(2,1)} convolution(%param_0.9, %param_1.9), dim_labels=bf_io->bf, metadata={op_name="jit(step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/evabyte.ffn/dot_general"}
+  ROOT %multiply.3 = bf16[128,160]{1,0:T(8,128)(2,1)} multiply(%convolution.7, %convolution.7), metadata={op_name="jit(step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/evabyte.ffn/mul"}
+}
+
+ENTRY %main (x: bf16[128,64], w: bf16[64,160], v: bf16[64,64]) -> bf16[128,160] {
+  %x = bf16[128,64]{1,0:T(8,128)(2,1)} parameter(0)
+  %w = bf16[64,160]{1,0:T(8,128)(2,1)} parameter(1)
+  %v = bf16[64,64]{1,0:T(8,128)(2,1)} parameter(2)
+  %convolution.8 = bf16[128,64]{1,0:T(8,128)(2,1)} convolution(%x, %v), dim_labels=bf_io->bf, metadata={op_name="jit(step)/jvp(evabyte.attn.proj)/dot_general"}
+  %dot.1 = bf16[128,160]{1,0:T(8,128)(2,1)} dot(%convolution.8, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/jvp(evabyte.ffn)/dot_general"}
+  %add.2 = bf16[128,160]{1,0:T(8,128)(2,1)} add(%dot.1, %dot.1), metadata={op_name="jit(step)/jvp(evabyte.ffn)/add"}
+  ROOT %convolution_multiply_fusion = bf16[128,160]{1,0:T(8,128)(2,1)} fusion(%x, %w), kind=kOutput, calls=%fused_computation.5, metadata={op_name="jit(step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/evabyte.ffn/mul"}
+}
+"""
+
+
+def test_the_reader_of_a_scopes_products_counts_convolutions_and_dots_inside_a_fusion_or_not_and_no_fusion_twice():
+    """`scope_products` on a few lines of a compiled program's text: under `evabyte.ffn` a `convolution` inside a
+    fused computation (a rematerialised one, by its `op_name`) and a `dot` that stands in no fusion: 2. Not counted:
+    the fusion that calls the first (its name and its `op_name` say `convolution` and `evabyte.ffn`; it is no
+    product instruction), the `add` and `multiply` under the scope, and the product under `evabyte.attn.proj`, which
+    is that scope's one. The gauge `ffn.products` is declared a gauge of the feed-forward layer."""
+    assert tracing.scope_products(PRODUCTS, 'evabyte.ffn') == 2
+    assert tracing.scope_products(PRODUCTS, 'rematted_computation/evabyte.ffn') == 1 == tracing.scope_products(PRODUCTS, 'evabyte.attn.proj')
+    assert tracing.scope_products(PRODUCTS, '') == 3 and tracing.scope_products(PRODUCTS, 'glm.dense_ffn') == 0 == tracing.scope_products('', 'evabyte.ffn')
+    assert tracing.scope_loops(PRODUCTS, '') == 0 == tracing.scope_products(PROGRAM, '')      # the other readers' text holds no product
+    assert tracing.SPANS['ffn.products'][0] == 'feed-forward' and tracing.SPANS['ffn.products'][1].startswith('gauge: ')
+
+
+@pytest.mark.parametrize('products', [None, 36], ids=['gauge-unset', 'gauge-set'])
+def test_the_log_line_names_the_feed_forward_products_only_where_a_kept_step_program_set_the_gauge(products, monkeypatch):
+    """`ffn.products` is read by `train.py`'s log line, after the host breakdown and before `kda scans` / `route
+    gathers`: `ffn products N`, the newest value; a run that kept no step program (or whose program holds no
+    product under `evabyte.ffn`) prints nothing of it."""
+    import train
+    snap = tracing.snapshot()
+    gauges = {k: v for k, v in snap['gauges'].items() if k != 'ffn.products'}
+    if products is not None:
+        gauges.update({'ffn.products': [(1, 44), (2, products)], 'kda.core_scans': [(3, 6)]})
+    monkeypatch.setattr(tracing, 'snapshot', lambda: dict(snap, gauges=gauges))
+    text = train._host_line(tracing.now_ns(), {})[0]
+    assert (' ffn products 36 kda scans 6' in text) if products else ('ffn products' not in text)

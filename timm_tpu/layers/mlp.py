@@ -12,6 +12,7 @@ from typing import Callable, Optional, Union
 
 import jax.numpy as jnp
 from flax import nnx
+from jax.ad_checkpoint import checkpoint_name
 
 from ..utils import tracing
 from .create_act import get_act_fn
@@ -21,6 +22,8 @@ from .norm import LayerNorm
 from .weight_init import trunc_normal_, zeros_
 
 __all__ = ['Mlp', 'GluMlp', 'SwiGLU', 'SwiGLUPacked', 'GatedMlp', 'ConvMlp', 'GlobalResponseNormMlp']
+
+FFN_UP = 'ffn_up'   # checkpoint_name of `SwiGLU`'s two up-products, for a block-level remat policy that lists it
 
 
 def _shard_hidden(x):
@@ -207,7 +210,9 @@ class SwiGLU(nnx.Module):
         self.drop2 = Dropout(drop_probs[1], rngs=rngs)
 
     def __call__(self, x):
-        x = _shard_hidden(self.act(self.fc1_g(x)) * self.fc1_x(x))
+        # the names are identities unless the caller's remat policy lists `FFN_UP` (`models/evabyte.py` does)
+        g, u = checkpoint_name(self.fc1_g(x), FFN_UP), checkpoint_name(self.fc1_x(x), FFN_UP)
+        x = _shard_hidden(self.act(g) * u)
         x = self.drop1(x)
         if self.norm is not None:
             x = self.norm(x)

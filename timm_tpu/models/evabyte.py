@@ -33,6 +33,7 @@ from flax import nnx
 
 from ..layers import ChunkedLinearAttention, SwiGLU, build_rotary_pos_embed_1d, chunk_window_pairs, trunc_normal_
 from ..layers.latent_attention import CORE_OUT
+from ..layers.mlp import FFN_UP
 from ..layers.moe import merge_counters
 from ..utils import tracing
 from ._builder import build_model_with_cfg
@@ -148,8 +149,11 @@ class EvaByte(nnx.Module):
     def _run_block(self, blk, x, rope):
         if not self.grad_checkpointing:
             return blk(x, rope)
-        # as `Glm4MoeLite._run_block`: a block is recomputed in the backward pass, but for the core's output
-        policy = jax.checkpoint_policies.save_only_these_names(CORE_OUT)
+        # as `Glm4MoeLite._run_block`: a block is recomputed in the backward pass, but for the core's output, and
+        # here for the feed-forward block's two up-products, so the second pass multiplies nothing in the SwiGLU
+        # (9 products a layer, not 11). The price is bfloat16 2 x S x intermediate a layer, held from the layer's
+        # forward pass to its backward one: every layer's pair but the running one's stands on top of the peak
+        policy = jax.checkpoint_policies.save_only_these_names(CORE_OUT, FFN_UP)
         return nnx.remat(lambda b, x, rope: b(x, rope), policy=policy)(blk, x, rope)
 
     def forward_features(self, ids, with_counters: bool = False):

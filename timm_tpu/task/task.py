@@ -516,6 +516,9 @@ class TrainingTask:
         scans = tracing.scope_loops(text, 'kda.core')
         if scans:       # a model with gated delta-rule layers: two a layer once the chunk-boundary states are kept
             tracing.gauge('kda.core_scans', scans)
+        products = tracing.scope_products(text, 'evabyte.ffn')
+        if products:    # a model of dense SwiGLU layers: nine a layer once the block keeps its two up-products
+            tracing.gauge('ffn.products', products)
         return compiled
 
     def _new_sentinel_state(self):

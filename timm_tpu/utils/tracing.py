@@ -129,6 +129,7 @@ SPANS = {
     'attn.eva_blocks': ('attention', 'step counter: the same for the cores under the chunk-window mask (queries on summaries and single keys)'),
     'attn.eva_pairs': ('attention', 'step counter: (query, key) pairs the chunk-window mask leaves, single keys and summaries, all heads held, layers and sequences (float32: from the shapes alone)'),
     'sconv.rows': ('short convolution', 'step counter: positions x gated short-convolution layers of the step (from the shapes): the rows its memory-bound middle moves'),
+    'ffn.products': ('feed-forward', 'gauge: matrix products (`convolution` / `dot` instructions, those inside fusions too) under `evabyte.ffn` in the step program\'s compiled text, by `scope_products`: 3 forward and 6 backward a layer once the block\'s rematerialisation keeps the two up-products (`layers/mlp.py` `FFN_UP`; 11 a layer when its second forward pass forms them again); set where the program is kept (`TrainingTask.lower_train_step`)'),
     'kda.core_scans': ('delta attention', 'gauge: `while` instructions under `kda.core` in the step program\'s compiled text, by `scope_loops`: one scan over chunks forward and one backward a gated delta-rule layer (the block\'s second forward pass finds the chunk-boundary states kept); set where the program is kept (`TrainingTask.lower_train_step`)'),
     'kda.rows': ('delta attention', 'step counter: positions x gated delta-rule layers of the step (from the shapes): the rows its elementwise middle moves and its recurrence visits'),
     'kda.chunks': ('delta attention', 'step counter: chunks x heads held x gated delta-rule layers of the step (from the shapes): the triangular systems solved and the scan\'s steps x heads'),
@@ -305,11 +306,25 @@ def scope_gathers(text: str, scope: str) -> tuple:
     return gathers, fast
 
 
+def _scope_ops(text: str, scope: str, ops: tuple) -> int:
+    """The instructions of a program's text, over all its computations (a fusion's among them), that are one of `ops`
+    and whose `op_name` lies under device scope `scope`."""
+    matches = map(_INSTRUCTION.match, (line for line in text.splitlines() if any(f' {op}(' in line for op in ops)))
+    return sum(m.group(3) in ops and scope in _op_name(m.group(4)) for m in matches if m)
+
+
 def scope_loops(text: str, scope: str) -> int:
     """The `while` instructions of a program's text, over all its computations, whose `op_name` lies under device
     scope `scope`: a `lax.scan` each, as long as the compiler leaves it a loop."""
-    matches = map(_INSTRUCTION.match, (line for line in text.splitlines() if ' while(' in line))
-    return sum(m.group(3) == 'while' and scope in _op_name(m.group(4)) for m in matches if m)
+    return _scope_ops(text, scope, ('while',))
+
+
+def scope_products(text: str, scope: str) -> int:
+    """The matrix products of a program's text, over all its computations, whose `op_name` lies under device scope
+    `scope`: `convolution` instructions (what the chip's compiler makes of a `dot_general`) and `dot`s, inside a
+    fusion or not. A fusion that holds one is not counted again. What a block's second forward pass multiplies
+    again shows here as products under `.../rematted_computation/<scope>/dot_general`."""
+    return _scope_ops(text, scope, ('convolution', 'dot'))
 
 
 def device_counter(name: str, value):

@@ -1143,6 +1143,8 @@ def _host_line(since_ns, counters_before, metrics=None, expert_layers=0):
         text += f" procs {procs[-1][1]} exits {did.get('loader.worker_exits', 0)}"
     if metrics and 'moe.fallback_layers' in metrics:
         text = f"fallback {int(metrics['moe.fallback_layers'])} of {expert_layers} layers " + text
+    if 'ffn.products' in snap['gauges']:        # as the two below: read from the kept step program's text
+        text += f" ffn products {snap['gauges']['ffn.products'][-1][1]}"
     if 'kda.core_scans' in snap['gauges']:      # as the route's gathers below: read from the kept step program's text
         text += f" kda scans {snap['gauges']['kda.core_scans'][-1][1]}"
     if 'moe.route_gathers' in snap['gauges']:   # set where the step program was compiled ahead of time and kept
